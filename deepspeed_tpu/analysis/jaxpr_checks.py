@@ -29,13 +29,9 @@ are cheap enough for CI.
 
 import numpy as np
 
-import deepspeed_tpu.utils.jax_compat  # noqa: F401 (installs jax.shard_map shim)
 import jax
 
-try:  # jax >= 0.4.30 moved the public IR types
-    from jax.extend.core import ClosedJaxpr, Jaxpr  # type: ignore
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import ClosedJaxpr, Jaxpr  # type: ignore
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = [
     "iter_eqns", "check_upcasts", "check_collectives", "check_callbacks",

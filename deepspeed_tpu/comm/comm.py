@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from deepspeed_tpu.utils import jax_compat  # noqa: F401  installs lax.axis_size on old jax
 
 from deepspeed_tpu.resilience import faults as _faults
 from deepspeed_tpu.utils.logging import logger

@@ -18,6 +18,12 @@ output matches the in-process fleet token for token (pinned by
 tests/test_kv_fabric.py and the ``bench_serving --fleet --two-process``
 leg).
 
+A host with ONE TPU cannot run this: both processes ask for
+``jax.devices()[0]`` of the same host, and a chip belongs to one process at a
+time — the child would fail or hang at start-up. It runs on the CPU backend
+(tests/test_kv_fabric.py) and would run with one chip per process; it is not
+on ``chip_smoke.py``'s path, and ROADMAP C5 decides its fate.
+
 Control protocol (JSON header + optional binary payload per message)::
 
     parent -> child                      child -> parent

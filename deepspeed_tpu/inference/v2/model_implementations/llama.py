@@ -65,11 +65,13 @@ def _quantize_kv_rows(x):
     return q.reshape(x.shape), scale.reshape(x.shape[:-1])
 
 
-def _scatter_kv(k_pool, v_pool, k, v, block_tables, seen, q_len, block_size):
+def _scatter_kv(k_pool, v_pool, k, v, block_tables, seen, q_len, block_size,
+                trash=None):
     """Write [S, Q, KV, Dh] new KVs into the [NB, KV, bs, Dh] pool via block
     tables.
 
-    Padded token slots are routed to the trash block (last block of the pool).
+    Padded token slots are routed to the ``trash`` block (default: the last
+    block of the pool).
     Analog of the reference's linear_blocked_kv_copy kernel. Quantized pools
     (``(int8, scale)`` pairs) quantize on-write: each token's row quantizes
     per (token, kv head) over Dh, and the fp32 scale scatters into the side
@@ -78,25 +80,27 @@ def _scatter_kv(k_pool, v_pool, k, v, block_tables, seen, q_len, block_size):
     k_pool, k_scale = _pool_parts(k_pool)
     v_pool, v_scale = _pool_parts(v_pool)
     S, Q = k.shape[:2]
-    nb = k_pool.shape[0]          # includes trash block
+    if trash is None:
+        trash = k_pool.shape[0] - 1
     pos = seen[:, None] + jnp.arange(Q)[None, :]              # [S, Q]
     valid = jnp.arange(Q)[None, :] < q_len[:, None]
     blk = jnp.take_along_axis(block_tables, pos // block_size, axis=1,
                               mode="clip")
-    bi = jnp.where(valid, blk, nb - 1).reshape(-1)            # [S*Q]
-    si = jnp.where(valid, pos % block_size, 0).reshape(-1)
+    # every leading dim is indexed — (block, head, slot) per [Dh] row, values
+    # [S*Q, KV, Dh] — so the scatter writes whole rows in the pool's own
+    # layout. Leaving the head dim a slice between two indexed dims made the
+    # chip's compiler re-lay the WHOLE pool out around the scatter.
+    bi = jnp.where(valid, blk, trash).reshape(-1, 1)          # [S*Q, 1]
+    si = jnp.where(valid, pos % block_size, 0).reshape(-1, 1)
+    hi = jnp.arange(k.shape[2])[None, :]                      # [1, KV]
     if k_scale is not None:
         k, ks = _quantize_kv_rows(k)          # int8 [S,Q,KV,Dh], f32 [S,Q,KV]
         v, vs = _quantize_kv_rows(v)
-        # scale pool advanced indices (dims 0 and 3) straddle the head slice
-        # and the unit dim, so values land as [S*Q, KV]
-        k_scale = k_scale.at[bi, :, 0, si].set(ks.reshape(S * Q, -1))
-        v_scale = v_scale.at[bi, :, 0, si].set(vs.reshape(S * Q, -1))
-    # advanced indices at dims (0, 2) straddle the head slice, so the token
-    # dim lands in front: values are [S*Q, KV, Dh]
-    k_pool = k_pool.at[bi, :, si].set(
+        k_scale = k_scale.at[bi, hi, 0, si].set(ks.reshape(S * Q, -1))
+        v_scale = v_scale.at[bi, hi, 0, si].set(vs.reshape(S * Q, -1))
+    k_pool = k_pool.at[bi, hi, si].set(
         k.reshape(S * Q, *k.shape[2:]).astype(k_pool.dtype))
-    v_pool = v_pool.at[bi, :, si].set(
+    v_pool = v_pool.at[bi, hi, si].set(
         v.reshape(S * Q, *v.shape[2:]).astype(v_pool.dtype))
     if k_scale is not None:
         return (k_pool, k_scale), (v_pool, v_scale)
@@ -183,8 +187,22 @@ def _ragged_trunk(cfg, params, k_pool, v_pool, tokens, q_len, seen,
     x = params["embed_tokens"].astype(cfg.dtype)[tokens]
     layers = params["layers"]["block"]
 
-    def layer_step(x, xs):
-        lp, kp, vp = xs
+    # The stacked pools [L, NB, ...] are ONE pool of L*NB pages to the layer
+    # loop (a free reshape): layer ``i`` owns pages [i*NB, (i+1)*NB), reached
+    # by offsetting the block tables. The pools ride the scan CARRY, the
+    # scatter updates them in place and the paged kernel reads pages through
+    # the tables — no layer's pool is ever sliced out or written back. As
+    # scan inputs and outputs the pools were two buffers each (the whole KV
+    # pool again as scratch) and every round moved them through HBM ~13x.
+    L = cfg.num_hidden_layers
+    nb = _pool_parts(k_pool)[0].shape[1]          # per layer, trash included
+    merge = lambda a: a.reshape((L * nb,) + a.shape[2:])
+    k_pool, v_pool = jax.tree.map(merge, (k_pool, v_pool))
+
+    def layer_step(carry, xs):
+        x, kp, vp = carry
+        lp, i = xs
+        layer_tables = block_tables + i * nb
         attn = lp["self_attn"]
         h = _rmsnorm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
 
@@ -199,8 +217,9 @@ def _ragged_trunk(cfg, params, k_pool, v_pool, tokens, q_len, seen,
         v = proj(attn["v_proj"]).reshape(S, Q, KV, Dh)
         q = rotary_embed(q, positions, cfg.rope_theta)
         k = rotary_embed(k, positions, cfg.rope_theta)
-        kp, vp = _scatter_kv(kp, vp, k, v, block_tables, seen, q_len, bs)
-        out = _paged_attention(q, kp, vp, block_tables, seen, bs, q_len=q_len,
+        kp, vp = _scatter_kv(kp, vp, k, v, layer_tables, seen, q_len, bs,
+                             trash=i * nb + nb - 1)
+        out = _paged_attention(q, kp, vp, layer_tables, seen, bs, q_len=q_len,
                                window=cfg.sliding_window,
                                prefer=module_preference(cfg, "attention"))
         o = out.reshape(S, Q, H * Dh) @ attn["o_proj"]["kernel"].astype(cfg.dtype)
@@ -212,9 +231,12 @@ def _ragged_trunk(cfg, params, k_pool, v_pool, tokens, q_len, seen,
         gate = jax.nn.silu(h @ mlp["gate_proj"]["kernel"].astype(cfg.dtype))
         up = h @ mlp["up_proj"]["kernel"].astype(cfg.dtype)
         x = x + (gate * up) @ mlp["down_proj"]["kernel"].astype(cfg.dtype)
-        return x, (kp, vp)
+        return (x, kp, vp), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(layer_step, x, (layers, k_pool, v_pool))
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        layer_step, (x, k_pool, v_pool), (layers, jnp.arange(L)))
+    split = lambda a: a.reshape((L, nb) + a.shape[1:])
+    k_pool, v_pool = jax.tree.map(split, (k_pool, v_pool))
 
     x = _rmsnorm(x, params["norm"]["scale"], cfg.rms_norm_eps)
     return x, k_pool, v_pool

@@ -70,11 +70,8 @@ class EvoformerAttnBuilder(OpBuilder):
     NAME = "evoformer_attn"
 
     def pallas_impl(self):
-        try:
-            from deepspeed_tpu.ops.pallas.flash_attention import flash_mha  # noqa: F401
-            return DS4Sci_EvoformerAttention
-        except Exception:
-            return None
+        from deepspeed_tpu.ops.pallas.flash_attention import flash_mha  # noqa: F401
+        return DS4Sci_EvoformerAttention
 
     def reference_impl(self):
         return DS4Sci_EvoformerAttention

@@ -176,10 +176,5 @@ class FlashAttnBuilder(OpBuilder):
         return mha_reference
 
     def pallas_impl(self):
-        try:
-            from deepspeed_tpu.ops.pallas.flash_attention import flash_mha
-            return flash_mha
-        except Exception:
-            # jax/libtpu version skew can surface as RuntimeError/AttributeError
-            # from the pallas import, not just ImportError — fall back either way
-            return None
+        from deepspeed_tpu.ops.pallas.flash_attention import flash_mha
+        return flash_mha

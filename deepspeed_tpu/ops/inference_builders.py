@@ -20,11 +20,8 @@ class RaggedOpsBuilder(OpBuilder):
         return _paged_attention_dense
 
     def pallas_impl(self):
-        try:
-            from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
-            return paged_mha
-        except Exception:
-            return None
+        from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
+        return paged_mha
 
 
 @register_op_builder
@@ -52,12 +49,9 @@ class InferenceCoreOpsBuilder(OpBuilder):
         return QuantizedParameter.dequantized
 
     def pallas_impl(self):
-        try:
-            from deepspeed_tpu.ops.pallas.quantized_matmul import (
-                quantized_matmul)
-            return quantized_matmul
-        except Exception:
-            return None
+        from deepspeed_tpu.ops.pallas.quantized_matmul import (
+            quantized_matmul)
+        return quantized_matmul
 
 
 @register_op_builder
@@ -72,11 +66,8 @@ class InferenceCutlassBuilder(OpBuilder):
         return _moe_ffn
 
     def pallas_impl(self):
-        try:
-            from deepspeed_tpu.ops.pallas.grouped_gemm import moe_ffn_gmm
-            return moe_ffn_gmm
-        except Exception:
-            return None
+        from deepspeed_tpu.ops.pallas.grouped_gemm import moe_ffn_gmm
+        return moe_ffn_gmm
 
 
 @register_op_builder
@@ -90,11 +81,8 @@ class TransformerInferenceBuilder(OpBuilder):
         return generate
 
     def pallas_impl(self):
-        try:
-            from deepspeed_tpu.ops.pallas.flash_attention import flash_mha
-            return flash_mha
-        except Exception:
-            return None
+        from deepspeed_tpu.ops.pallas.flash_attention import flash_mha
+        return flash_mha
 
 
 @register_op_builder

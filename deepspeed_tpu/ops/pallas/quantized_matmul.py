@@ -24,15 +24,38 @@ BM, BN, BK = 256, 256, 512  # ladder defaults; the tuning table overrides
 
 
 def _blocks_fit(bm, bn, bk, m, k, n, group_size):
-    """Whether a (bm, bn, bk) choice tiles these exact dims cleanly."""
+    """Whether a (bm, bn, bk) choice tiles these exact dims cleanly. The
+    scale block is [bk, bn/G]: Mosaic takes a lane dim only when it is a
+    multiple of 128 or the whole array's (the chip's compiler refuses the
+    rest — interpret mode does not notice)."""
     return (m % 8 == 0 and (m <= bm or m % bm == 0)
             and k % bk == 0 and n % bn == 0
-            and bn % group_size == 0 and group_size <= bn)
+            and bn % group_size == 0 and group_size <= bn
+            and (bn == n or (bn // group_size) % 128 == 0))
+
+
+def _ladder_blocks(n, group_size):
+    """The ladder's (bm, bn, bk) for an N-wide weight: the module defaults
+    where their scale block is legal, else the whole width in one block with
+    bk cut so the dequantized f32 block stays within VMEM (compiled for the
+    v5e at 512 x 4096 x 4096, tests/test_chip_compile.py). Widths past
+    ``MAX_WHOLE_N`` have no legal ladder entry and take the XLA path."""
+    bn, bk = BN, BK
+    if bn != n and (bn // group_size) % 128 != 0:
+        bn = n
+        while bk > 128 and bk * bn > 512 * 1024:
+            bk //= 2
+    return BM, bn, bk
+
+
+MAX_WHOLE_N = 4096
 
 
 def is_supported(m, k, n, group_size, num_bits):
     """Shapes the kernel tiles cleanly; callers fall back to XLA dequant."""
-    return num_bits == 8 and _blocks_fit(BM, BN, BK, m, k, n, group_size)
+    bm, bn, bk = _ladder_blocks(n, group_size)
+    return (num_bits == 8 and bn <= MAX_WHOLE_N
+            and _blocks_fit(bm, bn, bk, m, k, n, group_size))
 
 
 def _resolve_blocks(m, k, n, group_size, dtype):
@@ -45,7 +68,8 @@ def _resolve_blocks(m, k, n, group_size, dtype):
                            dims["n"], dims["g"])
 
     def ladder():
-        return {"block_m": BM, "block_n": BN, "block_k": BK}
+        bm, bn, bk = _ladder_blocks(n, group_size)
+        return {"block_m": bm, "block_n": bn, "block_k": bk}
 
     return registry.resolve_block_config(
         "quantized_matmul", {"m": m, "k": k, "n": n, "g": group_size}, dtype,
@@ -127,7 +151,7 @@ def quantized_matmul(x, q, scale, group_size, out_dtype=None,
 
     def accept(shard_shapes):
         (m, k), (_, n), _ = shard_shapes
-        return _blocks_fit(blocks[0], blocks[1], blocks[2], m, k, n,
+        return _blocks_fit(blocks[0], min(blocks[1], n), blocks[2], m, k, n,
                            group_size)
 
     return sharded_kernel_call(
@@ -144,6 +168,7 @@ def _quantized_matmul_local(x, q, scale, group_size, out_dtype=None,
     out_dtype = out_dtype or x.dtype
     BM_, BN_, BK_ = blocks if blocks is not None else (BM, BN, BK)
     bm = min(BM_, M)
+    BN_ = min(BN_, N)   # a whole-width block, seen from a tp shard of N
     nm, nn, nk = M // bm, N // BN_, K // BK_
 
     out = pl.pallas_call(

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepspeed_tpu.utils import jax_compat  # noqa: F401  installs jax.shard_map on old jax
 
 NEG_INF = -1e30
 

@@ -128,11 +128,8 @@ class SparseAttnBuilder(OpBuilder):
     NAME = "sparse_attn"
 
     def pallas_impl(self):
-        try:
-            from deepspeed_tpu.ops.pallas.block_sparse_attention import sparse_mha
-            return sparse_mha
-        except Exception:
-            return None
+        from deepspeed_tpu.ops.pallas.block_sparse_attention import sparse_mha
+        return sparse_mha
 
     def reference_impl(self):
         return sparse_attention

@@ -25,7 +25,6 @@ exchange records trace-time comm telemetry with both the logical fp32 bytes
 import jax.numpy as jnp
 from jax import lax
 
-from deepspeed_tpu.utils import jax_compat  # noqa: F401  installs lax.axis_size on old jax
 
 from deepspeed_tpu.ops.pallas.quant_collective import (
     block_dequantize,

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from deepspeed_tpu.utils import jax_compat  # noqa: F401  installs lax.axis_size on old jax
 
 
 def sign_compress(x, error, mask=None):

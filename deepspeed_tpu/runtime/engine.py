@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from deepspeed_tpu.utils import jax_compat  # noqa: F401  installs jax.shard_map on old jax
 from deepspeed_tpu.ops.adam import build_optimizer, set_lr
 from deepspeed_tpu.resilience import CorruptCheckpointError, faults as _faults
 from deepspeed_tpu.parallel import groups

@@ -79,12 +79,38 @@ class ZeroPartitioner:
                                 if topology.get_dim(a) > 1)
         self.zero_world = int(np.prod([topology.get_dim(a) for a in self.zero_axes])) if self.zero_axes else 1
         self.param_specs = param_specs  # pytree of P or None (model/tp specs)
+        self._unfit_logged = set()
         self.threshold = zero_config.stage3_param_persistence_threshold
 
     def _base_specs(self, params):
         if self.param_specs is None:
             return jax.tree.map(lambda _: None, params)
-        return self.param_specs
+        return jax.tree.map(self._fit_spec, params, self.param_specs,
+                            is_leaf=lambda x: x is None)
+
+    def _fit_spec(self, leaf, spec):
+        """A model's spec names mesh axes without knowing their extent: keep
+        an entry only where the dimension divides evenly (GPT-2's published
+        vocabulary, 50257, is odd — its vocab-split table stays replicated
+        over tp instead of failing the whole placement)."""
+        if spec is None or not hasattr(leaf, "shape"):
+            return spec
+        fitted = []
+        for d, entry in enumerate(tuple(spec)):
+            axes = entry if isinstance(entry, tuple) else (entry,)
+            n = int(np.prod([self.topology.get_dim(a)
+                             for a in axes if a is not None]))
+            if entry is not None and leaf.shape[d] % n != 0:
+                key = (tuple(leaf.shape), d, axes)
+                if key not in self._unfit_logged:
+                    self._unfit_logged.add(key)
+                    logger.warning(
+                        f"param spec {spec} on shape {tuple(leaf.shape)}: "
+                        f"dim {d} does not divide by {axes} (x{n}); "
+                        f"replicated")
+                entry = None
+            fitted.append(entry)
+        return P(*fitted)
 
     def _zero_tree(self, params, threshold, axes=None):
         base = self._base_specs(params)

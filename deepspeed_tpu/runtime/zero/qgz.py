@@ -33,7 +33,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.utils import jax_compat  # noqa: F401  installs jax.shard_map on old jax
 from deepspeed_tpu.runtime.comm.coalesced_collectives import exchange_reduce
 
 

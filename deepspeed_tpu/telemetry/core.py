@@ -78,25 +78,36 @@ _FLIGHT_SPAN_PREFIXES = ("Recovery/", "recovery/")
 _now = time.perf_counter
 _now_wall = time.time
 
-#: goodput-ledger taxonomy (docs/OBSERVABILITY.md). Every wall-second of an
+#: goodput-ledger classification (docs/OBSERVABILITY.md). Every wall-second of an
 #: enabled run lands in exactly one bucket; ``idle`` is the unattributed
 #: remainder (wall − sum of the others, floored at 0).
 LEDGER_CATEGORIES = ("compute", "comm", "compile", "ckpt", "stall", "idle")
 
 _COMPUTE_SPANS = frozenset({"fwd", "bwd", "step", "eval"})
 
-#: per-chip peak bf16 FLOP/s for the MFU denominator when the caller does not
-#: pass one to ``set_model_flops`` (same public specs bench.py uses; "cpu" is
-#: a nominal figure so CPU-mesh tests produce nonzero, comparable gauges)
-_PEAK_BF16_FLOPS = {
+#: THE per-chip peak bf16 FLOP/s table, keyed by ``device_kind`` (public
+#: specs: Google Cloud TPU documentation, per-generation system pages).
+#: bench.py and the scripts read it through ``peak_bf16_flops``; there is
+#: no CPU row and no default — MFU is a device metric.
+PEAK_BF16_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
     "TPU v5e": 197e12,
     "TPU v5": 459e12,
     "TPU v5p": 459e12,
     "TPU v6 lite": 918e12,
-    "cpu": 1e12,
 }
+
+
+def peak_bf16_flops(device_kind):
+    """Peak bf16 FLOP/s of one chip of ``device_kind``; an unknown device
+    raises (a guessed denominator would make the MFU meaningless)."""
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak FLOP/s known for device_kind "
+                       f"{device_kind!r}; add it to PEAK_BF16_FLOPS with "
+                       f"its source") from None
 
 
 def _ledger_category(span_name):
@@ -153,17 +164,14 @@ def _hist_quantile(h, q):
 
 
 def _default_peak_flops():
-    """Peak FLOP/s of one local device from its device_kind (0.0 when no
-    backend is reachable — MFU then reports 0 rather than raising)."""
-    try:
-        import jax
-        kind = jax.local_devices()[0].device_kind
-    except Exception:
+    """Peak FLOP/s of one local device for the ledger's MFU denominator when
+    the caller passed none: the table's row on a TPU (unknown kinds raise),
+    0.0 elsewhere — a run without an accelerator has no MFU to report."""
+    import jax
+    dev = jax.local_devices()[0]
+    if dev.platform != "tpu":
         return 0.0
-    for k, v in _PEAK_BF16_FLOPS.items():
-        if kind.lower().startswith(k.lower()):
-            return v
-    return _PEAK_BF16_FLOPS["TPU v5e"]
+    return peak_bf16_flops(dev.device_kind)
 
 
 # --- atexit export hook: registered AT MOST ONCE per process ---------------

@@ -43,6 +43,7 @@ import sys
 import threading
 import time
 import traceback
+import weakref
 
 FORMAT_VERSION = 1
 
@@ -196,7 +197,11 @@ def register_collector(name, fn):
     """Register a zero-arg callable whose return value is snapshotted into
     ``state.json["collectors"][name]`` at bundle-flush time (KV page
     census, fleet/router reports, config digests). Re-registering a name
-    overwrites — the newest owner wins."""
+    overwrites — the newest owner wins. A bound method is held weakly: the
+    registry must not keep an engine (and its device buffers — a KV pool, a
+    training state) alive after its owner dropped it."""
+    if hasattr(fn, "__self__"):
+        fn = weakref.WeakMethod(fn)
     with _STATE_LOCK:
         _collectors[name] = fn
 
@@ -326,7 +331,12 @@ def _flush_bundle(reason, detail, exit_code, dir, force, extra):
             collectors = {}
         else:
             existing = None
-            collectors = dict(_collectors)
+            collectors = {}
+            for name, fn in _collectors.items():
+                if isinstance(fn, weakref.WeakMethod):
+                    fn = fn()       # None once the owner was collected
+                if fn is not None:
+                    collectors[name] = fn
     if existing is not None:
         record("postmortem", "postmortem/skipped",
                {"reason": reason, "existing": existing})

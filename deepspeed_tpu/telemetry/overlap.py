@@ -5,7 +5,7 @@ compute via prefetch/overlap scheduling — but the ledger is host-timed, so
 everything inside one compiled ``step()`` books as "compute" and traced
 collectives carry zero device duration. This module is the missing fitness
 function: it reconstructs **per-device op timelines** and classifies every
-device interval into the four-way taxonomy
+device interval into one of four classes
 
 - **compute** — an XLA op interval that is not a collective;
 - **overlapped comm** — a collective interval covered by concurrent compute

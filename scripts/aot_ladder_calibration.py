@@ -28,13 +28,14 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("DS_TPU_ASSUME_TPU", "1")
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(os.path.dirname(os.path.dirname(
-                          os.path.abspath(__file__))), ".jax_cache"))
 
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+
+from deepspeed_tpu.utils import compile_cache  # noqa: E402
+
+compile_cache.enable()
 
 import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

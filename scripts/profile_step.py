@@ -2,8 +2,7 @@
 
 Writes a perfetto/tensorboard trace to ``/tmp/ds_tpu_trace`` and prints the
 top compiled-program cost split (from XLA's own cost analysis) so the next
-optimization lever is visible without a trace viewer. Takes the shared chip
-lease (``utils/chip_lease``) like bench.py — one TPU job at a time.
+optimization lever is visible without a trace viewer.
 
 ``DS_TPU_TELEMETRY=1`` enables the unified telemetry pipeline and emits one
 JSON payload line to stdout (bench payload convention) with the summary —
@@ -50,11 +49,6 @@ def main():
     ap.add_argument("--steps", type=int, default=5)
     ap.add_argument("--out", default="/tmp/ds_tpu_trace")
     args = ap.parse_args()
-
-    # one TPU job at a time: same per-host flock bench.py serializes on
-    # (no-op None on CPU-pinned runs; auto-released at process exit)
-    from deepspeed_tpu.utils import chip_lease
-    chip_lease.process_lease(name="profile_step")
 
     import jax
     import numpy as np

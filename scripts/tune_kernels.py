@@ -23,8 +23,6 @@ import sys
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(REPO_ROOT, ".jax_cache"))
 os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")  # chip-free host: libtpu
 # must not probe the GCP metadata server (30 HTTP retries per var)
 
@@ -50,9 +48,9 @@ def main(argv=None):
         # happen before the backend initializes (same as aot_tpu_check).
         import jax
         jax.config.update("jax_platforms", "cpu")
-        jax.config.update("jax_compilation_cache_dir",
-                          os.environ["JAX_COMPILATION_CACHE_DIR"])
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    from deepspeed_tpu.utils import compile_cache
+    compile_cache.enable()
 
     from deepspeed_tpu.autotuning import kernel_table, kernel_tuner
 

@@ -16,10 +16,6 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# tests target the modern jax.shard_map API; on older jax the compat module
-# installs a translating shim (check_vma -> check_rep, axis_names -> auto)
-from deepspeed_tpu.utils import jax_compat  # noqa: E402,F401
-
 import pytest  # noqa: E402
 
 _SLOW_LIST = os.path.join(os.path.dirname(__file__), "slow_tests.txt")
@@ -42,16 +38,6 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.slow)
 
 
-def pytest_runtest_setup(item):
-    """``onchip``-marked tests queue on the shared chip lease before touching
-    the accelerator, so a concurrent bench and pytest serialize instead of
-    wedging the TPU. Under the CPU pin above this is a no-op (process_lease
-    returns None); the lease is process-wide and released at exit."""
-    if item.get_closest_marker("onchip") is not None:
-        from deepspeed_tpu.utils import chip_lease
-        chip_lease.process_lease(name="pytest")
-
-
 @pytest.fixture(autouse=True)
 def _reset_groups():
     """Each test gets a fresh global topology registry."""
@@ -67,3 +53,12 @@ def eight_devices():
     if len(devs) < 8:
         pytest.skip("needs 8 virtual devices")
     return devs
+
+
+@pytest.fixture
+def pallas_interpret(monkeypatch):
+    """Pallas kernels in interpret mode — the only way they run on this CPU
+    backend. The program takes the mode from ``DS_TPU_PALLAS_INTERPRET`` and
+    from nothing else (never from the platform), so tests that drive a kernel
+    path which has no XLA twin ask for it here."""
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")

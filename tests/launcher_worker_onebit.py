@@ -28,7 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
-from deepspeed_tpu.utils.jax_compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 from deepspeed_tpu import dist  # noqa: E402
 from deepspeed_tpu.runtime.comm.compressed import (  # noqa: E402

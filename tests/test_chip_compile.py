@@ -1,0 +1,192 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e at the
+widths ``chip_smoke.py`` runs — a couple of seconds each, no chip needed.
+
+Interpret-mode tests cannot see what the chip's compiler refuses (a slice off
+the tiling, too much fast memory, a kernel that cannot be partitioned); these
+compiles can, and they guard every later PR at no chip time. A compile that
+passes is a compile, not a run: ``python chip_smoke.py`` is the run.
+
+This is the ONLY test file that loads the TPU's library: one process at a
+time may hold it, so the topology is described inside a module-scoped fixture
+(never while a module is imported) and every compile happens in this process.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+# the serve phase's geometry: Mistral-7B heads, pages of chip_smoke.REAL's size
+H, KV, DH, PAGE, MAX_BLOCKS = 32, 8, 128, 64, 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_tpu(monkeypatch):
+    """Steer kernel dispatch as on the chip (kernels on, v5e tuning table)
+    and keep the persistent compile cache out of it: an entry written by a
+    compile-only client cannot be read back and would only warn."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setenv("DS_TPU_ASSUME_TPU", "1")
+    monkeypatch.setenv("DS_TPU_KERNEL_TABLE_DEVICE", "tpu_v5e")
+    monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("DS_TPU_DISABLE_PALLAS", raising=False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        "no Mosaic kernel in the compiled program"
+    return compiled
+
+
+def _flash_args(one_chip, b, t, h, kv, dh):
+    sds = lambda n: jax.ShapeDtypeStruct((b, t, n, dh), jnp.bfloat16,
+                                         sharding=one_chip)
+    return sds(h), sds(kv), sds(kv)
+
+
+def test_flash_forward_gpt2_step_shape(for_tpu, one_chip):
+    from deepspeed_tpu.ops.flash_attention import mha
+    _compile(lambda q, k, v: mha(q, k, v, causal=True),
+             _flash_args(one_chip, 32, 1024, 12, 12, 64))
+
+
+def test_flash_forward_backward_gpt2_step_shape(for_tpu, one_chip):
+    from deepspeed_tpu.ops.flash_attention import mha
+
+    def loss(q, k, v):
+        return jnp.sum(mha(q, k, v, causal=True).astype(jnp.float32) ** 2)
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)),
+             _flash_args(one_chip, 32, 1024, 12, 12, 64))
+
+
+def test_flash_windowed_gqa_mistral_shape(for_tpu, one_chip):
+    from deepspeed_tpu.ops.flash_attention import mha
+    _compile(lambda q, k, v: mha(q, k, v, causal=True, window=4096),
+             _flash_args(one_chip, 2, 4096, H, KV, DH))
+
+
+def _paged_args(one_chip, seqs, q_tokens, int8):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    nb = 2048 + 1
+    pool = sds((nb, KV, PAGE, DH), jnp.int8 if int8 else jnp.bfloat16)
+    args = [sds((seqs, q_tokens, H, DH), jnp.bfloat16), pool, pool,
+            sds((seqs, MAX_BLOCKS), jnp.int32), sds((seqs,), jnp.int32),
+            sds((seqs,), jnp.int32)]
+    if int8:
+        scale = sds((nb, KV, 1, PAGE), jnp.float32)
+        args += [scale, scale]
+    return args
+
+
+@pytest.mark.parametrize("seqs,q_tokens,int8", [
+    (8, 8, False),      # decode round, bf16 pages
+    (8, 8, True),       # decode round, int8 pages + fp32 scales
+    (8, 512, False),    # a multi-token SplitFuse chunk
+], ids=["decode_fp", "decode_int8", "splitfuse_chunk"])
+def test_paged_attention_mistral_geometry(for_tpu, one_chip, seqs, q_tokens,
+                                          int8):
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
+
+    def fn(q, kp, vp, bt, seen, q_len, ks=None, vs=None):
+        return paged_mha(q, kp, vp, bt, seen, q_len, k_scale=ks, v_scale=vs,
+                         window=4096)
+
+    _compile(fn, _paged_args(one_chip, seqs, q_tokens, int8))
+
+
+def test_quantized_matmul_4096_wide(for_tpu, one_chip):
+    from deepspeed_tpu.ops.pallas.quantized_matmul import (is_supported,
+                                                           quantized_matmul)
+    m, k, n, g = 512, 4096, 4096, 128
+    assert is_supported(m, k, n, g, 8)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    _compile(lambda x, q, s: quantized_matmul(x, q, s, g),
+             (sds((m, k), jnp.bfloat16), sds((k, n), jnp.int8),
+              sds((k, n // g), jnp.float32)))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_block_quantize_4096_wide(for_tpu, one_chip, bits):
+    from deepspeed_tpu.ops.pallas.quant_collective import block_quantize
+    x = jax.ShapeDtypeStruct((64, 4096), jnp.float32, sharding=one_chip)
+    _compile(lambda v: block_quantize(v, num_bits=bits, group_size=2048),
+             (x,))
+
+
+def test_block_dequantize_reduce_4096_wide(for_tpu, one_chip):
+    from deepspeed_tpu.ops.pallas.quant_collective import (
+        block_dequantize_reduce)
+    peers, groups, g = 4, 128, 2048          # 128 groups = 64 rows x 4096
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    _compile(lambda q, s: block_dequantize_reduce(q, s, num_bits=8,
+                                                  group_size=g),
+             (sds((peers, groups * g), jnp.int8),
+              sds((peers, groups), jnp.float32)))
+
+
+@pytest.mark.filterwarnings(
+    "ignore:Error reading persistent compilation cache entry")
+def test_same_program_same_topology_same_cache_key(topo, tmp_path,
+                                                   monkeypatch):
+    """What a warm compile cache rests on: the same program lowered afresh
+    for the same described topology lands on the SAME cache entry, and a
+    different device assignment on another. Read off the cache directory —
+    the public behaviour — not off jax's private key function."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    x = jax.ShapeDtypeStruct((256, 256), jnp.float32)
+
+    def chip_smoke_cache_probe(v):
+        return jnp.sin(v) @ jnp.cos(v).T
+
+    def entries(mesh_shape):
+        mesh = Mesh(np.array(topo.devices).reshape(*mesh_shape), ("dp", "tp"))
+        jax.clear_caches()               # a fresh lowering, a fresh key
+        jax.jit(chip_smoke_cache_probe,
+                in_shardings=NamedSharding(mesh, P("dp", "tp"))
+                ).lower(x).compile()
+        return {p.name for p in tmp_path.iterdir()
+                if p.name.endswith("-cache")}
+
+    try:
+        first = entries((2, 2))
+        assert len(first) == 1, first
+        assert entries((2, 2)) == first          # same key: no second entry
+        assert len(entries((4, 1))) == 2         # another assignment: new key
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()

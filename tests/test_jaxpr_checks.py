@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 pytest.importorskip("jax")
-from deepspeed_tpu.utils import jax_compat  # noqa: F401 (jax.shard_map shim)
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P

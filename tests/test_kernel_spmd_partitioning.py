@@ -100,13 +100,12 @@ def test_flash_no_double_wrap_inside_shard_map(eight_devices):
     """Inside an explicit shard_map (Ulysses pattern) every mesh axis is
     already manual — the dispatcher must detect that and not nest."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_mha
-    from deepspeed_tpu.utils import jax_compat
     from jax.sharding import PartitionSpec as P
     q, k, v = _flash_inputs()
     ref = flash_mha(q, k, v, causal=True, interpret=True)
     mesh = _mesh(("dp", "tp"), (2, 2))
     with use_kernel_mesh(mesh):
-        out = jax_compat.shard_map(
+        out = jax.shard_map(
             lambda q_, k_, v_: flash_mha(q_, k_, v_, causal=True,
                                          interpret=True),
             mesh=mesh, in_specs=(P("dp"),) * 3, out_specs=P("dp"),

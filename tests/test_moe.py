@@ -282,7 +282,7 @@ class GmmExpertMLP(nn.Module):
 
 
 @pytest.mark.parametrize("k", [1, 2])
-def test_gmm_backend_matches_indices(k):
+def test_gmm_backend_matches_indices(pallas_interpret, k):
     mk = lambda mode: MOELayer(lambda: GmmExpertMLP(), num_experts=4, k=k,
                                capacity_factor=100.0, dispatch_mode=mode)
     gmm, routed = mk("gmm"), mk("indices")
@@ -297,7 +297,7 @@ def test_gmm_backend_matches_indices(k):
     np.testing.assert_array_equal(np.asarray(cnt_g), np.asarray(cnt_r))
 
 
-def test_gmm_backend_gradients_match():
+def test_gmm_backend_gradients_match(pallas_interpret):
     mk = lambda mode: MOELayer(lambda: GmmExpertMLP(), num_experts=4, k=2,
                                capacity_factor=100.0, dispatch_mode=mode)
     gmm, routed = mk("gmm"), mk("indices")
@@ -318,7 +318,7 @@ def test_gmm_backend_gradients_match():
                                    atol=5e-4, rtol=5e-4)
 
 
-def test_gmm_backend_param_tree_matches_vmap():
+def test_gmm_backend_param_tree_matches_vmap(pallas_interpret):
     """gmm creates kernels at vmap-identical paths (checkpoint/HF compat)."""
     mk = lambda mode: MOELayer(lambda: GmmExpertMLP(), num_experts=4, k=1,
                                dispatch_mode=mode)
@@ -340,7 +340,7 @@ def test_gmm_backend_rejects_incompatible_expert():
         layer.init(jax.random.PRNGKey(0), x)
 
 
-def test_mixtral_gmm_backend_forward_parity():
+def test_mixtral_gmm_backend_forward_parity(pallas_interpret):
     """Mixtral with moe_backend='gmm' matches the default backend on the
     same params (128-aligned tiny config)."""
     from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
@@ -381,7 +381,7 @@ def test_gmm_backend_rejects_tp_mesh():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("k", [1, 2])
-def test_dropless_gmm_matches_dense_all_experts(k):
+def test_dropless_gmm_matches_dense_all_experts(pallas_interpret, k):
     """drop_tokens=False consults no capacity at all (capacity_factor=inf
     semantics): the grouped-GEMM path must match the dense all-experts
     einsum formulation on the same params, with every routed choice kept."""
@@ -400,7 +400,7 @@ def test_dropless_gmm_matches_dense_all_experts(k):
     assert int(np.asarray(cnt_g).sum()) == 2 * 16 * k
 
 
-def test_dropless_skewed_batch_drops_nothing():
+def test_dropless_skewed_batch_drops_nothing(pallas_interpret):
     """Adversarial skew (every token's top choice is expert 0): the drop
     path sheds to capacity, the dropless path keeps all — and still matches
     the dense reference."""
@@ -468,7 +468,7 @@ def test_dropless_training_trajectory_matches_drop_path():
     np.testing.assert_allclose(run(True), run(False), atol=1e-5, rtol=0)
 
 
-def test_gmm_ep_dropless_matches_single_host(eight_devices):
+def test_gmm_ep_dropless_matches_single_host(pallas_interpret, eight_devices):
     """The expert-parallel dispatch/combine a2a round-trip (ep=2) must
     reproduce the single-host grouped-GEMM result on the same params."""
     from deepspeed_tpu.parallel import groups
@@ -490,7 +490,7 @@ def test_gmm_ep_dropless_matches_single_host(eight_devices):
     np.testing.assert_array_equal(np.asarray(cnt_ep), np.asarray(cnt_ref))
 
 
-def test_gmm_ep_gradients_flow(eight_devices):
+def test_gmm_ep_gradients_flow(pallas_interpret, eight_devices):
     """bits=None keeps the ep round-trip differentiable end to end: grads
     under the ep mesh match the single-host grads."""
     from deepspeed_tpu.parallel import groups
@@ -518,7 +518,7 @@ def test_gmm_ep_gradients_flow(eight_devices):
                                    atol=1e-5, rtol=1e-5)
 
 
-def test_gmm_ep_quantized_wire_records_telemetry(eight_devices):
+def test_gmm_ep_quantized_wire_records_telemetry(pallas_interpret, eight_devices):
     """a2a_wire_bits=8 ships the int8+scales wire: output stays close to
     the fp result and the dispatch/combine wire bytes land in telemetry at
     ~0.25x the logical payload."""

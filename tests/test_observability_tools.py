@@ -128,7 +128,6 @@ def _train_run(tmp_path, eight_devices):
     from jax.sharding import Mesh, PartitionSpec as P
     import deepspeed_tpu
     from deepspeed_tpu import comm as dist
-    from deepspeed_tpu.utils import jax_compat
     from tests.simple_model import SimpleModel, random_batches
 
     jl = tmp_path / "host0.jsonl"
@@ -149,7 +148,7 @@ def _train_run(tmp_path, eight_devices):
     mesh = Mesh(np.array(eight_devices), ("dp",))
 
     def _collective():
-        ar = jax.jit(jax_compat.shard_map(
+        ar = jax.jit(jax.shard_map(
             lambda x: dist.all_reduce(x, axis_name="dp"),
             mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), check_vma=False))
         jax.block_until_ready(ar(jnp.ones((8, 4), jnp.float32)))

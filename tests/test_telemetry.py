@@ -424,7 +424,6 @@ def test_train_loop_acceptance(eight_devices, tmp_path):
     import deepspeed_tpu
     from deepspeed_tpu import comm as dist
     from deepspeed_tpu.parallel.topology import use_kernel_mesh
-    from deepspeed_tpu.utils import jax_compat
     from tests.simple_model import SimpleModel, random_batches
 
     jl = tmp_path / "metrics.jsonl"
@@ -450,7 +449,7 @@ def test_train_loop_acceptance(eight_devices, tmp_path):
     # an explicit collective through the comm shim inside jit/shard_map —
     # traced at trace time with bytes from the tracer aval
     mesh = Mesh(np.array(eight_devices), ("dp",))
-    f = jax.jit(jax_compat.shard_map(
+    f = jax.jit(jax.shard_map(
         lambda x: dist.all_reduce(x, axis_name="dp"),
         mesh=mesh, in_specs=P("dp"), out_specs=P("dp"), check_vma=False))
     jax.block_until_ready(f(jnp.ones((8, 4), jnp.float32)))
