@@ -61,11 +61,11 @@ def run_bench():
 
     # DS_TPU_TELEMETRY=1 folds the unified-telemetry summary (span stats,
     # comm bytes/bandwidth, kernel-dispatch outcomes) into payload["extra"].
-    # Off by default: sample_sync would serialize the async dispatch the
-    # bench is measuring. docs/OBSERVABILITY.md has the schema.
+    # Off by default; no span waits for the device either way.
+    # docs/OBSERVABILITY.md has the schema.
     from deepspeed_tpu import telemetry
     if os.environ.get("DS_TPU_TELEMETRY") == "1":
-        telemetry.configure(enabled=True, sample_sync=False,
+        telemetry.configure(enabled=True,
                             chrome_trace_path=os.environ.get(
                                 "DS_TPU_TELEMETRY_TRACE", ""))
 
@@ -310,7 +310,7 @@ def run_moe_bench():
         raise RuntimeError(f"--moe needs 8 devices, have {n_dev}")
     # telemetry is always on for this leg: the traced comm records ARE the
     # wire-byte payload (trace-time, no steady-state sync)
-    telemetry.configure(enabled=True, sample_sync=False)
+    telemetry.configure(enabled=True)
 
     d_model, n_layers, experts, k, seq, batch = 256, 4, 4, 2, 128, 8
     wire_bits = 8

@@ -853,7 +853,7 @@ def main(argv=None):
     cache_dir = compile_cache.enable()
     entries_before = compile_cache.entry_count()
     # dispatch records (sharded / fallback / veto) are only kept when on
-    telemetry.configure(enabled=True, sample_sync=False)
+    telemetry.configure(enabled=True)
     t0 = time.perf_counter()
     if args.chips == 4:
         phase_multichip(REAL)

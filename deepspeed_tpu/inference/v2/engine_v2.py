@@ -109,6 +109,11 @@ class InferenceEngineV2:
         bs = self._state.kv_block_size
         self._max_blocks_per_seq = -(-sm.max_context // bs)
         self._host_sync_count = 0
+        # forwards dispatched so far: the ``round`` every span of a serving
+        # round carries (the scheduler reads it before composing)
+        self.round = 0
+        # [sequence bucket, chunk bucket] of the last forward's padded batch
+        self.last_batch_shape = (0, 0)
         # postmortem-bundle collector (telemetry/flightrec.py): the newest
         # engine's host-side KV pool stats ride every bundle — pure host
         # reads, so collection is safe even from an abnormal path
@@ -136,7 +141,8 @@ class InferenceEngineV2:
         tm = telemetry.get_telemetry()
         if tm.enabled:
             tm.count("host_sync", what=what)
-        return np.asarray(value)  # graftlint: allow[GL004] this IS the accounted fetch
+        with tm.span("serving/fetch", round=self.round - 1, what=what):
+            return np.asarray(value)  # graftlint: allow[GL004] this IS the accounted fetch
 
     # -- admission control (reference engine_v2.py:158-241) ----------------
     @property
@@ -219,7 +225,7 @@ class InferenceEngineV2:
     # -- serving (reference engine_v2.py:107) ------------------------------
     def _forward_device(self, batch_uids: List[int],
                         batch_tokens: List[np.ndarray],
-                        verify_k: int = None, defer_commit=()):
+                        verify_k: int = None, defer_commit=(), sample=None):
         """Run one ragged forward; returns the FULL padded [S_max, vocab]
         logits as a device array (no host transfer).
 
@@ -230,48 +236,68 @@ class InferenceEngineV2:
         (speculating rows — rejected chunk tails must be rolled back before
         any block digest is registered, or a wrong draft would poison the
         shared chain cache; the scheduler calls ``commit_prefix`` after
-        accept/rollback)."""
+        accept/rollback). ``sample``: a callable dispatched on the logits
+        behind the forward (the on-device sampler); its result is returned
+        in the logits' place."""
         verdict = self.can_schedule(batch_uids, [len(t) for t in batch_tokens])
         if not verdict.success:
             raise RuntimeError(f"cannot schedule batch: {verdict.reason}")
 
         tm = telemetry.get_telemetry()
-        sp = tm.span("serving/forward", seqs=len(batch_uids),
-                     tokens=int(sum(len(t) for t in batch_tokens))) \
-            if tm.enabled else None
+        rnd = self.round
+        # explicit begin/end, and the host-to-device copies as arguments of
+        # the jitted call: tracing a new batch shape inside ``with`` blocks
+        # cost set-up 0.07 s a shape more on the chip (PERF.md, PR 27)
+        sp = tm.span_begin("serving/build", round=rnd, seqs=len(batch_uids))
         sm = self._config.state_manager
         wrapper = RaggedBatchWrapper(sm.max_ragged_sequence_count,
                                      sm.max_ragged_batch_size,
                                      self._max_blocks_per_seq,
                                      self._state.kv_cache.trash_block)
         caching = self._state.prefix_cache is not None
+        real_tokens = context_tokens = 0
         for uid, toks in zip(batch_uids, batch_tokens):
             seq = self._state.get_or_create_sequence(uid)
             self._state.ensure_capacity(seq, len(toks))
             seq.in_flight_tokens = len(toks)
             if caching:
                 seq.tokens.extend(int(t) for t in toks)
+            real_tokens += len(toks)
+            if len(toks) == 1:
+                context_tokens += seq.seen_tokens
             wrapper.insert_sequence(uid, np.asarray(toks, np.int32),
                                     seq.seen_tokens, seq.kv_blocks)
         arrays = wrapper.build()
+        seq_bucket, chunk_bucket = self.last_batch_shape = \
+            arrays["tokens"].shape
+        sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
+               real_tokens=real_tokens,
+               padded_slots=seq_bucket * chunk_bucket,
+               context_tokens=context_tokens)
+        sp.end()
 
         kv = self._state.kv_cache
         # fwd_k/fwd_v are (int8, scale) pairs when kv_dtype="int8" — they
         # flow through the jitted forwards as pytree leaves
+        sp = tm.span_begin("serving/dispatch", round=rnd)
         if verify_k is not None:
             if self._verify_forward is None:
                 raise RuntimeError("no verify forward for this model family")
-            logits, k_pool, v_pool = self._verify_forward(
+            out, k_pool, v_pool = self._verify_forward(
                 self._model_config, self._params, kv.fwd_k, kv.fwd_v,
                 jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
                 jnp.asarray(arrays["seen"]), jnp.asarray(arrays["block_tables"]),
                 int(verify_k))
         else:
-            logits, k_pool, v_pool = self._ragged_forward(
+            out, k_pool, v_pool = self._ragged_forward(
                 self._model_config, self._params, kv.fwd_k, kv.fwd_v,
                 jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
                 jnp.asarray(arrays["seen"]), jnp.asarray(arrays["block_tables"]))
         kv.update(k_pool, v_pool)
+        if sample is not None:
+            out = sample(out)
+        sp.end()
+        self.round = rnd + 1
 
         for uid in batch_uids:
             seq = self._state.get_sequence(uid)
@@ -280,9 +306,31 @@ class InferenceEngineV2:
                 # register blocks as they FILL (not at flush) so concurrent
                 # requests sharing a prefix hit as early as possible
                 self._state.commit_cached_blocks(seq)
-        if sp is not None:
-            sp.end(logits)  # block_until_ready only when sample_sync is on
-        return logits
+        return out
+
+    @staticmethod
+    def _packed_sampler(sampler, n, temperatures, top_ks, top_ps, seeds,
+                        positions):
+        """``sampler(logits, fparams, iparams)`` with the five per-row
+        parameter vectors packed into two host arrays of the logits' padded
+        row count, so that the jit fast path moves them — per-dispatch host
+        time, not device math, bounds a fleet stepping several schedulers
+        per round."""
+        # arbitrary Python-int seeds (the host sampler accepted any) fold
+        # deterministically into the int31 space PRNGKey wants
+        seeds = [int(s) & 0x7FFFFFFF for s in seeds]
+
+        def sample(logits):
+            s_max = logits.shape[0]
+            fparams = np.zeros((2, s_max), np.float32)
+            fparams[0, :n] = temperatures
+            fparams[1, :n] = top_ps
+            iparams = np.zeros((3, s_max), np.int32)
+            iparams[0, :n] = top_ks
+            iparams[1, :n] = seeds
+            iparams[2, :n] = positions
+            return sampler(logits, fparams, iparams)
+        return sample
 
     def put(self, batch_uids: List[int],
             batch_tokens: List[np.ndarray]) -> np.ndarray:
@@ -303,27 +351,14 @@ class InferenceEngineV2:
         cross-replica overlap — fetching each result only when retiring
         tokens."""
         from deepspeed_tpu.inference.v2.sampling import sample_rows_packed
-        logits = self._forward_device(batch_uids, batch_tokens)
-        s_max = logits.shape[0]
-        n = len(batch_uids)
-        # arbitrary Python-int seeds (the host sampler accepted any) fold
-        # deterministically into the int31 space PRNGKey wants
-        seeds = [int(s) & 0x7FFFFFFF for s in seeds]
-        # pack the five per-row parameter vectors into two host arrays and
-        # let the jit fast path move them — per-dispatch host time, not
-        # device math, bounds a fleet stepping several schedulers per round
-        fparams = np.zeros((2, s_max), np.float32)
-        fparams[0, :n] = temperatures
-        fparams[1, :n] = top_ps
-        iparams = np.zeros((3, s_max), np.int32)
-        iparams[0, :n] = top_ks
-        iparams[1, :n] = seeds
-        iparams[2, :n] = positions
-        # return the PADDED [S-bucket] ids: a device-side ids[:n] would
+        # the PADDED [S-bucket] ids come back: a device-side ids[:n] would
         # compile one slice program per distinct live count (n is not
         # bucketed), a cold ~10ms stall every time a request finishes.
         # Callers fetch with np.asarray and read rows < n on the host.
-        return sample_rows_packed(logits, fparams, iparams)
+        return self._forward_device(
+            batch_uids, batch_tokens, sample=self._packed_sampler(
+                sample_rows_packed, len(batch_uids), temperatures, top_ks,
+                top_ps, seeds, positions))
 
     def put_sampled(self, batch_uids: List[int],
                     batch_tokens: List[np.ndarray],
@@ -371,20 +406,11 @@ class InferenceEngineV2:
         ``_forward_device`` (see there).
         """
         from deepspeed_tpu.inference.v2.sampling import verify_rows_packed
-        logits = self._forward_device(batch_uids, batch_tokens,
-                                      verify_k=int(k_max),
-                                      defer_commit=defer_commit)
-        s_max = logits.shape[0]
-        n = len(batch_uids)
-        seeds = [int(s) & 0x7FFFFFFF for s in seeds]
-        fparams = np.zeros((2, s_max), np.float32)
-        fparams[0, :n] = temperatures
-        fparams[1, :n] = top_ps
-        iparams = np.zeros((3, s_max), np.int32)
-        iparams[0, :n] = top_ks
-        iparams[1, :n] = seeds
-        iparams[2, :n] = positions
-        return verify_rows_packed(logits, fparams, iparams)
+        return self._forward_device(
+            batch_uids, batch_tokens, verify_k=int(k_max),
+            defer_commit=defer_commit, sample=self._packed_sampler(
+                verify_rows_packed, len(batch_uids), temperatures, top_ks,
+                top_ps, seeds, positions))
 
     def rollback(self, uid: int, n_tokens: int) -> None:
         """Roll ``uid``'s paged cursor back ``n_tokens`` (the rejected tail

@@ -16,9 +16,17 @@ Every lifecycle transition feeds the telemetry serving stream when enabled
 preempt/resume): TTFT/TPOT/e2e/queue-wait histograms, per-request
 Chrome-trace lanes, and per-step scheduler gauges (token-budget utilization,
 running/waiting counts, KV occupancy via ``engine.sample_kv_stats``).
-Disabled, every hook is a single boolean check — zero timing calls, zero
-allocations, zero syncs per step (pinned by
+Disabled, every such hook is a single boolean check — zero allocations and
+zero syncs per step, and the clock is read twice per REQUEST (at ``submit``
+and at its admission), never per round (pinned by
 tests/test_serving_observability.py).
+
+Whatever ``enabled`` says, each round opens ``telemetry.span``s
+(``serving/round`` > ``serving/compose``, the engine's ``serving/build`` /
+``dispatch`` / ``fetch``, ``serving/retire``) and marks each request's
+``serving/admit`` / ``first_token`` / ``finish``: profiler annotations that
+cost about a microsecond with no profiler session and never sync. All carry
+the engine's ``round``. docs/OBSERVABILITY.md lists their attributes.
 """
 
 import dataclasses
@@ -76,7 +84,8 @@ class _Request:
     pos_offset: int = 0
     done: bool = False
     preempted: bool = False  # KV host-swapped out (scheduler preemption)
-    # serving-telemetry timestamps (perf_counter; 0.0 = not yet / disabled)
+    # perf_counter timestamps: submit_ts and first_sched_ts always (0.0 =
+    # not yet), last_token_ts only with telemetry enabled
     submit_ts: float = 0.0
     first_sched_ts: float = 0.0
     last_token_ts: float = 0.0
@@ -111,6 +120,12 @@ class SplitFuseScheduler:
         # without telemetry
         self.prefill_tokens_executed = 0
         self.prefill_tokens_saved = 0
+        # batch occupancy without a profile: rounds dispatched, the tokens
+        # they carried and the [sequence bucket x chunk bucket] slots the
+        # engine padded them to (the sums of the ``serving/build`` spans)
+        self.rounds = 0
+        self.real_tokens = 0
+        self.padded_slots = 0
         # device_sampling=True (default) fuses temperature/top-k/top-p and
         # the categorical draw into the decode step on the accelerator: the
         # host receives one int32 per sequence instead of a [S, vocab] float
@@ -215,9 +230,9 @@ class SplitFuseScheduler:
                        temperature=float(temperature),
                        top_k=int(top_k), top_p=float(top_p),
                        seed=int(seed))
+        req.submit_ts = _now()
         tm = telemetry.get_telemetry()
         if tm.enabled:
-            req.submit_ts = _now()
             tm.serving_event("submitted")
             tm.record_request_phase(uid, "submit", req.submit_ts,
                                     prompt_tokens=len(prompt))
@@ -256,10 +271,10 @@ class SplitFuseScheduler:
                        prefill_pos=len(prompt), generated=generated)
         req.submit_ts = float(submit_ts)
         req.last_token_ts = float(last_token_ts)
+        # admitted where it was prefilled: queue-wait was recorded there
+        t = req.first_sched_ts = _now()
         tm = telemetry.get_telemetry()
         if tm.enabled:
-            t = _now()
-            req.first_sched_ts = t  # queue-wait was recorded at prefill
             tm.serving_event("adopted")
             tm.record_request_phase(uid, "adopt", t,
                                     seen_tokens=len(prompt),
@@ -326,6 +341,7 @@ class SplitFuseScheduler:
         r.done = True
         self._active -= 1
         self.terminal_events.append((uid, "cancelled"))
+        self._mark_finish(r, "cancelled", self._engine.round)
         if self._engine._state.get_sequence(uid) is not None:
             self._engine.flush(uid)
         tm = telemetry.get_telemetry()
@@ -402,6 +418,13 @@ class SplitFuseScheduler:
     def has_work(self):
         return any(not r.done for r in self._requests.values())
 
+    @staticmethod
+    def _mark_finish(r, reason, rnd):
+        """The request's terminal mark in the profiler's trace; ``rnd`` is
+        the round being retired, or the one composed next."""
+        telemetry.span("serving/finish", uid=r.uid, round=rnd,
+                       new_tokens=len(r.generated), reason=reason).end()
+
     def _compose(self):
         """Pick (uids, token-chunks) for one forward under the budget.
 
@@ -423,6 +446,7 @@ class SplitFuseScheduler:
                 r.done = True
                 self._active -= 1
                 self.terminal_events.append((r.uid, "evicted"))
+                self._mark_finish(r, "evicted", self._engine.round)
                 self._engine.flush(r.uid)
                 if tm.enabled:
                     t_evict = _now()
@@ -567,21 +591,12 @@ class SplitFuseScheduler:
                                     blocks=n_blocks)
         return True
 
-    def step(self):
-        """One scheduling round + forward. Returns uids finished this round."""
-        pending = self.step_begin()
-        return self.step_finish(pending) if pending is not None else []
-
-    def step_begin(self):
-        """Compose + dispatch one round WITHOUT fetching the result.
-
-        Returns an opaque pending handle for ``step_finish`` (None when
-        nothing was schedulable). The forward and on-device sampling stay
-        asynchronously dispatched in between — a fleet stepping N replicas
-        begins them all, then finishes them all, so the forwards run
-        concurrently across submeshes instead of serializing on each
-        replica's host fetch. ``step()`` is the fused single-replica form."""
-        tm = telemetry.get_telemetry()
+    def _propose(self):
+        """The round's (uids, chunks) as the engine admits them, both empty
+        when nothing can run: resume what fits, compose under the budget,
+        shrink until ``can_schedule`` agrees, preempt when starved. Also
+        returns the rows the shrink loop dropped and the sequences
+        preempted (0 or 1), for the ``serving/compose`` span."""
         self._try_resume()
         uids, chunks = self._compose()
         if not uids:
@@ -596,7 +611,7 @@ class SplitFuseScheduler:
                         f"no schedulable work for {self._starved} rounds: "
                         f"preempted sequence(s) cannot be resumed (KV cache "
                         f"too small for the request?)")
-            return None
+            return [], [], 0, 0
         # shrink the proposal until the engine admits it (KV pressure):
         # drafts shed first — a speculative decode row trims back to its
         # plain 1-token chunk (the draft tail is opportunistic; the row
@@ -605,6 +620,7 @@ class SplitFuseScheduler:
         # and thrash the pool resume/preempt forever — then whole chunks
         # drop largest-first and RE-validate; put() would raise on an
         # oversubscribed batch
+        shrunk = 0
         while uids:
             verdict = self._engine.can_schedule(uids, [len(c) for c in chunks])
             if verdict.success:
@@ -620,37 +636,72 @@ class SplitFuseScheduler:
             biggest = int(np.argmax([len(c) for c in chunks]))
             uids.pop(biggest)
             chunks.pop(biggest)
+            shrunk += 1
         if not uids:
             self._starved += 1
             # host-swap a blocked decode's KV before declaring starvation
             if self._preempt_for_progress():
                 self._starved = 0
-                return None
+                return [], [], shrunk, 1
             if self._starved > 3:
                 raise RuntimeError(
                     f"no schedulable work for {self._starved} rounds: "
                     f"{verdict.reason} (KV cache too small for any request?)")
-            return None
+            return [], [], shrunk, 0
         self._starved = 0
+        return uids, chunks, shrunk, 0
+
+    def step(self):
+        """One scheduling round + forward. Returns uids finished this round."""
+        with telemetry.span("serving/round", round=self._engine.round):
+            pending = self.step_begin()
+            return self.step_finish(pending) if pending is not None else []
+
+    def step_begin(self):
+        """Compose + dispatch one round WITHOUT fetching the result.
+
+        Returns an opaque pending handle for ``step_finish`` (None when
+        nothing was schedulable). The forward and on-device sampling stay
+        asynchronously dispatched in between — a fleet stepping N replicas
+        begins them all, then finishes them all, so the forwards run
+        concurrently across submeshes instead of serializing on each
+        replica's host fetch. ``step()`` is the fused single-replica form."""
+        tm = telemetry.get_telemetry()
         enabled = tm.enabled
+        rnd = self._engine.round
         t_fwd = 0.0
-        sched_tokens = 0
-        was_prefilling = None
-        if enabled:
-            t_fwd = _now()
-            was_prefilling = [self._requests[u].prefilling for u in uids]
+        sched_tokens = prefill_tokens = 0
+        was_prefilling = []
+        with tm.span("serving/compose", round=rnd) as sp:
+            uids, chunks, shrunk, preempted = self._propose()
+            if enabled:
+                t_fwd = _now()
             for row, uid in enumerate(uids):
                 r = self._requests[uid]
-                sched_tokens += len(chunks[row])
+                n = len(chunks[row])
+                sched_tokens += n
+                was_prefilling.append(r.prefilling)
+                if r.prefilling:
+                    prefill_tokens += n
                 if r.first_sched_ts == 0.0:
-                    r.first_sched_ts = t_fwd
-                    if r.submit_ts:
-                        tm.record_hist("serving/queue_wait_s",
-                                       t_fwd - r.submit_ts)
-                        tm.record_request_phase(uid, "queued", r.submit_ts,
-                                                t_fwd - r.submit_ts)
-                    tm.record_request_flow(uid, "prefill",
-                                           tokens=len(chunks[row]))
+                    r.first_sched_ts = _now()
+                    # a re-admitted request may come without its submit time
+                    waited = r.first_sched_ts - r.submit_ts \
+                        if r.submit_ts else 0.0
+                    tm.span("serving/admit", uid=uid, round=rnd,
+                            waited_us=int(waited * 1e6),
+                            prompt_tokens=len(r.prompt)).end()
+                    if enabled:
+                        if r.submit_ts:
+                            tm.record_hist("serving/queue_wait_s", waited)
+                            tm.record_request_phase(uid, "queued",
+                                                    r.submit_ts, waited)
+                        tm.record_request_flow(uid, "prefill", tokens=n)
+            sp.set(seqs=len(uids), prefill_tokens=prefill_tokens,
+                   decode_rows=len(uids) - sum(was_prefilling),
+                   shrunk=shrunk, preempted=preempted)
+        if not uids:
+            return None
         if self._spec:
             reqs = [self._requests[u] for u in uids]
             # each row's LAST verify column samples at: the next stream
@@ -685,14 +736,18 @@ class SplitFuseScheduler:
         else:
             logits = self._engine.put(uids, chunks)
             ids = None
+        seq_bucket, chunk_bucket = self._engine.last_batch_shape
+        self.rounds += 1
+        self.real_tokens += sched_tokens
+        self.padded_slots += seq_bucket * chunk_bucket
         return (uids, chunks, ids, logits, t_fwd, was_prefilling,
-                sched_tokens)
+                sched_tokens, rnd)
 
     def step_finish(self, pending):
         """Fetch a dispatched round's sampled ids and retire tokens /
         finished requests. Returns uids finished this round."""
-        uids, chunks, ids, logits, t_fwd, was_prefilling, sched_tokens = \
-            pending
+        (uids, chunks, ids, logits, t_fwd, was_prefilling, sched_tokens,
+         rnd) = pending
         tm = telemetry.get_telemetry()
         # t_fwd == 0.0 means telemetry was off at dispatch; recording phases
         # against a zero anchor would be garbage, so the round stays dark
@@ -701,6 +756,7 @@ class SplitFuseScheduler:
             # the only device sync of the round, accounted so
             # engine.host_sync_count audits the one-fetch-per-round budget
             ids = self._engine.host_fetch(ids, "scheduler/sampled_ids")
+        sp = tm.span_begin("serving/retire", round=rnd)
         spec = self._spec
         if enabled:
             t_done = _now()
@@ -711,6 +767,7 @@ class SplitFuseScheduler:
                 tm.record_request_phase(uid, phase, t_fwd, fwd_dur,
                                         tokens=len(chunks[row]))
         finished = []
+        new_tokens = 0
         # per-round speculation tallies (gauges + the router EWMA)
         n_decode_rows = decode_committed = drafted = accepted = occ_cols = 0
         for row, uid in enumerate(uids):
@@ -773,6 +830,9 @@ class SplitFuseScheduler:
                            else self._sample(r, logits[row])]
             first = not r.generated
             r.generated.extend(emitted)
+            new_tokens += len(emitted)
+            if first and emitted:
+                tm.span("serving/first_token", uid=uid, round=rnd).end()
             if enabled:
                 if first:
                     # TTFT spans submit->first generated token; a request
@@ -805,6 +865,7 @@ class SplitFuseScheduler:
                     continue
                 self._engine.flush(uid)
                 finished.append(uid)
+                self._mark_finish(r, "done", rnd)
                 if enabled:
                     tm.record_hist("serving/e2e_s",
                                    t_done - (r.submit_ts or t_fwd))
@@ -846,6 +907,8 @@ class SplitFuseScheduler:
             tm.serving_gauge("serving/waiting", waiting)
             tm.serving_gauge("serving/preempted", preempted)
             self._engine.sample_kv_stats()
+        sp.set(new_tokens=new_tokens, finished=len(finished))
+        sp.end()
         return finished
 
     def _sample(self, r, row_logits):

@@ -133,12 +133,14 @@ def _forward(q, k, v, cols, counts, block, causal, scale, interpret):
     )
     kernel = functools.partial(_kernel, block=block, n_steps=C, causal=causal,
                                scale=scale)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        interpret=interpret,
-    )(cols, counts, q, k, v)
+    with jax.named_scope("block_sparse_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+            name="block_sparse_attention",
+            interpret=interpret,
+        )(cols, counts, q, k, v)
 
 
 def sparse_mha(q, k, v, layout, block, causal=False, softmax_scale=None,

@@ -377,25 +377,27 @@ def _fwd(q, k, v, bias, segment_ids, causal, scale, window, interpret,
         in_specs += [qs, ks]
         args += list(_seg_inputs(segment_ids, B, tq, tk))
 
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(B, H, nq, nk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, bq, LANES), lambda b, h, iq, ik: (b, h, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, tq, dh), q.dtype),
-            jax.ShapeDtypeStruct((B, H, tq, LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, dh), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*args)
+    with jax.named_scope("flash_mha_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(B, H, nq, nk),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik: (b, h, iq, 0)),
+                pl.BlockSpec((1, 1, bq, LANES), lambda b, h, iq, ik: (b, h, iq, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, tq, dh), q.dtype),
+                jax.ShapeDtypeStruct((B, H, tq, LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, dh), jnp.float32),
+            ],
+            name="flash_mha_fwd",
+            interpret=interpret,
+        )(*args)
     # keep only column 0 as the residual: holding the lane-replicated copy
     # from forward to backward would be a 128x memory blow-up
     return out.transpose(0, 2, 1, 3), lse[..., 0]
@@ -546,15 +548,17 @@ def _bwd(causal, scale, window, interpret, blocks, res, g):
         _bwd_dq_kernel, causal=causal, scale=scale, window=window,
         bq=bq, bk=bk, nk=nk, off=tk - tq,
         has_bias=bias is not None, has_seg=seg_args is not None)
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(B, H, nq, nk),
-        in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik: (b, h, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, tq, dh), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
-        interpret=interpret,
-    )(*dq_args)
+    with jax.named_scope("flash_mha_bwd_dq"):
+        dq = pl.pallas_call(
+            dq_kernel,
+            grid=(B, H, nq, nk),
+            in_specs=dq_specs,
+            out_specs=pl.BlockSpec((1, 1, bq, dh), lambda b, h, iq, ik: (b, h, iq, 0)),
+            out_shape=jax.ShapeDtypeStruct((B, H, tq, dh), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, dh), jnp.float32)],
+            name="flash_mha_bwd_dq",
+            interpret=interpret,
+        )(*dq_args)
 
     # dK/dV: grid (B, H, nk, nq), q innermost; per-q-head results, GQA head
     # groups summed afterwards in XLA (rep is 1 for MHA so this is free there)
@@ -569,24 +573,26 @@ def _bwd(causal, scale, window, interpret, blocks, res, g):
         _bwd_dkv_kernel, causal=causal, scale=scale, window=window,
         bq=bq, bk=bk, nq=nq, off=tk - tq,
         has_bias=bias is not None, has_seg=seg_args is not None)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(B, H, nk, nq),
-        in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, dh), lambda b, h, ik, iq: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, dh), lambda b, h, ik, iq: (b, h, ik, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, tk, dh), k.dtype),
-            jax.ShapeDtypeStruct((B, H, tk, dh), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, dh), jnp.float32),
-            pltpu.VMEM((bk, dh), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*dkv_args)
+    with jax.named_scope("flash_mha_bwd_dkv"):
+        dk, dv = pl.pallas_call(
+            dkv_kernel,
+            grid=(B, H, nk, nq),
+            in_specs=dkv_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, bk, dh), lambda b, h, ik, iq: (b, h, ik, 0)),
+                pl.BlockSpec((1, 1, bk, dh), lambda b, h, ik, iq: (b, h, ik, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, tk, dh), k.dtype),
+                jax.ShapeDtypeStruct((B, H, tk, dh), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, dh), jnp.float32),
+                pltpu.VMEM((bk, dh), jnp.float32),
+            ],
+            name="flash_mha_bwd_dkv",
+            interpret=interpret,
+        )(*dkv_args)
 
     if rep > 1:
         dk = dk.reshape(B, KV, rep, tk, dh).sum(axis=2)

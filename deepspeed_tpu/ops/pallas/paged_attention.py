@@ -219,12 +219,14 @@ def _paged_mha_local(q, k_pool, v_pool, block_tables, seen, q_len, *,
                                window=int(window) if window else None,
                                quantized=quantized)
     # qt reshaped so kv-head is a real leading dim for the spec: [S*KV, rep*Q, Dh]
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KV, rep * Q, Dh), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), seen, q_len, jcap, *inputs)
+    with jax.named_scope("paged_attention"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, KV, rep * Q, Dh), q.dtype),
+            name="paged_attention",
+            interpret=interpret,
+        )(block_tables.astype(jnp.int32), seen, q_len, jcap, *inputs)
     return out.reshape(S, KV, rep, Q, Dh).transpose(0, 3, 1, 2, 4) \
               .reshape(S, Q, H, Dh)
 

@@ -270,16 +270,18 @@ def _quantize_rows_local(rows, num_bits, bg, interpret):
     bg = min(bg, n)
     gsw = gs if num_bits == 8 else gs // 2
     qdt = jnp.int8 if num_bits == 8 else jnp.uint8
-    return pl.pallas_call(
-        functools.partial(_quant_kernel, bits=num_bits),
-        grid=(n // bg,),
-        in_specs=[pl.BlockSpec((bg, gs), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((bg, gsw), lambda i: (i, 0)),
-                   pl.BlockSpec((bg, _SCALE_LANES), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, gsw), qdt),
-                   jax.ShapeDtypeStruct((n, _SCALE_LANES), jnp.float32)],
-        interpret=interpret,
-    )(rows)
+    with jax.named_scope("block_quantize"):
+        return pl.pallas_call(
+            functools.partial(_quant_kernel, bits=num_bits),
+            grid=(n // bg,),
+            in_specs=[pl.BlockSpec((bg, gs), lambda i: (i, 0))],
+            out_specs=[pl.BlockSpec((bg, gsw), lambda i: (i, 0)),
+                       pl.BlockSpec((bg, _SCALE_LANES), lambda i: (i, 0))],
+            out_shape=[jax.ShapeDtypeStruct((n, gsw), qdt),
+                       jax.ShapeDtypeStruct((n, _SCALE_LANES), jnp.float32)],
+            name="block_quantize",
+            interpret=interpret,
+        )(rows)
 
 
 def _dequantize_reduce_impl(q3, s2, num_bits, group_size, interpret,
@@ -341,16 +343,18 @@ def _deq_reduce_local(q3, sb, num_bits, bg, interpret):
     P_, N, gsw = q3.shape
     gs = gsw if num_bits == 8 else gsw * 2
     bg = min(bg, N)
-    return pl.pallas_call(
-        functools.partial(_deq_reduce_kernel, bits=num_bits, npeers=P_),
-        grid=(N // bg, P_),   # peers innermost: VMEM-resident accumulation
-        in_specs=[pl.BlockSpec((1, bg, gsw), lambda i, p: (p, i, 0)),
-                  pl.BlockSpec((1, bg, _SCALE_LANES), lambda i, p: (p, i, 0))],
-        out_specs=pl.BlockSpec((bg, gs), lambda i, p: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, gs), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bg, gs), jnp.float32)],
-        interpret=interpret,
-    )(q3, sb)
+    with jax.named_scope("block_dequantize_reduce"):
+        return pl.pallas_call(
+            functools.partial(_deq_reduce_kernel, bits=num_bits, npeers=P_),
+            grid=(N // bg, P_),   # peers innermost: VMEM-resident accumulation
+            in_specs=[pl.BlockSpec((1, bg, gsw), lambda i, p: (p, i, 0)),
+                      pl.BlockSpec((1, bg, _SCALE_LANES), lambda i, p: (p, i, 0))],
+            out_specs=pl.BlockSpec((bg, gs), lambda i, p: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((N, gs), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((bg, gs), jnp.float32)],
+            name="block_dequantize_reduce",
+            interpret=interpret,
+        )(q3, sb)
 
 
 def block_dequantize_reduce(q, scale, num_bits=8, group_size=DEFAULT_GROUP,

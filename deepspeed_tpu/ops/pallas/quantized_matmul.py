@@ -171,19 +171,21 @@ def _quantized_matmul_local(x, q, scale, group_size, out_dtype=None,
     BN_ = min(BN_, N)   # a whole-width block, seen from a tp shard of N
     nm, nn, nk = M // bm, N // BN_, K // BK_
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, nk=nk, bn=BN_, group_size=group_size),
-        grid=(nm, nn, nk),
-        in_specs=[
-            pl.BlockSpec((bm, BK_), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((BK_, BN_), lambda i, j, kk: (kk, j)),
-            # per-j scale block [bk, bn//G]: sliced by the DMA machinery
-            # here, never by an in-kernel lane-dim dynamic slice
-            pl.BlockSpec((BK_, BN_ // group_size), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, BN_), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, BN_), jnp.float32)],
-        interpret=interpret,
-    )(x, q, scale.astype(jnp.float32))
+    with jax.named_scope("quantized_matmul"):
+        out = pl.pallas_call(
+            functools.partial(_kernel, nk=nk, bn=BN_, group_size=group_size),
+            grid=(nm, nn, nk),
+            in_specs=[
+                pl.BlockSpec((bm, BK_), lambda i, j, kk: (i, kk)),
+                pl.BlockSpec((BK_, BN_), lambda i, j, kk: (kk, j)),
+                # per-j scale block [bk, bn//G]: sliced by the DMA machinery
+                # here, never by an in-kernel lane-dim dynamic slice
+                pl.BlockSpec((BK_, BN_ // group_size), lambda i, j, kk: (kk, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, BN_), lambda i, j, kk: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+            scratch_shapes=[pltpu.VMEM((bm, BN_), jnp.float32)],
+            name="quantized_matmul",
+            interpret=interpret,
+        )(x, q, scale.astype(jnp.float32))
     return out

@@ -96,13 +96,12 @@ class CommsLoggerConfig(DeepSpeedConfigModel):
 class TelemetryConfig(DeepSpeedConfigModel):
     """``telemetry`` section — the unified observability pipeline
     (deepspeed_tpu/telemetry). Disabled by default: every telemetry entry
-    point is then a constant-time no-op (no block_until_ready, no file I/O).
+    point but ``span`` is then a constant-time no-op (no file I/O), and a
+    span is only its ``jax.profiler`` annotation; no span ever syncs.
     See docs/OBSERVABILITY.md."""
     enabled = False
     jsonl_path = ""          # "" disables the JSON-lines metrics export
     chrome_trace_path = ""   # "" disables the chrome://tracing span export
-    sample_sync = True       # block_until_ready on span tokens when sampling
-    jax_annotations = False  # mirror spans into jax.profiler annotations
     monitor = True           # fan aggregates through MonitorMaster at
     #                          steps_per_print cadence
     memory = True            # HBM memory stream (record_memory samples at

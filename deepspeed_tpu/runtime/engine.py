@@ -1646,19 +1646,26 @@ class DeepSpeedEngine:
             self.timers(FORWARD_GLOBAL_TIMER).start()
         self.tput_timer.start()
         from deepspeed_tpu import telemetry
-        _span = telemetry.span_begin(FORWARD_GLOBAL_TIMER)
-        batch = self._shard_batch(batch)
+        fused = getattr(self, "_fused_step_fn", None) is not None
+        step = self.global_steps
+        _span = telemetry.span_begin(FORWARD_GLOBAL_TIMER, step=step,
+                                     fused=int(fused))
+        with telemetry.span("fwd/shard_batch", step=step):
+            batch = self._shard_batch(batch)
         if self._guards is not None and self._guards["checkify_on_overflow"]:
             self._last_guard_batch = batch  # for overflow localization
         try:
-            if getattr(self, "_fused_step_fn", None) is not None:
-                # fused_step config: grads + optimizer apply in ONE jit (GAS=1).
-                # The update is applied HERE; step() consumes the staged stats.
-                lr = self._schedule_fn(self.global_steps)
-                self.state, loss, stats = self._fused_step_fn(self.state, batch, lr)
-                self._pending_fused_stats = stats
-            else:
-                self.state, loss = self._micro_step_fn(self.state, batch)
+            with telemetry.span("fwd/dispatch", step=step):
+                if fused:
+                    # fused_step config: grads + optimizer apply in ONE jit
+                    # (GAS=1). The update is applied HERE; step() consumes
+                    # the staged stats.
+                    lr = self._schedule_fn(step)
+                    self.state, loss, stats = self._fused_step_fn(
+                        self.state, batch, lr)
+                    self._pending_fused_stats = stats
+                else:
+                    self.state, loss = self._micro_step_fn(self.state, batch)
         except Exception as e:
             telemetry.maybe_oom_postmortem(e)
             raise
@@ -1671,7 +1678,7 @@ class DeepSpeedEngine:
             else:
                 self._loss_accum = self._loss_accum + loss
                 self._loss_accum_n += 1
-        _span.end(token=loss)
+        _span.end()
         if self.wall_clock_breakdown:
             self.timers(FORWARD_GLOBAL_TIMER).stop(token=loss)
         return loss
@@ -1681,14 +1688,12 @@ class DeepSpeedEngine:
     def backward(self, loss=None, retain_graph=False):
         """API-parity shim: gradient computation/reduction already ran fused
         inside ``forward`` (see note there). The ``bwd`` telemetry span
-        therefore measures the wait for the in-flight fused program (its
-        token sync), not a separate grad pass."""
+        therefore marks this bookkeeping on the host, not a grad pass."""
         assert self._staged_loss is not None, "backward() called before forward()"
         from deepspeed_tpu import telemetry
-        with telemetry.span(BACKWARD_GLOBAL_TIMER) as _sp:
+        with telemetry.span(BACKWARD_GLOBAL_TIMER, step=self.global_steps):
             staged_loss = self._staged_loss
             self._staged_loss = None
-            _sp.token = staged_loss
         return staged_loss
 
     def is_gradient_accumulation_boundary(self):
@@ -1749,7 +1754,8 @@ class DeepSpeedEngine:
         from deepspeed_tpu import telemetry
         if telemetry.enabled():
             telemetry.count("host_sync", what=what)
-        return jax.device_get(value)
+        with telemetry.span("host_fetch", what=what):
+            return jax.device_get(value)
 
     @property
     def host_sync_count(self):
@@ -1769,7 +1775,7 @@ class DeepSpeedEngine:
         except _faults.InjectedFault as e:
             self._handle_slice_loss(e)
         from deepspeed_tpu import telemetry
-        _span = telemetry.span_begin(STEP_GLOBAL_TIMER)
+        _span = telemetry.span_begin(STEP_GLOBAL_TIMER, step=self.global_steps)
         if self.wall_clock_breakdown:
             self.timers(STEP_GLOBAL_TIMER).start()
         if self.is_gradient_accumulation_boundary():
@@ -1823,8 +1829,7 @@ class DeepSpeedEngine:
         self.global_samples += self.micro_batch_size * self.topology.data_parallel_size
         if self.wall_clock_breakdown:
             self.timers(STEP_GLOBAL_TIMER).stop()
-        _span.end(token=self._last_stats.loss_scale
-                  if (self._step_applied and self._last_stats is not None) else None)
+        _span.end()
         if self._step_applied and telemetry.enabled():
             # goodput/MFU ledger mark + HBM sample, once per optimizer step
             telemetry.ledger_step(step=self.global_steps)
@@ -2032,10 +2037,8 @@ class DeepSpeedEngine:
         self._ensure_initialized(batch)
         self._compiled()
         from deepspeed_tpu import telemetry
-        with telemetry.span("eval") as _sp:
-            out = self._eval_step_fn(self.state, self._shard_batch(batch))
-            _sp.token = out
-        return out
+        with telemetry.span("eval"):
+            return self._eval_step_fn(self.state, self._shard_batch(batch))
 
     def write_events(self, event_list):
         """Forward (name, value, step) event tuples to the monitor fan-out

@@ -10,20 +10,19 @@ feeds the same sinks::
 
     telemetry.configure(enabled=True, jsonl_path="metrics.jsonl",
                         chrome_trace_path="trace.json")
-    with telemetry.span("fwd") as sp:
-        loss = step(batch)
-        sp.token = loss          # span end block_until_ready's the token
+    with telemetry.span("fwd", step=3):   # ds/fwd in any profiler capture
+        loss = step(batch)                # never waits for the device
     telemetry.record("loss", float(loss), kind="gauge", step=1)
     print(telemetry.log_summary())
     telemetry.export_chrome_trace()
 
-Disabled (the default), every call here is a constant-time no-op — no jax
-sync, no file I/O. See docs/OBSERVABILITY.md for config keys, the exporter
+Disabled (the default), every call here but ``span`` is a constant-time
+no-op — no jax sync, no file I/O — and a span is only its profiler annotation. See docs/OBSERVABILITY.md for config keys, the exporter
 matrix and the dispatch reason-code table.
 """
 
 from deepspeed_tpu.telemetry import flightrec  # noqa: F401
-from deepspeed_tpu.telemetry.core import Telemetry, _NULL_SPAN  # noqa: F401
+from deepspeed_tpu.telemetry.core import Telemetry  # noqa: F401
 
 _GLOBAL = Telemetry()
 

@@ -2,10 +2,11 @@
 
 One object owns every measurement stream the runtime produces:
 
-- **spans** (``span("fwd")`` / ``span_begin``/``end``): wall-clock phases of
-  the train loop. A span may carry a jax array ``token``; when sampling is on
-  the span end calls ``jax.block_until_ready(token)`` so the measured
-  interval covers the device work, not just the async dispatch.
+- **spans** (``span("fwd", step=3)`` / ``span_begin``/``end``): host phases
+  of the train loop and the serving round. Every span is a
+  ``jax.profiler.TraceAnnotation`` named ``ds/<name>`` with its attributes,
+  so any profiler capture holds it on the device trace's clock; a span
+  never waits for the device (it times the host's part: the dispatch).
 - **metrics** (``record(name, value, kind, **tags)``): scalar samples,
   appended to an in-memory list and (when configured) a JSON-lines file.
 - **counters** (``count(name, **tags)``): monotone per-tag counts.
@@ -40,15 +41,17 @@ with per-host tracks and a straggler report.
 
 Exporters: Chrome-trace JSON (``chrome://tracing`` / Perfetto) for spans, a
 JSON-lines metrics file, Monitor fan-out events (``monitor_events``) for the
-CSV/TB/W&B backends, and an optional ``jax.profiler`` trace-annotation
-pass-through so spans also appear in real TPU profiles.
+CSV/TB/W&B backends; spans are in every ``jax.profiler`` capture whether
+the pipeline is enabled or not.
 
-Disabled (the default) every entry point is a constant-time no-op: no
-``block_until_ready``, no file I/O, no allocation beyond the guard check —
-see ``tests/test_telemetry.py::test_disabled_noop_fast_path``.
+Disabled (the default) every entry point but ``span`` is a constant-time
+no-op, and a span is one short-lived annotation object: no lock, no file
+I/O, no state kept — see
+``tests/test_telemetry.py::test_disabled_noop_fast_path``.
 
-This module deliberately imports only the standard library at module scope;
-jax is imported lazily inside the enabled-only paths.
+Beyond the standard library this module imports only ``jax.profiler`` (the
+annotation every span opens); the rest of jax is imported lazily inside the
+enabled-only paths.
 """
 
 import atexit
@@ -58,6 +61,8 @@ import os
 import socket
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 # the always-on black box (telemetry/flightrec.py): Fault/* and Recovery/*
 # events, SLO violations and memory samples are mirrored into its bounded
@@ -198,74 +203,54 @@ def _atexit_export_all():
         inst._atexit_export()
 
 
-class _NullSpan:
-    """Shared no-op span for the disabled fast path: entering/exiting does
-    nothing and assigning ``token`` is absorbed."""
-
-    __slots__ = ("token",)
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def end(self, token=None):
-        return None
-
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span:
+class _Span(TraceAnnotation):
     """A live scoped measurement. Usable as a context manager
-    (``with telemetry.span("fwd") as sp: ...; sp.token = loss``) or via the
-    explicit ``span_begin``/``end`` pair when the scope spans methods."""
+    (``with telemetry.span("fwd", step=3): ...``) or via the explicit
+    ``span_begin``/``end`` pair when the scope spans methods.
 
-    __slots__ = ("_tm", "name", "tags", "token", "_t0", "_annotation")
+    Every span IS a ``jax.profiler.TraceAnnotation`` named ``ds/<name>``
+    carrying its attributes, so it lands in any profiler capture on the
+    device trace's clock; with no profiler session that is one small object
+    and about a microsecond. ``_tm`` is the pipeline only when telemetry is
+    enabled: then the span also feeds the span stats, the JSONL file and
+    the Chrome trace. A span never waits for the device."""
+
+    __slots__ = ("_tm", "name", "tags", "_t0")
 
     def __init__(self, tm, name, tags):
+        super().__init__("ds/" + name, **tags)
         self._tm = tm
         self.name = name
         self.tags = tags
-        self.token = None
-        self._annotation = None
-        if tm.jax_annotations:
-            try:
-                import jax.profiler
-                self._annotation = jax.profiler.TraceAnnotation(name)
-                self._annotation.__enter__()
-            except Exception:
-                self._annotation = None
-        self._t0 = _now()
+        TraceAnnotation.__enter__(self)
+        # None once ended; the clock is read only for the pipeline's sinks
+        self._t0 = _now() if tm is not None else 0.0
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
-        self.end(self.token)
+        self.end()
         return False
 
+    def set(self, **attrs):
+        """Attributes known only once the span is open (a batch's bucket
+        after it is built): same sinks as those given at the start."""
+        self.tags.update(attrs)
+        self.set_metadata(**attrs)
+
     def end(self, token=None):
-        tm = self._tm
+        """Close the span (ending twice records once). ``token`` is accepted
+        for the callers that used to hand a device value over, and ignored."""
+        t0, self._t0 = self._t0, None
+        if t0 is None:
+            return 0.0
+        TraceAnnotation.__exit__(self, None, None, None)
+        tm, self._tm = self._tm, None
         if tm is None:
             return 0.0
-        self._tm = None  # ending twice records once
-        if token is None:
-            token = self.token
-        if token is not None and tm.sample_sync:
-            try:
-                import jax
-                jax.block_until_ready(token)
-            except Exception:
-                pass
-        dt = _now() - self._t0
-        if self._annotation is not None:
-            try:
-                self._annotation.__exit__(None, None, None)
-            except Exception:
-                pass
-        tm._end_span(self.name, self._t0, dt, self.tags)
+        dt = _now() - t0
+        tm._end_span(self.name, t0, dt, self.tags or None)
         return dt
 
 
@@ -278,8 +263,6 @@ class Telemetry:
         self.enabled = False
         self._reset_state()
         # exporter wiring (survives reset() so a reset mid-run keeps sinks)
-        self.sample_sync = True
-        self.jax_annotations = False
         self.jsonl_path = None
         self.chrome_trace_path = None
         self.monitor_prefix = "Telemetry/"
@@ -349,8 +332,7 @@ class Telemetry:
     # configuration
     # ------------------------------------------------------------------
     def configure(self, config=None, enabled=None, jsonl_path=None,
-                  chrome_trace_path=None, sample_sync=None,
-                  jax_annotations=None, memory=None, flops_per_step=None,
+                  chrome_trace_path=None, memory=None, flops_per_step=None,
                   peak_flops=None):
         """Configure from a ``TelemetryConfig`` (runtime/config.py
         ``telemetry`` section) and/or explicit overrides. Paths set to ""
@@ -364,11 +346,6 @@ class Telemetry:
                 chrome_trace_path = getattr(config, "chrome_trace_path",
                                             chrome_trace_path) \
                     if chrome_trace_path is None else chrome_trace_path
-                sample_sync = getattr(config, "sample_sync", sample_sync) \
-                    if sample_sync is None else sample_sync
-                jax_annotations = getattr(config, "jax_annotations",
-                                          jax_annotations) \
-                    if jax_annotations is None else jax_annotations
                 memory = getattr(config, "memory", memory) \
                     if memory is None else memory
                 flops_per_step = getattr(config, "flops_per_step",
@@ -376,10 +353,6 @@ class Telemetry:
                     if flops_per_step is None else flops_per_step
                 peak_flops = getattr(config, "peak_flops", peak_flops) \
                     if peak_flops is None else peak_flops
-            if sample_sync is not None:
-                self.sample_sync = bool(sample_sync)
-            if jax_annotations is not None:
-                self.jax_annotations = bool(jax_annotations)
             if memory is not None:
                 self.memory_enabled = bool(memory)
             if flops_per_step:
@@ -433,11 +406,10 @@ class Telemetry:
     # spans
     # ------------------------------------------------------------------
     def span(self, name, **tags):
-        """Scoped wall-clock measurement; ``_NULL_SPAN`` when disabled so the
-        off path never allocates or syncs."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, tags or None)
+        """Scoped measurement ``ds/<name>`` in the profiler's trace; enabled,
+        also in this pipeline's sinks. Never syncs; disabled it takes no
+        lock and keeps no state."""
+        return _Span(self if self.enabled else None, name, tags)
 
     span_begin = span  # same object, explicit begin/end idiom
 

@@ -551,7 +551,7 @@ def main():
     # diffing the cache dir's file set around each compile (a miss writes a
     # new cache entry, a hit does not).
     from deepspeed_tpu import telemetry
-    telemetry.configure(enabled=True, sample_sync=False)
+    telemetry.configure(enabled=True)
     cache_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
 
     def _cache_files():

@@ -415,7 +415,7 @@ def prefix_mix_bench(args, on_tpu):
             cache.hits = cache.misses = cache.tokens_saved = 0
             cache.insertions = cache.evictions = 0
         telemetry.reset()
-        telemetry.configure(enabled=True, sample_sync=False,
+        telemetry.configure(enabled=True,
                             chrome_trace_path=os.environ.get(
                                 "DS_TPU_TELEMETRY_TRACE", ""))
         tm = telemetry.get_telemetry()
@@ -533,7 +533,7 @@ def speculate_bench(args, on_tpu):
         sched.accepted_tokens = 0
         sched.rejected_tokens = 0
         telemetry.reset()
-        telemetry.configure(enabled=True, sample_sync=False,
+        telemetry.configure(enabled=True,
                             chrome_trace_path=os.environ.get(
                                 "DS_TPU_TELEMETRY_TRACE", ""))
         walls = []
@@ -691,7 +691,7 @@ def long_context_bench(args, on_tpu):
         cache.hits = cache.misses = cache.tokens_saved = 0
         cache.insertions = cache.evictions = 0
         telemetry.reset()
-        telemetry.configure(enabled=True, sample_sync=False,
+        telemetry.configure(enabled=True,
                             chrome_trace_path=os.environ.get(
                                 "DS_TPU_TELEMETRY_TRACE", ""))
         t0 = time.perf_counter()
@@ -905,7 +905,7 @@ def fleet_replay_bench(args, on_tpu):
         print(f"fleet[{label}]: warmup/compile {time.perf_counter()-t0:.1f}s",
               file=sys.stderr)
         telemetry.reset()
-        telemetry.configure(enabled=True, sample_sync=False,
+        telemetry.configure(enabled=True,
                             chrome_trace_path=os.environ.get(
                                 "DS_TPU_TELEMETRY_TRACE", ""))
         tm = telemetry.get_telemetry()
@@ -1338,7 +1338,7 @@ def chaos_replay_bench(args, on_tpu):
                              down_idle_rounds=30, cooldown_rounds=15)
 
     telemetry.reset()
-    telemetry.configure(enabled=True, sample_sync=False,
+    telemetry.configure(enabled=True,
                         chrome_trace_path=os.environ.get(
                             "DS_TPU_TELEMETRY_TRACE", ""))
     tm = telemetry.get_telemetry()
@@ -1505,7 +1505,7 @@ def replay_bench(args, on_tpu):
     # (re)start it clean after warmup so compile never pollutes TTFT — even
     # when DS_TPU_TELEMETRY=1 enabled it earlier
     telemetry.reset()
-    telemetry.configure(enabled=True, sample_sync=False,
+    telemetry.configure(enabled=True,
                         chrome_trace_path=os.environ.get(
                             "DS_TPU_TELEMETRY_TRACE", ""))
     tm = telemetry.get_telemetry()
@@ -1676,7 +1676,7 @@ def main():
     # telemetry stream up front; summaries land in each payload's extra
     if os.environ.get("DS_TPU_TELEMETRY") == "1":
         from deepspeed_tpu import telemetry
-        telemetry.configure(enabled=True, sample_sync=False,
+        telemetry.configure(enabled=True,
                             chrome_trace_path=os.environ.get(
                                 "DS_TPU_TELEMETRY_TRACE", ""))
 

@@ -90,7 +90,7 @@ def run_analytic(args):
     from deepspeed_tpu.telemetry import overlap
 
     ndev = min(len(jax.devices()), 8)
-    telemetry.configure(enabled=True, sample_sync=False)
+    telemetry.configure(enabled=True)
     mesh = Mesh(np.array(jax.devices()[:ndev]), ("dp",))
 
     B, D, F = args.batch, args.hidden, args.ffn

@@ -373,7 +373,7 @@ def check_qgz_wire():
     from deepspeed_tpu.runtime.comm.coalesced_collectives import (
         all_to_all_quant_reduce)
 
-    telemetry.configure(enabled=True, sample_sync=False)
+    telemetry.configure(enabled=True)
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("dpr", "dp"))
     grad = jax.ShapeDtypeStruct((8, 8192), jnp.float32)
     fn = jax.shard_map(
@@ -438,7 +438,7 @@ def check_moe_wire():
     from deepspeed_tpu.runtime.comm.coalesced_collectives import (
         moe_hierarchical_a2a)
 
-    telemetry.configure(enabled=True, sample_sync=False)
+    telemetry.configure(enabled=True)
     mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2), ("dpr", "ep"))
     # [inter, intra, rows, d_model] per-peer token slabs
     tok = jax.ShapeDtypeStruct((4, 2, 16, 2048), jnp.float32)

@@ -60,7 +60,7 @@ def main():
 
     telemetry_on = os.environ.get("DS_TPU_TELEMETRY") == "1"
     if telemetry_on:
-        telemetry.configure(enabled=True, sample_sync=False,
+        telemetry.configure(enabled=True,
                             chrome_trace_path=os.environ.get(
                                 "DS_TPU_TELEMETRY_TRACE", ""))
 

@@ -16,10 +16,16 @@ import chip_smoke  # noqa: E402
 
 @pytest.fixture
 def smoke_env(monkeypatch):
-    """Interpret-mode kernels + the dispatch records the checks read."""
+    """Interpret-mode kernels + the dispatch records the checks read; the
+    process-wide fallback notes start empty (another test file of the same
+    worker may have left some)."""
     from deepspeed_tpu import telemetry
+    from deepspeed_tpu.inference.v2.modules import heuristics
+    from deepspeed_tpu.ops import flash_attention as fa
     monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
-    telemetry.configure(enabled=True, sample_sync=False)
+    monkeypatch.setattr(heuristics, "_warned", set())
+    monkeypatch.setattr(fa, "_warned_shapes", set())
+    telemetry.configure(enabled=True)
     yield
     telemetry.configure(enabled=False)
     telemetry.reset()

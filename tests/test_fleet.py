@@ -29,13 +29,11 @@ pytestmark = pytest.mark.skipif(
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
     yield
     telemetry.close()
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
 
 
 @pytest.fixture(scope="module")
@@ -289,8 +287,7 @@ def test_fleet_telemetry_stream(served):
     event counts, queue/shed gauges, and handoff page/byte/latency totals
     with pages shipped == pages bound."""
     cfg, model, params = served
-    telemetry.configure(enabled=True, sample_sync=False,
-                        jax_annotations=False)
+    telemetry.configure(enabled=True)
     fleet = make_fleet(model, params)
     router = SLORouter(fleet, slo_ttft_s=60.0, prefix_affinity=False)
     requests = _requests(cfg, n=3, seed=23)

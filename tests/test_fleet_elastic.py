@@ -40,14 +40,12 @@ pytestmark = pytest.mark.skipif(
 def _clean_state():
     faults.reset()
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
     yield
     faults.reset()
     telemetry.close()
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
 
 
 @pytest.fixture(scope="module")
@@ -450,8 +448,7 @@ def test_shed_precedence_batch_absorbs_while_interactive_burns(served):
     untagged arrivals shed immediately (typed, per-class accounted) while
     interactive arrivals keep admitting — the precedence never reverses."""
     cfg, model, params = served
-    telemetry.configure(enabled=True, sample_sync=False,
-                        jax_annotations=False)
+    telemetry.configure(enabled=True)
     telemetry.set_slo_classes(SLO_CLASSES)
     # 5 violations in 15 observations = rate 1/3 against a 0.1 budget:
     # burn rate ~3.3 — the interactive class is burning

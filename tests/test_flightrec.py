@@ -39,14 +39,12 @@ def _clean():
     """Fresh, unconfigured recorder and DISABLED telemetry per test."""
     flightrec.reset()
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
     yield
     flightrec.reset()
     telemetry.close()
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
 
 
 # ---------------------------------------------------------------------------

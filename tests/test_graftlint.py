@@ -418,7 +418,6 @@ def test_telemetry_span_uses_injectable_clock(monkeypatch):
     monkeypatch.setattr(core, "_now", fake_now)
     tm = core.Telemetry()
     tm.enabled = True
-    tm.sample_sync = False
     sp = tm.span("pinned")
     dt = sp.end()
     assert dt == pytest.approx(1.5)  # exactly one tick between begin/end

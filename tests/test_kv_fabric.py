@@ -43,13 +43,11 @@ def _clean_faults():
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
     yield
     telemetry.close()
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
 
 
 # ---------------------------------------------------------------------------
@@ -512,8 +510,7 @@ def test_wire_telemetry_reports_true_wire_bytes(served):
     transport counter and undercuts the device-byte figure."""
     cfg, model, params = served
     prompts = _prefix_requests(cfg)
-    telemetry.configure(enabled=True, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=True, jsonl_path="", chrome_trace_path="")
     fleet, _ = _run_fleet(model, params, prompts)
     agg = telemetry.summary()["fleet"]["handoff"]
     st = fleet.transport.stats()

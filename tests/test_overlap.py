@@ -26,13 +26,11 @@ SCHEMA_PATH = os.path.join(os.path.dirname(os.path.dirname(
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
     yield
     telemetry.close()
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
 
 
 def _dev(*ivs):

@@ -1,0 +1,283 @@
+"""The program's own spans, as a ``jax.profiler`` capture holds them.
+
+``telemetry.span`` opens a ``TraceAnnotation`` named ``ds/<name>`` whether
+telemetry is enabled or not, so a capture on the CPU holds the serving
+round's and the train step's spans with their attributes: what
+``benchmark/program_spans.py`` reads on the chip.
+"""
+
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu import telemetry
+from deepspeed_tpu.inference.v2 import InferenceEngineV2
+from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
+from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+from deepspeed_tpu.models.mistral import MistralForCausalLM, tiny_mistral_config
+from deepspeed_tpu.parallel import groups
+from deepspeed_tpu.parallel.topology import MeshTopology
+
+PHASES = ("compose", "build", "dispatch", "fetch", "retire")
+
+
+@pytest.fixture(autouse=True)
+def _telemetry_off():
+    telemetry.reset()
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
+    yield
+    telemetry.reset()
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
+
+
+def _captured(trace_dir, run):
+    """Run ``run()`` inside a profiler session; the ``ds/`` events of the
+    capture as [(name without the prefix, start_ns, end_ns, attrs)]."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        out = run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            spans += [(e.name[3:], e.start_ns, e.start_ns + e.duration_ns,
+                       dict(e.stats))
+                      for e in line.events if e.name.startswith("ds/")]
+    return sorted(spans, key=lambda s: s[1]), out
+
+
+def _scheduler(max_context=64):
+    cfg = tiny_mistral_config()
+    model = MistralForCausalLM(cfg)
+    ids = np.zeros((1, 8), np.int32)
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": ids})["params"]
+    engine = InferenceEngineV2(model, params, config={
+        "state_manager": {"max_ragged_sequence_count": 4,
+                          "max_ragged_batch_size": 16,
+                          "max_context": max_context, "num_kv_blocks": 48},
+        "kv_cache": {"block_size": 8, "cache_dtype": "fp32"}})
+    return cfg, SplitFuseScheduler(engine)
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """Three requests of different lengths, one arriving late, served to the
+    end inside one capture: (spans, scheduler)."""
+    cfg, sched = _scheduler()
+    rng = np.random.default_rng(3)
+    prompt = lambda n: rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+    sched.submit(7, prompt(5), max_new_tokens=2)
+    sched.run_to_completion()         # compile outside the capture
+
+    def run():
+        sched.submit(11, prompt(21), max_new_tokens=4)
+        sched.submit(12, prompt(9), max_new_tokens=6)
+        for _ in range(3):
+            sched.step()
+        sched.submit(13, prompt(12), max_new_tokens=3)
+        sched.run_to_completion()
+        return sched
+    before = (sched.rounds, sched.real_tokens, sched.padded_slots,
+              sched.prefill_tokens_executed)
+    spans, _ = _captured(tmp_path_factory.mktemp("serve"), run)
+    return spans, sched, before
+
+
+def _named(spans, name):
+    return [s for s in spans if s[0] == name]
+
+
+def test_request_yields_admit_first_token_finish_with_one_uid(served):
+    spans, sched, _ = served
+    for uid, n_new in ((11, 4), (12, 6), (13, 3)):
+        events = [s for s in spans if s[3].get("uid") == uid]
+        assert [s[0] for s in events] == [
+            "serving/admit", "serving/first_token", "serving/finish"]
+        admit, first, finish = events
+        assert admit[3]["prompt_tokens"] == len(sched._requests[uid].prompt)
+        assert admit[3]["waited_us"] >= 0
+        assert admit[3]["round"] <= first[3]["round"] <= finish[3]["round"]
+        assert finish[3]["new_tokens"] == n_new
+        assert finish[3]["reason"] == "done"
+    # uid 13 arrived three rounds after the others were admitted
+    admitted = {s[3]["uid"]: s[3]["round"] for s in _named(spans, "serving/admit")}
+    assert admitted[13] >= admitted[11] + 3
+
+
+def test_phase_spans_lie_inside_their_round_and_carry_it(served):
+    spans, _, _ = served
+    rounds = _named(spans, "serving/round")
+    assert len(rounds) >= 6
+    numbers = [r[3]["round"] for r in rounds]
+    assert numbers == sorted(set(numbers)), "one round number per round"
+    for _, a, b, attrs in rounds:
+        inside = [s for s in spans if a <= s[1] and s[2] <= b
+                  and s[0] != "serving/round"]
+        assert {s[0] for s in inside} >= {"serving/" + p for p in PHASES}
+        assert all(s[3]["round"] == attrs["round"] for s in inside), inside
+    # and no phase span lies outside every round
+    for s in spans:
+        if s[0].startswith("serving/") and s[0] != "serving/round":
+            assert any(a <= s[1] and s[2] <= b for _, a, b, _ in rounds), s
+    # the phases of a round do not overlap, in the order the round runs them
+    for _, a, b, attrs in rounds:
+        order = [next(s for s in spans if s[0] == "serving/" + p
+                      and s[3]["round"] == attrs["round"]) for p in PHASES]
+        assert all(x[2] <= y[1] for x, y in zip(order, order[1:]))
+
+
+def test_build_counts_real_tokens_within_padded_slots(served):
+    spans, _, _ = served
+    builds = _named(spans, "serving/build")
+    assert builds
+    for _, _, _, a in builds:
+        assert a["padded_slots"] == a["seq_bucket"] * a["chunk_bucket"]
+        assert 0 < a["real_tokens"] <= a["padded_slots"]
+        assert a["seqs"] <= a["seq_bucket"]
+        assert a["context_tokens"] >= 0
+    composed = {s[3]["round"]: s[3] for s in _named(spans, "serving/compose")}
+    for _, _, _, a in builds:
+        c = composed[a["round"]]
+        assert c["seqs"] == a["seqs"]
+        assert c["prefill_tokens"] + c["decode_rows"] == a["real_tokens"]
+        assert c["shrunk"] == 0 and c["preempted"] == 0
+
+
+def test_sums_over_spans_equal_the_schedulers_counters(served):
+    spans, sched, before = served
+    builds = _named(spans, "serving/build")
+    total = lambda key: sum(s[3][key] for s in builds)
+    assert sched.rounds - before[0] == len(builds)
+    assert sched.real_tokens - before[1] == total("real_tokens")
+    assert sched.padded_slots - before[2] == total("padded_slots")
+    prefill = sum(s[3]["prefill_tokens"] for s in _named(spans, "serving/compose"))
+    assert sched.prefill_tokens_executed - before[3] == prefill == 21 + 9 + 12
+    retired = _named(spans, "serving/retire")
+    assert sum(s[3]["new_tokens"] for s in retired) == 4 + 6 + 3
+    assert sum(s[3]["finished"] for s in retired) == 3
+    assert 0 < sched.real_tokens <= sched.padded_slots
+
+
+def test_cancel_and_context_roof_mark_finish_with_their_reason(tmp_path):
+    cfg, sched = _scheduler(max_context=16)
+    rng = np.random.default_rng(5)
+    prompt = lambda n: rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+
+    def run():
+        sched.submit(1, prompt(12), max_new_tokens=100)   # hits the roof
+        sched.submit(2, prompt(6), max_new_tokens=100)
+        sched.step()
+        sched.cancel(2)
+        sched.run_to_completion()
+    spans, _ = _captured(tmp_path, run)
+    reasons = {s[3]["uid"]: s[3]["reason"] for s in _named(spans, "serving/finish")}
+    assert reasons == {1: "evicted", 2: "cancelled"}
+    assert dict(sched.drain_terminal()) == {1: "evicted", 2: "cancelled"}
+
+
+def test_train_step_yields_fwd_with_its_parts_inside(tmp_path):
+    model = GPT2LMHeadModel(GPT2Config.tiny(dtype=jnp.float32))
+    groups.reset()
+    devices = jax.devices()[:1]
+    engine = deepspeed_tpu.initialize(
+        model=model, mesh=MeshTopology(dp=1, devices=devices),
+        config={"train_micro_batch_size_per_gpu": 2,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})[0]
+    ids = np.random.default_rng(0).integers(0, 512, (2, 16)).astype(np.int32)
+    batch = {"input_ids": ids, "labels": ids}
+
+    def step():
+        loss = engine(batch)
+        engine.backward(loss)
+        engine.step()
+    step()                            # compile outside the capture
+
+    def run():
+        for _ in range(3):
+            step()
+        engine._host_fetch(jnp.zeros(2), "test/step")
+    spans, _ = _captured(tmp_path, run)
+    groups.reset()
+    fwds = _named(spans, "fwd")
+    assert [s[3]["step"] for s in fwds] == [1, 2, 3]
+    for _, a, b, attrs in fwds:
+        inside = [s for s in spans if a <= s[1] and s[2] <= b and s[0] != "fwd"]
+        assert [s[0] for s in inside] == ["fwd/shard_batch", "fwd/dispatch"]
+        assert all(s[3]["step"] == attrs["step"] for s in inside)
+        assert attrs["fused"] in (0, 1)
+    for name in ("bwd", "step"):
+        assert [s[3]["step"] for s in _named(spans, name)] == [1, 2, 3]
+    assert [s[3]["what"] for s in _named(spans, "host_fetch")] == ["test/step"]
+
+
+def test_spans_without_a_session_or_telemetry_grow_no_state():
+    """1,000 rounds' worth of spans with no profiler session and telemetry
+    disabled: nothing is kept anywhere in the pipeline."""
+    tm = telemetry.get_telemetry()
+    sizes = lambda: {k: len(v) for k, v in vars(tm).items()
+                     if isinstance(v, (list, dict))}
+    before = sizes()
+    for rnd in range(1000):
+        with telemetry.span("serving/round", round=rnd):
+            with telemetry.span("serving/compose", round=rnd) as sp:
+                telemetry.span("serving/admit", uid=rnd, round=rnd,
+                               waited_us=3, prompt_tokens=5).end()
+                sp.set(seqs=1, prefill_tokens=5, decode_rows=0)
+            with telemetry.span("serving/build", round=rnd) as sp:
+                sp.set(real_tokens=5, padded_slots=32)
+            with telemetry.span("serving/dispatch", round=rnd):
+                pass
+            with telemetry.span("serving/fetch", round=rnd, what="ids"):
+                pass
+            sp = telemetry.span_begin("serving/retire", round=rnd)
+            sp.set(new_tokens=1, finished=0)
+            assert sp._tm is None
+            sp.end()
+    assert sizes() == before
+    assert telemetry.summary() == {"enabled": False}
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_no_span_ever_waits_for_the_device(enabled, monkeypatch):
+    def _boom(*a, **k):
+        raise AssertionError("a span must never wait for the device")
+    monkeypatch.setattr(jax, "block_until_ready", _boom)
+    telemetry.configure(enabled=enabled)
+    cfg, sched = _scheduler()
+    sched.submit(1, np.arange(9, dtype=np.int32), max_new_tokens=3)
+    sched.run_to_completion()
+    pending = jnp.ones(4) * 2
+    with telemetry.span("fwd", step=0):
+        pass
+    assert telemetry.span_begin("step", step=0).end(token=pending) >= 0
+    stats = telemetry.get_telemetry().span_stats
+    if enabled:     # the same names in the pipeline's own sinks
+        assert stats["serving/build"][0] == sched.rounds
+        assert stats["serving/admit"][0] == 1
+        assert {"serving/round", "serving/compose", "serving/dispatch",
+                "serving/fetch", "serving/retire", "fwd", "step"} <= set(stats)
+    else:
+        assert stats == {}
+
+
+def test_flash_kernels_carry_their_fixed_names(pallas_interpret):
+    """The scope and the ``name=`` of each ``pallas_call`` reach the lowered
+    program: that name is what a device trace lists the kernel under."""
+    from deepspeed_tpu.ops.flash_attention import mha
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return mha(q, k, v, causal=True).sum()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q) \
+        .as_text(debug_info=True)
+    for name in ("flash_mha_fwd", "flash_mha_bwd_dq", "flash_mha_bwd_dkv"):
+        assert name in text, name

@@ -28,12 +28,10 @@ from tests.simple_model import SimpleModel, random_batches
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
     yield
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
 
 
 # ---------------------------------------------------------------- kernels
@@ -218,7 +216,7 @@ def test_qgz_dcn_wire_ratio_bound(eight_devices):
     the traced record_comm calls."""
     from deepspeed_tpu.runtime.comm.coalesced_collectives import (
         all_to_all_quant_reduce)
-    telemetry.configure(enabled=True, sample_sync=False)
+    telemetry.configure(enabled=True)
     mesh = _mesh2d(eight_devices)
     grad = jax.ShapeDtypeStruct((8, 8192), jnp.float32)
     fn = shard_map(lambda g: all_to_all_quant_reduce(
@@ -240,7 +238,7 @@ def test_qgz_hpz_wire_bytes_telemetry():
     DCN quantized. (The toy model's chunks are smaller than one quant group,
     so padding dominates here — the 0.3x ratio bound lives in
     test_qgz_dcn_wire_ratio_bound and scripts/perf_gate.py at real sizes.)"""
-    telemetry.configure(enabled=True, sample_sync=False)
+    telemetry.configure(enabled=True)
     cfg = dict(_BASE, zero_optimization=dict(
         _Z3, zero_hpz_partition_size=2, zero_quantized_gradients=True,
         zero_quantized_weights=True))

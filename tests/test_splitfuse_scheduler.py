@@ -116,9 +116,9 @@ def test_budget_respected(served):
     orig_fwd = engine._forward_device
     totals = []
 
-    def spy(uids, chunks):
+    def spy(uids, chunks, **kw):
         totals.append(sum(len(c) for c in chunks))
-        return orig_fwd(uids, chunks)
+        return orig_fwd(uids, chunks, **kw)
 
     engine._forward_device = spy
     sched.run_to_completion()

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu import telemetry
-from deepspeed_tpu.telemetry.core import _NULL_SPAN
 
 SCHEMA_PATH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "deepspeed_tpu", "telemetry",
@@ -31,13 +30,11 @@ SCHEMA_PATH = os.path.join(os.path.dirname(os.path.dirname(
 def _clean_telemetry():
     """Each test sees a fresh, DISABLED global pipeline with no sinks."""
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
     yield
     telemetry.close()
     telemetry.reset()
-    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="",
-                        sample_sync=True, jax_annotations=False)
+    telemetry.configure(enabled=False, jsonl_path="", chrome_trace_path="")
 
 
 # ---------------------------------------------------------------------------
@@ -45,9 +42,10 @@ def _clean_telemetry():
 # ---------------------------------------------------------------------------
 
 def test_disabled_noop_fast_path(tmp_path, monkeypatch):
-    """Disabled, every entry point is a constant-time no-op: the SAME null
-    span object comes back every time, no jax sync runs, and no file is
-    touched even when sink paths are configured."""
+    """Disabled, every entry point is a constant-time no-op and a span is
+    only its profiler annotation: it holds no reference to the pipeline and
+    records nothing, no jax sync runs, and no file is touched even when sink
+    paths are configured."""
     jl = tmp_path / "m.jsonl"
     telemetry.configure(jsonl_path=str(jl), chrome_trace_path="")
     assert not telemetry.enabled()
@@ -57,12 +55,14 @@ def test_disabled_noop_fast_path(tmp_path, monkeypatch):
     monkeypatch.setattr(jax, "block_until_ready", _boom)
 
     sp = telemetry.span("fwd", step=1)
-    assert sp is _NULL_SPAN
-    assert telemetry.span("bwd") is sp, "disabled spans share one null object"
-    sp.token = jnp.ones(4)  # absorbed
+    assert sp._tm is None, "a disabled span holds no reference to the pipeline"
+    assert telemetry.span("bwd")._tm is None
     with telemetry.span("scoped"):
         pass
-    assert sp.end(token=jnp.ones(4)) is None
+    assert not sp.end(token=jnp.ones(4)), "a disabled span measures nothing"
+    tm = telemetry.get_telemetry()
+    assert tm.span_stats == {} and tm.trace_events == [], \
+        "disabled spans record nothing"
 
     telemetry.record("loss", 1.0, step=1)
     telemetry.count("steps")
@@ -152,21 +152,22 @@ def test_configure_registers_atexit_once(monkeypatch, tmp_path):
 # spans / metrics / counters
 # ---------------------------------------------------------------------------
 
-def test_span_records_once_and_syncs_token():
+def test_span_records_once_and_never_waits(monkeypatch):
+    def _boom(*a, **k):
+        raise AssertionError("a span never waits for the device")
+    monkeypatch.setattr(jax, "block_until_ready", _boom)
     telemetry.configure(enabled=True)
-    synced = []
     with telemetry.span("fwd", step=3) as sp:
-        sp.token = jnp.ones((4,)) * 2
+        pending = jnp.ones((4,)) * 2
     sp.end()  # second end is a no-op
     s = telemetry.summary()
     assert s["spans"]["fwd"]["count"] == 1
     assert s["spans"]["fwd"]["total_s"] >= 0
     # explicit begin/end pair (the engine idiom for cross-method scopes)
     sp2 = telemetry.span_begin("step")
-    dt = sp2.end(token=jnp.zeros(2))
+    dt = sp2.end(token=pending)  # accepted for old callers, ignored
     assert dt >= 0
     assert telemetry.summary()["spans"]["step"]["count"] == 1
-    del synced
 
 
 def test_counters_accumulate_per_tag():
@@ -326,13 +327,14 @@ def test_telemetry_config_plumbing():
     cfg = DeepSpeedConfig({
         "train_batch_size": 8,
         "telemetry": {"enabled": True, "jsonl_path": "/tmp/x.jsonl",
-                      "sample_sync": False, "jax_annotations": True}})
+                      "memory": False}})
     tc = cfg.telemetry_config
-    assert tc.enabled and not tc.sample_sync and tc.jax_annotations
+    assert tc.enabled and not tc.memory
     assert tc.jsonl_path == "/tmp/x.jsonl"
     # defaults: fully off
     dflt = DeepSpeedConfig({"train_batch_size": 8}).telemetry_config
-    assert not dflt.enabled and dflt.sample_sync and dflt.monitor
+    assert not dflt.enabled and dflt.memory and dflt.monitor
+    assert not hasattr(dflt, "sample_sync") and not hasattr(dflt, "jax_annotations")
 
 
 # ---------------------------------------------------------------------------
