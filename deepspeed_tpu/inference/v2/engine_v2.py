@@ -11,12 +11,14 @@ import dataclasses
 from typing import Iterable, List, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
-from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
+from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
+    RaggedBatchWrapper, dispatch_rows, short_row_tokens)
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -25,6 +27,13 @@ class SchedulingResult:
     """Admission verdict (reference ``scheduling_utils.py``)."""
     success: bool
     reason: str = "ok"
+
+
+class DispatchedRound(list):
+    """What one round left on the device: [(rows, out)] per dispatch, ``rows``
+    indexing the round's uids and ``out`` that dispatch's padded
+    [S-bucket, ...] device array. ``InferenceEngineV2.host_fetch`` lands it
+    as ONE array with the rows in the order the round listed them."""
 
 
 class InferenceEngineV2:
@@ -109,11 +118,12 @@ class InferenceEngineV2:
         bs = self._state.kv_block_size
         self._max_blocks_per_seq = -(-sm.max_context // bs)
         self._host_sync_count = 0
-        # forwards dispatched so far: the ``round`` every span of a serving
+        # rounds dispatched so far (one per ``put*`` call, whatever the
+        # number of forwards it took): the ``round`` every span of a serving
         # round carries (the scheduler reads it before composing)
         self.round = 0
-        # [sequence bucket, chunk bucket] of the last forward's padded batch
-        self.last_batch_shape = (0, 0)
+        # [sequence bucket, chunk bucket] of each dispatch of the last round
+        self.last_batch_shapes = []
         # postmortem-bundle collector (telemetry/flightrec.py): the newest
         # engine's host-side KV pool stats ride every bundle — pure host
         # reads, so collection is safe even from an abnormal path
@@ -142,7 +152,17 @@ class InferenceEngineV2:
         if tm.enabled:
             tm.count("host_sync", what=what)
         with tm.span("serving/fetch", round=self.round - 1, what=what):
-            return np.asarray(value)  # graftlint: allow[GL004] this IS the accounted fetch
+            if not isinstance(value, DispatchedRound):
+                return jax.device_get(value)
+            # the dispatches' padded arrays land as they are, in one
+            # transfer: slicing or concatenating them on the device would
+            # compile a program per row count
+            fetched = jax.device_get([out for _, out in value])
+            n = sum(len(rows) for rows, _ in value)
+            merged = np.empty((n,) + fetched[0].shape[1:], fetched[0].dtype)
+            for (rows, _), out in zip(value, fetched):
+                merged[rows] = out[:len(rows)]
+            return merged
 
     # -- admission control (reference engine_v2.py:158-241) ----------------
     @property
@@ -226,126 +246,140 @@ class InferenceEngineV2:
     def _forward_device(self, batch_uids: List[int],
                         batch_tokens: List[np.ndarray],
                         verify_k: int = None, defer_commit=(), sample=None):
-        """Run one ragged forward; returns the FULL padded [S_max, vocab]
-        logits as a device array (no host transfer).
+        """Run one round's rows through the ragged forward, dispatched by
+        chunk-length class (``dispatch_rows``): the short rows together as
+        [D, 8], each long row alone as [1, C], back to back with the donated
+        pools threaded from one to the next and no fetch in between. Returns
+        a ``DispatchedRound`` of each dispatch's FULL padded [S-bucket, vocab]
+        logits as a device array (no host transfer); ``host_fetch`` lands it
+        as [len(uids), vocab].
 
         ``verify_k``: when set, dispatch the k-token verify forward instead
-        (same trunk, JX005-pinned) and return [S_max, verify_k, vocab]
+        (same trunk, JX005-pinned) and return [S-bucket, verify_k, vocab]
         logits covering the last ``verify_k`` chunk positions per row.
         ``defer_commit``: uids whose prefix-cache block commit is postponed
         (speculating rows — rejected chunk tails must be rolled back before
         any block digest is registered, or a wrong draft would poison the
         shared chain cache; the scheduler calls ``commit_prefix`` after
-        accept/rollback). ``sample``: a callable dispatched on the logits
-        behind the forward (the on-device sampler); its result is returned
-        in the logits' place."""
-        verdict = self.can_schedule(batch_uids, [len(t) for t in batch_tokens])
+        accept/rollback). ``sample``: a callable dispatched on each
+        dispatch's logits and rows behind its forward (the on-device
+        sampler); its result is returned in the logits' place."""
+        lengths = [len(t) for t in batch_tokens]
+        verdict = self.can_schedule(batch_uids, lengths)
         if not verdict.success:
             raise RuntimeError(f"cannot schedule batch: {verdict.reason}")
+        if verify_k is not None and self._verify_forward is None:
+            raise RuntimeError("no verify forward for this model family")
 
         tm = telemetry.get_telemetry()
         rnd = self.round
-        # explicit begin/end, and the host-to-device copies as arguments of
-        # the jitted call: tracing a new batch shape inside ``with`` blocks
-        # cost set-up 0.07 s a shape more on the chip (PERF.md, PR 27)
-        sp = tm.span_begin("serving/build", round=rnd, seqs=len(batch_uids))
         sm = self._config.state_manager
-        wrapper = RaggedBatchWrapper(sm.max_ragged_sequence_count,
-                                     sm.max_ragged_batch_size,
-                                     self._max_blocks_per_seq,
-                                     self._state.kv_cache.trash_block)
-        caching = self._state.prefix_cache is not None
-        real_tokens = context_tokens = 0
-        for uid, toks in zip(batch_uids, batch_tokens):
-            seq = self._state.get_or_create_sequence(uid)
-            self._state.ensure_capacity(seq, len(toks))
-            seq.in_flight_tokens = len(toks)
-            if caching:
-                seq.tokens.extend(int(t) for t in toks)
-            real_tokens += len(toks)
-            if len(toks) == 1:
-                context_tokens += seq.seen_tokens
-            wrapper.insert_sequence(uid, np.asarray(toks, np.int32),
-                                    seq.seen_tokens, seq.kv_blocks)
-        arrays = wrapper.build()
-        seq_bucket, chunk_bucket = self.last_batch_shape = \
-            arrays["tokens"].shape
-        sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
-               real_tokens=real_tokens,
-               padded_slots=seq_bucket * chunk_bucket,
-               context_tokens=context_tokens)
-        sp.end()
-
         kv = self._state.kv_cache
-        # fwd_k/fwd_v are (int8, scale) pairs when kv_dtype="int8" — they
-        # flow through the jitted forwards as pytree leaves
-        sp = tm.span_begin("serving/dispatch", round=rnd)
-        if verify_k is not None:
-            if self._verify_forward is None:
-                raise RuntimeError("no verify forward for this model family")
-            out, k_pool, v_pool = self._verify_forward(
-                self._model_config, self._params, kv.fwd_k, kv.fwd_v,
-                jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
-                jnp.asarray(arrays["seen"]), jnp.asarray(arrays["block_tables"]),
-                int(verify_k))
-        else:
-            out, k_pool, v_pool = self._ragged_forward(
-                self._model_config, self._params, kv.fwd_k, kv.fwd_v,
-                jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
-                jnp.asarray(arrays["seen"]), jnp.asarray(arrays["block_tables"]))
-        kv.update(k_pool, v_pool)
-        if sample is not None:
-            out = sample(out)
-        sp.end()
-        self.round = rnd + 1
+        caching = self._state.prefix_cache is not None
+        parts, self.last_batch_shapes = DispatchedRound(), []
+        for rows, min_seqs in dispatch_rows(lengths,
+                                            short_row_tokens(verify_k)):
+            # explicit begin/end, and the host-to-device copies as arguments
+            # of the jitted call: tracing a new batch shape inside ``with``
+            # blocks cost set-up 0.07 s a shape more on the chip (PERF.md,
+            # PR 27)
+            sp = tm.span_begin("serving/build", round=rnd, seqs=len(rows))
+            wrapper = RaggedBatchWrapper(sm.max_ragged_sequence_count,
+                                         sm.max_ragged_batch_size,
+                                         self._max_blocks_per_seq,
+                                         kv.trash_block)
+            real_tokens = context_tokens = 0
+            for i in rows:
+                uid, toks = batch_uids[i], batch_tokens[i]
+                seq = self._state.get_or_create_sequence(uid)
+                self._state.ensure_capacity(seq, len(toks))
+                seq.in_flight_tokens = len(toks)
+                if caching:
+                    seq.tokens.extend(int(t) for t in toks)
+                real_tokens += len(toks)
+                if len(toks) == 1:
+                    context_tokens += seq.seen_tokens
+                wrapper.insert_sequence(uid, np.asarray(toks, np.int32),
+                                        seq.seen_tokens, seq.kv_blocks)
+            arrays = wrapper.build(min_seqs)
+            seq_bucket, chunk_bucket = arrays["tokens"].shape
+            self.last_batch_shapes.append((seq_bucket, chunk_bucket))
+            sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
+                   real_tokens=real_tokens,
+                   padded_slots=seq_bucket * chunk_bucket,
+                   context_tokens=context_tokens)
+            sp.end()
 
-        for uid in batch_uids:
-            seq = self._state.get_sequence(uid)
-            seq.post_forward()
-            if caching and uid not in defer_commit:
-                # register blocks as they FILL (not at flush) so concurrent
-                # requests sharing a prefix hit as early as possible
-                self._state.commit_cached_blocks(seq)
-        return out
+            # fwd_k/fwd_v are (int8, scale) pairs when kv_dtype="int8" — they
+            # flow through the jitted forwards as pytree leaves
+            sp = tm.span_begin("serving/dispatch", round=rnd)
+            if verify_k is not None:
+                out, k_pool, v_pool = self._verify_forward(
+                    self._model_config, self._params, kv.fwd_k, kv.fwd_v,
+                    jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
+                    jnp.asarray(arrays["seen"]),
+                    jnp.asarray(arrays["block_tables"]), int(verify_k))
+            else:
+                out, k_pool, v_pool = self._ragged_forward(
+                    self._model_config, self._params, kv.fwd_k, kv.fwd_v,
+                    jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
+                    jnp.asarray(arrays["seen"]),
+                    jnp.asarray(arrays["block_tables"]))
+            kv.update(k_pool, v_pool)
+            if sample is not None:
+                out = sample(out, rows)
+            sp.end()
+            parts.append((rows, out))
+
+            for i in rows:
+                seq = self._state.get_sequence(batch_uids[i])
+                seq.post_forward()
+                if caching and batch_uids[i] not in defer_commit:
+                    # register blocks as they FILL (not at flush) so
+                    # concurrent requests sharing a prefix hit as early as
+                    # possible
+                    self._state.commit_cached_blocks(seq)
+        self.round = rnd + 1
+        return parts
 
     @staticmethod
-    def _packed_sampler(sampler, n, temperatures, top_ks, top_ps, seeds,
+    def _packed_sampler(sampler, temperatures, top_ks, top_ps, seeds,
                         positions):
-        """``sampler(logits, fparams, iparams)`` with the five per-row
-        parameter vectors packed into two host arrays of the logits' padded
-        row count, so that the jit fast path moves them — per-dispatch host
-        time, not device math, bounds a fleet stepping several schedulers
-        per round."""
+        """``sample(logits, rows)``: ``sampler(logits, fparams, iparams)``
+        with the five per-row parameter vectors of a dispatch's ``rows``
+        packed into two host arrays of the logits' padded row count, so that
+        the jit fast path moves them — per-dispatch host time, not device
+        math, bounds a fleet stepping several schedulers per round."""
         # arbitrary Python-int seeds (the host sampler accepted any) fold
         # deterministically into the int31 space PRNGKey wants
         seeds = [int(s) & 0x7FFFFFFF for s in seeds]
 
-        def sample(logits):
-            s_max = logits.shape[0]
+        def sample(logits, rows):
+            s_max, n = logits.shape[0], len(rows)
             fparams = np.zeros((2, s_max), np.float32)
-            fparams[0, :n] = temperatures
-            fparams[1, :n] = top_ps
+            fparams[0, :n] = [temperatures[i] for i in rows]
+            fparams[1, :n] = [top_ps[i] for i in rows]
             iparams = np.zeros((3, s_max), np.int32)
-            iparams[0, :n] = top_ks
-            iparams[1, :n] = seeds
-            iparams[2, :n] = positions
+            iparams[0, :n] = [top_ks[i] for i in rows]
+            iparams[1, :n] = [seeds[i] for i in rows]
+            iparams[2, :n] = [positions[i] for i in rows]
             return sampler(logits, fparams, iparams)
         return sample
 
     def put(self, batch_uids: List[int],
             batch_tokens: List[np.ndarray]) -> np.ndarray:
-        """Run one ragged forward; returns [len(uids), vocab] next-token logits."""
-        logits = self._forward_device(batch_uids, batch_tokens)
-        return self.host_fetch(logits[:len(batch_uids)], "serving/logits")
+        """Run one round; returns [len(uids), vocab] next-token logits."""
+        return self.host_fetch(self._forward_device(batch_uids, batch_tokens),
+                               "serving/logits")
 
     def put_sampled_device(self, batch_uids: List[int],
                            batch_tokens: List[np.ndarray],
                            temperatures, top_ks, top_ps, seeds,
                            positions):
         """``put_sampled`` without the final host fetch: returns the
-        [S-bucket] int32 ids as a DEVICE array (rows past ``len(uids)`` are
-        padding — callers read only the first ``len(uids)`` after fetching),
-        leaving the forward + sampler dispatched asynchronously. The
+        round's dispatches as a ``DispatchedRound`` of [S-bucket] int32 ids
+        on the DEVICE, leaving the forwards + samplers dispatched
+        asynchronously; ``host_fetch`` lands it as [len(uids)] ids. The
         two-phase scheduler step (``step_begin``/``step_finish``) uses this
         to keep several replicas' forwards in flight at once — the fleet's
         cross-replica overlap — fetching each result only when retiring
@@ -354,11 +388,11 @@ class InferenceEngineV2:
         # the PADDED [S-bucket] ids come back: a device-side ids[:n] would
         # compile one slice program per distinct live count (n is not
         # bucketed), a cold ~10ms stall every time a request finishes.
-        # Callers fetch with np.asarray and read rows < n on the host.
+        # ``host_fetch`` reads rows < n on the host.
         return self._forward_device(
             batch_uids, batch_tokens, sample=self._packed_sampler(
-                sample_rows_packed, len(batch_uids), temperatures, top_ks,
-                top_ps, seeds, positions))
+                sample_rows_packed, temperatures, top_ks, top_ps, seeds,
+                positions))
 
     def put_sampled(self, batch_uids: List[int],
                     batch_tokens: List[np.ndarray],
@@ -376,7 +410,7 @@ class InferenceEngineV2:
         """
         return self.host_fetch(self.put_sampled_device(
             batch_uids, batch_tokens, temperatures, top_ks, top_ps, seeds,
-            positions), "serving/sampled_ids")[:len(batch_uids)]
+            positions), "serving/sampled_ids")
 
     # -- speculative decode (draft-then-verify) ----------------------------
     @property
@@ -389,16 +423,16 @@ class InferenceEngineV2:
                           batch_tokens: List[np.ndarray],
                           temperatures, top_ks, top_ps, seeds,
                           positions, k_max: int, defer_commit=()):
-        """``put_sampled_device`` for a verify round: one forward through
-        the SAME ragged prefill kernel, but the sampler draws target tokens
-        at the last ``k_max`` chunk positions per row (LAST-aligned: column
+        """``put_sampled_device`` for a verify round: the same dispatches
+        through the SAME ragged prefill kernel, but the sampler draws target
+        tokens at the last ``k_max`` chunk positions per row (LAST-aligned: column
         ``k_max-1`` is each row's ordinary last-token draw). ``positions``
         gives each row's stream position for that FINAL column — column
         ``c`` is then the token plain decode would emit at stream position
-        ``positions[s] - (k_max-1) + c``. Returns PADDED device
-        [S-bucket, k_max] int32 ids (rows past ``len(uids)`` are padding);
-        the scheduler fetches once per round and walks each row's accept
-        prefix on the host.
+        ``positions[s] - (k_max-1) + c``. Returns a ``DispatchedRound`` of
+        PADDED device [S-bucket, k_max] int32 ids; the scheduler fetches once
+        per round (``host_fetch``: [len(uids), k_max]) and walks each row's
+        accept prefix on the host.
 
         ``k_max`` is static (a per-engine pow2 bucket), so one compiled
         verify program serves every round regardless of how many drafts
@@ -409,8 +443,8 @@ class InferenceEngineV2:
         return self._forward_device(
             batch_uids, batch_tokens, verify_k=int(k_max),
             defer_commit=defer_commit, sample=self._packed_sampler(
-                verify_rows_packed, len(batch_uids), temperatures, top_ks,
-                top_ps, seeds, positions))
+                verify_rows_packed, temperatures, top_ks, top_ps, seeds,
+                positions))
 
     def rollback(self, uid: int, n_tokens: int) -> None:
         """Roll ``uid``'s paged cursor back ``n_tokens`` (the rejected tail

@@ -8,9 +8,37 @@ per-sequence metadata (true new-token counts, tokens already in cache, block
 tables). Padding rows/cols are masked inside the model and their KV writes go
 to the trash block, so one compiled program serves any mix of prefill and
 decode — the property the reference gets from ragged kernels.
+
+One rectangle per round would give every row the longest row's width: 64
+decode rows beside one 400-token prompt chunk are 32768 slots. A round is
+therefore dispatched by chunk-length class (``dispatch_rows``): its short
+rows together as ``[D, 8]``, each long row alone as ``[1, C]``.
 """
 
 import numpy as np
+
+#: most new tokens of a short row: decode rows, verify rows of
+#: ``[last] + drafts``, a prompt's last few tokens
+SHORT_ROW_TOKENS = 8
+
+
+def short_row_tokens(verify_k=None):
+    """Most new tokens of a short row in a round that verifies ``verify_k``
+    positions a row (None: a plain round): a verify row stays short
+    whatever the verify width."""
+    return max(SHORT_ROW_TOKENS, verify_k or 0)
+
+
+def dispatch_rows(lengths, short):
+    """The dispatches of a round whose rows carry ``lengths`` new tokens, as
+    [(rows, min_seqs)]: the rows of at most ``short`` tokens together (if
+    any), padded to at least 4 sequences, then each longer row alone and
+    unpadded (``RaggedBatchWrapper.build(min_seqs)``). Only ``[D, short]``
+    and ``[1, C]`` batches follow from it, D and C powers of two, whatever
+    the round mixes."""
+    together = [i for i, n in enumerate(lengths) if n <= short]
+    alone = [([i], 1) for i, n in enumerate(lengths) if n > short]
+    return ([(together, 4)] if together else []) + alone
 
 
 class RaggedBatchWrapper:
@@ -48,19 +76,20 @@ class RaggedBatchWrapper:
     def uids(self):
         return [u for u, _, _, _ in self._rows]
 
-    def build(self):
+    def build(self, min_seqs=4):
         """Pad to the static [S, Q] / [S, MB] device layout.
 
         S and Q are bucketed to the smallest power of two covering the batch
         (min 4 sequences / 8 tokens) to bound recompiles while keeping decode
-        batches cheap.
+        batches cheap; ``dispatch_rows`` says which ``min_seqs`` a dispatch
+        takes.
         """
-        S = 4
+        S = min_seqs
         while S < len(self._rows):
             S *= 2
         S = min(S, self.max_seqs)
         longest = max((len(t) for _, t, _, _ in self._rows), default=1)
-        Q = 8
+        Q = SHORT_ROW_TOKENS
         while Q < longest:
             Q *= 2
         Q = min(Q, self.max_q)

@@ -22,11 +22,13 @@ and at its admission), never per round (pinned by
 tests/test_serving_observability.py).
 
 Whatever ``enabled`` says, each round opens ``telemetry.span``s
-(``serving/round`` > ``serving/compose``, the engine's ``serving/build`` /
-``dispatch`` / ``fetch``, ``serving/retire``) and marks each request's
-``serving/admit`` / ``first_token`` / ``finish``: profiler annotations that
-cost about a microsecond with no profiler session and never sync. All carry
-the engine's ``round``. docs/OBSERVABILITY.md lists their attributes.
+(``serving/round`` > ``serving/compose``, the engine's ``serving/build`` +
+``dispatch`` once per dispatch of the round, its one ``fetch``,
+``serving/retire``) and marks each request's ``serving/admit`` /
+``first_token`` / ``finish``: profiler annotations that cost about a
+microsecond with no profiler session and never sync. All carry the engine's
+``round``, one value per ``step()``. docs/OBSERVABILITY.md lists their
+attributes.
 """
 
 import dataclasses
@@ -36,6 +38,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from deepspeed_tpu import telemetry
+from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import short_row_tokens
 
 # module-level alias so tests can prove the disabled path never reads the
 # clock (monkeypatching time.perf_counter itself would break jax internals)
@@ -120,10 +123,12 @@ class SplitFuseScheduler:
         # without telemetry
         self.prefill_tokens_executed = 0
         self.prefill_tokens_saved = 0
-        # batch occupancy without a profile: rounds dispatched, the tokens
-        # they carried and the [sequence bucket x chunk bucket] slots the
-        # engine padded them to (the sums of the ``serving/build`` spans)
+        # batch occupancy without a profile: rounds, the dispatches they
+        # took (short rows together, each long row alone), the tokens they
+        # carried and the [sequence bucket x chunk bucket] slots the engine
+        # padded them to (the sums of the ``serving/build`` spans)
         self.rounds = 0
+        self.dispatches = 0
         self.real_tokens = 0
         self.padded_slots = 0
         # device_sampling=True (default) fuses temperature/top-k/top-p and
@@ -697,8 +702,10 @@ class SplitFuseScheduler:
                             tm.record_request_phase(uid, "queued",
                                                     r.submit_ts, waited)
                         tm.record_request_flow(uid, "prefill", tokens=n)
+            short = short_row_tokens(self._kmax)
             sp.set(seqs=len(uids), prefill_tokens=prefill_tokens,
                    decode_rows=len(uids) - sum(was_prefilling),
+                   long_rows=sum(len(c) > short for c in chunks),
                    shrunk=shrunk, preempted=preempted)
         if not uids:
             return None
@@ -736,10 +743,11 @@ class SplitFuseScheduler:
         else:
             logits = self._engine.put(uids, chunks)
             ids = None
-        seq_bucket, chunk_bucket = self._engine.last_batch_shape
+        shapes = self._engine.last_batch_shapes
         self.rounds += 1
+        self.dispatches += len(shapes)
         self.real_tokens += sched_tokens
-        self.padded_slots += seq_bucket * chunk_bucket
+        self.padded_slots += sum(s * q for s, q in shapes)
         return (uids, chunks, ids, logits, t_fwd, was_prefilling,
                 sched_tokens, rnd)
 

@@ -170,3 +170,41 @@ def test_host_sync_counter_in_telemetry(tmp_path):
         assert any("get_lr" in tag for tag in counters["host_sync"])
     finally:
         telemetry.configure(enabled=False)
+
+
+@pytest.mark.parametrize("device_sampling", [True, False])
+def test_serving_round_is_one_fetch_whatever_its_dispatches(
+        counted_device_get, device_sampling):
+    """A serving round costs ONE accounted fetch (one ``device_get`` of its
+    dispatches' results as a list), whether it took one dispatch (decode
+    rows only) or several (decode rows together, each prompt chunk alone)."""
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    cfg = LlamaConfig.tiny(scan_layers=True, remat=False)
+    model = LlamaForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        {"input_ids": np.zeros((1, 8), np.int32)})["params"]
+    engine = InferenceEngineV2(model, params, config={
+        "state_manager": {"max_ragged_sequence_count": 8,
+                          "max_ragged_batch_size": 64,
+                          "max_context": 64, "num_kv_blocks": 48},
+        "kv_cache": {"block_size": 8, "cache_dtype": "fp32"}})
+    sched = SplitFuseScheduler(engine, device_sampling=device_sampling)
+    rng = np.random.default_rng(7)
+    submit = lambda uid, n: sched.submit(
+        uid, rng.integers(0, cfg.vocab_size, n).astype(np.int32),
+        max_new_tokens=6)
+    submit(0, 5)
+    seen = set()
+    for arrivals in ([], [(1, 20)], [(2, 12), (3, 30)], [(4, 3)], [], []):
+        for uid, n in arrivals:
+            submit(uid, n)
+        syncs, gets = engine.host_sync_count, counted_device_get.calls
+        sched.step()
+        assert engine.host_sync_count == syncs + 1
+        assert counted_device_get.calls == gets + 1
+        seen.add(len(engine.last_batch_shapes))
+    assert seen == {1, 2, 3}, "rounds of one, two and three dispatches"
+    assert (sched.rounds, sched.dispatches) == (6, 1 + 2 + 3 + 1 + 1 + 1)

@@ -87,7 +87,7 @@ def served(tmp_path_factory):
         sched.run_to_completion()
         return sched
     before = (sched.rounds, sched.real_tokens, sched.padded_slots,
-              sched.prefill_tokens_executed)
+              sched.prefill_tokens_executed, sched.dispatches)
     spans, _ = _captured(tmp_path_factory.mktemp("serve"), run)
     return spans, sched, before
 
@@ -118,7 +118,8 @@ def test_phase_spans_lie_inside_their_round_and_carry_it(served):
     rounds = _named(spans, "serving/round")
     assert len(rounds) >= 6
     numbers = [r[3]["round"] for r in rounds]
-    assert numbers == sorted(set(numbers)), "one round number per round"
+    assert numbers == list(range(numbers[0], numbers[0] + len(rounds))), \
+        "one round number per step(), whatever the number of dispatches"
     for _, a, b, attrs in rounds:
         inside = [s for s in spans if a <= s[1] and s[2] <= b
                   and s[0] != "serving/round"]
@@ -128,11 +129,19 @@ def test_phase_spans_lie_inside_their_round_and_carry_it(served):
     for s in spans:
         if s[0].startswith("serving/") and s[0] != "serving/round":
             assert any(a <= s[1] and s[2] <= b for _, a, b, _ in rounds), s
-    # the phases of a round do not overlap, in the order the round runs them
+    # the phases of a round do not overlap, in the order the round runs
+    # them: compose, a build and a dispatch per dispatch, ONE fetch, retire
     for _, a, b, attrs in rounds:
-        order = [next(s for s in spans if s[0] == "serving/" + p
-                      and s[3]["round"] == attrs["round"]) for p in PHASES]
+        order = [s for s in spans if s[0] != "serving/round"
+                 and s[0][8:] in PHASES and s[3]["round"] == attrs["round"]]
         assert all(x[2] <= y[1] for x, y in zip(order, order[1:]))
+        names = [s[0][8:] for s in order]
+        k = names.count("build")
+        assert names == ["compose"] + ["build", "dispatch"] * k + \
+            ["fetch", "retire"], names
+    assert any(len([s for s in _named(spans, "serving/build")
+                    if s[3]["round"] == n]) > 1 for n in numbers), \
+        "no round of this run took more than one dispatch"
 
 
 def test_build_counts_real_tokens_within_padded_slots(served):
@@ -144,11 +153,17 @@ def test_build_counts_real_tokens_within_padded_slots(served):
         assert 0 < a["real_tokens"] <= a["padded_slots"]
         assert a["seqs"] <= a["seq_bucket"]
         assert a["context_tokens"] >= 0
-    composed = {s[3]["round"]: s[3] for s in _named(spans, "serving/compose")}
-    for _, _, _, a in builds:
-        c = composed[a["round"]]
-        assert c["seqs"] == a["seqs"]
-        assert c["prefill_tokens"] + c["decode_rows"] == a["real_tokens"]
+    # a round's dispatches together carry what the round composed: the
+    # short rows in one [D, 8] batch, each long row alone in a [1, C] one
+    for _, _, _, c in _named(spans, "serving/compose"):
+        mine = [a for _, _, _, a in builds if a["round"] == c["round"]]
+        total = lambda key: sum(a[key] for a in mine)
+        assert c["seqs"] == total("seqs")
+        assert c["prefill_tokens"] + c["decode_rows"] == total("real_tokens")
+        alone = [a for a in mine if a["seq_bucket"] == 1]
+        assert c["long_rows"] == len(alone) == len(mine) - (c["seqs"] > len(alone))
+        assert all(a["seqs"] == 1 and a["real_tokens"] > 8 for a in alone)
+        assert all(a["chunk_bucket"] == 8 for a in mine if a["seq_bucket"] > 1)
         assert c["shrunk"] == 0 and c["preempted"] == 0
 
 
@@ -156,7 +171,8 @@ def test_sums_over_spans_equal_the_schedulers_counters(served):
     spans, sched, before = served
     builds = _named(spans, "serving/build")
     total = lambda key: sum(s[3][key] for s in builds)
-    assert sched.rounds - before[0] == len(builds)
+    assert sched.rounds - before[0] == len({s[3]["round"] for s in builds})
+    assert sched.dispatches - before[4] == len(builds) > sched.rounds - before[0]
     assert sched.real_tokens - before[1] == total("real_tokens")
     assert sched.padded_slots - before[2] == total("padded_slots")
     prefill = sum(s[3]["prefill_tokens"] for s in _named(spans, "serving/compose"))
@@ -231,7 +247,7 @@ def test_spans_without_a_session_or_telemetry_grow_no_state():
             with telemetry.span("serving/compose", round=rnd) as sp:
                 telemetry.span("serving/admit", uid=rnd, round=rnd,
                                waited_us=3, prompt_tokens=5).end()
-                sp.set(seqs=1, prefill_tokens=5, decode_rows=0)
+                sp.set(seqs=1, prefill_tokens=5, decode_rows=0, long_rows=0)
             with telemetry.span("serving/build", round=rnd) as sp:
                 sp.set(real_tokens=5, padded_slots=32)
             with telemetry.span("serving/dispatch", round=rnd):

@@ -1,0 +1,222 @@
+"""A serving round dispatched by chunk-length class: the short rows (at most
+8 new tokens) together as ``[D, 8]``, each long row alone as ``[1, C]``
+(``ragged_wrapper.dispatch_rows``), where one ``[sequences x chunk]``
+rectangle gave every row the longest row's width.
+
+The layout changes no result: every request emits what it emits when served
+alone, and each dispatch's logits are those of ``ragged_forward`` on the
+whole round padded into one rectangle. It bounds the programs: the shapes a
+mixed run dispatches are those that the benchmark's warm-up calls reach.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference.v2 import InferenceEngineV2
+from deepspeed_tpu.inference.v2.model_implementations.llama import ragged_forward
+from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
+    RaggedBatchWrapper, dispatch_rows, short_row_tokens)
+from deepspeed_tpu.inference.v2.sampling import sample_rows_packed
+from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
+from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+MAX_SEQS, MAX_TOKENS = 8, 32
+# (prompt tokens, new tokens, temperature, top_k, top_p, seed, submitted
+# before round): lengths on both sides of 8 and of the 32-token budget, so
+# that chunks of 9..32 tokens join 0..7 running decodes
+REQUESTS = [(5, 12, 0.0, 0, 1.0, 0, 0), (40, 6, 0.8, 0, 1.0, 11, 0),
+            (17, 9, 0.0, 0, 1.0, 0, 2), (9, 10, 1.1, 20, 0.9, 12, 2),
+            (30, 5, 0.0, 0, 1.0, 0, 4), (3, 8, 0.7, 0, 0.95, 13, 4),
+            (26, 7, 0.0, 0, 1.0, 0, 5), (12, 6, 0.9, 8, 1.0, 14, 7),
+            (64, 4, 0.0, 0, 1.0, 0, 9)]
+
+
+@pytest.fixture(scope="module")
+def served():
+    cfg = LlamaConfig.tiny(scan_layers=True, remat=False)
+    model = LlamaForCausalLM(cfg)
+    ids = np.zeros((1, 8), np.int32)
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": ids})["params"]
+    rng = np.random.default_rng(28)
+    prompts = [rng.integers(0, cfg.vocab_size, r[0]).astype(np.int32)
+               for r in REQUESTS]
+    return cfg, model, params, prompts
+
+
+def _engine(model, params):
+    return InferenceEngineV2(model, params, config={
+        "state_manager": {"max_ragged_sequence_count": MAX_SEQS,
+                          "max_ragged_batch_size": MAX_TOKENS,
+                          "max_context": 128, "num_kv_blocks": 96},
+        "kv_cache": {"block_size": 8, "cache_dtype": "fp32"}})
+
+
+def _submit(sched, uid, prompt):
+    _, n_new, temperature, top_k, top_p, seed, _ = REQUESTS[uid]
+    sched.submit(uid, prompt, max_new_tokens=n_new, temperature=temperature,
+                 top_k=top_k, top_p=top_p, seed=seed)
+
+
+def _buckets(lo, hi):
+    out, x = [], lo
+    while x < hi:
+        out.append(x)
+        x *= 2
+    return out + [hi]
+
+
+def _warm_pass(engine):
+    """The row recipe of the benchmark's warm-up (``_warm_shapes`` of
+    benchmark/drivers/serve.py, copied): for every (s, q) one row of
+    ``min(q, budget - (s - 1))`` tokens and s - 1 rows of one. Returns the
+    set of batch shapes it dispatched."""
+    shapes = set()
+    for s in _buckets(4, MAX_SEQS):
+        for q in _buckets(8, MAX_TOKENS):
+            longest = min(q, MAX_TOKENS - (s - 1))
+            if longest <= q // 2 and q > 8:
+                continue
+            uids = list(range(900_000, 900_000 + s))
+            toks = [np.zeros(longest, np.int32)] + [np.zeros(1, np.int32)] * (s - 1)
+            engine.put_sampled(uids, toks, temperatures=[0.0] * s, top_ks=[0] * s,
+                               top_ps=[1.0] * s, seeds=[0] * s, positions=[0] * s)
+            for u in uids:
+                engine.flush(u)
+            shapes.update(engine.last_batch_shapes)
+    return shapes
+
+
+class _Spy:
+    """Stands in for the engine's ragged forward: keeps each dispatch's
+    arrays and logits, and a copy of the pools as they were when the round
+    began (the forward donates them)."""
+
+    def __init__(self, engine):
+        self.engine, self.rounds = engine, {}
+
+    def __call__(self, cfg, params, k_pool, v_pool, tokens, q_len, seen, tables):
+        rnd = self.rounds.setdefault(self.engine.round, {"dispatches": []})
+        if not rnd["dispatches"]:
+            rnd["pools"] = jax.tree.map(jnp.copy, (k_pool, v_pool))
+        out = ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen, tables)
+        rnd["dispatches"].append(tuple(np.asarray(a) for a in
+                                       (tokens, q_len, seen, tables, out[0])))
+        return out
+
+
+@pytest.fixture(scope="module")
+def mixed_run(served):
+    """The requests served together, arriving while others decode: (engine,
+    scheduler, spy, shapes of the warm pass, shapes of the run, programs
+    compiled by the run)."""
+    cfg, model, params, prompts = served
+    engine = _engine(model, params)
+    warm = _warm_pass(engine)
+    compiled = lambda: (ragged_forward._cache_size(), sample_rows_packed._cache_size())
+    before = compiled()
+    spy = engine._ragged_forward = _Spy(engine)
+    sched = SplitFuseScheduler(engine)
+    shapes, rnd = set(), 0
+    while rnd == 0 or sched.has_work:
+        for uid, r in enumerate(REQUESTS):
+            if r[6] == rnd:
+                _submit(sched, uid, prompts[uid])
+        sched.step()
+        shapes.update(engine.last_batch_shapes)
+        rnd += 1
+    assert rnd > max(r[6] for r in REQUESTS)
+    new_programs = tuple(b - a for a, b in zip(before, compiled()))
+    return engine, sched, spy, warm, shapes, new_programs
+
+
+def test_every_request_emits_what_it_emits_alone(served, mixed_run):
+    cfg, model, params, prompts = served
+    together = mixed_run[1].results()
+    engine = _engine(model, params)
+    for uid, r in enumerate(REQUESTS):
+        sched = SplitFuseScheduler(engine)
+        _submit(sched, uid, prompts[uid])
+        alone = sched.run_to_completion()[uid]
+        assert len(alone) == r[1]
+        assert together[uid].tolist() == alone.tolist(), uid
+
+
+def test_each_dispatch_matches_the_round_as_one_rectangle(mixed_run):
+    """``ragged_forward`` called directly on the round's rows padded into one
+    [sequence bucket, chunk bucket] rectangle, as ``build()`` lays out any
+    rows it is given, over the pools as the round found them."""
+    engine, _, spy, _, _, _ = mixed_run
+    sm = engine._config.state_manager
+    split = compared = 0
+    for rnd in spy.rounds.values():
+        wrapper = RaggedBatchWrapper(sm.max_ragged_sequence_count,
+                                     sm.max_ragged_batch_size,
+                                     engine._max_blocks_per_seq,
+                                     engine._state.kv_cache.trash_block)
+        expected = []
+        for tokens, q_len, seen, tables, logits in rnd["dispatches"]:
+            for i in np.flatnonzero(q_len):
+                wrapper.insert_sequence(len(expected), tokens[i, :q_len[i]],
+                                        int(seen[i]), tables[i])
+                expected.append(logits[i])
+        rect = wrapper.build()
+        assert rect["tokens"].shape[0] >= 4, "the rectangle pads to 4 rows"
+        out, _, _ = ragged_forward(
+            engine._model_config, engine._params, *rnd["pools"],
+            *(jnp.asarray(rect[k]) for k in ("tokens", "q_len", "seen", "block_tables")))
+        np.testing.assert_allclose(np.asarray(out)[:len(expected)], np.stack(expected),
+                                   rtol=2e-4, atol=2e-4)
+        split += len(rnd["dispatches"]) > 1
+        compared += len(expected)
+    assert split >= 5 and compared >= 50, (split, compared)
+
+
+def test_dispatched_shapes_are_the_ones_warm_up_reaches(mixed_run):
+    _, sched, _, warm, shapes, new_programs = mixed_run
+    family = {(d, 8) for d in _buckets(4, MAX_SEQS)} | \
+        {(1, c) for c in _buckets(16, MAX_TOKENS)}
+    assert warm == family
+    assert shapes == warm, "the run is meant to reach every shape"
+    assert new_programs == (0, 0), "the run compiled what the warm pass had not"
+    assert sched.dispatches > sched.rounds
+    assert 0 < sched.real_tokens <= sched.padded_slots
+
+
+@pytest.mark.parametrize("lengths, short, expected", [
+    ([1, 1, 1], 8, [([0, 1, 2], 4)]),
+    ([1, 300, 1, 8], 8, [([0, 2, 3], 4), ([1], 1)]),
+    ([9, 1, 200], 8, [([1], 4), ([0], 1), ([2], 1)]),
+    ([16], 8, [([0], 1)]),
+    ([5, 16, 17], 16, [([0, 1], 4), ([2], 1)]),
+    ([], 8, []),
+])
+def test_dispatch_rows_by_class(lengths, short, expected):
+    assert dispatch_rows(lengths, short) == expected
+
+
+def test_a_verify_row_is_short_whatever_the_verify_width():
+    assert short_row_tokens() == short_row_tokens(4) == short_row_tokens(8) == 8
+    assert short_row_tokens(16) == 16
+
+
+def test_put_returns_rows_in_the_order_given(served):
+    """``put`` (host logits) over a round of three dispatches: every row's
+    logits are those of that row put alone, in the order given."""
+    cfg, model, params, prompts = served
+    engine = _engine(model, params)
+    uids = [0, 1, 2, 3, 4]
+    toks = [prompts[4][:12], prompts[0][:3], prompts[1][:9], prompts[2][:1],
+            prompts[3][:7]]
+    base = engine.host_sync_count
+    together = engine.put(uids, toks)
+    assert engine.host_sync_count == base + 1
+    assert sorted(engine.last_batch_shapes) == [(1, 16), (1, 16), (4, 8)]
+    assert together.shape == (5, cfg.vocab_size)
+    for u in uids:
+        engine.flush(u)
+    for u, t in zip(uids, toks):
+        alone = engine.put([u], [t])
+        engine.flush(u)
+        np.testing.assert_allclose(together[u], alone[0], rtol=2e-4, atol=2e-4)
