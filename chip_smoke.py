@@ -496,13 +496,12 @@ def _serve_requests(p, sched, uid0, vocab):
 def _lowered_decode(engine, cfg):
     """The ragged forward lowered at a decode round's shape (4 slots x 8)."""
     import jax.numpy as jnp
-    kv = engine._state.kv_cache
     S, Q = 4, 8
     return engine._ragged_forward.lower(
-        engine._model_config, engine._params, kv.fwd_k, kv.fwd_v,
+        engine._model_config, engine._params, engine._state.cache_view(),
         jnp.zeros((S, Q), jnp.int32), jnp.ones((S,), jnp.int32),
         jnp.zeros((S,), jnp.int32),
-        jnp.zeros((S, engine._max_blocks_per_seq), jnp.int32))
+        {"kv": jnp.zeros((S, engine._max_blocks_per_seq), jnp.int32)})
 
 
 def _serve_once(p, cfg, model, params, probes, kv_dtype):

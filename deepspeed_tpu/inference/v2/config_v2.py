@@ -32,7 +32,6 @@ class DSStateManagerConfig(DeepSpeedConfigModel):
 
 class KVCacheConfig(DeepSpeedConfigModel):
     block_size = 64
-    num_allocation_groups = 1
     cache_dtype = "bf16"
 
 

@@ -2,10 +2,17 @@
 ``build_hf_engine``): HF checkpoint directory in, ragged serving engine out.
 
 Families (reference maps eight policies, :68-129): llama / llama2 / mistral /
-qwen2 route to the scanned llama ragged implementation (qkv-bias and
-sliding-window handled per config), mixtral to the MoE ragged implementation.
+qwen2 / qwen / internlm route to the scanned llama ragged implementation
+(qkv-bias and sliding-window handled per config), mixtral to the MoE ragged
+implementation, falcon / phi (phi-1/2) to the parallel block, opt to its own.
 Weights come through the HF converter (``checkpoint/hf.py``) directly in the
-serving dtype.
+serving dtype. phi4flash (Phi-4-mini-flash-reasoning: Mamba, window and full
+differential attention, gated memory units) is served from an in-tree model
+through ``build_engine``; it has no HF converter, and no prefix cache,
+speculation or page export yet.
+
+A family is three things, resolved here: its ragged forward, its verify
+forward (or None) and its cache groups (``ragged/cache_groups.py``).
 """
 
 import numpy as np
@@ -13,9 +20,21 @@ import numpy as np
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.utils.logging import logger
 
+#: families ``build_hf_engine`` loads from a checkpoint directory
 SUPPORTED_FAMILIES = ("llama", "mistral", "qwen2", "mixtral", "falcon", "phi",
                       "opt", "qwen", "internlm")  # qwen(v1)/internlm load as
                                                   # llama trees (hf.py)
+#: families ``build_engine`` serves from an in-tree model and tree
+SERVED_FAMILIES = SUPPORTED_FAMILIES + ("phi4flash",)
+
+_FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
+                     "ParallelBlockConfig": "falcon",
+                     "OPTConfig": "opt",
+                     "Phi4FlashConfig": "phi4flash"}
+
+
+def _family(model, family):
+    return family or _FAMILY_OF_CONFIG.get(type(model.config).__name__, "llama")
 
 
 def build_hf_engine(path, engine_config=None, dtype=None):
@@ -54,13 +73,12 @@ def build_hf_engine(path, engine_config=None, dtype=None):
 def resolve_forward_fn(model, family=None):
     """The ragged implementation for a model family (the reference's policy
     map, ``engine_factory.py:68-129``)."""
-    if family is None:
-        name = type(model.config).__name__
-        family = {"MixtralConfig": "mixtral",
-                  "ParallelBlockConfig": "falcon",
-                  "OPTConfig": "opt"}.get(name, "llama")
+    family = _family(model, family)
     if family == "mixtral":
         from deepspeed_tpu.inference.v2.model_implementations.mixtral import (
+            ragged_forward)
+    elif family == "phi4flash":
+        from deepspeed_tpu.inference.v2.model_implementations.phi4flash import (
             ragged_forward)
     elif family in ("falcon", "phi"):
         from deepspeed_tpu.inference.v2.model_implementations.parallel_block import (
@@ -78,20 +96,26 @@ def resolve_verify_fn(model, family=None):
     """The k-token verify forward for a model family, or ``None`` when the
     family has no speculative-verify implementation yet (the engine refuses
     speculation rather than silently falling back to a different program)."""
-    if family is None:
-        name = type(model.config).__name__
-        family = {"MixtralConfig": "mixtral",
-                  "ParallelBlockConfig": "falcon",
-                  "OPTConfig": "opt"}.get(name, "llama")
-    if family in ("mixtral", "falcon", "phi", "opt"):
+    if _family(model, family) in ("mixtral", "falcon", "phi", "opt",
+                                  "phi4flash"):
         return None
     from deepspeed_tpu.inference.v2.model_implementations.llama import (
         ragged_forward_verify)
     return ragged_forward_verify
 
 
+def resolve_cache_groups(model):
+    """What the model keeps per sequence between dispatches: its own
+    ``cache_groups(config)`` where the stack is not homogeneous, else the one
+    paged group of K and V in every layer."""
+    from deepspeed_tpu.inference.v2.ragged.cache_groups import homogeneous
+    declared = getattr(model, "cache_groups", None)
+    return declared(model.config) if declared else homogeneous(model.config)
+
+
 def build_engine(model, params, engine_config=None, family=None):
-    """Build a ragged engine from an in-tree flax model + param tree."""
+    """Build a ragged engine from an in-tree model + param tree."""
     return InferenceEngineV2(model, params, engine_config,
                              forward_fn=resolve_forward_fn(model, family),
-                             verify_fn=resolve_verify_fn(model, family))
+                             verify_fn=resolve_verify_fn(model, family),
+                             cache_groups=resolve_cache_groups(model))

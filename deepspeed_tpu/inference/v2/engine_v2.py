@@ -37,16 +37,24 @@ class DispatchedRound(list):
 
 
 class InferenceEngineV2:
-    """Serve a Llama-family model over a paged KV cache.
+    """Serve a model through its ragged forward over the per-sequence state
+    its cache groups declare: the llama family (llama, mistral, qwen2,
+    internlm trees), mixtral, the parallel block (falcon, phi-1/2) and opt
+    over one paged KV cache; phi4flash over full-attention pages, window
+    pages that are freed behind the window, and slots of recurrent state
+    (no prefix cache, speculation or page export for it yet).
 
     Args:
-        model: ``LlamaForCausalLM`` (scan_layers=True) — provides config.
+        model: the in-tree model — provides ``config`` (and, where the
+            stack is not homogeneous, ``cache_groups``).
         params: trained parameter pytree.
         config: ``RaggedInferenceEngineConfig`` or dict.
+        forward_fn, verify_fn, cache_groups: what ``engine_factory``
+            resolved for the family; resolved here when left out.
     """
 
     def __init__(self, model, params, config=None, forward_fn=None,
-                 verify_fn=None):
+                 verify_fn=None, cache_groups=None):
         if not isinstance(config, RaggedInferenceEngineConfig):
             config = RaggedInferenceEngineConfig(config or {})
         self._config = config
@@ -60,6 +68,9 @@ class InferenceEngineV2:
         if verify_fn is None:
             from deepspeed_tpu.inference.v2.engine_factory import resolve_verify_fn
             verify_fn = resolve_verify_fn(model)
+        if cache_groups is None:
+            from deepspeed_tpu.inference.v2.engine_factory import resolve_cache_groups
+            cache_groups = resolve_cache_groups(model)
         if type(cfg).__name__ != "MixtralConfig" and \
                 not getattr(cfg, "scan_layers", True):
             raise ValueError("ragged llama engine requires scan_layers=True params")
@@ -104,16 +115,13 @@ class InferenceEngineV2:
         if pins:
             cfg = _dc.replace(cfg, serve_modules=pins)
             self._model_config = cfg
-        head_dim = getattr(cfg, "head_dim", None) or \
-            cfg.hidden_size // cfg.num_attention_heads
-        kv_heads = getattr(cfg, "num_key_value_heads",
-                           cfg.num_attention_heads)  # OPT has no GQA field
-        self._state = DSStateManager(config, cfg.num_hidden_layers,
-                                     kv_heads, head_dim)
+        self._state = DSStateManager(config, cache_groups)
         # KV host-spill transfers (prefix blocks demoted to the DRAM tier)
         # land through the SAME accounted fetch as logits/sampled ids, so
         # host_sync_count + graftlint audit them like every other boundary
         self._state.kv_cache.set_host_fetch(self.host_fetch)
+        for _, cache in self._state.paged_groups.values():
+            cache.set_host_fetch(self.host_fetch)
         sm = config.state_manager
         bs = self._state.kv_block_size
         self._max_blocks_per_seq = -(-sm.max_context // bs)
@@ -124,6 +132,11 @@ class InferenceEngineV2:
         self.round = 0
         # [sequence bucket, chunk bucket] of each dispatch of the last round
         self.last_batch_shapes = []
+        # of the last round's dispatches, summed (zero for a model of one
+        # paged group): pages its windows freed, slots of state held
+        self.last_window_pages_freed = 0
+        self.last_state_slots = 0
+        self._window_freed_reported = 0
         # postmortem-bundle collector (telemetry/flightrec.py): the newest
         # engine's host-side KV pool stats ride every bundle — pure host
         # reads, so collection is safe even from an abnormal path
@@ -216,6 +229,8 @@ class InferenceEngineV2:
         if sum(lengths) > sm.max_ragged_batch_size:
             return SchedulingResult(False, "too many tokens")
         need, new_seqs = 0, 0
+        further, new_slots = {}, 0
+        has_further = self._state.has_further_groups
         for uid, n in zip(uids, lengths):
             seq = self._state.get_sequence(uid)
             seen = seq.seen_tokens if seq else 0
@@ -230,10 +245,21 @@ class InferenceEngineV2:
             have = seq.cur_allocated_blocks if seq else 0
             need += self._state.blocks_needed_for(seen, have, n,
                                                   self._state.kv_block_size)
+            if has_further:
+                for name, k in self._state.further_blocks_needed(
+                        seq, seen, n).items():
+                    further[name] = further.get(name, 0) + k
+                new_slots += seq is None or seq.slot is None
         if self._state.n_tracked_sequences + new_seqs > sm.max_tracked_sequences:
             return SchedulingResult(False, "too many tracked sequences")
         if need > self.free_blocks:
             return SchedulingResult(False, "not enough KV blocks")
+        for name, k in further.items():
+            if k > self._state.paged_groups[name][1].free_blocks:
+                return SchedulingResult(False, f"not enough {name} blocks")
+        if self._state.slot_group is not None and \
+                new_slots > self._state.free_slots:
+            return SchedulingResult(False, "no free state slot")
         return SchedulingResult(True)
 
     def get_remaining_block_capacity(self, uid: int) -> int:
@@ -277,6 +303,8 @@ class InferenceEngineV2:
         kv = self._state.kv_cache
         caching = self._state.prefix_cache is not None
         parts, self.last_batch_shapes = DispatchedRound(), []
+        further = self._state.has_further_groups
+        self.last_window_pages_freed = self.last_state_slots = 0
         for rows, min_seqs in dispatch_rows(lengths,
                                             short_row_tokens(verify_k)):
             # explicit begin/end, and the host-to-device copies as arguments
@@ -289,6 +317,7 @@ class InferenceEngineV2:
                                          self._max_blocks_per_seq,
                                          kv.trash_block)
             real_tokens = context_tokens = 0
+            seqs = []
             for i in rows:
                 uid, toks = batch_uids[i], batch_tokens[i]
                 seq = self._state.get_or_create_sequence(uid)
@@ -301,8 +330,13 @@ class InferenceEngineV2:
                     context_tokens += seq.seen_tokens
                 wrapper.insert_sequence(uid, np.asarray(toks, np.int32),
                                         seq.seen_tokens, seq.kv_blocks)
+                seqs.append(seq)
             arrays = wrapper.build(min_seqs)
             seq_bucket, chunk_bucket = arrays["tokens"].shape
+            tables = {"kv": arrays["block_tables"]}
+            if further:
+                tables.update(self._state.group_tables(seqs, seq_bucket))
+                self._note_further_groups(sp)
             self.last_batch_shapes.append((seq_bucket, chunk_bucket))
             sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
                    real_tokens=real_tokens,
@@ -313,19 +347,20 @@ class InferenceEngineV2:
             # fwd_k/fwd_v are (int8, scale) pairs when kv_dtype="int8" — they
             # flow through the jitted forwards as pytree leaves
             sp = tm.span_begin("serving/dispatch", round=rnd)
+            # the cache (named groups of pools, donated) is threaded from
+            # one dispatch of the round to the next
+            tables = {name: jnp.asarray(t) for name, t in tables.items()}
             if verify_k is not None:
-                out, k_pool, v_pool = self._verify_forward(
-                    self._model_config, self._params, kv.fwd_k, kv.fwd_v,
+                out, cache = self._verify_forward(
+                    self._model_config, self._params, self._state.cache_view(),
                     jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
-                    jnp.asarray(arrays["seen"]),
-                    jnp.asarray(arrays["block_tables"]), int(verify_k))
+                    jnp.asarray(arrays["seen"]), tables, int(verify_k))
             else:
-                out, k_pool, v_pool = self._ragged_forward(
-                    self._model_config, self._params, kv.fwd_k, kv.fwd_v,
+                out, cache = self._ragged_forward(
+                    self._model_config, self._params, self._state.cache_view(),
                     jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
-                    jnp.asarray(arrays["seen"]),
-                    jnp.asarray(arrays["block_tables"]))
-            kv.update(k_pool, v_pool)
+                    jnp.asarray(arrays["seen"]), tables)
+            self._state.cache_update(cache)
             if sample is not None:
                 out = sample(out, rows)
             sp.end()
@@ -334,6 +369,8 @@ class InferenceEngineV2:
             for i in rows:
                 seq = self._state.get_sequence(batch_uids[i])
                 seq.post_forward()
+                if further:
+                    self._state.retire_window(seq)
                 if caching and batch_uids[i] not in defer_commit:
                     # register blocks as they FILL (not at flush) so
                     # concurrent requests sharing a prefix hit as early as
@@ -341,6 +378,26 @@ class InferenceEngineV2:
                     self._state.commit_cached_blocks(seq)
         self.round = rnd + 1
         return parts
+
+    def _note_further_groups(self, sp):
+        """On a dispatch's ``serving/build`` span, for a model with further
+        cache groups: slots of state and pages held after this dispatch's
+        allocation, and the pages the windows freed since the last dispatch
+        (the previous round's retire)."""
+        census = self._state.census()
+        freed = self._state.window_pages_freed - self._window_freed_reported
+        self._window_freed_reported += freed
+        self.last_window_pages_freed += freed
+        self.last_state_slots += census["state_slots"]
+        sp.set(window_pages_freed=freed, **census)
+
+    def state_slot(self, uid: int):
+        """The slot of recurrent state ``uid`` holds, taken now if it has
+        none (admission: the round that first schedules it has passed
+        ``can_schedule``); None for a model without a slot group."""
+        if self._state.slot_group is None:
+            return None
+        return self._state.take_slot(self._state.get_or_create_sequence(uid))
 
     @staticmethod
     def _packed_sampler(sampler, temperatures, top_ks, top_ps, seeds,
@@ -554,6 +611,11 @@ class InferenceEngineV2:
 
     def blocks_to_resume(self, uid: int) -> int:
         return self._state.blocks_to_resume(uid)
+
+    def further_groups_fit_resume(self, uid: int) -> bool:
+        """``blocks_to_resume`` counts the "kv" group; this answers for the
+        window pages and the slot a preempted sequence took to the host."""
+        return self._state.further_groups_fit_resume(uid)
 
     @property
     def swap_stats(self):

@@ -108,12 +108,13 @@ def _scatter_kv(k_pool, v_pool, k, v, block_tables, seen, q_len, block_size,
 
 
 def _paged_attention(q, k_pool, v_pool, block_tables, seen, block_size,
-                     q_len=None, window=None, prefer=None):
+                     q_len=None, window=None, prefer=None, softmax_scale=None):
     """Grouped-query attention over per-sequence paged KV: the Pallas
     blocked-flash kernel (ops/pallas/paged_attention.py — O(seen) HBM reads)
     when the heuristics layer selects it, dense gather fallback elsewhere.
     ``window``: Mistral-style sliding window. ``prefer``: config pin from
-    the modules registry. q: [S,Q,H,Dh] -> [S,Q,H,Dh]."""
+    the modules registry. ``softmax_scale``: None is ``1/sqrt(Dh)``.
+    q: [S,Q,H,Dh] -> [S,Q,H,Dh]."""
     kp, ks = _pool_parts(k_pool)
     if q_len is not None:
         from deepspeed_tpu.inference.v2.modules.heuristics import (
@@ -122,14 +123,18 @@ def _paged_attention(q, k_pool, v_pool, block_tables, seen, block_size,
                                          preference=prefer)
         if impl == "pallas_paged":
             vp, vs = _pool_parts(v_pool)
-            return fn(q, kp, vp, block_tables, seen, q_len,
-                      k_scale=ks, v_scale=vs, window=window)
+            if softmax_scale is None:
+                return fn(q, kp, vp, block_tables, seen, q_len,
+                          k_scale=ks, v_scale=vs, window=window)
+            return fn(q, kp, vp, block_tables, seen, q_len, k_scale=ks,
+                      v_scale=vs, window=window, softmax_scale=softmax_scale)
     return _paged_attention_dense(q, k_pool, v_pool, block_tables, seen,
-                                  block_size, window=window)
+                                  block_size, window=window,
+                                  softmax_scale=softmax_scale)
 
 
 def _paged_attention_dense(q, k_pool, v_pool, block_tables, seen, block_size,
-                           window=None):
+                           window=None, softmax_scale=None):
     """Pure-XLA reference path (gathers the full table; numerics twin of the
     Pallas kernel — including the fused-dequant int8 path, which it
     reproduces as gather-then-dequantize with broadcast scales)."""
@@ -138,7 +143,7 @@ def _paged_attention_dense(q, k_pool, v_pool, block_tables, seen, block_size,
     S, Q, H, Dh = q.shape
     KV = k_pool.shape[1]
     rep = H // KV
-    scale = 1.0 / (Dh ** 0.5)
+    scale = 1.0 / (Dh ** 0.5) if softmax_scale is None else softmax_scale
     MB = block_tables.shape[1]
 
     def one_seq(q_s, bt_s, seen_s):
@@ -242,25 +247,28 @@ def _ragged_trunk(cfg, params, k_pool, v_pool, tokens, q_len, seen,
     return x, k_pool, v_pool
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
-def ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen,
-                   block_tables):
-    """One ragged forward step.
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
+    """One ragged forward step. ``cache`` and ``tables`` are the state
+    manager's pytrees (``ragged/cache_groups.py``); this family has the one
+    paged group: ``cache["kv"]`` the (K, V) pools, ``tables["kv"]`` the block
+    tables.
 
-    Returns (last-token logits [S, V], new k_pool, new v_pool).
+    Returns (last-token logits [S, V], new cache).
     """
+    k_pool, v_pool = cache["kv"]
     x, k_pool, v_pool = _ragged_trunk(cfg, params, k_pool, v_pool, tokens,
-                                      q_len, seen, block_tables)
+                                      q_len, seen, tables["kv"])
     # logits_gather analog: only the last real token of each sequence
     last = jnp.take_along_axis(
         x, jnp.maximum(q_len - 1, 0)[:, None, None], axis=1)[:, 0]
     logits = last @ params["lm_head"].astype(cfg.dtype).T
-    return logits.astype(jnp.float32), k_pool, v_pool
+    return logits.astype(jnp.float32), {"kv": (k_pool, v_pool)}
 
 
-@functools.partial(jax.jit, static_argnums=(0, 8), donate_argnums=(2, 3))
-def ragged_forward_verify(cfg, params, k_pool, v_pool, tokens, q_len, seen,
-                          block_tables, k_max):
+@functools.partial(jax.jit, static_argnums=(0, 7), donate_argnums=(2,))
+def ragged_forward_verify(cfg, params, cache, tokens, q_len, seen, tables,
+                          k_max):
     """One ragged forward returning per-row logits for the last ``k_max``
     chunk positions instead of just the last token — the verify half of
     draft-then-verify decode. The trunk (embed -> layer scan -> norm) is
@@ -277,10 +285,11 @@ def ragged_forward_verify(cfg, params, k_pool, v_pool, tokens, q_len, seen,
     length) read their last-token logits at column ``k_max-1`` exactly as
     they would read ``ragged_forward``'s output.
 
-    Returns (logits [S, k_max, V] fp32, new k_pool, new v_pool).
+    Returns (logits [S, k_max, V] fp32, new cache).
     """
+    k_pool, v_pool = cache["kv"]
     x, k_pool, v_pool = _ragged_trunk(cfg, params, k_pool, v_pool, tokens,
-                                      q_len, seen, block_tables)
+                                      q_len, seen, tables["kv"])
     # per-column gather + matmul, each fenced to the exact [S, D] @ [D, V]
     # shape the plain forward lowers: XLA would otherwise merge the columns
     # into one batched dot whose different tiling perturbs low-order bits —
@@ -295,4 +304,4 @@ def ragged_forward_verify(cfg, params, k_pool, v_pool, tokens, q_len, seen,
         g = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
         g = jax.lax.optimization_barrier(g)
         cols.append((g @ W).astype(jnp.float32))
-    return jnp.stack(cols, axis=1), k_pool, v_pool
+    return jnp.stack(cols, axis=1), {"kv": (k_pool, v_pool)}

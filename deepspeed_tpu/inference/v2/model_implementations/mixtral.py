@@ -73,10 +73,11 @@ def _moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, force_einsum=False,
                       out_e.astype(jnp.float32)).astype(dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
-def ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen,
-                   block_tables):
-    """One ragged Mixtral forward step -> (last-token logits, new pools)."""
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
+    """One ragged Mixtral forward step -> (last-token logits, new cache);
+    the contract is ``llama.ragged_forward``'s."""
+    (k_pool, v_pool), block_tables = cache["kv"], tables["kv"]
     S, Q = tokens.shape
     H, KV = cfg.num_attention_heads, cfg.num_key_value_heads
     Dh = cfg.hidden_size // H
@@ -124,4 +125,4 @@ def ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen,
     last = jnp.take_along_axis(
         x, jnp.maximum(q_len - 1, 0)[:, None, None], axis=1)[:, 0]
     logits = last @ params["lm_head"].astype(cfg.dtype).T
-    return logits.astype(jnp.float32), k_pool, v_pool
+    return logits.astype(jnp.float32), {"kv": (k_pool, v_pool)}

@@ -20,10 +20,11 @@ from deepspeed_tpu.inference.v2.model_implementations.parallel_block import (
 from deepspeed_tpu.inference.v2.modules.module_registry import module_preference
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
-def ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen,
-                   block_tables):
-    """One ragged OPT forward step -> (last-token logits, new pools)."""
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
+    """One ragged OPT forward step -> (last-token logits, new cache);
+    the contract is ``llama.ragged_forward``'s."""
+    (k_pool, v_pool), block_tables = cache["kv"], tables["kv"]
     S, Q = tokens.shape
     H = cfg.num_attention_heads
     Dh = cfg.hidden_size // H
@@ -74,4 +75,4 @@ def ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen,
     last = jnp.take_along_axis(
         x, jnp.maximum(q_len - 1, 0)[:, None, None], axis=1)[:, 0]
     logits = last @ embed.T  # tied lm_head
-    return logits.astype(jnp.float32), k_pool, v_pool
+    return logits.astype(jnp.float32), {"kv": (k_pool, v_pool)}

@@ -26,10 +26,11 @@ def _layernorm(x, scale, bias, eps):
     return ((x32 - mu) * jax.lax.rsqrt(var + eps) * scale + bias).astype(x.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2, 3))
-def ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen,
-                   block_tables):
-    """One ragged Falcon/Phi forward step -> (last-token logits, new pools)."""
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
+    """One ragged Falcon/Phi forward step -> (last-token logits, new cache);
+    the contract is ``llama.ragged_forward``'s."""
+    (k_pool, v_pool), block_tables = cache["kv"], tables["kv"]
     S, Q = tokens.shape
     H, KV, Dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     bs = _pool_block_size(k_pool)  # [L, NB, KV, bs, Dh] (pair when int8)
@@ -78,4 +79,4 @@ def ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen,
     logits = last @ head.T
     if "lm_head_bias" in params:
         logits = logits + params["lm_head_bias"].astype(cfg.dtype)
-    return logits.astype(jnp.float32), k_pool, v_pool
+    return logits.astype(jnp.float32), {"kv": (k_pool, v_pool)}

@@ -193,6 +193,11 @@ class BlockedKVCache:
         ``host_fetch`` in so the host-sync ratchet sees KV swap traffic."""
         self._fetch = fetch
 
+    def land_arrays(self, arrays, what):
+        """Land a tuple of dispatched device arrays on host through the
+        accounted fetch (the state manager's slot leaves use it too)."""
+        return self._fetch_arrays(arrays, what)
+
     def _fetch_arrays(self, arrays, what):
         """Land a tuple of dispatched device arrays on host."""
         if self._fetch is not None:

@@ -1,7 +1,13 @@
 """Ragged state manager (mirrors reference
 ``deepspeed/inference/v2/ragged/ragged_manager.py:19``): tracks live sequences
-and owns the blocked KV cache."""
+and owns what they keep on the device between dispatches, as the model's
+cache groups declare it (``ragged/cache_groups.py``): the blocked KV cache of
+the ``"kv"`` group, further paged groups whose pages are freed behind a
+window, and a slot group of recurrent state."""
 
+import numpy as np
+
+from deepspeed_tpu.inference.v2.ragged.cache_groups import PagedGroup, SlotGroup
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
 from deepspeed_tpu.inference.v2.ragged.prefix_cache import PrefixCache
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
@@ -10,9 +16,16 @@ from deepspeed_tpu.utils.logging import logger
 
 class DSStateManager:
 
-    def __init__(self, config, num_layers, num_kv_heads, head_dim):
+    def __init__(self, config, groups):
         self._config = config
         sm, kv = config.state_manager, config.kv_cache
+        primary, further = groups[0], groups[1:]
+        if not isinstance(primary, PagedGroup) or primary.name != "kv":
+            raise ValueError("a model's first cache group is the paged "
+                             "group \"kv\"")
+        num_layers, num_kv_heads, head_dim = \
+            primary.layers, primary.kv_heads, primary.head_dim
+        self._init_further_groups(config, further)
         num_blocks = sm.num_kv_blocks
         if num_blocks is None:
             num_blocks = self._blocks_from_memory_budget(
@@ -54,6 +67,184 @@ class DSStateManager:
         logger.info(f"DSStateManager: {num_blocks} KV blocks x {kv.block_size} "
                     f"tokens ({num_layers} layers, {num_kv_heads} kv heads, "
                     f"prefix_caching={'on' if self.prefix_cache else 'off'})")
+        for name, (g, cache) in self.paged_groups.items():
+            logger.info(f"DSStateManager: group {name!r}: {cache.num_blocks} "
+                        f"blocks x {g.layers} layers, window {g.window}")
+        if self.slot_group is not None:
+            logger.info(f"DSStateManager: group {self.slot_group.name!r}: "
+                        f"{self.trash_slot} slots")
+
+    def _init_further_groups(self, config, further):
+        """Paged groups beyond ``"kv"`` (an allocator and pools each) and
+        the slot group (pools and a free list). What the ``"kv"`` group's
+        prefix cache, page wire and speculation rollback would have to carry
+        for them does not exist yet, so those are refused here."""
+        sm, kv = config.state_manager, config.kv_cache
+        self.paged_groups = {}       # name -> (PagedGroup, BlockedKVCache)
+        self.table_width = {}        # name -> entries of a sequence's table
+        self.slot_group = None
+        self.slot_pools = {}
+        self._free_slots = []
+        self.window_pages_freed = 0  # pages the windows gave back, in all
+        if not further:
+            return
+        # a prefix hit or a rolled-back draft would need the window pages
+        # and the recurrent state as they were at that token
+        for on, option in ((getattr(config, "prefix_caching", False), "prefix_caching"),
+                           (config.speculative.enabled, "speculative.enabled"),
+                           (sm.kv_dtype != "fp", "kv_dtype int8")):
+            if on:
+                raise ValueError(f"{option} is not supported for a model with "
+                                 f"more than the one paged cache group")
+        bs = kv.block_size
+        for g in further:
+            if isinstance(g, SlotGroup):
+                if self.slot_group is not None:
+                    raise ValueError("one slot group a model")
+                import jax.numpy as jnp
+                self.slot_group = g
+                n = sm.max_ragged_sequence_count
+                # +1: the trash slot padded rows read and write
+                self.slot_pools = {
+                    name: jnp.zeros((shape[0], n + 1) + tuple(shape[1:]), dtype)
+                    for name, shape, dtype in g.leaves}
+                self._free_slots = list(range(n - 1, -1, -1))
+                continue
+            if g.window:
+                # a sequence holds the window's pages, one more while a page
+                # fills, and its share of a round's new tokens
+                per_seq = -(-g.window // bs) + 2
+                blocks = sm.max_ragged_sequence_count * per_seq \
+                    + -(-sm.max_ragged_batch_size // bs)
+                width = -(-g.window // bs) + -(-sm.max_ragged_batch_size // bs) + 1
+            else:
+                blocks = sm.num_kv_blocks or self._blocks_from_memory_budget(
+                    g.layers, g.kv_heads, g.head_dim, kv)
+                width = -(-sm.max_context // bs)
+            self.paged_groups[g.name] = (g, BlockedKVCache(
+                g.layers, blocks, bs, g.kv_heads, g.head_dim, kv.cache_dtype))
+            self.table_width[g.name] = width
+
+    @property
+    def has_further_groups(self):
+        return bool(self.paged_groups) or self.slot_group is not None
+
+    @property
+    def trash_slot(self):
+        return self._config.state_manager.max_ragged_sequence_count
+
+    @property
+    def free_slots(self):
+        return len(self._free_slots)
+
+    @property
+    def slots_in_use(self):
+        return self.trash_slot - self.free_slots if self.slot_group else 0
+
+    def census(self):
+        """Slots of state and pages held by tracked sequences right now: the
+        ``"kv"`` group's and the further paged groups' together."""
+        held = lambda cache: cache.num_blocks - cache.free_blocks
+        return {"state_slots": self.slots_in_use,
+                "global_pages": held(self.kv_cache),
+                "window_pages": sum(held(c) for _, c in self.paged_groups.values())}
+
+    # -- the cache and tables pytrees of a dispatch -------------------------
+    def cache_view(self):
+        """The donated ``cache`` argument of a forward: ``{"kv": (K, V)}``
+        and, for a model that declared them, the further groups' pools."""
+        kv = self.kv_cache
+        view = {"kv": (kv.fwd_k, kv.fwd_v)}
+        for name, (_, cache) in self.paged_groups.items():
+            view[name] = (cache.fwd_k, cache.fwd_v)
+        if self.slot_group is not None:
+            view[self.slot_group.name] = self.slot_pools
+        return view
+
+    def cache_update(self, view):
+        """Swap in the cache a forward returned."""
+        self.kv_cache.update(*view["kv"])
+        for name, (_, cache) in self.paged_groups.items():
+            cache.update(*view[name])
+        if self.slot_group is not None:
+            self.slot_pools = view[self.slot_group.name]
+
+    def group_tables(self, seqs, n_rows):
+        """The further groups' entries of a dispatch's ``tables`` for the
+        sequences ``seqs`` (its rows in order) padded to ``n_rows``: a paged
+        group's live pages ``[n_rows, width]`` and the token its first page
+        starts at (``<name>_base``), the slot group's slot ids."""
+        out = {}
+        bs = self.kv_block_size
+        for name, (_, cache) in self.paged_groups.items():
+            table = np.full((n_rows, self.table_width[name]),
+                            cache.trash_block, np.int32)
+            base = np.zeros((n_rows,), np.int32)
+            for i, seq in enumerate(seqs):
+                blocks = seq.group_blocks.get(name, ())
+                table[i, :len(blocks)] = blocks
+                base[i] = seq.group_base.get(name, 0) * bs
+            out[name], out[name + "_base"] = table, base
+        if self.slot_group is not None:
+            ids = np.full((n_rows,), self.trash_slot, np.int32)
+            ids[:len(seqs)] = [seq.slot for seq in seqs]
+            out[self.slot_group.name] = ids
+        return out
+
+    def first_live_block(self, group, seen):
+        """Index of the first block a query at position ``seen`` or later
+        can still see in a group of window ``group.window``."""
+        if not group.window:
+            return 0
+        return max(0, (seen - group.window + 1) // self.kv_block_size)
+
+    def further_blocks_needed(self, seq, seen, new_tokens):
+        """{group name: extra pages} to run ``new_tokens`` more tokens of a
+        sequence (``seq`` None: a new one) in the further paged groups."""
+        need = {}
+        end = -(-(seen + new_tokens) // self.kv_block_size)
+        for name, (g, _) in self.paged_groups.items():
+            if seq is None or name not in seq.group_base:
+                held_to = self.first_live_block(g, seen)
+            else:
+                held_to = seq.group_base[name] + len(seq.group_blocks[name])
+            need[name] = max(0, end - held_to)
+        return need
+
+    def take_slot(self, seq):
+        """Give ``seq`` its slot of recurrent state (kept until flush or
+        swap-out). The slot is not cleared: the first chunk's dispatch
+        starts from zero state (its rows have ``seen`` 0)."""
+        if self.slot_group is not None and seq.slot is None:
+            if not self._free_slots:
+                raise RuntimeError("no free state slot")
+            seq.slot = self._free_slots.pop()
+        return seq.slot
+
+    def _release_further(self, seq):
+        for name, (_, cache) in self.paged_groups.items():
+            cache.free(seq.group_blocks.pop(name, []))
+            seq.group_base.pop(name, None)
+        if seq.slot is not None:
+            self._free_slots.append(seq.slot)
+            seq.slot = None
+
+    def retire_window(self, seq):
+        """After a forward: free the pages of ``seq`` that lie wholly before
+        ``seen - window`` in every windowed group. Returns the count."""
+        freed = 0
+        for name, (g, cache) in self.paged_groups.items():
+            if not g.window or name not in seq.group_base:
+                continue
+            drop = self.first_live_block(g, seq.seen_tokens) - seq.group_base[name]
+            if drop > 0:
+                blocks = seq.group_blocks[name]
+                cache.free(blocks[:drop])
+                del blocks[:drop]
+                seq.group_base[name] += drop
+                freed += drop
+        self.window_pages_freed += freed
+        return freed
 
     @staticmethod
     def _blocks_from_memory_budget(num_layers, num_kv_heads, head_dim, kv,
@@ -164,6 +355,20 @@ class DSStateManager:
                  "nvme_kv_demotions": hs.get("nvme_demotions", 0)}
         if self.prefix_cache is not None:
             stats.update(self.prefix_cache.stats())
+        if self.has_further_groups:
+            # occupancy per group; "kv" repeats the device census above
+            groups = {"kv": {"total": total, "free": free,
+                             "occupancy": occupancy}}
+            for name, (_, cache) in self.paged_groups.items():
+                groups[name] = {"total": cache.num_blocks,
+                                "free": cache.free_blocks,
+                                "occupancy": cache.occupancy,
+                                "freed_by_window": self.window_pages_freed}
+            if self.slot_group is not None:
+                groups[self.slot_group.name] = {
+                    "total": self.trash_slot, "free": self.free_slots,
+                    "occupancy": self.slots_in_use / self.trash_slot}
+            stats["groups"] = groups
         return stats
 
     def sample_kv_stats(self, point="step"):
@@ -278,6 +483,9 @@ class DSStateManager:
             raise ValueError(f"rollback of untracked sequence {uid}")
         if n_tokens <= 0:
             return
+        if self.has_further_groups:
+            raise ValueError("rollback is not supported for a model with "
+                             "more than the one paged cache group")
         assert seq.in_flight_tokens == 0, "cannot roll back mid-forward"
         assert not seq.is_swapped, "cannot roll back a swapped sequence"
         bs = self.kv_block_size
@@ -307,6 +515,7 @@ class DSStateManager:
         if seq is None:
             logger.warning(f"flush of untracked sequence {uid}")
             return
+        self._release_further(seq)
         if self.prefix_cache is not None and not seq.is_swapped:
             self.commit_cached_blocks(seq)
             self.kv_cache.free(list(reversed(seq.kv_blocks)))
@@ -347,6 +556,13 @@ class DSStateManager:
         return {uid: self.prefix_cache.held_prefix_len(chain)
                 for uid, chain in chains.items()}
 
+    def _refuse_page_wire(self, what):
+        if self.has_further_groups:
+            raise ValueError(
+                f"page {what} is not supported for a model with more than "
+                f"the one paged cache group: the wire carries \"kv\" pages "
+                f"only, not window pages or recurrent state")
+
     def export_sequence_pages(self, uid):
         """Detach ``uid``'s KV pages for shipping to another engine's pool
         (single-sequence form of ``export_sequences_pages``). Returns a
@@ -371,6 +587,7 @@ class DSStateManager:
         DESTINATION's prefix cache already holds — those rows are excluded
         from the gather and ride as ``skipped_digests`` instead, for the
         importer to re-acquire locally. Requires prefix caching."""
+        self._refuse_page_wire("export")
         for uid in uids:  # validate everything before mutating anything
             seq = self._seqs.get(uid)
             if seq is None:
@@ -432,6 +649,7 @@ class DSStateManager:
         next commit. All-or-nothing: on any failure the partially created
         sequences and all imported blocks are released. Returns the total
         bound block count."""
+        self._refuse_page_wire("import")
         for m in handle["seqs"]:
             if m["uid"] in self._seqs:
                 raise ValueError(f"uid {m['uid']} already tracked")
@@ -496,6 +714,17 @@ class DSStateManager:
         assert seq.in_flight_tokens == 0, "cannot swap a sequence mid-forward"
         seq.swap_handle = self.kv_cache.swap_out(seq.kv_blocks)
         seq.kv_blocks = []
+        # the live window pages and the slot's leaves travel with them
+        for name, (_, cache) in self.paged_groups.items():
+            if name in seq.group_blocks:
+                seq.group_swap[name] = cache.swap_out(seq.group_blocks[name])
+                seq.group_blocks[name] = []
+        if seq.slot is not None:
+            leaves = tuple(pool[:, seq.slot] for pool in self.slot_pools.values())
+            seq.group_swap[self.slot_group.name] = \
+                self.kv_cache.land_arrays(leaves, "kv_cache/swap_out")
+            self._free_slots.append(seq.slot)
+            seq.slot = None
         self.swap_outs += 1
 
     def swap_in_sequence(self, uid):
@@ -505,11 +734,35 @@ class DSStateManager:
             return
         seq.kv_blocks = list(self.kv_cache.swap_in(seq.swap_handle))
         seq.swap_handle = None
+        for name, (_, cache) in self.paged_groups.items():
+            if name in seq.group_swap:
+                seq.group_blocks[name] = list(
+                    cache.swap_in(seq.group_swap.pop(name)))
+        if self.slot_group is not None and self.slot_group.name in seq.group_swap:
+            leaves = seq.group_swap.pop(self.slot_group.name)
+            slot = self.take_slot(seq)
+            self.slot_pools = {
+                name: pool.at[:, slot].set(leaf) for (name, pool), leaf in
+                zip(self.slot_pools.items(), leaves)}
         self.swap_ins += 1
 
     def blocks_to_resume(self, uid):
         seq = self._seqs[uid]
         return seq.swap_handle["n"] if seq.is_swapped else 0
+
+    def further_groups_fit_resume(self, uid):
+        """Whether the further groups have room for what ``uid`` took to the
+        host (``blocks_to_resume`` answers for the "kv" group): its window
+        pages and one more a group, and a slot."""
+        seq = self._seqs[uid]
+        for name, (_, cache) in self.paged_groups.items():
+            if name in seq.group_swap and \
+                    cache.free_blocks < seq.group_swap[name]["n"] + 1:
+                return False
+        if self.slot_group is not None and \
+                self.slot_group.name in seq.group_swap:
+            return bool(self._free_slots)
+        return True
 
     # -- block arithmetic --------------------------------------------------
     def blocks_needed(self, seq, new_tokens):
@@ -521,3 +774,13 @@ class DSStateManager:
         extra = self.blocks_needed(seq, new_tokens)
         if extra:
             seq.extend_blocks(self.kv_cache.reserve(extra))
+        if not self.has_further_groups:
+            return
+        self.take_slot(seq)
+        need = self.further_blocks_needed(seq, seq.seen_tokens, new_tokens)
+        for name, (g, cache) in self.paged_groups.items():
+            if name not in seq.group_base:
+                seq.group_base[name] = self.first_live_block(g, seq.seen_tokens)
+                seq.group_blocks[name] = []
+            if need[name]:
+                seq.group_blocks[name].extend(cache.reserve(need[name]))

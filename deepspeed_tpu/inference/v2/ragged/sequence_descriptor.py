@@ -2,7 +2,7 @@
 ``deepspeed/inference/v2/ragged/sequence_descriptor.py``)."""
 
 import dataclasses
-from typing import List
+from typing import Dict, List, Optional
 
 
 @dataclasses.dataclass
@@ -20,6 +20,15 @@ class DSSequenceDescriptor:
     # tokens[:(i+1)*block_size] and labels kv_blocks[i] in the cache)
     tokens: List[int] = dataclasses.field(default_factory=list)
     digests: List[bytes] = dataclasses.field(default_factory=list)
+    # models with more than the one paged group (ragged/cache_groups.py):
+    # per further paged group the pages held and the index (in blocks from
+    # the sequence's start) of the first of them, pages before it having been
+    # freed behind the window; the slot of recurrent state; and while swapped
+    # out, those groups' host copies
+    group_blocks: Dict[str, List[int]] = dataclasses.field(default_factory=dict)
+    group_base: Dict[str, int] = dataclasses.field(default_factory=dict)
+    slot: Optional[int] = None
+    group_swap: Dict[str, object] = dataclasses.field(default_factory=dict)
 
     @property
     def is_swapped(self) -> bool:

@@ -131,6 +131,12 @@ class SplitFuseScheduler:
         self.dispatches = 0
         self.real_tokens = 0
         self.padded_slots = 0
+        # for a model with further cache groups (ragged/cache_groups.py),
+        # the sums of the same spans' ``window_pages_freed`` and
+        # ``state_slots``: pages the windows gave back, and slots of
+        # recurrent state held, summed over dispatches
+        self.window_pages_freed = 0
+        self.state_slots = 0
         # device_sampling=True (default) fuses temperature/top-k/top-p and
         # the categorical draw into the decode step on the accelerator: the
         # host receives one int32 per sequence instead of a [S, vocab] float
@@ -533,7 +539,8 @@ class SplitFuseScheduler:
                 continue
             grow = state.blocks_needed_for(seq.seen_tokens, need, 1,
                                            state.kv_block_size)
-            if need and self._engine.free_blocks >= need + grow:
+            if need and self._engine.free_blocks >= need + grow and \
+                    self._engine.further_groups_fit_resume(r.uid):
                 self._engine.resume(r.uid)
                 r.preempted = False
                 tm = telemetry.get_telemetry()
@@ -693,9 +700,13 @@ class SplitFuseScheduler:
                     # a re-admitted request may come without its submit time
                     waited = r.first_sched_ts - r.submit_ts \
                         if r.submit_ts else 0.0
-                    tm.span("serving/admit", uid=uid, round=rnd,
-                            waited_us=int(waited * 1e6),
-                            prompt_tokens=len(r.prompt)).end()
+                    admit = dict(uid=uid, round=rnd,
+                                 waited_us=int(waited * 1e6),
+                                 prompt_tokens=len(r.prompt))
+                    slot = self._engine.state_slot(uid)
+                    if slot is not None:
+                        admit["slot"] = slot
+                    tm.span("serving/admit", **admit).end()
                     if enabled:
                         if r.submit_ts:
                             tm.record_hist("serving/queue_wait_s", waited)
@@ -748,6 +759,8 @@ class SplitFuseScheduler:
         self.dispatches += len(shapes)
         self.real_tokens += sched_tokens
         self.padded_slots += sum(s * q for s, q in shapes)
+        self.window_pages_freed += self._engine.last_window_pages_freed
+        self.state_slots += self._engine.last_state_slots
         return (uids, chunks, ids, logits, t_fwd, was_prefilling,
                 sched_tokens, rnd)
 

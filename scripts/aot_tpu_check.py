@@ -260,7 +260,7 @@ def bench_leg_programs():
                 jax.ShapeDtypeStruct((S,), jnp.int32),
                 jax.ShapeDtypeStruct((S, MB), jnp.int32))
         return (lambda p, kp, vp, t, ql, sn, bt: ragged_forward(
-            cfg, p, kp, vp, t, ql, sn, bt)), args
+            cfg, p, {"kv": (kp, vp)}, t, ql, sn, {"kv": bt})), args
 
     def device_sampler():
         from deepspeed_tpu.inference.v2.sampling import sample_rows
@@ -483,7 +483,8 @@ def multichip_programs(topo):
 
         def fn(p, kp, vp, t, ql, sn, bt):
             with use_kernel_mesh(mesh):
-                return ragged_forward(cfg, p, kp, vp, t, ql, sn, bt)
+                return ragged_forward(cfg, p, {"kv": (kp, vp)}, t, ql, sn,
+                                      {"kv": bt})
 
         return fn, abstract, in_shardings
 

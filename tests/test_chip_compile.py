@@ -119,6 +119,42 @@ def test_paged_attention_mistral_geometry(for_tpu, one_chip, seqs, q_tokens,
     _compile(fn, _paged_args(one_chip, seqs, q_tokens, int8))
 
 
+@pytest.mark.parametrize("rows,tokens", [(64, 8), (4, 8), (1, 16), (1, 512)],
+                         ids=["decode64", "decode4", "chunk16", "chunk512"])
+def test_selective_scan_phi4flash_widths(for_tpu, one_chip, rows, tokens):
+    """The scan at Phi-4-mini-flash's d_inner 5120 x d_state 16, at the
+    shapes the engine dispatches: [D, 8] short rows and [1, C] chunks."""
+    from deepspeed_tpu.ops.pallas.selective_scan import selective_scan
+    di, n = 5120, 16
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    f32 = jnp.float32
+    args = (sds((rows, tokens, di), jnp.bfloat16), sds((rows, tokens, di), f32),
+            sds((n, di), f32), sds((rows, tokens, n), f32), sds((rows, tokens, n), f32),
+            sds((di,), f32), sds((rows, n, di), f32), sds((rows,), jnp.int32))
+    _compile(selective_scan, args)
+
+
+@pytest.mark.parametrize("seqs,q_tokens,table", [(64, 8, 256), (1, 512, 17)],
+                         ids=["decode_full_layer", "chunk_window_ring"])
+def test_paged_attention_differential_pairs_geometry(for_tpu, one_chip, seqs,
+                                                     q_tokens, table):
+    """Phi-4-mini-flash's differential attention through the paged kernel:
+    40 zero-padded query heads of 128 over 10 page rows (a PAIR of K or V
+    heads each), scale 1/sqrt(64), the window layers' 17-entry ring."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = sds((640, 10, 64, 128), jnp.bfloat16)
+    args = (sds((seqs, q_tokens, 40, 128), jnp.bfloat16), pool, pool,
+            sds((seqs, table), jnp.int32), sds((seqs,), jnp.int32),
+            sds((seqs,), jnp.int32))
+
+    def fn(q, kp, vp, bt, seen, q_len):
+        return paged_mha(q, kp, vp, bt, seen, q_len, softmax_scale=0.125,
+                         window=512 if table == 17 else None)
+
+    _compile(fn, args)
+
+
 def test_quantized_matmul_4096_wide(for_tpu, one_chip):
     from deepspeed_tpu.ops.pallas.quantized_matmul import (is_supported,
                                                            quantized_matmul)

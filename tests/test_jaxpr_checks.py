@@ -382,9 +382,10 @@ def serving_decode_trace():
     kv = engine._state.kv_cache
     return jax.make_jaxpr(
         partial(engine._ragged_forward, engine._model_config))(
-            engine._params, kv.k_pool, kv.v_pool,
+            engine._params, {"kv": (kv.k_pool, kv.v_pool)},
             jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
-            jnp.asarray(arrays["seen"]), jnp.asarray(arrays["block_tables"]))
+            jnp.asarray(arrays["seen"]),
+            {"kv": jnp.asarray(arrays["block_tables"])})
 
 
 def test_serving_decode_step_is_clean(serving_decode_trace):
@@ -429,9 +430,10 @@ def verify_parity_traces():
                             seq.kv_blocks)
     arrays = wrapper.build()
     kv = engine._state.kv_cache
-    args = (engine._params, kv.k_pool, kv.v_pool,
+    args = (engine._params, {"kv": (kv.k_pool, kv.v_pool)},
             jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
-            jnp.asarray(arrays["seen"]), jnp.asarray(arrays["block_tables"]))
+            jnp.asarray(arrays["seen"]),
+            {"kv": jnp.asarray(arrays["block_tables"])})
     mc = engine._model_config
     plain = jax.make_jaxpr(partial(engine._ragged_forward, mc))(*args)
     verify = jax.make_jaxpr(

@@ -96,13 +96,13 @@ class _Spy:
     def __init__(self, engine):
         self.engine, self.rounds = engine, {}
 
-    def __call__(self, cfg, params, k_pool, v_pool, tokens, q_len, seen, tables):
+    def __call__(self, cfg, params, cache, tokens, q_len, seen, tables):
         rnd = self.rounds.setdefault(self.engine.round, {"dispatches": []})
         if not rnd["dispatches"]:
-            rnd["pools"] = jax.tree.map(jnp.copy, (k_pool, v_pool))
-        out = ragged_forward(cfg, params, k_pool, v_pool, tokens, q_len, seen, tables)
+            rnd["pools"] = jax.tree.map(jnp.copy, cache)
+        out = ragged_forward(cfg, params, cache, tokens, q_len, seen, tables)
         rnd["dispatches"].append(tuple(np.asarray(a) for a in
-                                       (tokens, q_len, seen, tables, out[0])))
+                                       (tokens, q_len, seen, tables["kv"], out[0])))
         return out
 
 
@@ -163,9 +163,10 @@ def test_each_dispatch_matches_the_round_as_one_rectangle(mixed_run):
                 expected.append(logits[i])
         rect = wrapper.build()
         assert rect["tokens"].shape[0] >= 4, "the rectangle pads to 4 rows"
-        out, _, _ = ragged_forward(
-            engine._model_config, engine._params, *rnd["pools"],
-            *(jnp.asarray(rect[k]) for k in ("tokens", "q_len", "seen", "block_tables")))
+        out, _ = ragged_forward(
+            engine._model_config, engine._params, rnd["pools"],
+            *(jnp.asarray(rect[k]) for k in ("tokens", "q_len", "seen")),
+            {"kv": jnp.asarray(rect["block_tables"])})
         np.testing.assert_allclose(np.asarray(out)[:len(expected)], np.stack(expected),
                                    rtol=2e-4, atol=2e-4)
         split += len(rnd["dispatches"]) > 1

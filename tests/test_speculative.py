@@ -161,14 +161,15 @@ def test_verify_forward_last_column_bit_exact(served, eight_devices):
 
     def args():
         # fresh pool copies per call: both forwards donate their pools
-        return (engine._params, jnp.array(kv.k_pool), jnp.array(kv.v_pool),
+        return (engine._params,
+                {"kv": (jnp.array(kv.k_pool), jnp.array(kv.v_pool))},
                 jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
                 jnp.asarray(arrays["seen"]),
-                jnp.asarray(arrays["block_tables"]))
+                {"kv": jnp.asarray(arrays["block_tables"])})
 
-    plain, _, _ = engine._ragged_forward(mc, *args())
+    plain, _ = engine._ragged_forward(mc, *args())
     for k_max in (2, 4, 8):
-        ver, _, _ = engine._verify_forward(mc, *args(), k_max)
+        ver, _ = engine._verify_forward(mc, *args(), k_max)
         assert ver.shape[1] == k_max
         for row in range(len(chunks)):
             np.testing.assert_array_equal(
