@@ -132,6 +132,11 @@ class InferenceEngineV2:
         self.round = 0
         # [sequence bucket, chunk bucket] of each dispatch of the last round
         self.last_batch_shapes = []
+        # of the last round's dispatches, summed: the pages of the "kv"
+        # group its rows' contexts reach (what the paged kernel walks) and
+        # the slots of the block tables it was handed (rows x width)
+        self.last_live_pages = 0
+        self.last_table_slots = 0
         # of the last round's dispatches, summed (zero for a model of one
         # paged group): pages its windows freed, slots of state held
         self.last_window_pages_freed = 0
@@ -305,6 +310,7 @@ class InferenceEngineV2:
         parts, self.last_batch_shapes = DispatchedRound(), []
         further = self._state.has_further_groups
         self.last_window_pages_freed = self.last_state_slots = 0
+        self.last_live_pages = self.last_table_slots = 0
         for rows, min_seqs in dispatch_rows(lengths,
                                             short_row_tokens(verify_k)):
             # explicit begin/end, and the host-to-device copies as arguments
@@ -316,7 +322,7 @@ class InferenceEngineV2:
                                          sm.max_ragged_batch_size,
                                          self._max_blocks_per_seq,
                                          kv.trash_block)
-            real_tokens = context_tokens = 0
+            real_tokens = context_tokens = live_pages = 0
             seqs = []
             for i in rows:
                 uid, toks = batch_uids[i], batch_tokens[i]
@@ -328,6 +334,8 @@ class InferenceEngineV2:
                 real_tokens += len(toks)
                 if len(toks) == 1:
                     context_tokens += seq.seen_tokens
+                live_pages += -(-(seq.seen_tokens + len(toks))
+                                // self._state.kv_block_size)
                 wrapper.insert_sequence(uid, np.asarray(toks, np.int32),
                                         seq.seen_tokens, seq.kv_blocks)
                 seqs.append(seq)
@@ -336,12 +344,16 @@ class InferenceEngineV2:
             tables = {"kv": arrays["block_tables"]}
             if further:
                 tables.update(self._state.group_tables(seqs, seq_bucket))
-                self._note_further_groups(sp)
+                self._note_further_groups(sp, seqs, seq_bucket)
             self.last_batch_shapes.append((seq_bucket, chunk_bucket))
+            table_slots = seq_bucket * self._max_blocks_per_seq
+            self.last_live_pages += live_pages
+            self.last_table_slots += table_slots
             sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
                    real_tokens=real_tokens,
                    padded_slots=seq_bucket * chunk_bucket,
-                   context_tokens=context_tokens)
+                   context_tokens=context_tokens,
+                   live_pages=live_pages, table_slots=table_slots)
             sp.end()
 
             # fwd_k/fwd_v are (int8, scale) pairs when kv_dtype="int8" — they
@@ -379,12 +391,19 @@ class InferenceEngineV2:
         self.round = rnd + 1
         return parts
 
-    def _note_further_groups(self, sp):
+    def _note_further_groups(self, sp, seqs, seq_bucket):
         """On a dispatch's ``serving/build`` span, for a model with further
         cache groups: slots of state and pages held after this dispatch's
-        allocation, and the pages the windows freed since the last dispatch
-        (the previous round's retire)."""
+        allocation, the pages the windows freed since the last dispatch
+        (the previous round's retire), and each further paged group's
+        ``<name>_live_pages`` of the rows ``seqs`` beside the
+        ``<name>_table_slots`` of its table."""
         census = self._state.census()
+        for name in self._state.paged_groups:
+            census[name + "_live_pages"] = sum(
+                len(seq.group_blocks.get(name, ())) for seq in seqs)
+            census[name + "_table_slots"] = \
+                seq_bucket * self._state.table_width[name]
         freed = self._state.window_pages_freed - self._window_freed_reported
         self._window_freed_reported += freed
         self.last_window_pages_freed += freed

@@ -131,6 +131,11 @@ class SplitFuseScheduler:
         self.dispatches = 0
         self.real_tokens = 0
         self.padded_slots = 0
+        # the sums of the same spans' ``live_pages`` and ``table_slots``:
+        # pages of the "kv" group the dispatched rows' contexts reach (the
+        # paged kernel's work) and slots of the block tables handed to it
+        self.live_pages = 0
+        self.table_slots = 0
         # for a model with further cache groups (ragged/cache_groups.py),
         # the sums of the same spans' ``window_pages_freed`` and
         # ``state_slots``: pages the windows gave back, and slots of
@@ -759,6 +764,8 @@ class SplitFuseScheduler:
         self.dispatches += len(shapes)
         self.real_tokens += sched_tokens
         self.padded_slots += sum(s * q for s, q in shapes)
+        self.live_pages += self._engine.last_live_pages
+        self.table_slots += self._engine.last_table_slots
         self.window_pages_freed += self._engine.last_window_pages_freed
         self.state_slots += self._engine.last_state_slots
         return (uids, chunks, ids, logits, t_fwd, was_prefilling,

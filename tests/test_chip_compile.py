@@ -90,12 +90,12 @@ def test_flash_windowed_gqa_mistral_shape(for_tpu, one_chip):
              _flash_args(one_chip, 2, 4096, H, KV, DH))
 
 
-def _paged_args(one_chip, seqs, q_tokens, int8):
+def _paged_args(one_chip, seqs, q_tokens, int8, table=MAX_BLOCKS):
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     nb = 2048 + 1
     pool = sds((nb, KV, PAGE, DH), jnp.int8 if int8 else jnp.bfloat16)
     args = [sds((seqs, q_tokens, H, DH), jnp.bfloat16), pool, pool,
-            sds((seqs, MAX_BLOCKS), jnp.int32), sds((seqs,), jnp.int32),
+            sds((seqs, table), jnp.int32), sds((seqs,), jnp.int32),
             sds((seqs,), jnp.int32)]
     if int8:
         scale = sds((nb, KV, 1, PAGE), jnp.float32)
@@ -103,20 +103,54 @@ def _paged_args(one_chip, seqs, q_tokens, int8):
     return args
 
 
-@pytest.mark.parametrize("seqs,q_tokens,int8", [
-    (8, 8, False),      # decode round, bf16 pages
-    (8, 8, True),       # decode round, int8 pages + fp32 scales
-    (8, 512, False),    # a multi-token SplitFuse chunk
-], ids=["decode_fp", "decode_int8", "splitfuse_chunk"])
+@pytest.mark.parametrize("seqs,q_tokens,int8,table", [
+    (8, 8, False, MAX_BLOCKS),      # decode round, bf16 pages
+    (8, 8, True, MAX_BLOCKS),       # decode round, int8 pages + fp32 scales
+    (8, 512, False, MAX_BLOCKS),    # a multi-token SplitFuse chunk
+    # what the benchmark's Mistral cells dispatch, over their 64-slot table:
+    # every KV head a grid step at [D, 8], one a step in row tiles at [1, 512]
+    (64, 8, False, 64), (4, 8, False, 64), (1, 512, False, 64),
+    (1, 512, True, 64),
+], ids=["decode_fp", "decode_int8", "splitfuse_chunk", "cell_decode64",
+        "cell_decode4", "cell_chunk512", "cell_chunk512_int8"])
 def test_paged_attention_mistral_geometry(for_tpu, one_chip, seqs, q_tokens,
-                                          int8):
+                                          int8, table):
     from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
 
     def fn(q, kp, vp, bt, seen, q_len, ks=None, vs=None):
         return paged_mha(q, kp, vp, bt, seen, q_len, k_scale=ks, v_scale=vs,
                          window=4096)
 
-    _compile(fn, _paged_args(one_chip, seqs, q_tokens, int8))
+    _compile(fn, _paged_args(one_chip, seqs, q_tokens, int8, table))
+
+
+def test_paged_attention_walk_under_dp2_tp2(for_tpu, topo):
+    """The cells' [64, 8] decode dispatch across four chips: rows over dp,
+    KV heads (the pools' second dim) over tp, so each kernel walks 32 rows'
+    pages for its 4 heads out of its shard of every page."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
+    from deepspeed_tpu.parallel.topology import use_kernel_mesh
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    sds = lambda shape, dt, *spec: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, P(*spec)))
+    pool = sds((2049, KV, PAGE, DH), jnp.bfloat16, None, "tp")
+    args = (sds((64, 8, H, DH), jnp.bfloat16, "dp", None, "tp"), pool, pool,
+            sds((64, 64), jnp.int32, "dp"), sds((64,), jnp.int32, "dp"),
+            sds((64,), jnp.int32, "dp"))
+    with use_kernel_mesh(mesh):
+        _compile(lambda *a: paged_mha(*a, window=4096), args)
+
+
+def test_paged_attention_narrow_head_dim_keeps_the_grid(for_tpu, one_chip):
+    """Heads of 64 (OPT, Falcon, GPT-2): Mosaic cannot copy by hand out of
+    a pool whose rows do not fill a lane tile, so such a pool takes the
+    grid kernel, its pages fetched by the pipeline's index maps."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = sds((512, 12, PAGE, 64), jnp.bfloat16)
+    _compile(paged_mha, (sds((8, 8, 12, 64), jnp.bfloat16), pool, pool,
+                         sds((8, 32), jnp.int32), sds((8,), jnp.int32),
+                         sds((8,), jnp.int32)))
 
 
 @pytest.mark.parametrize("rows,tokens", [(64, 8), (4, 8), (1, 16), (1, 512)],
@@ -134,8 +168,10 @@ def test_selective_scan_phi4flash_widths(for_tpu, one_chip, rows, tokens):
     _compile(selective_scan, args)
 
 
-@pytest.mark.parametrize("seqs,q_tokens,table", [(64, 8, 256), (1, 512, 17)],
-                         ids=["decode_full_layer", "chunk_window_ring"])
+@pytest.mark.parametrize("seqs,q_tokens,table", [
+    (64, 8, 256), (1, 512, 17), (64, 8, 17), (1, 512, 256)],
+    ids=["decode_full_layer", "chunk_window_ring", "decode_window_ring",
+         "chunk_full_layer"])
 def test_paged_attention_differential_pairs_geometry(for_tpu, one_chip, seqs,
                                                      q_tokens, table):
     """Phi-4-mini-flash's differential attention through the paged kernel:

@@ -130,9 +130,12 @@ def test_flash_indivisible_falls_back(eight_devices):
 
 # --------------------------------------------------------------------- paged
 
-def test_paged_mha_parity(eight_devices):
+@pytest.mark.parametrize("Dh", [64, 128], ids=["grid", "walk"])
+def test_paged_mha_parity(eight_devices, Dh):
+    """Heads of 64 take the grid kernel, heads of 128 the walk over live
+    pages: under tp each sees its shard of the KV heads, under dp its rows."""
     from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
-    S, Q, H, KV, Dh, NB, bs, MB = 4, 2, 4, 2, 64, 10, 16, 4
+    S, Q, H, KV, NB, bs, MB = 4, 2, 4, 2, 10, 16, 4
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(ks[0], (S, Q, H, Dh), jnp.float32)
     kp = jax.random.normal(ks[1], (NB, KV, bs, Dh), jnp.float32)

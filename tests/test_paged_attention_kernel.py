@@ -100,3 +100,144 @@ def test_sliding_window_matches_dense(window):
                                    window=window)
     np.testing.assert_allclose(valid_rows(out_k, q_len),
                                valid_rows(out_d, q_len), atol=2e-4, rtol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# The walk over a sequence's live pages (pools whose rows fill a lane tile,
+# Dh % 128 == 0): manual copies, a trip count read from ``seen + q_len``.
+# Every slot of a table past its row's live count points at a page of NaN
+# (of NaN scales for int8 pages): a kernel that reads past the live count
+# returns NaN; the dense twin reads the same pools with that page zeroed.
+# ---------------------------------------------------------------------------
+
+from deepspeed_tpu.ops.pallas.paged_attention import LANES, _walk_plan
+
+# 128 KB a page in float32, as Mistral's bf16 pages are
+WALK = dict(KV=4, Dh=256, bs=32)
+
+
+def _pages_a_trip(Q, rep, MB):
+    """Of a float32 call over float32 pages, as ``make_walk_case`` builds."""
+    return _walk_plan(rep * Q, WALK["KV"], WALK["bs"], WALK["Dh"], 4, 4, MB)[2]
+
+
+def make_walk_case(live, Q=1, rep=4, MB=256, q_len=None, dtype=jnp.float32,
+                   seed=0):
+    """One row a ``live`` entry: its context ends in its ``live``-th page."""
+    KV, Dh, bs = WALK["KV"], WALK["Dh"], WALK["bs"]
+    S, NB = len(live), sum(live) + 2
+    poison, trash = NB - 2, NB - 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (S, Q, KV * rep, Dh), dtype)
+    k_pool = jax.random.normal(ks[1], (NB, KV, bs, Dh), dtype)
+    v_pool = jax.random.normal(ks[2], (NB, KV, bs, Dh), dtype)
+    rng = np.random.default_rng(seed)
+    q_len = np.full((S,), Q, np.int32) if q_len is None else np.asarray(q_len, np.int32)
+    pages = rng.permutation(NB - 2)
+    bt = np.full((S, MB), poison, np.int32)
+    seen = np.zeros((S,), np.int32)
+    for i, n in enumerate(live):
+        if q_len[i] == 0:                 # a padded row: the trash block
+            bt[i] = trash
+            continue
+        bt[i, :n], pages = pages[:n], pages[n:]
+        # the last token lands in page n: seen + q_len in ((n-1)*bs, n*bs]
+        seen[i] = rng.integers(max((n - 1) * bs + 1 - q_len[i], 0),
+                               n * bs - q_len[i] + 1)
+    return (q, k_pool, v_pool, jnp.asarray(bt), jnp.asarray(seen),
+            jnp.asarray(q_len)), poison
+
+
+def check_walk(case, poison, atol=2e-4, rtol=1e-3, **kw):
+    q, kp, vp, bt, seen, q_len = case
+    assert kp.shape[-1] % LANES == 0, "this geometry takes the grid kernel"
+    nan = lambda pool: pool.at[poison].set(jnp.nan)
+    out_k = paged_mha(q, nan(kp), nan(vp), bt, seen, q_len, interpret=True, **kw)
+    zero = lambda pool: pool.at[poison].set(0)
+    out_d = _paged_attention_dense(q, zero(kp), zero(vp), bt, seen,
+                                   kp.shape[2], **kw)
+    assert np.isfinite(np.asarray(out_k, np.float32)).all(), \
+        "the walk read past a row's live pages, or left a padded row undefined"
+    assert valid_rows(out_k, q_len).size
+    np.testing.assert_allclose(valid_rows(out_k, q_len).astype(np.float32),
+                               valid_rows(out_d, q_len).astype(np.float32),
+                               atol=atol, rtol=rtol)
+
+
+@pytest.mark.parametrize("live", ["one", "a_trip", "a_trip_and_one", "table"])
+def test_walk_live_pages_at_the_trip_boundaries(live):
+    MB = 256
+    P = _pages_a_trip(1, 4, MB)
+    assert 1 < P < MB - 1, "the table has to be wider than a trip"
+    n = {"one": 1, "a_trip": P, "a_trip_and_one": P + 1, "table": MB}[live]
+    check_walk(*make_walk_case([n, 2 * P + 3, n], MB=MB, seed=n))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_walk_wide_table_few_live_pages(seed):
+    """A 256-slot table of which 3-50 slots are live, as a decode round of
+    short contexts under a long ``max_context`` has it."""
+    live = np.random.default_rng(seed).integers(3, 51, size=6)
+    check_walk(*make_walk_case([int(n) for n in live], Q=8, seed=seed))
+
+
+def test_walk_first_token_seen_zero():
+    case, poison = make_walk_case([1, 1], Q=1)
+    q, kp, vp, bt, seen, q_len = case
+    check_walk((q, kp, vp, bt, jnp.zeros_like(seen), q_len), poison)
+
+
+def test_walk_padded_rows_cost_one_finite_page():
+    """Rows with ``q_len`` 0 (a sequence bucket's padding: ``seen`` 0, the
+    trash block) between real ones: finite, and the real rows unmoved."""
+    check_walk(*make_walk_case([5, 1, 70, 1, 9], Q=8,
+                               q_len=[8, 0, 3, 0, 1]))
+
+
+@pytest.mark.parametrize("Q", [1, 8, 64, 384])
+def test_walk_query_tokens(Q):
+    """Pure decode, the [D, 8] short-row class, a chunk, and a chunk whose
+    ``rep * Q`` rows (1536) are walked in row tiles, one KV head a step."""
+    check_walk(*make_walk_case([-(-Q // WALK["bs"]) + 2, 41], Q=Q, MB=64, seed=Q))
+
+
+@pytest.mark.parametrize("rep", [1, 4])
+def test_walk_query_heads_a_kv_head(rep):
+    check_walk(*make_walk_case([2, 37, 11], Q=8, rep=rep, seed=rep))
+
+
+@pytest.mark.parametrize("Q", [8, 64])
+def test_walk_ring_table_with_window(Q):
+    """A window group's table: a ring of at most 17 pages, positions
+    relative to its first page, the window cutting into the oldest."""
+    check_walk(*make_walk_case([17, 9, 3], Q=Q, MB=17, seed=5),
+               window=8 * WALK["bs"])
+
+
+def test_walk_softmax_scale():
+    check_walk(*make_walk_case([4, 30], Q=8), softmax_scale=0.125)
+
+
+def test_walk_bf16():
+    check_walk(*make_walk_case([1, 40, 9], Q=8, dtype=jnp.bfloat16),
+               atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("Q", [1, 8])
+def test_walk_int8_pages_with_scales(Q):
+    """int8 pages take the walk; their scale rows are gathered through the
+    table clamped to the live pages, so the poisoned page's NaN scales are
+    never read either."""
+    from test_kv_tiering import _quantize_pool
+    (q, kp, vp, bt, seen, q_len), poison = make_walk_case([1, 33, 6], Q=Q,
+                                                          MB=64, seed=Q)
+    (kq, ks), (vq, vs) = _quantize_pool(kp), _quantize_pool(vp)
+    nan = lambda scale: scale.at[poison].set(jnp.nan)
+    out_k = paged_mha(q, kq, vq, bt, seen, q_len, k_scale=nan(ks),
+                      v_scale=nan(vs), interpret=True)
+    zero = lambda scale: scale.at[poison].set(0)
+    out_d = _paged_attention_dense(q, (kq, zero(ks)), (vq, zero(vs)), bt,
+                                   seen, kq.shape[2])
+    assert np.isfinite(np.asarray(out_k)).all()
+    np.testing.assert_allclose(valid_rows(out_k, q_len),
+                               valid_rows(out_d, q_len), atol=2e-4, rtol=1e-3)

@@ -264,6 +264,10 @@ def test_spans_carry_the_groups_and_their_sums_are_the_counters(served, tmp_path
     for a in builds:
         assert 1 <= a["state_slots"] <= 2 and a["global_pages"] > 0
         assert a["state_slots"] <= a["window_pages"] <= a["state_slots"] * 7
+        # the ring's walk is its live pages, never its table's width
+        assert a["seqs"] <= a["window_live_pages"] <= a["window_pages"]
+        assert a["window_live_pages"] < a["window_table_slots"] < a["table_slots"]
+        assert a["seqs"] <= a["live_pages"] <= a["global_pages"]
     assert sum(a["window_pages_freed"] for a in builds) == \
         sched.window_pages_freed - before[0] > 0
     assert sum(a["state_slots"] for a in builds) == sched.state_slots - before[1]

@@ -87,7 +87,8 @@ def served(tmp_path_factory):
         sched.run_to_completion()
         return sched
     before = (sched.rounds, sched.real_tokens, sched.padded_slots,
-              sched.prefill_tokens_executed, sched.dispatches)
+              sched.prefill_tokens_executed, sched.dispatches,
+              sched.live_pages, sched.table_slots)
     spans, _ = _captured(tmp_path_factory.mktemp("serve"), run)
     return spans, sched, before
 
@@ -181,6 +182,25 @@ def test_sums_over_spans_equal_the_schedulers_counters(served):
     assert sum(s[3]["new_tokens"] for s in retired) == 4 + 6 + 3
     assert sum(s[3]["finished"] for s in retired) == 3
     assert 0 < sched.real_tokens <= sched.padded_slots
+
+
+def test_live_pages_and_table_slots_sum_to_the_schedulers_counters(served):
+    """What the paged kernel walks beside what its grid used to step over:
+    a dispatch's rows reach ``ceil((seen + new) / block)`` pages each, and
+    its tables hold ``sequence bucket x width`` slots."""
+    spans, sched, before = served
+    builds = _named(spans, "serving/build")
+    total = lambda key: sum(s[3][key] for s in builds)
+    assert sched.live_pages - before[5] == total("live_pages")
+    assert sched.table_slots - before[6] == total("table_slots")
+    width = sched._engine._max_blocks_per_seq
+    for _, _, _, a in builds:
+        assert a["table_slots"] == a["seq_bucket"] * width
+        # every row reaches a page; none reaches past its table
+        assert a["seqs"] <= a["live_pages"] <= a["seqs"] * width
+        assert a["live_pages"] * sched._engine._state.kv_block_size >= \
+            a["real_tokens"] + a["context_tokens"]
+    assert 0 < sched.live_pages < sched.table_slots
 
 
 def test_cancel_and_context_roof_mark_finish_with_their_reason(tmp_path):
