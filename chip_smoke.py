@@ -183,14 +183,12 @@ def dispatch_stats():
 
 def check_dispatch(allowed_fallbacks=("no_mesh", "trivial_mesh"), since=None):
     """No kernel call on the path took a quiet way out: no shape-based flash
-    reference fallback, no dense paged-attention fallback, no shard_map veto,
-    no fallback other than the single-device ones."""
-    from deepspeed_tpu.inference.v2.modules import heuristics
+    reference fallback, no shard_map veto, no fallback other than the
+    single-device ones (the dense paged-attention twin's is such a record:
+    ``ops.registry.takes_kernel``)."""
     from deepspeed_tpu.ops import flash_attention as fa
     check(not fa._warned_shapes,
           f"flash attention fell back to XLA for {sorted(map(str, fa._warned_shapes))}")
-    check(not heuristics._warned,
-          f"serving modules fell back: {sorted(heuristics._warned)}")
     since = since or {}
     bad = {k: n for k, n in dispatch_stats().items()
            if n > since.get(k, 0) and (

@@ -67,29 +67,28 @@ class QuantizedParameter:
                           num_bits=self.num_bits, group_size=self.group_size,
                           dtype=dtype)
 
-    def matmul(self, x, out_dtype=None, impl=None):
-        """``x @ dequant(self)`` through the serving modules registry
-        (reference cuda_linear / mixed_gemm slot): 'fused_dequant' = the
-        Pallas dequant-GEMM kernel (HBM reads stay int8-sized),
-        'dense_dequant' = XLA dequantize-then-matmul. ``impl`` pins a name
-        (raising if it cannot serve this shape); None picks per hardware.
+    def matmul(self, x, out_dtype=None):
+        """``x @ dequant(self)`` (reference cuda_linear / mixed_gemm slot):
+        the Pallas fused dequant-GEMM kernel (HBM reads stay int8-sized) for
+        a 2-D weight when Pallas is on and the shapes tile, else XLA
+        dequantize-then-matmul.
 
         Integration status: this is the serving-layer API for the fused
         path; the v1 engine's dense-dequant proxy remains the default until
         the kernel is validated on hardware (scripts/tpu_kernel_smoke.py)."""
-        from deepspeed_tpu.inference.v2.modules.heuristics import (
-            instantiate_linear)
+        from deepspeed_tpu.ops.pallas import quantized_matmul as qm
+        from deepspeed_tpu.ops.registry import pallas_interpret, takes_kernel
         M = int(np.prod(x.shape[:-1]))
-        if len(self.shape) == 2:
+        if takes_kernel(
+                "quantized_matmul",
+                len(self.shape) == 2 and qm.is_supported(
+                    M, *self.shape, self.group_size, self.num_bits),
+                f"[{M}, K] @ {tuple(self.shape)} (group {self.group_size}, "
+                f"{self.num_bits} bits) not kernel-tileable"):
             K, N = self.shape
-        else:
-            K = N = None
-        name, fn = instantiate_linear(M, K, N, self.group_size,
-                                      self.num_bits, ndim=len(self.shape),
-                                      preference=impl)
-        if name == "fused_dequant":
-            out = fn(x.reshape(M, K), self.q, self.scale, self.group_size,
-                     out_dtype=out_dtype)
+            out = qm.quantized_matmul(x.reshape(M, K), self.q, self.scale,
+                                      self.group_size, out_dtype=out_dtype,
+                                      interpret=pallas_interpret())
             return out.reshape(x.shape[:-1] + (N,))
         return x @ self.dequantized(out_dtype or x.dtype)
 

@@ -35,19 +35,6 @@ class KVCacheConfig(DeepSpeedConfigModel):
     cache_dtype = "bf16"
 
 
-class ModulesConfig(DeepSpeedConfigModel):
-    """Per-interface implementation pins (reference ``modules/heuristics.py``
-    chooses per hardware; a named pin here overrides it — see
-    ``modules/module_registry.py``). "auto" = heuristic choice. Pins the
-    engine's forwards would never read are REJECTED at construction: moe on
-    a dense model, and any non-auto linear (the ragged forwards carry fp
-    weights — quantized-linear pins flow through
-    ``QuantizedParameter.matmul(impl=...)`` instead)."""
-    attention = "auto"        # "pallas_paged" | "dense"
-    moe = "auto"              # "megablox" | "einsum" (Mixtral engines only)
-    linear = "auto"           # must stay "auto" here; see docstring
-
-
 class SpeculativeConfig(DeepSpeedConfigModel):
     """Draft-then-verify decode knobs.
 
@@ -77,7 +64,6 @@ class RaggedInferenceEngineConfig(DeepSpeedConfigModel):
     tensor_parallel = {"tp_size": 1}
     state_manager = DSStateManagerConfig()
     kv_cache = KVCacheConfig()
-    modules = ModulesConfig()
     # block-granular prefix caching with copy-on-write sharing
     # (ragged/prefix_cache.py). Default off: generation is bit-exact either
     # way (test-pinned) but the knob gates all hashing/refcount bookkeeping

@@ -15,26 +15,43 @@ A family is three things, resolved here: its ragged forward, its verify
 forward (or None) and its cache groups (``ragged/cache_groups.py``).
 """
 
+import importlib
+
 import numpy as np
 
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.utils.logging import logger
 
-#: families ``build_hf_engine`` loads from a checkpoint directory
-SUPPORTED_FAMILIES = ("llama", "mistral", "qwen2", "mixtral", "falcon", "phi",
-                      "opt", "qwen", "internlm")  # qwen(v1)/internlm load as
-                                                  # llama trees (hf.py)
-#: families ``build_engine`` serves from an in-tree model and tree
-SERVED_FAMILIES = SUPPORTED_FAMILIES + ("phi4flash",)
+#: a family is one row: its module under ``model_implementations``, which
+#: exports ``ragged_forward`` and, where the family has one,
+#: ``ragged_forward_verify``
+_IMPLEMENTATION = {"llama": "llama", "mistral": "llama", "qwen2": "llama",
+                   "qwen": "llama", "internlm": "llama",  # llama trees (hf.py)
+                   "mixtral": "mixtral", "falcon": "parallel_block",
+                   "phi": "parallel_block", "opt": "opt",
+                   "phi4flash": "phi4flash"}
 
+#: families ``build_engine`` serves from an in-tree model and tree
+SERVED_FAMILIES = tuple(_IMPLEMENTATION)
+#: families ``build_hf_engine`` loads from a checkpoint directory
+SUPPORTED_FAMILIES = tuple(f for f in SERVED_FAMILIES
+                           if f != "phi4flash")  # it has no HF converter
+
+#: the one place a config class names its family; any other is a llama tree
 _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
                      "ParallelBlockConfig": "falcon",
                      "OPTConfig": "opt",
                      "Phi4FlashConfig": "phi4flash"}
 
 
-def _family(model, family):
-    return family or _FAMILY_OF_CONFIG.get(type(model.config).__name__, "llama")
+def _implementation(model, family):
+    family = family or _FAMILY_OF_CONFIG.get(type(model.config).__name__,
+                                             "llama")
+    name = _IMPLEMENTATION[family]
+    if name == "llama" and not getattr(model.config, "scan_layers", True):
+        raise ValueError("ragged llama engine requires scan_layers=True params")
+    return importlib.import_module(
+        f"deepspeed_tpu.inference.v2.model_implementations.{name}")
 
 
 def build_hf_engine(path, engine_config=None, dtype=None):
@@ -73,35 +90,15 @@ def build_hf_engine(path, engine_config=None, dtype=None):
 def resolve_forward_fn(model, family=None):
     """The ragged implementation for a model family (the reference's policy
     map, ``engine_factory.py:68-129``)."""
-    family = _family(model, family)
-    if family == "mixtral":
-        from deepspeed_tpu.inference.v2.model_implementations.mixtral import (
-            ragged_forward)
-    elif family == "phi4flash":
-        from deepspeed_tpu.inference.v2.model_implementations.phi4flash import (
-            ragged_forward)
-    elif family in ("falcon", "phi"):
-        from deepspeed_tpu.inference.v2.model_implementations.parallel_block import (
-            ragged_forward)
-    elif family == "opt":
-        from deepspeed_tpu.inference.v2.model_implementations.opt import (
-            ragged_forward)
-    else:
-        from deepspeed_tpu.inference.v2.model_implementations.llama import (
-            ragged_forward)
-    return ragged_forward
+    return _implementation(model, family).ragged_forward
 
 
 def resolve_verify_fn(model, family=None):
     """The k-token verify forward for a model family, or ``None`` when the
     family has no speculative-verify implementation yet (the engine refuses
     speculation rather than silently falling back to a different program)."""
-    if _family(model, family) in ("mixtral", "falcon", "phi", "opt",
-                                  "phi4flash"):
-        return None
-    from deepspeed_tpu.inference.v2.model_implementations.llama import (
-        ragged_forward_verify)
-    return ragged_forward_verify
+    return getattr(_implementation(model, family), "ragged_forward_verify",
+                   None)
 
 
 def resolve_cache_groups(model):

@@ -71,50 +71,12 @@ class InferenceEngineV2:
         if cache_groups is None:
             from deepspeed_tpu.inference.v2.engine_factory import resolve_cache_groups
             cache_groups = resolve_cache_groups(model)
-        if type(cfg).__name__ != "MixtralConfig" and \
-                not getattr(cfg, "scan_layers", True):
-            raise ValueError("ragged llama engine requires scan_layers=True params")
         self._ragged_forward = forward_fn
         self._verify_forward = verify_fn
         if config.speculative.enabled and verify_fn is None:
             raise ValueError(
                 "speculative.enabled requires a verify forward; "
                 f"{type(cfg).__name__} has none (resolve_verify_fn)")
-        # module pins ride the STATIC model config (a frozen dataclass, jit
-        # cache key), so two engines with different pins can never share a
-        # compiled program traced under the other's selection. Names are
-        # validated HERE — a typo'd pin must fail before the KV pool is
-        # allocated, not at the first traced forward.
-        import dataclasses as _dc
-        from deepspeed_tpu.inference.v2.modules import module_registry as _mr
-        from deepspeed_tpu.inference.v2.modules import heuristics  # noqa: F401 (registers rows)
-        pins = tuple(sorted(
-            (iface, name) for iface, name in
-            ((i, getattr(config.modules, i)) for i in
-             ("attention", "moe", "linear")) if name != "auto"))
-        for iface, name in pins:
-            if iface == "linear":
-                # the ragged forwards carry fp weights; the linear interface
-                # is consumed by QuantizedParameter.matmul (v1 quantized
-                # serving). A pin that nothing would read must not pretend.
-                raise _mr.UnsupportedModuleError(
-                    "modules.linear pins apply to the quantized serving "
-                    "path (QuantizedParameter.matmul(impl=...)); the v2 "
-                    "ragged engine has no quantized linear to swap")
-            if iface == "moe" and type(cfg).__name__ != "MixtralConfig":
-                # only the Mixtral forward routes through _moe_ffn; a moe
-                # pin on a dense model would install but never be read
-                raise _mr.UnsupportedModuleError(
-                    f"modules.moe pinned to {name!r} but "
-                    f"{type(cfg).__name__} has no MoE layer to swap")
-            known = {i.name for i in _mr.registered(iface)}
-            if name not in known:
-                raise _mr.UnknownModuleError(
-                    f"unknown {iface} implementation {name!r} pinned in "
-                    f"config.modules; registered: {sorted(known)}")
-        if pins:
-            cfg = _dc.replace(cfg, serve_modules=pins)
-            self._model_config = cfg
         self._state = DSStateManager(config, cache_groups)
         # KV host-spill transfers (prefix blocks demoted to the DRAM tier)
         # land through the SAME accounted fetch as logits/sampled ids, so

@@ -20,15 +20,19 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.llama import rotary_embed
-from deepspeed_tpu.inference.v2.model_implementations.llama import (
-    _paged_attention, _pool_block_size, _pool_layer, _pool_set_layer,
-    _rmsnorm, _scatter_kv)
-from deepspeed_tpu.inference.v2.modules.module_registry import module_preference
+from deepspeed_tpu.inference.v2.model_implementations.llama import _rmsnorm
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
+    _paged_attention, _pool_block_size, _scatter_kv, last_token, layer_rows,
+    layer_trash, merge_layers, pool_pages_per_layer, split_layers)
+from deepspeed_tpu.ops.registry import pallas_interpret, takes_kernel
 
 
-def _moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, force_einsum=False,
-             prefer=None):
-    """Grouped-expert FFN over a flat token batch.
+def _moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, force_einsum=False):
+    """Grouped-expert FFN over a flat token batch: the ragged grouped GEMM
+    (ops/pallas/grouped_gemm.py: tokens sorted by expert, no capacity
+    dimension) when Pallas is on and the dims tile, else the GShard dense
+    dispatch-combine einsum below, which ``force_einsum`` pins as the tests'
+    oracle.
 
     x: [T, D]; gate_wg: [D, E]; w1/w3: [E, D, F]; w2: [E, F, D].
     Returns [T, D].
@@ -43,16 +47,15 @@ def _moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, force_einsum=False,
     C = T
 
     # single routing implementation for both dispatch backends
-    from deepspeed_tpu.ops.pallas.grouped_gemm import topk_router
-    top_vals, top_idx = topk_router(x, gate_wg, k)       # [T, k]
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    top_vals, top_idx = gg.topk_router(x, gate_wg, k)    # [T, k]
 
-    if not force_einsum:
-        from deepspeed_tpu.inference.v2.modules.heuristics import (
-            instantiate_moe)
-        impl, fn = instantiate_moe(D, w1.shape[-1], preference=prefer)
-        if impl == "megablox":
-            return fn(x, top_vals, top_idx, w1, w2, w3, n_experts=E,
-                      dtype=dtype)
+    F = w1.shape[-1]
+    if not force_einsum and takes_kernel(
+            "moe_ffn_gmm", gg.is_supported(D, F),
+            f"dims ({D}, {F}) not 128-tileable for gmm"):
+        return gg.moe_ffn_gmm(x, top_vals, top_idx, w1, w2, w3, n_experts=E,
+                              dtype=dtype, interpret=pallas_interpret())
 
     # top_k_gating: position of each (token, slot) inside its expert's bucket
     onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)       # [T, k, E]
@@ -81,12 +84,15 @@ def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
     S, Q = tokens.shape
     H, KV = cfg.num_attention_heads, cfg.num_key_value_heads
     Dh = cfg.hidden_size // H
+    L = cfg.num_hidden_layers
     bs = _pool_block_size(k_pool)  # [L, NB, KV, bs, Dh] (pair when int8)
+    nb = pool_pages_per_layer(k_pool)
     positions = seen[:, None] + jnp.arange(Q)[None, :]
 
     x = params["embed_tokens"].astype(cfg.dtype)[tokens]
 
-    def layer_step(x, lp, kp, vp):
+    def layer_step(x, kp, vp, lp, i):
+        layer_tables = layer_rows(block_tables, i, nb)
         attn = lp["self_attn"]
         h = _rmsnorm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
         q = (h @ attn["q_proj"]["kernel"].astype(cfg.dtype)).reshape(S, Q, H, Dh)
@@ -94,9 +100,9 @@ def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
         v = (h @ attn["v_proj"]["kernel"].astype(cfg.dtype)).reshape(S, Q, KV, Dh)
         q = rotary_embed(q, positions, cfg.rope_theta)
         k = rotary_embed(k, positions, cfg.rope_theta)
-        kp, vp = _scatter_kv(kp, vp, k, v, block_tables, seen, q_len, bs)
-        out = _paged_attention(q, kp, vp, block_tables, seen, bs, q_len=q_len,
-                               prefer=module_preference(cfg, "attention"))
+        kp, vp = _scatter_kv(kp, vp, k, v, layer_tables, seen, q_len, bs,
+                             trash=layer_trash(i, nb))
+        out = _paged_attention(q, kp, vp, layer_tables, seen, bs, q_len)
         x = x + out.reshape(S, Q, H * Dh) @ attn["o_proj"]["kernel"].astype(cfg.dtype)
 
         moe = lp["block_sparse_moe"]
@@ -108,21 +114,18 @@ def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
                      ex["w2"]["kernel"].astype(cfg.dtype),
                      ex["w3"]["kernel"].astype(cfg.dtype),
                      k=cfg.num_experts_per_tok,
-                     dtype=cfg.dtype,
-                     prefer=module_preference(cfg, "moe"))
+                     dtype=cfg.dtype)
         return x + y.reshape(S, Q, -1), kp, vp
 
-    # non-scanned stack: per-layer pools are [L, ...]; loop is unrolled (the
-    # layer count is static and the weights differ per layer)
-    for i in range(cfg.num_hidden_layers):
-        x, kpi, vpi = layer_step(x, params[f"layers_{i}"],
-                                 _pool_layer(k_pool, i),
-                                 _pool_layer(v_pool, i))
-        k_pool = _pool_set_layer(k_pool, i, kpi)
-        v_pool = _pool_set_layer(v_pool, i, vpi)
+    # non-scanned stack: the loop is unrolled (the layer count is static and
+    # the weights differ per layer) over the one merged pool (paged_layer.py,
+    # "The layout")
+    k_pool, v_pool = merge_layers((k_pool, v_pool))
+    for i in range(L):
+        x, k_pool, v_pool = layer_step(x, k_pool, v_pool,
+                                       params[f"layers_{i}"], i)
+    k_pool, v_pool = split_layers((k_pool, v_pool), L)
 
     x = _rmsnorm(x, params["norm"]["scale"], cfg.rms_norm_eps)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(q_len - 1, 0)[:, None, None], axis=1)[:, 0]
-    logits = last @ params["lm_head"].astype(cfg.dtype).T
+    logits = last_token(x, q_len) @ params["lm_head"].astype(cfg.dtype).T
     return logits.astype(jnp.float32), {"kv": (k_pool, v_pool)}

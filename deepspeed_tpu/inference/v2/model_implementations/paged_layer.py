@@ -1,0 +1,207 @@
+"""How a layer of a ragged forward touches its paged cache: the one place
+that knows how the stacked pools are laid out for the layer loop, how a
+round's K and V rows are written into them, how they are read and which
+kernel reads them (the reference's ragged kernel set
+``inference/v2/kernels/ragged_ops``: linear_blocked_kv_rotary -> scatter into
+the paged cache, blocked_flash -> paged attention, logits_gather ->
+last-token logits). Every family's forward calls these and nothing else of
+the cache.
+
+The layout. The state manager hands a forward stacked pools ``[L, NB+1,
+KV, bs, Dh]`` (an ``(int8, scale)`` pair when ``kv_dtype="int8"``), the last
+page of every layer being that layer's trash page, which absorbs the writes
+of padded rows (``ragged/kv_cache.py`` allocates it). To the layer loop they
+are ONE pool of ``L * (NB+1)`` pages (a free reshape, ``merge_layers``):
+layer ``i`` owns pages ``[i * (NB+1), (i+1) * (NB+1))``, reached by
+offsetting the block tables (``layer_rows``), its trash page among them
+(``layer_trash``). The merged pools ride the CARRY of the layer loop, the
+scatter updates them in place and the paged kernel reads pages through the
+tables: no layer's pool is ever sliced out or written back. As scan inputs
+and outputs the pools were two buffers each (the whole KV pool again as
+scratch) and every round moved them through HBM ~13x. Pools of slots (a
+recurrent state a sequence) are merged and offset the same way.
+"""
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.ops.flash_attention import NEG_INF
+from deepspeed_tpu.ops.registry import pallas_interpret, takes_kernel
+
+
+# -- the layout ---------------------------------------------------------------
+
+def merge_layers(pools):
+    """Every leaf ``[L, n, ...]`` of ``pools`` (any pytree of stacked pools)
+    as ``[L * n, ...]``."""
+    return jax.tree.map(
+        lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:]), pools)
+
+
+def split_layers(pools, layers):
+    """Undo ``merge_layers`` for pools of ``layers`` layers."""
+    return jax.tree.map(
+        lambda a: a.reshape((layers, a.shape[0] // layers) + a.shape[1:]),
+        pools)
+
+
+def layer_rows(tables, i, nb):
+    """``tables`` (page or slot ids within a layer) as ids of layer ``i`` in
+    the merged pool of ``nb`` rows a layer. ``i``: a Python int or a traced
+    scalar."""
+    return tables + i * nb
+
+
+def layer_trash(i, nb):
+    """Layer ``i``'s trash page in the merged pool: the last of its ``nb``."""
+    return i * nb + nb - 1
+
+
+def _pool_parts(pool):
+    """A KV pool is either an array (fp) or an ``(int8, scale)`` pair
+    (``state_manager.kv_dtype="int8"``) — split without probing."""
+    return pool if isinstance(pool, tuple) else (pool, None)
+
+
+def pool_pages_per_layer(pool):
+    """Pages a layer (trash included) of a possibly-quantized STACKED pool
+    [L, NB+1, KV, bs, Dh]."""
+    return _pool_parts(pool)[0].shape[1]
+
+
+def _pool_block_size(pool):
+    """Block size from a possibly-quantized STACKED pool [L, NB, KV, bs, Dh]."""
+    return _pool_parts(pool)[0].shape[3]
+
+
+# -- the write ----------------------------------------------------------------
+
+def _quantize_kv_rows(x):
+    """[..., Dh] fp -> (int8 [..., Dh], fp32 scale [...]) — the per-row
+    symmetric wire format of ``quant_collective`` applied per token row.
+    Uses the module's jnp twin (the Pallas producer kernel needs
+    group_size >= 256; KV rows are Dh wide), fused into the jitted forward."""
+    from deepspeed_tpu.ops.pallas.quant_collective import _quantize_rows_ref
+    q, scale = _quantize_rows_ref(
+        x.astype(jnp.float32).reshape(-1, x.shape[-1]), 8)
+    return q.reshape(x.shape), scale.reshape(x.shape[:-1])
+
+
+def _scatter_kv(k_pool, v_pool, k, v, block_tables, seen, q_len, block_size,
+                trash):
+    """Write [S, Q, KV, Dh] new KVs into the [NB, KV, bs, Dh] pool via block
+    tables.
+
+    Padded token slots are routed to the ``trash`` page.
+    Analog of the reference's linear_blocked_kv_copy kernel. Quantized pools
+    (``(int8, scale)`` pairs) quantize on-write: each token's row quantizes
+    per (token, kv head) over Dh, and the fp32 scale scatters into the side
+    pool [NB, KV, 1, bs] under the same block/slot indices.
+    """
+    k_pool, k_scale = _pool_parts(k_pool)
+    v_pool, v_scale = _pool_parts(v_pool)
+    S, Q = k.shape[:2]
+    pos = seen[:, None] + jnp.arange(Q)[None, :]              # [S, Q]
+    valid = jnp.arange(Q)[None, :] < q_len[:, None]
+    blk = jnp.take_along_axis(block_tables, pos // block_size, axis=1,
+                              mode="clip")
+    # every leading dim is indexed — (block, head, slot) per [Dh] row, values
+    # [S*Q, KV, Dh] — so the scatter writes whole rows in the pool's own
+    # layout. Leaving the head dim a slice between two indexed dims made the
+    # chip's compiler re-lay the WHOLE pool out around the scatter.
+    bi = jnp.where(valid, blk, trash).reshape(-1, 1)          # [S*Q, 1]
+    si = jnp.where(valid, pos % block_size, 0).reshape(-1, 1)
+    hi = jnp.arange(k.shape[2])[None, :]                      # [1, KV]
+    if k_scale is not None:
+        k, ks = _quantize_kv_rows(k)          # int8 [S,Q,KV,Dh], f32 [S,Q,KV]
+        v, vs = _quantize_kv_rows(v)
+        k_scale = k_scale.at[bi, hi, 0, si].set(ks.reshape(S * Q, -1))
+        v_scale = v_scale.at[bi, hi, 0, si].set(vs.reshape(S * Q, -1))
+    k_pool = k_pool.at[bi, hi, si].set(
+        k.reshape(S * Q, *k.shape[2:]).astype(k_pool.dtype))
+    v_pool = v_pool.at[bi, hi, si].set(
+        v.reshape(S * Q, *v.shape[2:]).astype(v_pool.dtype))
+    if k_scale is not None:
+        return (k_pool, k_scale), (v_pool, v_scale)
+    return k_pool, v_pool
+
+
+# -- the read -----------------------------------------------------------------
+
+def _paged_attention(q, k_pool, v_pool, block_tables, seen, block_size,
+                     q_len, window=None, softmax_scale=None):
+    """Grouped-query attention over per-sequence paged KV: the Pallas
+    blocked-flash kernel (ops/pallas/paged_attention.py — O(seen) HBM reads)
+    when Pallas is on and the shapes tile, the dense gather twin elsewhere.
+    ``window``: Mistral-style sliding window. ``softmax_scale``: None is
+    ``1/sqrt(Dh)``. q: [S,Q,H,Dh] -> [S,Q,H,Dh]."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    kp, ks = _pool_parts(k_pool)
+    # the batch dims are left out of the warning: one a model, not one a shape
+    if takes_kernel("paged_mha", pa.is_supported(q.shape, kp.shape),
+                    f"q heads {tuple(q.shape[2:])} over pages "
+                    f"{tuple(kp.shape[1:])} violate the kernel's tiling "
+                    f"(need H%KV==0, Dh<=256, block_size%8==0), "
+                    f"O(max_context) reads"):
+        vp, vs = _pool_parts(v_pool)
+        return pa.paged_mha(q, kp, vp, block_tables, seen, q_len,
+                            k_scale=ks, v_scale=vs,
+                            softmax_scale=softmax_scale, window=window,
+                            interpret=pallas_interpret())
+    return _paged_attention_dense(q, k_pool, v_pool, block_tables, seen,
+                                  block_size, window=window,
+                                  softmax_scale=softmax_scale)
+
+
+def _paged_attention_dense(q, k_pool, v_pool, block_tables, seen, block_size,
+                           window=None, softmax_scale=None):
+    """Pure-XLA reference path (gathers the full table; numerics twin of the
+    Pallas kernel — including the fused-dequant int8 path, which it
+    reproduces as gather-then-dequantize with broadcast scales)."""
+    k_pool, k_scale = _pool_parts(k_pool)
+    v_pool, v_scale = _pool_parts(v_pool)
+    S, Q, H, Dh = q.shape
+    KV = k_pool.shape[1]
+    rep = H // KV
+    scale = 1.0 / (Dh ** 0.5) if softmax_scale is None else softmax_scale
+    MB = block_tables.shape[1]
+
+    def one_seq(q_s, bt_s, seen_s):
+        keys, vals = k_pool[bt_s], v_pool[bt_s]       # [MB, KV, bs, Dh]
+        if k_scale is not None:
+            # scale rows [MB, KV, 1, bs] -> per-token column [MB, KV, bs, 1]
+            keys = keys.astype(jnp.float32) * \
+                jnp.swapaxes(k_scale[bt_s], -1, -2)
+            vals = vals.astype(jnp.float32) * \
+                jnp.swapaxes(v_scale[bt_s], -1, -2)
+        # [MB, KV, bs, Dh] -> token-major [MB*bs, KV, Dh]
+        keys = (keys.transpose(0, 2, 1, 3)
+                .reshape(MB * block_size, KV, Dh).astype(q_s.dtype))
+        vals = (vals.transpose(0, 2, 1, 3)
+                .reshape(MB * block_size, KV, Dh).astype(q_s.dtype))
+        qg = q_s.reshape(Q, KV, rep, Dh)
+        logits = jnp.einsum("qkrd,skd->krqs", qg, keys).astype(jnp.float32) * scale
+        key_pos = jnp.arange(MB * block_size)[None, :]
+        qry_pos = (seen_s + jnp.arange(Q))[:, None]
+        visible = key_pos <= qry_pos
+        if window:
+            visible = visible & (key_pos > qry_pos - window)
+        logits = jnp.where(visible, logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q_s.dtype)
+        return jnp.einsum("krqs,skd->qkrd", probs, vals).reshape(Q, H, Dh)
+
+    return jax.vmap(one_seq)(q, block_tables, seen)
+
+
+# -- the logits gather --------------------------------------------------------
+
+def token_at(x, idx):
+    """``x`` [S, Q, D] at chunk position ``idx`` [S] of each row -> [S, D]."""
+    return jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+
+
+def last_token(x, q_len):
+    """logits_gather analog: ``x`` [S, Q, D] at the last real token of each
+    row's chunk -> [S, D]. A row of no tokens (``q_len`` 0) reads position
+    0."""
+    return token_at(x, jnp.maximum(q_len - 1, 0))

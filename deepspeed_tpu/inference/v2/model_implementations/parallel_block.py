@@ -13,10 +13,9 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.parallel_block import partial_rotary
-from deepspeed_tpu.inference.v2.model_implementations.llama import (
-    _paged_attention, _pool_block_size, _pool_layer, _pool_set_layer,
-    _scatter_kv)
-from deepspeed_tpu.inference.v2.modules.module_registry import module_preference
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
+    _paged_attention, _pool_block_size, _scatter_kv, last_token, layer_rows,
+    layer_trash, merge_layers, pool_pages_per_layer, split_layers)
 
 
 def _layernorm(x, scale, bias, eps):
@@ -33,7 +32,9 @@ def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
     (k_pool, v_pool), block_tables = cache["kv"], tables["kv"]
     S, Q = tokens.shape
     H, KV, Dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    L = cfg.num_hidden_layers
     bs = _pool_block_size(k_pool)  # [L, NB, KV, bs, Dh] (pair when int8)
+    nb = pool_pages_per_layer(k_pool)
     positions = seen[:, None] + jnp.arange(Q)[None, :]
 
     embed = params["embed_tokens"].astype(cfg.dtype)
@@ -45,8 +46,12 @@ def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
             y = y + p["bias"].astype(cfg.dtype)
         return y
 
-    for i in range(cfg.num_hidden_layers):
+    # the unrolled loop runs over the one merged pool (paged_layer.py, "The
+    # layout")
+    k_pool, v_pool = merge_layers((k_pool, v_pool))
+    for i in range(L):
         lp = params[f"layers_{i}"]
+        layer_tables = layer_rows(block_tables, i, nb)
         ln = lp["input_layernorm"]
         h = _layernorm(x, ln["scale"], ln["bias"], cfg.layer_norm_eps)
         if cfg.fused_qkv:
@@ -60,23 +65,20 @@ def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
             v = lin(lp["v_proj"], h).reshape(S, Q, KV, Dh)
         q = partial_rotary(q, positions, cfg.rope_theta, cfg.rotary_dim)
         k = partial_rotary(k, positions, cfg.rope_theta, cfg.rotary_dim)
-        kp, vp = _scatter_kv(_pool_layer(k_pool, i), _pool_layer(v_pool, i),
-                             k, v, block_tables, seen, q_len, bs)
-        k_pool = _pool_set_layer(k_pool, i, kp)
-        v_pool = _pool_set_layer(v_pool, i, vp)
-        attn = _paged_attention(q, kp, vp, block_tables, seen, bs, q_len=q_len,
-                                prefer=module_preference(cfg, "attention"))
+        k_pool, v_pool = _scatter_kv(k_pool, v_pool, k, v, layer_tables, seen,
+                                     q_len, bs, trash=layer_trash(i, nb))
+        attn = _paged_attention(q, k_pool, v_pool, layer_tables, seen, bs,
+                                q_len)
         attn_out = lin(lp["dense"], attn.reshape(S, Q, H * Dh))
         mlp_out = lin(lp["fc2"], jax.nn.gelu(lin(lp["fc1"], h),
                                              approximate=not cfg.gelu_exact))
         x = x + attn_out + mlp_out
+    k_pool, v_pool = split_layers((k_pool, v_pool), L)
 
     fl = params["final_layernorm"]
     x = _layernorm(x, fl["scale"], fl["bias"], cfg.layer_norm_eps)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(q_len - 1, 0)[:, None, None], axis=1)[:, 0]
     head = embed if cfg.tie_lm_head else params["lm_head"].astype(cfg.dtype)
-    logits = last @ head.T
+    logits = last_token(x, q_len) @ head.T
     if "lm_head_bias" in params:
         logits = logits + params["lm_head_bias"].astype(cfg.dtype)
     return logits.astype(jnp.float32), {"kv": (k_pool, v_pool)}

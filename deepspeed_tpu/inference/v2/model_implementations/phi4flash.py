@@ -32,11 +32,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.model_implementations.llama import (
-    _paged_attention, _scatter_kv)
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
+    _paged_attention, _scatter_kv, last_token, layer_rows, layer_trash,
+    merge_layers, split_layers)
 from deepspeed_tpu.inference.v2.model_implementations.parallel_block import (
     _layernorm)
-from deepspeed_tpu.inference.v2.modules.module_registry import module_preference
 from deepspeed_tpu.ops.registry import pallas_enabled, pallas_interpret
 
 
@@ -124,9 +124,8 @@ def _diff_attention(cfg, x, p, k_pool, v_pool, tables, seen, q_len, layer,
     q = qkv[..., :H * Dh].reshape(S, Q, H // 2, 2, 1, Dh)
     half = jnp.eye(2, dtype=q.dtype)[:, :, None]              # [2, 2, 1]
     qz = (q * half).reshape(S, Q, H, 2 * Dh)                  # own half, else 0
-    o = _paged_attention(qz, k_pool, v_pool, tables, seen, bs, q_len=q_len,
-                         window=window, softmax_scale=Dh ** -0.5,
-                         prefer=module_preference(cfg, "attention"))
+    o = _paged_attention(qz, k_pool, v_pool, tables, seen, bs, q_len,
+                         window=window, softmax_scale=Dh ** -0.5)
     o = o.reshape(S, Q, H // 2, 2, 2 * Dh).astype(f32)
     lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(layer, f32))
     lam = jnp.exp(jnp.sum(m["lambda_q1"].astype(f32) * m["lambda_k1"].astype(f32))) \
@@ -154,33 +153,31 @@ def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
     seen_win = seen - tables["window_base"]
     fresh = seen == 0
 
-    # every stacked pool is ONE pool to its layer loop (llama.py's
-    # _ragged_trunk): layer i owns rows [i*n, (i+1)*n), reached by offsetting
-    # the tables, and the pools ride the scan carry
-    nbw, ns = k_win.shape[1], conv.shape[1]
-    merge = lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:])
-    split = lambda a, like: a.reshape(like.shape)
-    kw, vw, cv, sm = merge(k_win), merge(v_win), merge(conv), merge(ssm)
-    kf, vf = merge(k_full), merge(v_full)
+    # every stacked pool is one merged pool on its loop's carry
+    # (paged_layer.py, "The layout"), the slots of state like the pages
+    nbw, nbf, ns = k_win.shape[1], k_full.shape[1], conv.shape[1]
+    kw, vw, cv, sm, kf, vf = merge_layers(
+        (k_win, v_win, conv, ssm, k_full, v_full))
 
     x = params["embed_tokens"].astype(cfg.dtype)[tokens]
 
     def front(carry, xs):
         x, kw, vw, cv, sm = carry
         p, i = xs
-        x, cv, sm, _ = _mamba(cfg, x, p["mamba"], cv, sm, slots + i * ns,
-                              q_len, fresh)
+        x, cv, sm, _ = _mamba(cfg, x, p["mamba"], cv, sm,
+                              layer_rows(slots, i, ns), q_len, fresh)
         x, kw, vw = _diff_attention(
-            cfg, x, p["window"], kw, vw, t_win + i * nbw, seen_win, q_len,
-            2 * i + 1, True, cfg.sliding_window, i * nbw + nbw - 1)
+            cfg, x, p["window"], kw, vw, layer_rows(t_win, i, nbw), seen_win,
+            q_len, 2 * i + 1, True, cfg.sliding_window, layer_trash(i, nbw))
         return (x, kw, vw, cv, sm), None
 
     (x, kw, vw, cv, sm), _ = jax.lax.scan(
         front, (x, kw, vw, cv, sm), (params["front"], jnp.arange(P)))
     x, cv, sm, memory = _mamba(cfg, x, params["middle_mamba"], cv, sm,
-                               slots + P * ns, q_len, fresh)
+                               layer_rows(slots, P, ns), q_len, fresh)
     x, kf, vf = _diff_attention(cfg, x, params["full"], kf, vf, t_full, seen,
-                                q_len, half + 1, True, None, kf.shape[0] - 1)
+                                q_len, half + 1, True, None,
+                                layer_trash(0, nbf))
 
     def back(x, xs):
         p, i = xs
@@ -192,10 +189,9 @@ def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
     x, _ = jax.lax.scan(back, x, (params["back"], jnp.arange(cfg.back_periods)))
 
     x = _ln(x, params["final_layernorm"], cfg.layer_norm_eps)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(q_len - 1, 0)[:, None, None], axis=1)[:, 0]
+    last = last_token(x, q_len)
     logits = last @ params["embed_tokens"].astype(cfg.dtype).T   # tied
-    cache = {"kv": (split(kf, k_full), split(vf, v_full)),
-             "window": (split(kw, k_win), split(vw, v_win)),
-             "state": {"conv": split(cv, conv), "ssm": split(sm, ssm)}}
+    cache = {"kv": split_layers((kf, vf), 1),
+             "window": split_layers((kw, vw), P),
+             "state": split_layers({"conv": cv, "ssm": sm}, conv.shape[0])}
     return logits.astype(jnp.float32), cache

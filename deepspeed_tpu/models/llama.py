@@ -43,10 +43,6 @@ class LlamaConfig:
     scan_layers: bool = True
     remat: bool = True
     dtype: Any = jnp.bfloat16
-    # serving-module pins ((interface, impl_name) pairs) installed by
-    # InferenceEngineV2 so the choice participates in the jit cache key —
-    # see inference/v2/modules/module_registry.py
-    serve_modules: Any = None
 
     def __post_init__(self):
         if self.head_dim is None:

@@ -42,9 +42,6 @@ class MixtralConfig:
     rope_theta: float = 1e6
     remat: bool = True
     dtype: Any = jnp.bfloat16
-    # serving-module pins ((interface, impl_name) pairs) installed by
-    # InferenceEngineV2 — see inference/v2/modules/module_registry.py
-    serve_modules: Any = None
 
     @staticmethod
     def tiny(**kw):

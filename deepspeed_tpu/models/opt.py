@@ -30,9 +30,6 @@ class OPTConfig:
     dropout: float = 0.0
     layer_norm_epsilon: float = 1e-5
     scan_layers: bool = True
-    # serving-module pins ((interface, impl_name) pairs) installed by
-    # InferenceEngineV2 — see inference/v2/modules/module_registry.py
-    serve_modules: Any = None
     remat: bool = True
     dtype: Any = jnp.bfloat16
 

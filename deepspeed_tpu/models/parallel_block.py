@@ -52,9 +52,6 @@ class ParallelBlockConfig:
     tie_lm_head: bool = False
     remat: bool = True
     dtype: Any = jnp.bfloat16
-    # serving-module pins ((interface, impl_name) pairs) installed by
-    # InferenceEngineV2 — see inference/v2/modules/module_registry.py
-    serve_modules: Any = None
 
     def _bias(self, which):
         v = getattr(self, which)

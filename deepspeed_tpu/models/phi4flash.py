@@ -55,7 +55,6 @@ class Phi4FlashConfig:
     mamba_dt_rank: Any = None        # None: ceil(hidden_size / 16)
     subln_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
-    serve_modules: Any = None
 
     def __post_init__(self):
         if self.mamba_dt_rank is None:

@@ -15,7 +15,7 @@ class RaggedOpsBuilder(OpBuilder):
     NAME = "ragged_ops"
 
     def reference_impl(self):
-        from deepspeed_tpu.inference.v2.model_implementations.llama import (
+        from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
             _paged_attention_dense)
         return _paged_attention_dense
 

@@ -77,6 +77,25 @@ def pallas_enabled():
     return jax.devices()[0].platform == "tpu"
 
 
+def takes_kernel(kernel, supported, detail):
+    """Whether a call site with a pure-XLA twin takes its Pallas kernel:
+    Pallas is on and the kernel tiles the call's shapes (``supported``, the
+    kernel module's ``is_supported``). A no is a dispatch record (``kernel``,
+    "fallback", "no_tpu" | "unsupported_shape") and, with Pallas on, one
+    warning naming ``detail``: a backend that is off is expected, shapes that
+    lose the fast path on the chip are not."""
+    from deepspeed_tpu import telemetry
+    from deepspeed_tpu.utils.logging import warning_once
+    if not pallas_enabled():
+        telemetry.record_dispatch(kernel, "fallback", "no_tpu")
+        return False
+    if not supported:
+        telemetry.record_dispatch(kernel, "fallback", "unsupported_shape")
+        warning_once(f"{kernel}: {detail}; XLA fallback")
+        return False
+    return True
+
+
 # ---------------------------------------------------------------------------
 # Block-config resolution — the tuning-table half of kernel dispatch.
 #

@@ -90,7 +90,7 @@ def smoke_flash():
 
 
 def smoke_paged():
-    from deepspeed_tpu.inference.v2.model_implementations.llama import (
+    from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
         _paged_attention_dense)
     from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
 

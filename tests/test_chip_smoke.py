@@ -20,10 +20,8 @@ def smoke_env(monkeypatch):
     process-wide fallback notes start empty (another test file of the same
     worker may have left some)."""
     from deepspeed_tpu import telemetry
-    from deepspeed_tpu.inference.v2.modules import heuristics
     from deepspeed_tpu.ops import flash_attention as fa
     monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
-    monkeypatch.setattr(heuristics, "_warned", set())
     monkeypatch.setattr(fa, "_warned_shapes", set())
     telemetry.configure(enabled=True)
     yield
@@ -90,6 +88,20 @@ def test_fleet_parity_rejects_a_token_that_is_no_near_tie(smoke_env):
         chip_smoke._same_or_near_tie(
             p, cfg, params, {0: prompt}, {0: [best] + tail},
             {0: [worst] + tail})
+
+
+def test_dense_paged_attention_fallback_fails_the_check(smoke_env):
+    """Pages of 12 tokens do not tile the paged kernel: the forward takes the
+    dense twin, and the dispatch check that passed before it refuses."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference.v2.model_implementations import paged_layer
+    chip_smoke.check_dispatch()
+    pool = jnp.zeros((8, 2, 12, 64))
+    paged_layer._paged_attention(
+        jnp.zeros((2, 1, 4, 64)), pool, pool, jnp.zeros((2, 4), jnp.int32),
+        jnp.zeros((2,), jnp.int32), 12, jnp.ones((2,), jnp.int32))
+    with pytest.raises(chip_smoke.SmokeFailure, match="paged_mha.*unsupported_shape"):
+        chip_smoke.check_dispatch()
 
 
 def test_failed_check_raises(smoke_env, monkeypatch):

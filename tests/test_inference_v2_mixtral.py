@@ -105,10 +105,23 @@ def test_build_hf_engine_rejects_unknown_family(tmp_path):
         build_hf_engine(d)
 
 
-def test_heuristics_dense_on_cpu():
-    from deepspeed_tpu.inference.v2.modules.heuristics import instantiate_attention
-    impl, fn = instantiate_attention((2, 1, 4, 64), (8, 16, 2, 64))
-    assert impl == "dense" and fn is None  # cpu test mesh
+def test_paged_attention_dense_on_cpu(monkeypatch):
+    """Kernel-eligible shapes, but no TPU and no interpret mode: the read is
+    the dense twin's program, and no Pallas call is traced."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference.v2.model_implementations import paged_layer
+    monkeypatch.delenv("DS_TPU_PALLAS_INTERPRET", raising=False)
+    q = jnp.zeros((2, 1, 4, 64))
+    pool = jnp.zeros((8, 2, 16, 64))
+    tables, seen = jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32)
+    q_len = jnp.ones((2,), jnp.int32)
+    took = jax.make_jaxpr(lambda *a: paged_layer._paged_attention(
+        *a, 16, q_len))(q, pool, pool, tables, seen)
+    dense = jax.make_jaxpr(lambda *a: paged_layer._paged_attention_dense(
+        *a, 16))(q, pool, pool, tables, seen)
+    assert "pallas_call" not in str(took)
+    assert str(took) == str(dense)
 
 
 def test_qwen2_bias_through_v2_engine(tmp_path):

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
-from deepspeed_tpu.inference.v2.model_implementations.llama import (
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
     _paged_attention_dense)
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler

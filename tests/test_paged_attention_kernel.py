@@ -12,7 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.model_implementations.llama import (
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
     _paged_attention_dense)
 from deepspeed_tpu.ops.pallas.paged_attention import is_supported, paged_mha
 
@@ -92,8 +92,6 @@ def test_is_supported():
 def test_sliding_window_matches_dense(window):
     """Mistral-style windowed masking in the kernel (the only path serving
     windowed models on real TPU) vs the dense twin."""
-    from deepspeed_tpu.inference.v2.model_implementations.llama import (
-        _paged_attention_dense)
     q, kp, vp, bt, seen, q_len = make_case(S=3, Q=2, seed=7)
     out_k = paged_mha(q, kp, vp, bt, seen, q_len, window=window, interpret=True)
     out_d = _paged_attention_dense(q, kp, vp, bt, seen, kp.shape[2],

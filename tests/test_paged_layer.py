@@ -1,0 +1,319 @@
+"""``model_implementations/paged_layer.py``: the one place a ragged forward
+touches its paged cache and picks its kernel, and the three call sites that
+choose a Pallas kernel from what they can observe (Pallas on, shapes tile)
+with a dispatch record for the other way out."""
+
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu import telemetry
+from deepspeed_tpu.inference.v2.model_implementations import (
+    mixtral, opt, paged_layer, parallel_block)
+
+
+@pytest.fixture
+def dispatch(monkeypatch):
+    """Interpret-mode kernels and the dispatch records of this test alone:
+    ``records()`` -> {(kernel, outcome, reason)} without the tuning rows and
+    the single-device ones of the kernel's own dispatch."""
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.delenv("DS_TPU_DISABLE_PALLAS", raising=False)
+    telemetry.reset()
+    telemetry.configure(enabled=True)
+    yield lambda: {k for k in telemetry.get_telemetry().dispatch_stats
+                   if k[1] != "tuning" and k[2] != "no_mesh"}
+    telemetry.configure(enabled=False)
+    telemetry.reset()
+
+
+def _traces_kernel(fn, *args):
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+# -- which kernel reads the pages ---------------------------------------------
+
+@pytest.mark.parametrize("block_size,disable,kernel,record", [
+    (8, False, True, None),
+    (12, False, False, ("paged_mha", "fallback", "unsupported_shape")),
+    (8, True, False, ("paged_mha", "fallback", "no_tpu")),
+], ids=["tileable", "block-size-12", "pallas-disabled"])
+def test_paged_attention_choice(dispatch, monkeypatch, block_size, disable,
+                                kernel, record):
+    """The kernel for shapes it tiles, the dense twin for a block size it
+    refuses and with Pallas off, each way out with its record and reason;
+    either way the dense twin's numbers."""
+    if disable:
+        monkeypatch.setenv("DS_TPU_DISABLE_PALLAS", "1")
+    rng = np.random.default_rng(0)
+    S, Q, H, KV, Dh, NB, MB = 3, 2, 4, 2, 64, 10, 3
+    q = jnp.asarray(rng.normal(size=(S, Q, H, Dh)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(NB, KV, block_size, Dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(NB, KV, block_size, Dh)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(NB - 1)[:S * MB].reshape(S, MB),
+                         jnp.int32)
+    seen = jnp.asarray([0, 5, block_size + 1], jnp.int32)
+    q_len = jnp.asarray([2, 1, 2], jnp.int32)
+
+    def read(q, kp, vp):
+        return paged_layer._paged_attention(q, kp, vp, tables, seen,
+                                            block_size, q_len, window=None)
+
+    assert _traces_kernel(read, q, kp, vp) == kernel
+    assert dispatch() == ({record} if record else set())
+    want = paged_layer._paged_attention_dense(q, kp, vp, tables, seen,
+                                              block_size)
+    valid = np.arange(Q)[None, :] < np.asarray(q_len)[:, None]
+    np.testing.assert_allclose(np.asarray(read(q, kp, vp))[valid],
+                               np.asarray(want)[valid], atol=2e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("d_model,d_ff,kernel", [(128, 256, True),
+                                                 (96, 256, False)])
+def test_moe_ffn_choice(dispatch, d_model, d_ff, kernel):
+    """The grouped GEMM for 128-tileable dims, the einsum otherwise; the
+    einsum is the oracle of both."""
+    rng = np.random.default_rng(1)
+    T, E = 8, 4
+    x = jnp.asarray(rng.normal(size=(T, d_model)), jnp.float32)
+    gate = jnp.asarray(rng.normal(size=(d_model, E)), jnp.float32)
+    w1, w3 = (jnp.asarray(rng.normal(size=(E, d_model, d_ff)) / 8, jnp.float32)
+              for _ in range(2))
+    w2 = jnp.asarray(rng.normal(size=(E, d_ff, d_model)) / 8, jnp.float32)
+
+    def ffn(x, **kw):
+        return mixtral._moe_ffn(x, gate, w1, w2, w3, k=2, dtype=jnp.float32,
+                                **kw)
+
+    assert _traces_kernel(ffn, x) == kernel
+    assert dispatch() == (set() if kernel else
+                          {("moe_ffn_gmm", "fallback", "unsupported_shape")})
+    assert not _traces_kernel(lambda x: ffn(x, force_einsum=True), x)
+    np.testing.assert_allclose(np.asarray(ffn(x)),
+                               np.asarray(ffn(x, force_einsum=True)),
+                               atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("shape,group,kernel", [((512, 512), 128, True),
+                                                ((2, 128, 128), 64, False)],
+                         ids=["2d-kernel", "3d-dense"])
+def test_quantized_matmul_choice(dispatch, shape, group, kernel):
+    """The fused dequant-GEMM for a 2-D weight it tiles, in parity with
+    dequantize-then-matmul; a 3-D weight takes the dense path."""
+    from deepspeed_tpu.inference.quantization import quantize_param_tree
+    w = np.random.default_rng(0).normal(size=shape).astype(np.float32)
+    qp = quantize_param_tree({"k": {"kernel": w}}, num_bits=8,
+                             group_size=group)["k"]["kernel"]
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(8, shape[-2])),
+                    jnp.float32)
+    assert _traces_kernel(qp.matmul, x) == kernel
+    assert dispatch() == (
+        set() if kernel else
+        {("quantized_matmul", "fallback", "unsupported_shape")})
+    np.testing.assert_allclose(np.asarray(qp.matmul(x)),
+                               np.asarray(x @ qp.dequantized(x.dtype)),
+                               rtol=2e-2, atol=2e-2)
+
+
+# -- the layout: what a forward writes, and where -----------------------------
+
+def _mixtral():
+    from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
+    cfg = MixtralConfig.tiny(remat=False, dtype=jnp.float32)
+    return mixtral, MixtralForCausalLM(cfg)
+
+
+def _opt(scan_layers):
+    from deepspeed_tpu.models.opt import OPTConfig, OPTForCausalLM
+    cfg = OPTConfig.tiny(scan_layers=scan_layers, remat=False,
+                         dtype=jnp.float32)
+    return opt, OPTForCausalLM(cfg)
+
+
+def _parallel(config):
+    from deepspeed_tpu.models.parallel_block import ParallelBlockForCausalLM
+    return parallel_block, ParallelBlockForCausalLM(
+        config(remat=False, dtype=jnp.float32))
+
+
+def _falcon():
+    from deepspeed_tpu.models.falcon import tiny_falcon_config
+    return _parallel(tiny_falcon_config)
+
+
+def _phi():
+    from deepspeed_tpu.models.phi import tiny_phi_config
+    return _parallel(tiny_phi_config)
+
+
+FAMILIES = {"mixtral": _mixtral, "opt-scan": lambda: _opt(True),
+            "opt-layers": lambda: _opt(False), "falcon": _falcon,
+            "phi": _phi}
+
+BS, NB, MB = 8, 6, 3          # tokens a page, pages a layer (+1 trash), table
+SENTINEL = 77
+
+
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def family(request):
+    impl, model = FAMILIES[request.param]()
+    ids = np.zeros((1, 8), np.int32)
+    params = model.init(jax.random.PRNGKey(0), {"input_ids": ids})["params"]
+    return impl, model.config, params
+
+
+def _pools(cfg, int8):
+    """Stacked K and V pools [L, NB+1, KV, BS, Dh] holding SENTINEL (pairs
+    with their scale pools when ``int8``)."""
+    from deepspeed_tpu.inference.v2.ragged.cache_groups import homogeneous
+    g, = homogeneous(cfg)
+    shape = (g.layers, NB + 1, g.kv_heads, BS, g.head_dim)
+
+    def pool():
+        if not int8:
+            return jnp.full(shape, SENTINEL, jnp.float32)
+        return (jnp.full(shape, SENTINEL, jnp.int8),
+                jnp.full(shape[:3] + (1, BS), SENTINEL, jnp.float32))
+    return pool(), pool()
+
+
+def _mixed_batch(cfg):
+    """A prompt chunk across a page boundary, a decode row deep in its
+    second page, a first chunk of 3 and a padded row (no tokens, a table of
+    trash pages): rows x 8 positions, most of them padding."""
+    rng = np.random.default_rng(5)
+    q_len = np.asarray([8, 1, 3, 0], np.int32)
+    seen = np.asarray([5, 13, 0, 0], np.int32)
+    tables = np.full((4, MB), NB, np.int32)
+    tables[0, :2], tables[1, :2], tables[2, :1] = [4, 1], [0, 5], [2]
+    tokens = rng.integers(0, cfg.vocab_size, (4, 8)).astype(np.int32)
+    return tokens, q_len, seen, tables
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["fp", "int8"])
+def test_forward_writes_the_pages_of_a_layer_by_layer_write(
+        family, int8, monkeypatch):
+    """One forward over a mixed batch leaves the stacked pools as writing
+    each layer's K and V rows into THAT layer's pool, page by page through
+    the sequence's own table, leaves them: every real token in its page and
+    slot, every padded position in its layer's trash page, every other page
+    as it was."""
+    impl, cfg, params = family
+    tokens, q_len, seen, tables = _mixed_batch(cfg)
+    wrote = []                       # a layer's (k, v) rows, in layer order
+    scatter = impl._scatter_kv
+
+    def recording(k_pool, v_pool, k, v, *rest, **kw):
+        wrote.append((np.asarray(k), np.asarray(v)))
+        return scatter(k_pool, v_pool, k, v, *rest, **kw)
+
+    monkeypatch.setattr(impl, "_scatter_kv", recording)
+    with jax.disable_jit():          # the loop's values, not its tracers
+        _, cache = impl.ragged_forward(
+            cfg, params, {"kv": _pools(cfg, int8)}, jnp.asarray(tokens),
+            jnp.asarray(q_len), jnp.asarray(seen), {"kv": jnp.asarray(tables)})
+    assert len(wrote) == cfg.num_hidden_layers
+
+    # the layer-by-layer write, in numpy, on dequantised pools
+    shape = np.shape(_pools(cfg, False)[0])
+    blank = float(SENTINEL * (SENTINEL if int8 else 1))   # int8 x its scale
+    want = [np.full(shape, blank, np.float32) for _ in "kv"]
+    touched = np.zeros(shape[:2], bool)
+    for layer, rows in enumerate(wrote):
+        touched[layer, NB] = True                 # its trash page: any value
+        for s in range(len(q_len)):
+            for t in range(q_len[s]):
+                page = tables[s, (seen[s] + t) // BS]
+                for pool, row in zip(want, rows):
+                    pool[layer, page, :, (seen[s] + t) % BS] = row[s, t]
+                touched[layer, page] = True
+
+    for got, ref in zip(cache["kv"], want):
+        data, scale = paged_layer._pool_parts(got)
+        assert data.shape == shape
+        data = np.asarray(data, np.float32)
+        tol = np.zeros_like(data)
+        if int8:                     # a written row is off by half a step
+            scale = np.swapaxes(np.asarray(scale), -1, -2)   # [.., BS, 1]
+            assert (scale[~touched] == SENTINEL).all()
+            data, tol = data * scale, 0.51 * scale + tol
+        real = touched.copy()
+        real[:, NB] = False
+        assert (np.abs(data - ref)[real] <= tol[real]).all()
+        assert (data[~touched] == blank).all()
+        # padded positions went somewhere: to each layer's own trash page
+        assert (np.asarray(paged_layer._pool_parts(got)[0])[:, NB]
+                != SENTINEL).any(axis=(1, 2, 3)).all()
+
+
+def _walk(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
+
+
+def test_no_layer_of_the_pool_is_sliced_out_and_written_back(family):
+    """The traced forward never updates a slice of a stacked pool
+    (``pool.at[i].set(layer)``) and never hands a stacked pool to a scan as
+    input or output: the pools are merged and ride the loop's carry."""
+    impl, cfg, params = family
+    tokens, q_len, seen, tables = _mixed_batch(cfg)
+    pools = _pools(cfg, False)
+    stacked = pools[0].shape
+    jaxpr = jax.make_jaxpr(
+        lambda p, c, *a: impl.ragged_forward(cfg, p, c, *a[:-1],
+                                             {"kv": a[-1]}))(
+        params, {"kv": pools}, tokens, q_len, seen, tables)
+    scans = 0
+    for eqn in _walk(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        if name in ("dynamic_update_slice", "scatter"):
+            assert eqn.invars[0].aval.shape != stacked, eqn
+        if name == "scan":
+            scans += 1
+            carried = eqn.params["num_consts"] + eqn.params["num_carry"]
+            through = [v.aval.shape for v in eqn.invars[carried:]] + \
+                [v.aval.shape for v in eqn.outvars[eqn.params["num_carry"]:]]
+            assert stacked not in through, through
+    assert scans == (1 if "layers" in params else 0)
+
+
+# -- the logits gather --------------------------------------------------------
+
+def test_a_row_of_no_tokens_reads_position_zero():
+    x = jnp.arange(2 * 4 * 3, dtype=jnp.float32).reshape(2, 4, 3)
+    got = paged_layer.last_token(x, jnp.asarray([0, 3], jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(x)[[0, 1], [0, 2]])
+
+
+# -- a family is one row ------------------------------------------------------
+
+@pytest.mark.parametrize("name,module,verify", [
+    ("mixtral", "mixtral", False), ("opt-scan", "opt", False),
+    ("falcon", "parallel_block", False), ("phi", "parallel_block", False)])
+def test_the_factory_resolves_a_family_to_its_module(name, module, verify):
+    from deepspeed_tpu.inference.v2 import engine_factory as ef
+    _, model = FAMILIES[name]()
+    assert ef.resolve_forward_fn(model).__module__.endswith(
+        "model_implementations." + module)
+    assert (ef.resolve_verify_fn(model) is not None) == verify
+    by_name = ef.resolve_forward_fn(model, family="mistral")
+    assert by_name.__module__.endswith("model_implementations.llama")
+    assert ef.resolve_verify_fn(model, family="qwen2") is not None
+
+
+def test_the_llama_forward_refuses_unstacked_layers():
+    from deepspeed_tpu.inference.v2 import engine_factory as ef
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    model = LlamaForCausalLM(LlamaConfig.tiny(scan_layers=False, remat=False))
+    with pytest.raises(ValueError, match="scan_layers=True"):
+        ef.resolve_forward_fn(model)
+    stacked = LlamaForCausalLM(dataclasses.replace(model.config,
+                                                   scan_layers=True))
+    assert ef.resolve_verify_fn(stacked).__name__ == "ragged_forward_verify"
