@@ -240,9 +240,10 @@ class InferenceEngineV2:
                         batch_tokens: List[np.ndarray],
                         verify_k: int = None, defer_commit=(), sample=None):
         """Run one round's rows through the ragged forward, dispatched by
-        chunk-length class (``dispatch_rows``): the short rows together as
-        [D, 8], each long row alone as [1, C], back to back with the donated
-        pools threaded from one to the next and no fetch in between. Returns
+        chunk-length class (``dispatch_rows``): the rows of one new token
+        together as [D, 1] (a verify round's short rows as [D, max(8, k)]),
+        every other row alone as [1, C], back to back with the donated pools
+        threaded from one to the next and no fetch in between. Returns
         a ``DispatchedRound`` of each dispatch's FULL padded [S-bucket, vocab]
         logits as a device array (no host transfer); ``host_fetch`` lands it
         as [len(uids), vocab].
@@ -273,8 +274,8 @@ class InferenceEngineV2:
         further = self._state.has_further_groups
         self.last_window_pages_freed = self.last_state_slots = 0
         self.last_live_pages = self.last_table_slots = 0
-        for rows, min_seqs in dispatch_rows(lengths,
-                                            short_row_tokens(verify_k)):
+        for rows, min_seqs, min_tokens in dispatch_rows(
+                lengths, short_row_tokens(verify_k)):
             # explicit begin/end, and the host-to-device copies as arguments
             # of the jitted call: tracing a new batch shape inside ``with``
             # blocks cost set-up 0.07 s a shape more on the chip (PERF.md,
@@ -301,7 +302,7 @@ class InferenceEngineV2:
                 wrapper.insert_sequence(uid, np.asarray(toks, np.int32),
                                         seq.seen_tokens, seq.kv_blocks)
                 seqs.append(seq)
-            arrays = wrapper.build(min_seqs)
+            arrays = wrapper.build(min_seqs, min_tokens)
             seq_bucket, chunk_bucket = arrays["tokens"].shape
             tables = {"kv": arrays["block_tables"]}
             if further:

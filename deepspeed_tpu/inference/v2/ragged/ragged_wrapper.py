@@ -12,33 +12,45 @@ decode — the property the reference gets from ragged kernels.
 One rectangle per round would give every row the longest row's width: 64
 decode rows beside one 400-token prompt chunk are 32768 slots. A round is
 therefore dispatched by chunk-length class (``dispatch_rows``): its short
-rows together as ``[D, 8]``, each long row alone as ``[1, C]``.
+rows together, each other row alone as ``[1, C]``. In a plain round the
+short rows are the rows of exactly ONE new token, the decode rows, and go as
+``[D, 1]``: the dense layers, the KV write and the scan cost what a
+dispatch's token slots are, padding included, and a decode row has one. A
+verify round's rows carry ``[last] + drafts`` and go as ``[D, max(8, k)]``.
 """
 
 import numpy as np
 
-#: most new tokens of a short row: decode rows, verify rows of
-#: ``[last] + drafts``, a prompt's last few tokens
+#: floor of the width of a verify round's short class (``short_row_tokens``).
+#: A plain round's short class is one token wide and does not read this.
 SHORT_ROW_TOKENS = 8
+
+#: least width of a row dispatched alone: a prompt's last 2-8 tokens take the
+#: ``[1, 16]`` program that chunks of 9-16 tokens compile anyway, so no
+#: twelfth program is warmed for them
+LONE_ROW_TOKENS = 16
 
 
 def short_row_tokens(verify_k=None):
-    """Most new tokens of a short row in a round that verifies ``verify_k``
-    positions a row (None: a plain round): a verify row stays short
-    whatever the verify width."""
-    return max(SHORT_ROW_TOKENS, verify_k or 0)
+    """Most new tokens of a row of a round's short class, which is also that
+    class's width: 1 in a plain round (``verify_k`` None or 0), in a round
+    that verifies ``verify_k`` positions a row the verify width and at least
+    ``SHORT_ROW_TOKENS`` (a verify row stays short whatever the width)."""
+    return max(SHORT_ROW_TOKENS, verify_k) if verify_k else 1
 
 
 def dispatch_rows(lengths, short):
     """The dispatches of a round whose rows carry ``lengths`` new tokens, as
-    [(rows, min_seqs)]: the rows of at most ``short`` tokens together (if
-    any), padded to at least 4 sequences, then each longer row alone and
-    unpadded (``RaggedBatchWrapper.build(min_seqs)``). Only ``[D, short]``
-    and ``[1, C]`` batches follow from it, D and C powers of two, whatever
-    the round mixes."""
+    [(rows, min_seqs, min_tokens)] for ``RaggedBatchWrapper.build``: the rows
+    of at most ``short`` tokens together (if any), padded to at least 4
+    sequences and exactly ``short`` tokens, then each longer row alone, its
+    width at least ``LONE_ROW_TOKENS``. Only ``[D, short]`` and ``[1, C]``
+    batches follow from it, D and C powers of two, whatever the round
+    mixes."""
     together = [i for i, n in enumerate(lengths) if n <= short]
-    alone = [([i], 1) for i, n in enumerate(lengths) if n > short]
-    return ([(together, 4)] if together else []) + alone
+    alone = [([i], 1, LONE_ROW_TOKENS) for i, n in enumerate(lengths)
+             if n > short]
+    return ([(together, 4, short)] if together else []) + alone
 
 
 class RaggedBatchWrapper:
@@ -76,20 +88,20 @@ class RaggedBatchWrapper:
     def uids(self):
         return [u for u, _, _, _ in self._rows]
 
-    def build(self, min_seqs=4):
+    def build(self, min_seqs=4, min_tokens=1):
         """Pad to the static [S, Q] / [S, MB] device layout.
 
-        S and Q are bucketed to the smallest power of two covering the batch
-        (min 4 sequences / 8 tokens) to bound recompiles while keeping decode
-        batches cheap; ``dispatch_rows`` says which ``min_seqs`` a dispatch
-        takes.
+        S and Q are bucketed to the smallest power-of-two multiple of
+        ``min_seqs`` / ``min_tokens`` covering the batch, to bound recompiles
+        while keeping decode batches cheap; ``dispatch_rows`` says which
+        floors a dispatch takes.
         """
         S = min_seqs
         while S < len(self._rows):
             S *= 2
         S = min(S, self.max_seqs)
         longest = max((len(t) for _, t, _, _ in self._rows), default=1)
-        Q = SHORT_ROW_TOKENS
+        Q = min_tokens
         while Q < longest:
             Q *= 2
         Q = min(Q, self.max_q)

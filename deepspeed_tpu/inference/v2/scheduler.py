@@ -124,9 +124,9 @@ class SplitFuseScheduler:
         self.prefill_tokens_executed = 0
         self.prefill_tokens_saved = 0
         # batch occupancy without a profile: rounds, the dispatches they
-        # took (short rows together, each long row alone), the tokens they
-        # carried and the [sequence bucket x chunk bucket] slots the engine
-        # padded them to (the sums of the ``serving/build`` spans)
+        # took (the short class together, every other row alone), the tokens
+        # they carried and the [sequence bucket x chunk bucket] slots the
+        # engine padded them to (the sums of the ``serving/build`` spans)
         self.rounds = 0
         self.dispatches = 0
         self.real_tokens = 0

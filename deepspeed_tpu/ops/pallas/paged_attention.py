@@ -21,8 +21,9 @@ the TPU's DMA engines rather than translated:
 - the grid is ``(seqs, kv_heads // heads)``. ``heads`` (KV heads a step),
   the query-row tile and ``pages`` follow from the shapes so that the
   resident query state, the page buffers and the score tile fit VMEM
-  (``_walk_plan``): a ``[D, 8]`` decode dispatch takes all its KV heads in
-  one step, a ``[1, 512]`` chunk one head a step in row tiles of 512.
+  (``_walk_plan``): a ``[D, 1]`` decode dispatch (and a verify round's
+  ``[D, 8]``) takes all its KV heads in one step, a ``[1, 512]`` chunk one
+  head a step in row tiles of 512.
 
 A pool whose rows do not fill a lane tile (``Dh`` of 64, 80, 96) cannot be
 copied by hand: Mosaic slices such an HBM array only for the grid's own

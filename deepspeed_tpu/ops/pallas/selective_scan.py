@@ -8,8 +8,10 @@ For every row ``r`` and real position ``t < q_len[r]``::
 with ``h`` a ``[d_state, d_inner]`` float32 state per row that enters as
 ``h0`` and leaves as ``h_T`` (the state after position ``q_len - 1``).
 Positions ``>= q_len`` are not run: their ``y`` is zero and they leave ``h``
-untouched, so a padded ``[D, 8]`` decode dispatch advances each row by its own
-count of real tokens and a row of no tokens hands its state back as it came.
+untouched, so a padded dispatch (a ``[1, C]`` chunk of fewer than ``C`` tokens,
+a ``[D, 1]`` decode dispatch of fewer than ``D`` rows, whose one time step is
+padded to a chunk of eight here) advances each row by its own count of real
+tokens and a row of no tokens hands its state back as it came.
 
 TPU design: grid ``(rows, d_inner blocks)``; ``q_len`` is scalar-prefetched;
 the state block lives in VMEM scratch across the time loop; ``d_inner`` is the

@@ -110,35 +110,38 @@ def smoke_paged():
     check("paged_mha decode", np.asarray(out)[mask], np.asarray(ref)[mask],
           atol=0.05)
 
-    # the walk over live pages (heads of 128): a ragged [8, 8] dispatch over
-    # a 256-slot table of which a row has 1-50 pages live, one row padding;
-    # every dead slot points at a page of NaN, which the dense twin reads
-    # zeroed and the kernel must never read
-    S, Q, H, KV, Dh, bs, MB = 8, 8, 32, 8, 128, 64, 256
-    live = rng.integers(1, 51, size=S)
-    NB = int(live.sum()) + 2
-    poison, trash = NB - 2, NB - 1
-    ks = jax.random.split(jax.random.PRNGKey(2), 3)
-    q = jax.random.normal(ks[0], (S, Q, H, Dh), jnp.bfloat16)
-    kp = jax.random.normal(ks[1], (NB, KV, bs, Dh), jnp.bfloat16)
-    vp = jax.random.normal(ks[2], (NB, KV, bs, Dh), jnp.bfloat16)
-    bt = np.full((S, MB), poison, np.int32)
-    pages = rng.permutation(NB - 2)
-    for i, n in enumerate(live):
-        bt[i, :n], pages = pages[:n], pages[n:]
-    q_len = rng.integers(1, Q + 1, size=S).astype(np.int32)
-    seen = (live * bs - q_len - rng.integers(0, bs - Q, size=S)).astype(np.int32)
-    bt[-1], seen[-1], q_len[-1] = trash, 0, 0
-    nan = lambda pool: pool.at[poison].set(jnp.nan)
-    out = jax.jit(paged_mha)(q, nan(kp), nan(vp), bt, seen, q_len)
-    zero = lambda pool: pool.at[poison].set(0)
-    ref = _paged_attention_dense(q, zero(kp), zero(vp), jnp.asarray(bt),
-                                 jnp.asarray(seen), bs)
-    mask = np.arange(Q)[None, :] < q_len[:, None]
-    assert np.isfinite(np.asarray(out, np.float32)).all(), \
-        "paged_mha read past a row's live pages"
-    check("paged_mha ragged walk, wide table", np.asarray(out)[mask],
-          np.asarray(ref)[mask], atol=0.05)
+    # the walk over live pages (heads of 128): a row has 1-50 pages live, the
+    # last row is padding; a ragged [8, 8] dispatch (a verify round) over a
+    # 256-slot table and a [64, 1] one (a decode round: 4 query rows a KV
+    # head, under a sublane tile) over the cells' 64 slots; every dead slot
+    # points at a page of NaN, which the dense twin reads zeroed and the
+    # kernel must never read
+    H, KV, Dh, bs = 32, 8, 128, 64
+    for S, Q, MB in ((8, 8, 256), (64, 1, 64)):
+        live = rng.integers(1, 51, size=S)
+        NB = int(live.sum()) + 2
+        poison, trash = NB - 2, NB - 1
+        ks = jax.random.split(jax.random.PRNGKey(2), 3)
+        q = jax.random.normal(ks[0], (S, Q, H, Dh), jnp.bfloat16)
+        kp = jax.random.normal(ks[1], (NB, KV, bs, Dh), jnp.bfloat16)
+        vp = jax.random.normal(ks[2], (NB, KV, bs, Dh), jnp.bfloat16)
+        bt = np.full((S, MB), poison, np.int32)
+        pages = rng.permutation(NB - 2)
+        for i, n in enumerate(live):
+            bt[i, :n], pages = pages[:n], pages[n:]
+        q_len = rng.integers(1, Q + 1, size=S).astype(np.int32)
+        seen = (live * bs - q_len - rng.integers(0, bs - Q, size=S)).astype(np.int32)
+        bt[-1], seen[-1], q_len[-1] = trash, 0, 0
+        nan = lambda pool: pool.at[poison].set(jnp.nan)
+        out = jax.jit(paged_mha)(q, nan(kp), nan(vp), bt, seen, q_len)
+        zero = lambda pool: pool.at[poison].set(0)
+        ref = _paged_attention_dense(q, zero(kp), zero(vp), jnp.asarray(bt),
+                                     jnp.asarray(seen), bs)
+        mask = np.arange(Q)[None, :] < q_len[:, None]
+        assert np.isfinite(np.asarray(out, np.float32)).all(), \
+            "paged_mha read past a row's live pages"
+        check(f"paged_mha ragged walk [{S}, {Q}], table of {MB}",
+              np.asarray(out)[mask], np.asarray(ref)[mask], atol=0.05)
 
 
 def smoke_block_sparse():

@@ -108,11 +108,15 @@ def _paged_args(one_chip, seqs, q_tokens, int8, table=MAX_BLOCKS):
     (8, 8, True, MAX_BLOCKS),       # decode round, int8 pages + fp32 scales
     (8, 512, False, MAX_BLOCKS),    # a multi-token SplitFuse chunk
     # what the benchmark's Mistral cells dispatch, over their 64-slot table:
-    # every KV head a grid step at [D, 8], one a step in row tiles at [1, 512]
+    # every KV head a grid step at [D, 1] (4 query rows a head, under a
+    # sublane tile) and at a verify round's [D, 8], one a step in row tiles
+    # at [1, 512]
+    (64, 1, False, 64), (4, 1, False, 64), (64, 1, True, 64),
     (64, 8, False, 64), (4, 8, False, 64), (1, 512, False, 64),
     (1, 512, True, 64),
 ], ids=["decode_fp", "decode_int8", "splitfuse_chunk", "cell_decode64",
-        "cell_decode4", "cell_chunk512", "cell_chunk512_int8"])
+        "cell_decode4", "cell_decode64_int8", "cell_verify64", "cell_verify4",
+        "cell_chunk512", "cell_chunk512_int8"])
 def test_paged_attention_mistral_geometry(for_tpu, one_chip, seqs, q_tokens,
                                           int8, table):
     from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
@@ -148,16 +152,18 @@ def test_paged_attention_narrow_head_dim_keeps_the_grid(for_tpu, one_chip):
     from deepspeed_tpu.ops.pallas.paged_attention import paged_mha
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     pool = sds((512, 12, PAGE, 64), jnp.bfloat16)
-    _compile(paged_mha, (sds((8, 8, 12, 64), jnp.bfloat16), pool, pool,
-                         sds((8, 32), jnp.int32), sds((8,), jnp.int32),
-                         sds((8,), jnp.int32)))
+    for q_tokens in (1, 8):                 # a decode round, a verify round
+        _compile(paged_mha, (sds((8, q_tokens, 12, 64), jnp.bfloat16), pool,
+                             pool, sds((8, 32), jnp.int32),
+                             sds((8,), jnp.int32), sds((8,), jnp.int32)))
 
 
-@pytest.mark.parametrize("rows,tokens", [(64, 8), (4, 8), (1, 16), (1, 512)],
-                         ids=["decode64", "decode4", "chunk16", "chunk512"])
+@pytest.mark.parametrize("rows,tokens", [(64, 1), (4, 1), (64, 8), (1, 16), (1, 512)],
+                         ids=["decode64", "decode4", "rows64x8", "chunk16", "chunk512"])
 def test_selective_scan_phi4flash_widths(for_tpu, one_chip, rows, tokens):
     """The scan at Phi-4-mini-flash's d_inner 5120 x d_state 16, at the
-    shapes the engine dispatches: [D, 8] short rows and [1, C] chunks."""
+    shapes the engine dispatches, [D, 1] decode rows and [1, C] chunks, and
+    at [64, 8]."""
     from deepspeed_tpu.ops.pallas.selective_scan import selective_scan
     di, n = 5120, 16
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -169,9 +175,10 @@ def test_selective_scan_phi4flash_widths(for_tpu, one_chip, rows, tokens):
 
 
 @pytest.mark.parametrize("seqs,q_tokens,table", [
-    (64, 8, 256), (1, 512, 17), (64, 8, 17), (1, 512, 256)],
+    (64, 1, 256), (1, 512, 17), (64, 1, 17), (1, 512, 256), (64, 8, 256),
+    (64, 8, 17)],
     ids=["decode_full_layer", "chunk_window_ring", "decode_window_ring",
-         "chunk_full_layer"])
+         "chunk_full_layer", "rows64x8_full_layer", "rows64x8_window_ring"])
 def test_paged_attention_differential_pairs_geometry(for_tpu, one_chip, seqs,
                                                      q_tokens, table):
     """Phi-4-mini-flash's differential attention through the paged kernel:
@@ -189,6 +196,70 @@ def test_paged_attention_differential_pairs_geometry(for_tpu, one_chip, seqs,
                          window=512 if table == 17 else None)
 
     _compile(fn, args)
+
+
+class _Captured(Exception):
+    pass
+
+
+def _cell_program(name, rows, one_chip):
+    """(forward, cfg, arguments as shapes on ``one_chip``) of the dispatch
+    ``rows`` decoding sequences make in the engine of the benchmark's
+    configuration ``name``: the configuration's widths, layers and dtypes
+    (weights as shapes only), its engine limits, a small page pool."""
+    import json
+    import os
+    from benchmark import harness, weights
+    from benchmark.drivers import serve_phi4flash
+    from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    from deepspeed_tpu.models.mistral import MistralForCausalLM, mistral_config
+    from deepspeed_tpu.models.phi4flash import (Phi4FlashConfig,
+                                                Phi4FlashForCausalLM)
+    with open(os.path.join(harness.ROOT, "benchmark", "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    if cfg["driver"] == "serve_phi4flash":
+        model = Phi4FlashForCausalLM(Phi4FlashConfig(
+            dtype=jnp.bfloat16, **{k: cfg[k] for k in serve_phi4flash.MODEL_KEYS},
+            **cfg["assumed"]["sizes"]))
+    else:
+        model = MistralForCausalLM(mistral_config(dtype=jnp.bfloat16, **{
+            k: cfg[k] for k in (
+                "vocab_size", "hidden_size", "intermediate_size",
+                "num_hidden_layers", "num_attention_heads",
+                "num_key_value_heads", "max_position_embeddings",
+                "sliding_window", "rms_norm_eps", "rope_theta")}))
+    spec = harness.load("references", cfg["reference"]).param_spec(cfg)
+    params = jax.eval_shape(lambda: weights.make_params(0, spec))
+    engine = build_engine(model, params, dict(
+        cfg["engine"], state_manager=dict(cfg["engine"]["state_manager"],
+                                          num_kv_blocks=256)))
+    forward, got = engine._ragged_forward, []
+
+    def spy(*args):
+        got.extend(args)
+        raise _Captured
+
+    engine._ragged_forward = spy
+    with pytest.raises(_Captured):
+        engine.put(list(range(rows)), [np.zeros(1, np.int32)] * rows)
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), tuple(got[1:]))
+    return forward, got[0], shapes
+
+
+@pytest.mark.parametrize("name,rows,bucket,kernels", [
+    ("mistral-7b-l16", 64, 64, 1), ("mistral-7b-l16", 3, 4, 1),
+    ("phi4-mini-flash", 64, 64, 5), ("phi4-mini-flash", 3, 4, 5)],
+    ids=["mistral64", "mistral4", "phi4flash64", "phi4flash4"])
+def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, name, rows,
+                                             bucket, kernels):
+    """The WHOLE ragged forward of a decode round, [64, 1] and [4, 1], as the
+    benchmark's serving cells dispatch it: every layer at the published
+    widths, the paged kernel (and phi4flash's scan) at one token a row."""
+    forward, cfg, shapes = _cell_program(name, rows, one_chip)
+    assert shapes[2].shape == (bucket, 1)                     # the tokens
+    compiled = forward.lower(cfg, *shapes).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= kernels
 
 
 def test_quantized_matmul_4096_wide(for_tpu, one_chip):

@@ -177,7 +177,8 @@ def test_serving_round_is_one_fetch_whatever_its_dispatches(
         counted_device_get, device_sampling):
     """A serving round costs ONE accounted fetch (one ``device_get`` of its
     dispatches' results as a list), whether it took one dispatch (decode
-    rows only) or several (decode rows together, each prompt chunk alone)."""
+    rows only) or several (the rows of one token together, every other row
+    alone: uid 4's prompt of 3 tokens too)."""
     from deepspeed_tpu.inference.v2 import InferenceEngineV2
     from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
     from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -207,4 +208,4 @@ def test_serving_round_is_one_fetch_whatever_its_dispatches(
         assert counted_device_get.calls == gets + 1
         seen.add(len(engine.last_batch_shapes))
     assert seen == {1, 2, 3}, "rounds of one, two and three dispatches"
-    assert (sched.rounds, sched.dispatches) == (6, 1 + 2 + 3 + 1 + 1 + 1)
+    assert (sched.rounds, sched.dispatches) == (6, 1 + 2 + 3 + 2 + 1 + 1)

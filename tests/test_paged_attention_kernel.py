@@ -88,11 +88,13 @@ def test_is_supported():
     assert not is_supported((2, 1, 8, 64), (8, 2, 12, 64))   # bs % 8
 
 
+@pytest.mark.parametrize("Q", [1, 2])
 @pytest.mark.parametrize("window", [8, 24])
-def test_sliding_window_matches_dense(window):
+def test_sliding_window_matches_dense(window, Q):
     """Mistral-style windowed masking in the kernel (the only path serving
-    windowed models on real TPU) vs the dense twin."""
-    q, kp, vp, bt, seen, q_len = make_case(S=3, Q=2, seed=7)
+    windowed models on real TPU) vs the dense twin; heads of 64 on the grid,
+    a [D, 1] decode dispatch among them."""
+    q, kp, vp, bt, seen, q_len = make_case(S=3, Q=Q, seed=7)
     out_k = paged_mha(q, kp, vp, bt, seen, q_len, window=window, interpret=True)
     out_d = _paged_attention_dense(q, kp, vp, bt, seen, kp.shape[2],
                                    window=window)
@@ -194,17 +196,20 @@ def test_walk_padded_rows_cost_one_finite_page():
 
 @pytest.mark.parametrize("Q", [1, 8, 64, 384])
 def test_walk_query_tokens(Q):
-    """Pure decode, the [D, 8] short-row class, a chunk, and a chunk whose
+    """A [D, 1] decode dispatch, a verify round's [D, 8], a chunk, and a chunk whose
     ``rep * Q`` rows (1536) are walked in row tiles, one KV head a step."""
     check_walk(*make_walk_case([-(-Q // WALK["bs"]) + 2, 41], Q=Q, MB=64, seed=Q))
 
 
+@pytest.mark.parametrize("Q", [1, 8])
 @pytest.mark.parametrize("rep", [1, 4])
-def test_walk_query_heads_a_kv_head(rep):
-    check_walk(*make_walk_case([2, 37, 11], Q=8, rep=rep, seed=rep))
+def test_walk_query_heads_a_kv_head(rep, Q):
+    """``rep x Q`` query rows a KV head: 1 and 4 rows (under a sublane tile)
+    in a [D, 1] decode dispatch."""
+    check_walk(*make_walk_case([2, 37, 11], Q=Q, rep=rep, seed=rep))
 
 
-@pytest.mark.parametrize("Q", [8, 64])
+@pytest.mark.parametrize("Q", [1, 8, 64])
 def test_walk_ring_table_with_window(Q):
     """A window group's table: a ring of at most 17 pages, positions
     relative to its first page, the window cutting into the oldest."""
@@ -212,12 +217,14 @@ def test_walk_ring_table_with_window(Q):
                window=8 * WALK["bs"])
 
 
-def test_walk_softmax_scale():
-    check_walk(*make_walk_case([4, 30], Q=8), softmax_scale=0.125)
+@pytest.mark.parametrize("Q", [1, 8])
+def test_walk_softmax_scale(Q):
+    check_walk(*make_walk_case([4, 30], Q=Q), softmax_scale=0.125)
 
 
-def test_walk_bf16():
-    check_walk(*make_walk_case([1, 40, 9], Q=8, dtype=jnp.bfloat16),
+@pytest.mark.parametrize("Q", [1, 8])
+def test_walk_bf16(Q):
+    check_walk(*make_walk_case([1, 40, 9], Q=Q, dtype=jnp.bfloat16),
                atol=3e-2, rtol=3e-2)
 
 

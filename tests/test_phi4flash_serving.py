@@ -112,10 +112,18 @@ def test_a_bfloat16_state_or_a_dropped_state_fails_the_tolerance(served):
     assert seq.seen_tokens == 32 and _worst(got, want[0]) > 10 * TOLERANCE
 
 
-def test_short_rows_of_one_to_eight_tokens_advance_each_row_by_its_own(served):
-    """One [4, 8] dispatch after another with rows of 1-8 real tokens: a
-    padded position that advanced the state or shifted the convolution's
-    columns would show in the row's next logits."""
+@pytest.mark.parametrize("rectangle", [False, True], ids=["by-class", "one-rectangle"])
+def test_short_rows_of_one_to_eight_tokens_advance_each_row_by_its_own(
+        served, monkeypatch, rectangle):
+    """Rounds of four rows of 1-8 real tokens. As the engine dispatches them:
+    the rows of one token together as [4, 1], every other row alone as
+    [1, 16]. As ONE [4, 8] rectangle (the layout of a verify round, which the
+    forward is written for): a padded position that advanced the state or
+    shifted the convolution's columns would show in the row's next logits."""
+    from deepspeed_tpu.inference.v2 import engine_v2
+    if rectangle:
+        monkeypatch.setattr(engine_v2, "dispatch_rows", lambda lengths, short:
+                            [(list(range(len(lengths))), 4, 8)])
     ids, want = served[4], served[5]
     engine = _engine(served, state_manager=dict(ENGINE["state_manager"],
                                                 max_ragged_batch_size=32))
@@ -124,11 +132,44 @@ def test_short_rows_of_one_to_eight_tokens_advance_each_row_by_its_own(served):
     for lengths in [(8, 3, 1, 5), (1, 8, 2, 7), (4, 1, 8, 1), (2, 6, 1, 3), (1, 1, 1, 1)]:
         out = engine.put(list(range(4)), [ids[u][pos[u]:pos[u] + n]
                                           for u, n in enumerate(lengths)])
-        assert engine.last_batch_shapes == [(4, 8)]
+        ones = lengths.count(1)
+        assert engine.last_batch_shapes == (
+            [(4, 8)] if rectangle else [(4, 1)] + [(1, 16)] * (4 - ones))
         for u, n in enumerate(lengths):
             pos[u] += n
             worst = max(worst, float(np.max(np.abs(out[u] - want[u][pos[u] - 1]))))
     assert worst < TOLERANCE
+
+
+@pytest.mark.parametrize("rows", [4, 3], ids=["full", "one-padded-row"])
+def test_a_decode_round_of_one_token_rows_advances_every_kind_of_state(served, rows):
+    """``rows`` sequences decode together as [4, 1], 30 rounds: each row's
+    slot of state, its window ring (pages freed behind the window) and the
+    full layer's shared pages advance by that one token; a padded row writes
+    the trash slot and page."""
+    ids, want = served[4], served[5]
+    engine = _engine(served)
+    uids = list(range(rows))
+    pos = {}
+    for u in uids:
+        pos[u] = 7 + 3 * u                       # prompts of 7, 10, 13, 16 tokens
+        engine.put([u], [ids[u][:pos[u]]])
+    freed = engine._state.window_pages_freed
+    worst = 0.0
+    for _ in range(30):
+        out = engine.put(uids, [ids[u][pos[u]:pos[u] + 1] for u in uids])
+        assert engine.last_batch_shapes == [(4, 1)]
+        for u in uids:
+            pos[u] += 1
+            worst = max(worst, float(np.max(np.abs(out[u] - want[u][pos[u] - 1]))))
+    assert worst < TOLERANCE
+    assert engine._state.window_pages_freed - freed >= 6 * rows
+    for u in uids:
+        seq = engine._state.get_sequence(u)
+        assert seq.seen_tokens == pos[u]
+        assert len(seq.kv_blocks) == -(-pos[u] // 4), "the full layer keeps every page"
+        assert len(seq.group_blocks["window"]) <= 3, "the ring holds the window's pages"
+    assert len({engine._state.get_sequence(u).slot for u in uids}) == rows
 
 
 def test_the_ring_frees_pages_and_changes_no_logit(served):

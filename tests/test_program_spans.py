@@ -154,8 +154,8 @@ def test_build_counts_real_tokens_within_padded_slots(served):
         assert 0 < a["real_tokens"] <= a["padded_slots"]
         assert a["seqs"] <= a["seq_bucket"]
         assert a["context_tokens"] >= 0
-    # a round's dispatches together carry what the round composed: the
-    # short rows in one [D, 8] batch, each long row alone in a [1, C] one
+    # a round's dispatches together carry what the round composed: the rows
+    # of one token in one [D, 1] batch, every other row alone in a [1, C] one
     for _, _, _, c in _named(spans, "serving/compose"):
         mine = [a for _, _, _, a in builds if a["round"] == c["round"]]
         total = lambda key: sum(a[key] for a in mine)
@@ -163,8 +163,10 @@ def test_build_counts_real_tokens_within_padded_slots(served):
         assert c["prefill_tokens"] + c["decode_rows"] == total("real_tokens")
         alone = [a for a in mine if a["seq_bucket"] == 1]
         assert c["long_rows"] == len(alone) == len(mine) - (c["seqs"] > len(alone))
-        assert all(a["seqs"] == 1 and a["real_tokens"] > 8 for a in alone)
-        assert all(a["chunk_bucket"] == 8 for a in mine if a["seq_bucket"] > 1)
+        assert all(a["seqs"] == 1 and a["real_tokens"] > 1 and
+                   a["chunk_bucket"] >= 16 for a in alone)
+        assert all(a["chunk_bucket"] == 1 and a["real_tokens"] == a["seqs"]
+                   for a in mine if a["seq_bucket"] > 1)
         assert c["shrunk"] == 0 and c["preempted"] == 0
 
 

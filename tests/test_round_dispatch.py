@@ -1,7 +1,9 @@
-"""A serving round dispatched by chunk-length class: the short rows (at most
-8 new tokens) together as ``[D, 8]``, each long row alone as ``[1, C]``
-(``ragged_wrapper.dispatch_rows``), where one ``[sequences x chunk]``
-rectangle gave every row the longest row's width.
+"""A serving round dispatched by chunk-length class
+(``ragged_wrapper.dispatch_rows``): in a plain round the rows of ONE new
+token together as ``[D, 1]`` and every other row alone as ``[1, C >= 16]``;
+in a verify round the rows of at most ``max(8, k)`` tokens together as
+``[D, max(8, k)]``. One ``[sequences x chunk]`` rectangle gave every row the
+longest row's width.
 
 The layout changes no result: every request emits what it emits when served
 alone, and each dispatch's logits are those of ``ragged_forward`` on the
@@ -24,8 +26,8 @@ from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
 MAX_SEQS, MAX_TOKENS = 8, 32
 # (prompt tokens, new tokens, temperature, top_k, top_p, seed, submitted
-# before round): lengths on both sides of 8 and of the 32-token budget, so
-# that chunks of 9..32 tokens join 0..7 running decodes
+# before round): lengths on both sides of 16 and of the 32-token budget, so
+# that chunks of 2..32 tokens join 0..7 running decodes
 REQUESTS = [(5, 12, 0.0, 0, 1.0, 0, 0), (40, 6, 0.8, 0, 1.0, 11, 0),
             (17, 9, 0.0, 0, 1.0, 0, 2), (9, 10, 1.1, 20, 0.9, 12, 2),
             (30, 5, 0.0, 0, 1.0, 0, 4), (3, 8, 0.7, 0, 0.95, 13, 4),
@@ -65,6 +67,12 @@ def _buckets(lo, hi):
         out.append(x)
         x *= 2
     return out + [hi]
+
+
+def _family():
+    """``{[D, 1]} + {[1, C >= 16]}`` at the test's limits."""
+    return {(d, 1) for d in _buckets(4, MAX_SEQS)} | \
+        {(1, c) for c in _buckets(16, MAX_TOKENS)}
 
 
 def _warm_pass(engine):
@@ -176,34 +184,92 @@ def test_each_dispatch_matches_the_round_as_one_rectangle(mixed_run):
 
 def test_dispatched_shapes_are_the_ones_warm_up_reaches(mixed_run):
     _, sched, _, warm, shapes, new_programs = mixed_run
-    family = {(d, 8) for d in _buckets(4, MAX_SEQS)} | \
-        {(1, c) for c in _buckets(16, MAX_TOKENS)}
-    assert warm == family
+    assert warm == _family()
     assert shapes == warm, "the run is meant to reach every shape"
     assert new_programs == (0, 0), "the run compiled what the warm pass had not"
     assert sched.dispatches > sched.rounds
     assert 0 < sched.real_tokens <= sched.padded_slots
 
 
+def test_the_recipe_leaves_one_forward_program_a_shape(served):
+    """11 at the cells' limits (64 sequences, 512 tokens: ``{[D, 1]: 5} +
+    {[1, C]: 6}``), scaled to this test's: the warm pass compiles one forward
+    and one sampler program a shape and no other."""
+    cfg, model, params, _ = served
+    engine = _engine(model, params)
+    jax.clear_caches()
+    warm = _warm_pass(engine)
+    assert warm == _family()
+    assert ragged_forward._cache_size() == len(warm)
+    assert sample_rows_packed._cache_size() == len({s for s, _ in warm})
+
+
 @pytest.mark.parametrize("lengths, short, expected", [
-    ([1, 1, 1], 8, [([0, 1, 2], 4)]),
-    ([1, 300, 1, 8], 8, [([0, 2, 3], 4), ([1], 1)]),
-    ([9, 1, 200], 8, [([1], 4), ([0], 1), ([2], 1)]),
-    ([16], 8, [([0], 1)]),
-    ([5, 16, 17], 16, [([0, 1], 4), ([2], 1)]),
+    # a plain round: the rows of one token together, one token wide
+    ([1, 1, 1], 1, [([0, 1, 2], 4, 1)]),
+    ([1, 300, 1, 8], 1, [([0, 2], 4, 1), ([1], 1, 16), ([3], 1, 16)]),
+    ([9, 1, 200], 1, [([1], 4, 1), ([0], 1, 16), ([2], 1, 16)]),
+    ([16], 1, [([0], 1, 16)]),
+    ([2], 1, [([0], 1, 16)]),
+    # a verify round: rows of [last] + drafts stay together
+    ([1, 300, 1, 8], 8, [([0, 2, 3], 4, 8), ([1], 1, 16)]),
+    ([5, 16, 17], 16, [([0, 1], 4, 16), ([2], 1, 16)]),
+    ([], 1, []),
     ([], 8, []),
 ])
 def test_dispatch_rows_by_class(lengths, short, expected):
     assert dispatch_rows(lengths, short) == expected
 
 
-def test_a_verify_row_is_short_whatever_the_verify_width():
-    assert short_row_tokens() == short_row_tokens(4) == short_row_tokens(8) == 8
+def test_a_plain_row_is_short_at_one_token_a_verify_row_whatever_the_width():
+    assert short_row_tokens() == short_row_tokens(None) == short_row_tokens(0) == 1
+    assert short_row_tokens(2) == short_row_tokens(4) == short_row_tokens(8) == 8
     assert short_row_tokens(16) == 16
 
 
+def test_a_prompt_tail_of_five_tokens_goes_alone_beside_the_decode_rows(served):
+    """Decode rows plus a prompt's last 5 tokens: the decode rows as [4, 1],
+    the tail alone as [1, 16], each row's logits those of that row put
+    alone."""
+    cfg, model, params, prompts = served
+    engine = _engine(model, params)
+    uids = [0, 1, 2, 3]
+    first = [prompts[1][:5], prompts[4][:3], prompts[6][:4], prompts[8][:11]]
+    engine.put(uids, first)
+    # rows 0..2 decode one token, row 3 ends its 16-token prompt 5 tokens on
+    toks = [prompts[1][5:6], prompts[4][3:4], prompts[6][4:5], prompts[8][11:16]]
+    together = engine.put(uids, toks)
+    assert engine.last_batch_shapes == [(4, 1), (1, 16)]
+    for u in uids:
+        engine.flush(u)
+    for u, a, b in zip(uids, first, toks):
+        engine.put([u], [a])
+        alone = engine.put([u], [b])
+        engine.flush(u)
+        np.testing.assert_allclose(together[u], alone[0], rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("k_max, shapes", [
+    (4, [(4, 8), (1, 16)]), (16, [(4, 16), (1, 32)])],
+    ids=["k4", "k16"])
+def test_a_verify_round_keeps_its_short_class(served, k_max, shapes):
+    """Rows of ``[last] + drafts`` (1..max(8, k) tokens) go together as
+    ``[D, max(8, k)]`` whatever a plain round does; a longer chunk alone."""
+    cfg, model, params, prompts = served
+    engine = _engine(model, params)
+    uids = [0, 1, 2]
+    toks = [prompts[1][:1], prompts[4][:min(k_max, 8)],
+            prompts[8][:max(8, k_max) + 1]]
+    n = len(uids)
+    ids = engine.host_fetch(engine.put_verify_device(
+        uids, toks, temperatures=[0.0] * n, top_ks=[0] * n, top_ps=[1.0] * n,
+        seeds=[0] * n, positions=[0] * n, k_max=k_max), "test")
+    assert engine.last_batch_shapes == shapes
+    assert ids.shape == (n, k_max)
+
+
 def test_put_returns_rows_in_the_order_given(served):
-    """``put`` (host logits) over a round of three dispatches: every row's
+    """``put`` (host logits) over a round of five dispatches: every row's
     logits are those of that row put alone, in the order given."""
     cfg, model, params, prompts = served
     engine = _engine(model, params)
@@ -213,7 +279,7 @@ def test_put_returns_rows_in_the_order_given(served):
     base = engine.host_sync_count
     together = engine.put(uids, toks)
     assert engine.host_sync_count == base + 1
-    assert sorted(engine.last_batch_shapes) == [(1, 16), (1, 16), (4, 8)]
+    assert sorted(engine.last_batch_shapes) == [(1, 16)] * 4 + [(4, 1)]
     assert together.shape == (5, cfg.vocab_size)
     for u in uids:
         engine.flush(u)

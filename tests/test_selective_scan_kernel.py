@@ -1,7 +1,7 @@
 """The selective-scan kernel (``ops/pallas/selective_scan.py``): interpret
-mode against its jnp twin at the two shapes the engine dispatches, ``[1, C]``
-prompt chunks and ``[D, 8]`` short rows, and the twin against the recurrence
-written out in numpy."""
+mode against its jnp twin at the shapes the engine dispatches (``[1, C]``
+prompt chunks, ``[D, 1]`` decode rows) and at ragged ``[D, 8]`` rows, and the
+twin against the recurrence written out in numpy."""
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +27,8 @@ def _data(R, T, Di, N, seed=0, dtype=jnp.bfloat16):
     (1, 16, 256, 16, [13]),                 # a [1, C] chunk, not full
     (1, 64, 128, 8, [64]),                  # a full one
     (4, 8, 128, 16, [1, 8, 0, 3]),          # [D, 8]: decode, full, padded row, a tail
+    (4, 1, 128, 16, [1, 1, 0, 1]),          # [D, 1]: a decode dispatch, a padded row
+    (8, 1, 1280, 16, [1] * 8),              # the same over two blocks of d_inner
     (8, 8, 1280, 16, [1] * 8),              # two blocks of d_inner
     (2, 12, 128, 8, [12, 5]),               # T no multiple of the time chunk
 ])
