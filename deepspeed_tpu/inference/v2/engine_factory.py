@@ -9,7 +9,8 @@ Weights come through the HF converter (``checkpoint/hf.py``) directly in the
 serving dtype. phi4flash (Phi-4-mini-flash-reasoning: Mamba, window and full
 differential attention, gated memory units) is served from an in-tree model
 through ``build_engine``; it has no HF converter, and no prefix cache,
-speculation or page export yet.
+speculation or page export yet. mellum2 (Mellum2: window and full attention
+layers over two paged groups, sparse experts in every layer) likewise.
 
 A family is three things, resolved here: its ragged forward, its verify
 forward (or None) and its cache groups (``ragged/cache_groups.py``).
@@ -29,19 +30,21 @@ _IMPLEMENTATION = {"llama": "llama", "mistral": "llama", "qwen2": "llama",
                    "qwen": "llama", "internlm": "llama",  # llama trees (hf.py)
                    "mixtral": "mixtral", "falcon": "parallel_block",
                    "phi": "parallel_block", "opt": "opt",
-                   "phi4flash": "phi4flash"}
+                   "phi4flash": "phi4flash", "mellum2": "mellum2"}
 
 #: families ``build_engine`` serves from an in-tree model and tree
 SERVED_FAMILIES = tuple(_IMPLEMENTATION)
 #: families ``build_hf_engine`` loads from a checkpoint directory
-SUPPORTED_FAMILIES = tuple(f for f in SERVED_FAMILIES
-                           if f != "phi4flash")  # it has no HF converter
+SUPPORTED_FAMILIES = tuple(
+    f for f in SERVED_FAMILIES
+    if f not in ("phi4flash", "mellum2"))  # they have no HF converter
 
 #: the one place a config class names its family; any other is a llama tree
 _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
                      "ParallelBlockConfig": "falcon",
                      "OPTConfig": "opt",
-                     "Phi4FlashConfig": "phi4flash"}
+                     "Phi4FlashConfig": "phi4flash",
+                     "Mellum2Config": "mellum2"}
 
 
 def _implementation(model, family):

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
+from deepspeed_tpu.inference.v2.model_implementations.moe_layer import (
+    expert_rows)
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
     RaggedBatchWrapper, dispatch_rows, short_row_tokens)
@@ -104,6 +106,14 @@ class InferenceEngineV2:
         self.last_window_pages_freed = 0
         self.last_state_slots = 0
         self._window_freed_reported = 0
+        # for a model with sparse experts (every layer of such a family has
+        # them): expert rows a real token takes through the stack, and of
+        # the last round's dispatches, summed, the rows that reached the
+        # expert GEMMs for real tokens and for padded slots
+        self._expert_fanout = (getattr(cfg, "num_experts_per_tok", 0) or 0,
+                               cfg.num_hidden_layers)
+        self.last_expert_rows = 0
+        self.last_expert_rows_padded = 0
         # postmortem-bundle collector (telemetry/flightrec.py): the newest
         # engine's host-side KV pool stats ride every bundle — pure host
         # reads, so collection is safe even from an abnormal path
@@ -274,6 +284,7 @@ class InferenceEngineV2:
         further = self._state.has_further_groups
         self.last_window_pages_freed = self.last_state_slots = 0
         self.last_live_pages = self.last_table_slots = 0
+        self.last_expert_rows = self.last_expert_rows_padded = 0
         for rows, min_seqs, min_tokens in dispatch_rows(
                 lengths, short_row_tokens(verify_k)):
             # explicit begin/end, and the host-to-device copies as arguments
@@ -312,6 +323,11 @@ class InferenceEngineV2:
             table_slots = seq_bucket * self._max_blocks_per_seq
             self.last_live_pages += live_pages
             self.last_table_slots += table_slots
+            if self._expert_fanout[0]:
+                took, padded = expert_rows(real_tokens, *self._expert_fanout)
+                self.last_expert_rows += took
+                self.last_expert_rows_padded += padded
+                sp.set(expert_rows=took, expert_rows_padded=padded)
             sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
                    real_tokens=real_tokens,
                    padded_slots=seq_bucket * chunk_bucket,

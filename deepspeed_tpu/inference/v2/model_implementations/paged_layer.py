@@ -74,6 +74,12 @@ def _pool_block_size(pool):
     return _pool_parts(pool)[0].shape[3]
 
 
+def real_slots(q_len, chunk):
+    """Which of a dispatch's ``[S, chunk]`` token slots hold a real token:
+    the first ``q_len`` of each row. ``[S, chunk]`` bool."""
+    return jnp.arange(chunk)[None, :] < q_len[:, None]
+
+
 # -- the write ----------------------------------------------------------------
 
 def _quantize_kv_rows(x):

@@ -142,6 +142,12 @@ class SplitFuseScheduler:
         # recurrent state held, summed over dispatches
         self.window_pages_freed = 0
         self.state_slots = 0
+        # for a model with sparse experts, the sums of the same spans'
+        # ``expert_rows`` and ``expert_rows_padded``: rows that reached the
+        # expert GEMMs for real tokens (tokens x experts a token x expert
+        # layers) and for padded token slots
+        self.expert_rows = 0
+        self.expert_rows_padded = 0
         # device_sampling=True (default) fuses temperature/top-k/top-p and
         # the categorical draw into the decode step on the accelerator: the
         # host receives one int32 per sequence instead of a [S, vocab] float
@@ -768,6 +774,8 @@ class SplitFuseScheduler:
         self.table_slots += self._engine.last_table_slots
         self.window_pages_freed += self._engine.last_window_pages_freed
         self.state_slots += self._engine.last_state_slots
+        self.expert_rows += self._engine.last_expert_rows
+        self.expert_rows_padded += self._engine.last_expert_rows_padded
         return (uids, chunks, ids, logits, t_fwd, was_prefilling,
                 sched_tokens, rnd)
 

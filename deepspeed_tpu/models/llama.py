@@ -109,6 +109,57 @@ def rotary_embed(x, positions, theta=10000.0):
     return out.astype(x.dtype)
 
 
+def rope_frequencies(head_dim, theta, yarn=None):
+    """``(inv_freq [head_dim / 2], scale)`` of one kind of layer: the default
+    ``theta ** (-2i / head_dim)`` with scale 1, or with ``yarn`` (the
+    ``rope_parameters`` section of such a layer type: ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``,
+    ``attention_factor``) YaRN's blend ``interp * (1 - e) + extrap * e``,
+    ``interp = extrap / factor``, ``e`` falling from 1 to 0 between the
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times over the
+    original length, with cos and sin scaled by ``attention_factor`` (None:
+    ``0.1 ln(factor) + 1``). Plain Python and numpy: a model computes its
+    tables once, outside its layer loop."""
+    import math
+
+    import numpy as np
+    i = np.arange(0, head_dim, 2, dtype=np.float64)
+    extrap = theta ** (-i / head_dim)
+    if not yarn:
+        return jnp.asarray(extrap, jnp.float32), 1.0
+    factor = float(yarn["factor"])
+    orig = yarn["original_max_position_embeddings"]
+
+    def turns(beta):          # the dimension that turns ``beta`` times
+        return head_dim * math.log(orig / (beta * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(turns(yarn.get("beta_fast", 32))), 0)
+    high = min(math.ceil(turns(yarn.get("beta_slow", 1))), head_dim - 1)
+    ramp = np.clip((i / 2 - low) / max(high - low, 1e-3), 0.0, 1.0)
+    e = 1.0 - ramp
+    inv_freq = (extrap / factor) * (1.0 - e) + extrap * e
+    scale = yarn.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0
+    return jnp.asarray(inv_freq, jnp.float32), float(scale)
+
+
+def rotary_tables(positions, inv_freq, scale=1.0):
+    """``(cos, sin)`` ``[B, T, 1, Dh / 2]`` float32 of ``rope_frequencies``'s
+    result at ``positions`` [B, T]."""
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    return (jnp.cos(angles) * scale)[:, :, None, :], \
+        (jnp.sin(angles) * scale)[:, :, None, :]
+
+
+def rotary_apply(x, cos, sin):
+    """``rotary_embed`` with the tables given (``rotary_tables``): the same
+    adjacent-column pairs. x: [B, T, H, Dh]."""
+    x1, x2 = x[..., ::2], x[..., 1::2]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 class LlamaAttention(nn.Module):
     config: LlamaConfig
 

@@ -32,8 +32,9 @@ import dataclasses
 import math
 from typing import Any
 
-import jax
 import jax.numpy as jnp
+
+from deepspeed_tpu.models.param_rows import init_tree
 
 
 @dataclasses.dataclass(frozen=True)
@@ -182,18 +183,7 @@ class Phi4FlashForCausalLM:
 
     def init_params(self, rng):
         """A random tree (normal with each row's std; constants as given)."""
-        tree = {}
-        for i, (path, shape, fill, dtype, _) in enumerate(param_spec(self.config)):
-            if isinstance(fill, tuple):
-                leaf = jnp.full(shape, fill[1], dtype)
-            else:
-                leaf = (jax.random.normal(jax.random.fold_in(rng, i), shape,
-                                          jnp.float32) * fill).astype(dtype)
-            node = tree
-            for name in path[:-1]:
-                node = node.setdefault(name, {})
-            node[path[-1]] = leaf
-        return tree
+        return init_tree(param_spec(self.config), rng)
 
     @staticmethod
     def cache_groups(cfg):

@@ -61,9 +61,9 @@ class InferenceCutlassBuilder(OpBuilder):
     NAME = "inference_cutlass_builder"
 
     def reference_impl(self):
-        from deepspeed_tpu.inference.v2.model_implementations.mixtral import (
-            _moe_ffn)
-        return _moe_ffn
+        from deepspeed_tpu.inference.v2.model_implementations.moe_layer import (
+            moe_ffn)
+        return moe_ffn
 
     def pallas_impl(self):
         from deepspeed_tpu.ops.pallas.grouped_gemm import moe_ffn_gmm

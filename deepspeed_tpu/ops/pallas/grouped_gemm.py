@@ -58,18 +58,21 @@ def topk_router(x, gate_wg, k):
     THE routing implementation — both the megablox and the einsum dispatch
     paths consume its (top_vals [T, k], top_idx [T, k]) so gating numerics
     can never diverge between backends."""
-    logits = (x @ gate_wg).astype(jnp.float32)
+    logits = jnp.dot(x, gate_wg, preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     top_vals, top_idx = jax.lax.top_k(probs, k)
     return top_vals / jnp.sum(top_vals, axis=-1, keepdims=True), top_idx
 
 
 def moe_ffn_gmm(x, top_vals, top_idx, w1, w2, w3, *, n_experts, dtype,
-                interpret=False, block_config=None):
+                valid=None, interpret=False, block_config=None):
     """Mixtral-style expert FFN: silu(x@w1) * (x@w3) @ w2 per expert, routed
     by precomputed (top_vals, top_idx) from :func:`topk_router`.
 
-    x [T, D]; w1/w3 [E, D, F]; w2 [E, F, D] -> [T, D].
+    x [T, D]; w1/w3 [E, D, F]; w2 [E, F, D] -> [T, D]. ``valid`` [T] bool
+    (None: all): a token that is not valid takes no expert rows (its rows
+    sort past every expert's group, which the grouped GEMM never visits) and
+    gets zeros.
 
     SPMD: tokens shard over the active mesh's data axes (dp AND ep — under
     expert parallelism the token batch is split across the expert world, the
@@ -105,15 +108,19 @@ def moe_ffn_gmm(x, top_vals, top_idx, w1, w2, w3, *, n_experts, dtype,
     tiling = (block_config.get("tile_m"), block_config.get("tile_k"),
               block_config.get("tile_n"))
 
-    def call(x_, tv_, ti_, w1_, w2_, w3_):
-        return _moe_ffn_gmm_local(x_, tv_, ti_, w1_, w2_, w3_,
+    if valid is None:
+        valid = jnp.ones((T,), bool)
+
+    def call(x_, tv_, ti_, ok_, w1_, w2_, w3_):
+        return _moe_ffn_gmm_local(x_, tv_, ti_, ok_, w1_, w2_, w3_,
                                   n_experts=n_experts, dtype=dtype,
                                   interpret=interpret, tiling=tiling)
 
     wr = (None, None, None)
     return sharded_kernel_call(
-        call, [x, top_vals, top_idx, w1, w2, w3],
-        [("data", None), ("data", None), ("data", None), wr, wr, wr],
+        call, [x, top_vals, top_idx, valid, w1, w2, w3],
+        [("data", None), ("data", None), ("data", None), ("data",),
+         wr, wr, wr],
         ("data", None), name="moe_ffn_gmm", block_config=block_config)
 
 
@@ -158,42 +165,45 @@ def moe_ffn_gmm_rows(x_rows, row_experts, w1, w2, w3, *, n_experts, dtype,
     return jnp.take(y, inv, axis=0)
 
 
-def _moe_ffn_gmm_local(x, top_vals, top_idx, w1, w2, w3, *, n_experts, dtype,
-                       interpret=False, tiling=None):
+def _moe_ffn_gmm_local(x, top_vals, top_idx, valid, w1, w2, w3, *, n_experts,
+                       dtype, interpret=False, tiling=None):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     T, D = x.shape
     E = n_experts
     k = top_idx.shape[-1]
     tm, tk, tn = tiling if tiling is not None else (ROW_ALIGN, 128, 128)
-
-    # moe_scatter: stable sort of the T*k (token, expert) rows by expert
-    flat_e = top_idx.reshape(-1)                         # [T*k]
-    order = jnp.argsort(flat_e, stable=True)
-    token_of = jnp.arange(T * k, dtype=jnp.int32) // k
-    xs = jnp.take(x, token_of[order], axis=0)            # [T*k, D] grouped
-
     rows = T * k
-    pad = (-rows) % tm  # pad rows to the m-tile so every group tiles cleanly
-    group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
-    if pad:
-        # pad rows ride in the LAST expert's group; outputs are dropped
-        xs = jnp.concatenate(
-            [xs, jnp.zeros((pad, D), xs.dtype)], axis=0)
-        group_sizes = group_sizes.at[E - 1].add(pad)
+    pad = (-rows) % tm  # rows padded to the m-tile; no group holds the pad
+
+    # moe_scatter: stable sort of the T*k (token, expert) rows by expert. A
+    # token that is not valid sorts as expert E, past every group: the group
+    # sizes then sum to the valid rows only and gmm never visits the rest
+    # (nor the pad), whose output rows stay unwritten and are masked below
+    with jax.named_scope("moe_sort"):
+        flat_e = jnp.where(jnp.repeat(valid, k), top_idx.reshape(-1), E)
+        order = jnp.argsort(flat_e, stable=True)
+        token_of = jnp.arange(rows, dtype=jnp.int32) // k
+        xs = jnp.take(x, token_of[order], axis=0)        # [T*k, D] grouped
+        group_sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(
+            1, mode="drop")
+        if pad:
+            xs = jnp.concatenate(
+                [xs, jnp.zeros((pad, D), xs.dtype)], axis=0)
 
     def grouped(lhs, rhs):
-        return gmm(lhs, rhs, group_sizes,
-                   preferred_element_type=jnp.float32,
-                   tiling=(tm, tk, tn),
-                   interpret=interpret).astype(dtype)
+        with jax.named_scope("moe_ffn_gmm"):
+            return gmm(lhs, rhs, group_sizes,
+                       preferred_element_type=jnp.float32,
+                       tiling=(tm, tk, tn),
+                       interpret=interpret).astype(dtype)
 
     h = jax.nn.silu(grouped(xs, w1)) * grouped(xs, w3)   # [rows+pad, F]
-    y = grouped(h, w2)                                   # [rows+pad, D]
-    y = y[:rows]
+    y = grouped(h, w2)[:rows]                            # [rows, D]
 
     # moe_gather: unsort, weight by gate, combine the k slots
-    inv = jnp.argsort(order, stable=True)
-    y = jnp.take(y, inv, axis=0).reshape(T, k, D)
-    return jnp.sum(y.astype(jnp.float32) * top_vals[..., None],
-                   axis=1).astype(dtype)
+    with jax.named_scope("moe_unsort"):
+        inv = jnp.argsort(order, stable=True)
+        y = jnp.take(y, inv, axis=0).reshape(T, k, D)
+        y = jnp.where(valid[:, None, None], y.astype(jnp.float32), 0.0)
+        return jnp.sum(y * top_vals[..., None], axis=1).astype(dtype)

@@ -165,8 +165,8 @@ def smoke_block_sparse():
 
 
 def smoke_grouped_gemm():
-    from deepspeed_tpu.inference.v2.model_implementations.mixtral import (
-        _moe_ffn)
+    from deepspeed_tpu.inference.v2.model_implementations.moe_layer import (
+        moe_ffn)
     from deepspeed_tpu.ops.pallas.grouped_gemm import moe_ffn_gmm, topk_router
 
     ks = jax.random.split(jax.random.PRNGKey(4), 5)
@@ -179,8 +179,8 @@ def smoke_grouped_gemm():
     tv, ti = topk_router(x, gate, k)
     out = jax.jit(lambda *a: moe_ffn_gmm(*a, n_experts=E, dtype=jnp.bfloat16))(
         x, tv, ti, w1, w2, w3)
-    ref = _moe_ffn(x, gate, w1, w2, w3, k=k, dtype=jnp.bfloat16,
-                   force_einsum=True)
+    ref = moe_ffn(x, gate, w1, w2, w3, k=k, dtype=jnp.bfloat16,
+                  force_einsum=True)
     check("moe_ffn_gmm", out, ref, atol=0.05)
 
 

@@ -212,6 +212,7 @@ def _cell_program(name, rows, one_chip):
     from benchmark import harness, weights
     from benchmark.drivers import serve_phi4flash
     from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    from deepspeed_tpu.models.mellum2 import Mellum2Config, Mellum2ForCausalLM
     from deepspeed_tpu.models.mistral import MistralForCausalLM, mistral_config
     from deepspeed_tpu.models.phi4flash import (Phi4FlashConfig,
                                                 Phi4FlashForCausalLM)
@@ -221,6 +222,8 @@ def _cell_program(name, rows, one_chip):
         model = Phi4FlashForCausalLM(Phi4FlashConfig(
             dtype=jnp.bfloat16, **{k: cfg[k] for k in serve_phi4flash.MODEL_KEYS},
             **cfg["assumed"]["sizes"]))
+    elif cfg["driver"] == "serve_mellum2":
+        model = Mellum2ForCausalLM(Mellum2Config.from_hf(cfg, dtype=jnp.bfloat16))
     else:
         model = MistralForCausalLM(mistral_config(dtype=jnp.bfloat16, **{
             k: cfg[k] for k in (
@@ -249,13 +252,16 @@ def _cell_program(name, rows, one_chip):
 
 @pytest.mark.parametrize("name,rows,bucket,kernels", [
     ("mistral-7b-l16", 64, 64, 1), ("mistral-7b-l16", 3, 4, 1),
-    ("phi4-mini-flash", 64, 64, 5), ("phi4-mini-flash", 3, 4, 5)],
-    ids=["mistral64", "mistral4", "phi4flash64", "phi4flash4"])
+    ("phi4-mini-flash", 64, 64, 5), ("phi4-mini-flash", 3, 4, 5),
+    ("mellum2-l12", 64, 64, 48)],
+    ids=["mistral64", "mistral4", "phi4flash64", "phi4flash4", "mellum2-64"])
 def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, name, rows,
                                              bucket, kernels):
     """The WHOLE ragged forward of a decode round, [64, 1] and [4, 1], as the
     benchmark's serving cells dispatch it: every layer at the published
-    widths, the paged kernel (and phi4flash's scan) at one token a row."""
+    widths, the paged kernel (and phi4flash's scan; for mellum2 the paged
+    kernel and the three grouped GEMMs in each of 12 layers, over 512 expert
+    rows of which a padded row takes none) at one token a row."""
     forward, cfg, shapes = _cell_program(name, rows, one_chip)
     assert shapes[2].shape == (bucket, 1)                     # the tokens
     compiled = forward.lower(cfg, *shapes).compile()

@@ -9,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.model_implementations.mixtral import _moe_ffn
+from deepspeed_tpu.inference.v2.model_implementations.moe_layer import moe_ffn
 from deepspeed_tpu.ops.pallas.grouped_gemm import (is_supported, moe_ffn_gmm,
                                                    topk_router)
 
@@ -30,8 +30,8 @@ def test_matches_einsum_oracle(T):
     tv, ti = topk_router(x, gate, k)
     got = moe_ffn_gmm(x, tv, ti, w1, w2, w3, n_experts=gate.shape[1],
                       dtype=jnp.float32, interpret=True)
-    want = _moe_ffn(x, gate, w1, w2, w3, k=k, dtype=jnp.float32,
-                    force_einsum=True)
+    want = moe_ffn(x, gate, w1, w2, w3, k=k, dtype=jnp.float32,
+                   force_einsum=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
 
@@ -49,8 +49,8 @@ def test_skewed_routing():
     tv, ti = topk_router(x, gate, 1)
     got = moe_ffn_gmm(x, tv, ti, w1, w2, w3, n_experts=gate.shape[1],
                       dtype=jnp.float32, interpret=True)
-    want = _moe_ffn(x, gate, w1, w2, w3, k=1, dtype=jnp.float32,
-                    force_einsum=True)
+    want = moe_ffn(x, gate, w1, w2, w3, k=1, dtype=jnp.float32,
+                   force_einsum=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
 

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2.model_implementations import (
-    mixtral, opt, paged_layer, parallel_block)
+    mixtral, moe_layer, opt, paged_layer, parallel_block)
 
 
 @pytest.fixture
@@ -86,8 +86,8 @@ def test_moe_ffn_choice(dispatch, d_model, d_ff, kernel):
     w2 = jnp.asarray(rng.normal(size=(E, d_ff, d_model)) / 8, jnp.float32)
 
     def ffn(x, **kw):
-        return mixtral._moe_ffn(x, gate, w1, w2, w3, k=2, dtype=jnp.float32,
-                                **kw)
+        return moe_layer.moe_ffn(x, gate, w1, w2, w3, k=2, dtype=jnp.float32,
+                                 **kw)
 
     assert _traces_kernel(ffn, x) == kernel
     assert dispatch() == (set() if kernel else
