@@ -42,7 +42,10 @@ KERNEL_KNOBS = {
     "flash_mha": ("block_q", "block_k"),
     "paged_mha": (),
     "sparse_mha": (),
-    "moe_ffn_gmm": ("tile_m", "tile_k", "tile_n"),
+    # one tile_m for the FFN's three GEMMs, and tile_k / tile_n for each of
+    # its two GEMM shapes: up [rows, d] @ [d, f], down [rows, f] @ [f, d]
+    "moe_ffn_gmm": ("tile_m", "up_tile_k", "up_tile_n",
+                    "down_tile_k", "down_tile_n"),
     "quantized_matmul": ("block_m", "block_n", "block_k"),
     "block_quantize": ("block_g",),
     "block_dequantize_reduce": ("block_g",),

@@ -33,6 +33,10 @@ VMEM_BUDGET = 16 * 1024 * 1024  # per-core VMEM; pre-filter only, Mosaic is
 
 GRID_STEP_SECONDS = 5e-7  # per-grid-step dispatch overhead for the proxy
 
+GMM_TUNING_EXPERTS = 4  # experts of the moe_ffn_gmm tuning program
+
+GMM_CANDIDATES_A_GEMM = 4  # tilings swept for each of the FFN's GEMM shapes
+
 #: per-chip HBM bandwidth (bytes/s) for the roofline proxy denominator
 _HBM_BYTES_PER_S = {
     "tpu_v4": 1228e9,
@@ -90,13 +94,18 @@ def candidate_space(kernel, dims, dtype):
                 for bk in (256, 512, 1024)
                 if _blocks_fit(bm, bn, bk, m, k, n, g)]
     if kernel == "moe_ffn_gmm":
-        from deepspeed_tpu.ops.pallas.grouped_gemm import _tiling_fits
-        d, f = dims["d"], dims["f"]
-        return [{"tile_m": tm, "tile_k": tk, "tile_n": tn}
-                for tm in (128, 256, 512)
-                for tk in (128, 256, 512)
-                for tn in (128, 256, 512)
-                if _tiling_fits(tm, tk, tn, d, f)]
+        # a GEMM at a time: each shape's few best tilings by its own (k, n),
+        # fewest grid steps a visited group first, and megablox's 128^3
+        from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+        d, f, db = dims["d"], dims["f"], _dtype_bytes(dtype)
+
+        def swept(k, n):
+            every = gg.gmm_tilings(k, n, db)        # 128^3 is its last
+            return every[:GMM_CANDIDATES_A_GEMM] + every[
+                max(GMM_CANDIDATES_A_GEMM, len(every) - 1):]
+
+        return [gg.ffn_blocks(up, down)
+                for up in swept(d, f) for down in swept(f, d)]
     if kernel in ("block_quantize", "block_dequantize_reduce"):
         from deepspeed_tpu.ops.pallas.quant_collective import _blocks_fit
         rows, g = dims["rows"], dims["g"]
@@ -118,11 +127,11 @@ def grid_steps(kernel, dims, config):
         return ((dims["m"] // bm) * (dims["n"] // config["block_n"])
                 * (dims["k"] // config["block_k"]))
     if kernel == "moe_ffn_gmm":
-        rows = -(-dims["rows"] // config["tile_m"]) * config["tile_m"]
-        per_gemm = ((rows // config["tile_m"])
-                    * (dims["d"] // config["tile_k"])
-                    * (dims["f"] // config["tile_n"]))
-        return 3 * per_gemm
+        from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+        rows, d, f = dims["rows"], dims["d"], dims["f"]
+        up, down = gg.ffn_tilings(config)
+        return (2 * gg.gmm_grid_steps(rows, GMM_TUNING_EXPERTS, d, f, up)
+                + gg.gmm_grid_steps(rows, GMM_TUNING_EXPERTS, f, d, down))
     if kernel == "block_quantize":
         bg = min(config["block_g"], dims["rows"])
         return dims["rows"] // bg
@@ -149,8 +158,9 @@ def vmem_bytes(kernel, dims, dtype, config):
         io = (bm * bk * db + bk * bn * 1 + bk * (bn // dims["g"]) * 4) * 2
         return io + bm * bn * 4 + bk * bn * 4          # acc + dequant temp
     if kernel == "moe_ffn_gmm":
-        tm, tk, tn = config["tile_m"], config["tile_k"], config["tile_n"]
-        return (tm * tk + tk * tn) * db * 2 + tm * tn * 4
+        from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+        return max(gg.gmm_vmem_bytes(tiling, db)
+                   for tiling in gg.ffn_tilings(config))
     if kernel == "block_quantize":
         bg = min(config["block_g"], dims["rows"])
         g = dims["g"]
@@ -198,7 +208,7 @@ def build_program(kernel, dims, dtype, config):
 
     if kernel == "moe_ffn_gmm":
         from deepspeed_tpu.ops.pallas.grouped_gemm import moe_ffn_gmm
-        E, topk = 4, 2
+        E, topk = GMM_TUNING_EXPERTS, 2
         T = max(dims["rows"] // topk, 1)
         d, f = dims["d"], dims["f"]
         args = (jax.ShapeDtypeStruct((T, d), dtype),

@@ -121,7 +121,9 @@ def kernel_programs():
 
     def grouped_gemm():
         from deepspeed_tpu.ops.pallas.grouped_gemm import moe_ffn_gmm
-        T, D, F, E, k = 40, 128, 256, 4, 2
+        # Mellum2's widths: 2304 and 896 share no divisor over 128, so the
+        # up GEMMs' tiles (a whole expert a block) are not the down GEMM's
+        T, D, F, E, k = 40, 2304, 896, 64, 8
         args = (jax.ShapeDtypeStruct((T, D), jnp.bfloat16),
                 jax.ShapeDtypeStruct((T, k), jnp.float32),
                 jax.ShapeDtypeStruct((T, k), jnp.int32),

@@ -268,6 +268,35 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, name, rows,
     assert compiled.as_text().count("tpu_custom_call") >= kernels
 
 
+@pytest.mark.parametrize("tokens,k,experts,d,f,dtype,grad", [
+    (512, 8, 64, 2304, 896, jnp.bfloat16, False),
+    (64, 2, 8, 4096, 14336, jnp.bfloat16, False),
+    (512, 2, 8, 4096, 14336, jnp.float32, True)],
+    ids=["mellum2-chunk512", "mixtral-decode64", "mixtral-f32-train"])
+def test_grouped_gemm_ffn_at_the_rules_tiles(for_tpu, one_chip, tokens, k,
+                                             experts, d, f, dtype, grad):
+    """The expert FFN alone at the tiles ``grouped_gemm.gmm_tiling`` picks for
+    each GEMM's own widths: Mosaic's count of the blocks' VMEM has the last
+    word over the rule's reckoning. The training call compiles its backward
+    too (megablox's 128^3 there)."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    args = (sds((tokens, d), dtype), sds((tokens, k), jnp.float32),
+            sds((tokens, k), jnp.int32), sds((experts, d, f), dtype),
+            sds((experts, f, d), dtype), sds((experts, d, f), dtype))
+
+    def ffn(x, tv, ti, w1, w2, w3):
+        return gg.moe_ffn_gmm(x, tv, ti, w1, w2, w3, n_experts=experts,
+                              dtype=dtype)
+
+    def loss(x, tv, ti, w1, w2, w3):
+        return jnp.sum(ffn(x, tv, ti, w1, w2, w3).astype(jnp.float32) ** 2)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 3, 4, 5)) if grad else ffn,
+                        args)
+    assert compiled.as_text().count("tpu_custom_call") >= (9 if grad else 3)
+
+
 def test_quantized_matmul_4096_wide(for_tpu, one_chip):
     from deepspeed_tpu.ops.pallas.quantized_matmul import (is_supported,
                                                            quantized_matmul)

@@ -334,6 +334,32 @@ def test_candidate_space_respects_divisibility():
                                         "bfloat16") == [{}]
 
 
+@pytest.mark.parametrize("d,f", [(2304, 896), (128, 256), (4096, 14336)],
+                         ids=["mellum2", "bench-shape", "mixtral"])
+def test_moe_candidates_and_grid_steps_go_by_gemm(d, f):
+    """The grouped GEMM's candidates are enumerated a GEMM (the up GEMMs by
+    (d, f), the down GEMM by (f, d)); the rule's pick comes first, megablox's
+    128^3 is among them, and the proxy's grid steps count the group visits."""
+    from deepspeed_tpu.ops.pallas import grouped_gemm as gg
+    dims = {"rows": 512, "d": d, "f": f}
+    cands = kernel_tuner.candidate_space("moe_ffn_gmm", dims, "bfloat16")
+    assert cands[0] == gg.ffn_blocks(gg.gmm_tiling(d, f, 2),
+                                     gg.gmm_tiling(f, d, 2))
+    assert gg.ffn_blocks((128,) * 3, (128,) * 3) in cands
+    assert len(cands) <= (kernel_tuner.GMM_CANDIDATES_A_GEMM + 1) ** 2
+    for c in cands:
+        assert gg._tiling_fits(c, d, f)
+        assert (kernel_tuner.vmem_bytes("moe_ffn_gmm", dims, "bfloat16", c)
+                <= kernel_tuner.VMEM_BUDGET)
+        BlockConfig.make("moe_ffn_gmm", **c)        # exactly the five knobs
+        up, down = gg.ffn_tilings(c)
+        visits = 512 // 128 + kernel_tuner.GMM_TUNING_EXPERTS - 1
+        assert kernel_tuner.grid_steps("moe_ffn_gmm", dims, c) == visits * (
+            2 * (d // up[1]) * (f // up[2]) + (f // down[1]) * (d // down[2]))
+    steps = [kernel_tuner.grid_steps("moe_ffn_gmm", dims, c) for c in cands]
+    assert steps[0] == min(steps)
+
+
 def test_chip_free_rank_orders_by_proxy_score():
     fake = _fake_compile_fn(score_of=lambda i: 1e9 * i)  # later = worse
     ranking, device = kernel_tuner.chip_free_rank(
