@@ -10,10 +10,15 @@ serving dtype. phi4flash (Phi-4-mini-flash-reasoning: Mamba, window and full
 differential attention, gated memory units) is served from an in-tree model
 through ``build_engine``; it has no HF converter, and no prefix cache,
 speculation or page export yet. mellum2 (Mellum2: window and full attention
-layers over two paged groups, sparse experts in every layer) likewise.
+layers over two paged groups, sparse experts in every layer) likewise, and
+kanana2 (Kanana-2, a DeepSeek-V3 tree: latent attention over ONE paged group
+of one leaf, sigmoid-routed experts beside a shared one, of which the tree may
+hold a share).
 
 A family is three things, resolved here: its ragged forward, its verify
-forward (or None) and its cache groups (``ragged/cache_groups.py``).
+forward (or None) and its cache groups (``ragged/cache_groups.py``); a fourth
+where its module has one, ``prepare_params(cfg, params)``, the tree as its
+forward reads it, made once when the engine is built.
 """
 
 import importlib
@@ -30,21 +35,23 @@ _IMPLEMENTATION = {"llama": "llama", "mistral": "llama", "qwen2": "llama",
                    "qwen": "llama", "internlm": "llama",  # llama trees (hf.py)
                    "mixtral": "mixtral", "falcon": "parallel_block",
                    "phi": "parallel_block", "opt": "opt",
-                   "phi4flash": "phi4flash", "mellum2": "mellum2"}
+                   "phi4flash": "phi4flash", "mellum2": "mellum2",
+                   "kanana2": "kanana2"}
 
 #: families ``build_engine`` serves from an in-tree model and tree
 SERVED_FAMILIES = tuple(_IMPLEMENTATION)
 #: families ``build_hf_engine`` loads from a checkpoint directory
 SUPPORTED_FAMILIES = tuple(
     f for f in SERVED_FAMILIES
-    if f not in ("phi4flash", "mellum2"))  # they have no HF converter
+    if f not in ("phi4flash", "mellum2", "kanana2"))  # no HF converter
 
 #: the one place a config class names its family; any other is a llama tree
 _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
                      "ParallelBlockConfig": "falcon",
                      "OPTConfig": "opt",
                      "Phi4FlashConfig": "phi4flash",
-                     "Mellum2Config": "mellum2"}
+                     "Mellum2Config": "mellum2",
+                     "Kanana2Config": "kanana2"}
 
 
 def _implementation(model, family):
@@ -115,6 +122,9 @@ def resolve_cache_groups(model):
 
 def build_engine(model, params, engine_config=None, family=None):
     """Build a ragged engine from an in-tree model + param tree."""
+    prepare = getattr(_implementation(model, family), "prepare_params", None)
+    if prepare is not None:
+        params = prepare(model.config, params)
     return InferenceEngineV2(model, params, engine_config,
                              forward_fn=resolve_forward_fn(model, family),
                              verify_fn=resolve_verify_fn(model, family),

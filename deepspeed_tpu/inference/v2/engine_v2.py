@@ -111,9 +111,22 @@ class InferenceEngineV2:
         # the last round's dispatches, summed, the rows that reached the
         # expert GEMMs for real tokens and for padded slots
         self._expert_fanout = (getattr(cfg, "num_experts_per_tok", 0) or 0,
-                               cfg.num_hidden_layers)
+                               getattr(cfg, "num_expert_layers",
+                                       cfg.num_hidden_layers))
         self.last_expert_rows = 0
         self.last_expert_rows_padded = 0
+        # where the tree holds a share of the experts the router scores:
+        # (experts held, experts routed over), constant on every dispatch
+        held = getattr(cfg, "experts_held", None)
+        self._expert_share = (held[1], cfg.n_routed_experts) if held else None
+        # where the "kv" group's page is one leaf (a latent row a token):
+        # bytes of a token's row in one layer, and of the last round's
+        # dispatches, summed, the pages held after each one's allocation
+        kvc = self._state.kv_cache
+        self._latent_row_bytes = (
+            kvc.k_pool.shape[2] * kvc.k_pool.shape[4]
+            * kvc.k_pool.dtype.itemsize) if self._state.one_leaf else 0
+        self.last_latent_pages = 0
         # postmortem-bundle collector (telemetry/flightrec.py): the newest
         # engine's host-side KV pool stats ride every bundle — pure host
         # reads, so collection is safe even from an abnormal path
@@ -285,6 +298,7 @@ class InferenceEngineV2:
         self.last_window_pages_freed = self.last_state_slots = 0
         self.last_live_pages = self.last_table_slots = 0
         self.last_expert_rows = self.last_expert_rows_padded = 0
+        self.last_latent_pages = 0
         for rows, min_seqs, min_tokens in dispatch_rows(
                 lengths, short_row_tokens(verify_k)):
             # explicit begin/end, and the host-to-device copies as arguments
@@ -328,6 +342,14 @@ class InferenceEngineV2:
                 self.last_expert_rows += took
                 self.last_expert_rows_padded += padded
                 sp.set(expert_rows=took, expert_rows_padded=padded)
+                if self._expert_share:
+                    sp.set(experts_held=self._expert_share[0],
+                           experts_routed_over=self._expert_share[1])
+            if self._latent_row_bytes:
+                held = kv.num_blocks - kv.free_blocks
+                self.last_latent_pages += held
+                sp.set(latent_pages=held,
+                       latent_row_bytes=self._latent_row_bytes)
             sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
                    real_tokens=real_tokens,
                    padded_slots=seq_bucket * chunk_bucket,

@@ -1,0 +1,141 @@
+"""Ragged forward for Kanana-2 (``models/kanana2.py`` has the architecture, a
+DeepSeek-V3 tree): latent attention (MLA) over ONE paged group of one leaf,
+one leading dense layer, then sparse-expert layers with a shared expert.
+
+``cache["kv"]`` is ``(pages,)``: ``[layers, NB+1, 1, bs, W]``, a row a token
+and layer holding the normalised latent ``c`` (``kv_lora_rank`` columns), the
+rotated shared ``k_pe`` and zeros up to a whole lane tile
+(``Kanana2Config.latent_row_width``). The row is written whole
+(``paged_layer._scatter_latent``) and read whole, once, for the scores and for
+the values.
+
+The read is ABSORBED, for a decode row and for a chunk alike: ``q_lat_i =
+q_nope_i W_UK_i^T`` puts a head's query into the row's own columns,
+``paged_mla`` (or its dense twin) scores it against the row and sums the row's
+latent part, ``o_i = o_lat_i W_UV_i`` takes that to the head's values, so a
+page is read once and never up-projected. (The form that up-projects the live
+rows to every head's ``k_nope`` and ``v`` first was timed on the chip and lost
+at every chunk but 128 tokens: PERF.md, PR 37.) ``W_UK`` and ``W_UV`` are cut
+out of ``kv_b_proj`` once, when the engine is built (``prepare_params``).
+
+The expert layer is ``moe_layer.moe_ffn`` (shared with Mixtral and Mellum2)
+told the scoring, the shared expert and which experts this tree holds. The
+dense layer and the expert layer are each a jit of their own (``_layer``'s
+static ``dense``), so that a program of any depth traces two functions.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.v2.model_implementations.llama import _rmsnorm
+from deepspeed_tpu.inference.v2.model_implementations.moe_layer import moe_ffn
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
+    _latent_attention, _pool_block_size, _scatter_latent, last_token,
+    layer_rows, layer_trash, merge_layers, pool_pages_per_layer, real_slots,
+    split_layers)
+from deepspeed_tpu.models.llama import (
+    rope_frequencies, rotary_apply, rotary_tables)
+
+
+def prepare_params(cfg, params):
+    """The tree as the forward reads it: each layer's ``kv_b_proj`` [r, H *
+    (nope + v)] cut once into ``w_uk`` [r, H, nope] and ``w_uv`` [r, H, v],
+    whole buffers of their own, instead of two strided slices a dispatch. A
+    tree of shapes (a compile for a described chip) gives a tree of shapes."""
+    H, dn = cfg.num_attention_heads, cfg.qk_nope_head_dim
+
+    def cut(kv_b):
+        kv_b = kv_b.reshape(kv_b.shape[0], H, -1)
+        return kv_b[..., :dn] + 0, kv_b[..., dn:] + 0
+
+    out = dict(params)
+    for l in range(cfg.num_hidden_layers):
+        layer = dict(params[f"layers_{l}"])
+        attn = dict(layer["self_attn"])
+        kv_b = attn.pop("kv_b_proj")["kernel"]
+        attn["w_uk"], attn["w_uv"] = jax.eval_shape(cut, kv_b) \
+            if isinstance(kv_b, jax.ShapeDtypeStruct) else cut(kv_b)
+        layer["self_attn"] = attn
+        out[f"layers_{l}"] = layer
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _layer(cfg, dense, lp, x, pool, tables, seen, q_len, real, rope, trash):
+    """One decoder layer over x [S, Q, d] against the merged pool of latent
+    pages; ``tables`` and ``trash`` are this layer's. ``trash`` is a traced
+    scalar so that the layers of one kind share ONE traced and lowered
+    function (``mellum2._layer``)."""
+    S, Q, _ = x.shape
+    H, r = cfg.num_attention_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    W, bs = pool.shape[-1], pool.shape[2]
+    eps, dt = cfg.rms_norm_eps, cfg.dtype
+    attn = lp["self_attn"]
+    w_uk, w_uv = attn["w_uk"].astype(dt), attn["w_uv"].astype(dt)
+    h = _rmsnorm(x, lp["input_layernorm"]["scale"], eps)
+    with jax.named_scope("mla_attn"):
+        with jax.named_scope("mla_q"):
+            q = (h @ attn["q_proj"]["kernel"].astype(dt)).reshape(S, Q, H, dn + dr)
+            q_lat = jnp.einsum("sqhd,chd->sqhc", q[..., :dn], w_uk)
+            q_row = jnp.concatenate(
+                [q_lat, rotary_apply(q[..., dn:], *rope),
+                 jnp.zeros((S, Q, H, W - r - dr), dt)], -1)
+        with jax.named_scope("mla_latent_write"):
+            ckv = h @ attn["kv_a_proj"]["kernel"].astype(dt)      # [S, Q, r + dr]
+            c = _rmsnorm(ckv[..., :r], attn["kv_a_layernorm"]["scale"], eps)
+            k_pe = rotary_apply(ckv[..., None, r:], *rope)[..., 0, :]
+            row = jnp.concatenate(
+                [c, k_pe, jnp.zeros((S, Q, W - r - dr), dt)], -1)
+            pool = _scatter_latent(pool, row, tables, seen, q_len, bs, trash)
+        with jax.named_scope("mla_read"):
+            o_lat = _latent_attention(q_row, pool, tables, seen, bs, q_len,
+                                      r, cfg.softmax_scale)
+        with jax.named_scope("mla_out"):
+            o = jnp.einsum("sqhc,chd->sqhd", o_lat, w_uv)
+            x = x + o.reshape(S, Q, H * dv) @ attn["o_proj"]["kernel"].astype(dt)
+
+    h = _rmsnorm(x, lp["post_attention_layernorm"]["scale"], eps)
+    if dense:
+        mlp = lp["mlp"]
+        w = lambda name: mlp[name]["kernel"].astype(dt)
+        return x + (jax.nn.silu(h @ w("gate_proj")) * (h @ w("up_proj"))) \
+            @ w("down_proj"), pool
+    moe = lp["moe"]
+    y = moe_ffn(h.reshape(S * Q, -1), moe["router"]["kernel"].astype(dt),
+                moe["w1"].astype(dt), moe["w2"].astype(dt), moe["w3"].astype(dt),
+                k=cfg.num_experts_per_tok, dtype=dt, valid=real,
+                scoring="sigmoid", score_bias=moe["router"]["bias"],
+                routed_scale=cfg.routed_scaling_factor,
+                shared=tuple(moe["shared"][n].astype(dt) for n in ("w1", "w2", "w3")),
+                experts_held=cfg.experts_held)
+    return x + y.reshape(S, Q, -1), pool
+
+
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def ragged_forward(cfg, params, cache, tokens, q_len, seen, tables):
+    """One ragged forward step -> (last-token logits [S, V], new cache); the
+    contract is ``llama.ragged_forward``'s. ``params``: ``prepare_params``'s."""
+    S, Q = tokens.shape
+    (pool,) = cache["kv"]
+    layers, nb = pool.shape[0], pool_pages_per_layer(pool)
+    assert _pool_block_size(pool) == pool.shape[3]
+    positions = seen[:, None] + jnp.arange(Q)[None, :]
+    real = real_slots(q_len, Q).reshape(S * Q)
+    rope = rotary_tables(positions, *rope_frequencies(
+        cfg.qk_rope_head_dim, cfg.rope_theta))
+
+    # the stacked pool is one merged pool on the loop's carry
+    # (paged_layer.py, "The layout")
+    pool = merge_layers(pool)
+    x = params["embed_tokens"].astype(cfg.dtype)[tokens]
+    for l in range(cfg.num_hidden_layers):
+        x, pool = _layer(cfg, cfg.is_dense(l), params[f"layers_{l}"], x, pool,
+                         layer_rows(tables["kv"], l, nb), seen, q_len, real,
+                         rope, jnp.int32(layer_trash(l, nb)))
+
+    x = _rmsnorm(x, params["norm"]["scale"], cfg.rms_norm_eps)
+    logits = last_token(x, q_len) @ params["lm_head"].astype(cfg.dtype).T
+    return logits.astype(jnp.float32), {"kv": (split_layers(pool, layers),)}

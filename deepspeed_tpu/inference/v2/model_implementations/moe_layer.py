@@ -1,9 +1,11 @@
 """The sparse-expert feed-forward layer of a ragged forward: the one place
-shared by every family that has one (``mixtral.py``, ``mellum2.py``).
+shared by every family that has one (``mixtral.py``, ``mellum2.py``,
+``kanana2.py``).
 
-``moe_ffn`` routes a flat batch of token slots (softmax over all experts, the
-``k`` largest, renormalised: ``grouped_gemm.topk_router``) and runs the chosen
-experts' SwiGLU: the ragged grouped GEMM (``ops/pallas/grouped_gemm.py``:
+``moe_ffn`` routes a flat batch of token slots (by default softmax over all
+experts, the ``k`` largest, renormalised: ``grouped_gemm.topk_router``; with
+``scoring="sigmoid"`` the DeepSeek-V3 router, ``sigmoid_router``) and runs the
+chosen experts' SwiGLU: the ragged grouped GEMM (``ops/pallas/grouped_gemm.py``:
 rows sorted by expert, no capacity dimension) when Pallas is on and the dims
 tile, else the GShard dense dispatch-combine einsum below, which
 ``force_einsum`` pins as the tests' oracle.
@@ -16,10 +18,21 @@ it) or has an all-zero dispatch row (the einsum), and its output is zero. With
 it would be whole groups. ``expert_rows`` is what the engine's spans and the
 scheduler's counters say of it.
 
+A share of the experts. ``experts_held = (first, count)`` says that ``w1`` /
+``w2`` / ``w3`` hold the ``count`` experts from ``first`` on, of the router's
+whole width: the layer routes over ALL experts (the gate's columns, the
+weights' normalisation over all ``k`` chosen), and computes its own experts'
+part of the sum. A row whose expert is not held is sorted past every group
+exactly as a padded slot's rows are (expert index ``count``: the GEMM never
+visits it; in the einsum a zero dispatch row). A token none of whose experts
+are held gets the shared expert's output alone. Nothing stands in for the
+other shares or their exchange.
+
 Scopes for the device trace: everything here is under ``moe_ffn``; inside it
 the router under ``moe_router``, the sort and gather of rows under
 ``moe_sort``, each grouped GEMM under ``moe_ffn_gmm``, the unsort and the
-weighted sum of a token's ``k`` rows under ``moe_unsort``.
+weighted sum of a token's ``k`` rows under ``moe_unsort``, a shared expert
+(every token's, dense) under ``moe_shared``.
 """
 
 import jax
@@ -31,16 +44,43 @@ from deepspeed_tpu.ops.registry import pallas_interpret, takes_kernel
 def expert_rows(real_tokens, k, layers):
     """``(expert_rows, expert_rows_padded)`` of a dispatch of ``real_tokens``
     real tokens through ``layers`` expert layers of ``k`` experts a token:
-    rows that reach the expert GEMMs for real tokens, and for the dispatch's
-    padded slots. The second is 0 however many those are, because ``moe_ffn``
-    sorts slots that are not ``valid`` past every group."""
+    rows ROUTED for real tokens, and for the dispatch's padded slots. The
+    second is 0 however many those are, because ``moe_ffn`` sorts slots that
+    are not ``valid`` past every group. Under a share of the experts
+    (``experts_held``) the first is still every row the router chose, held
+    here or not: which of them land on this share's experts is data, known on
+    the device alone."""
     return real_tokens * k * layers, 0
 
 
+def sigmoid_router(x, gate_wg, bias, k, scale):
+    """The DeepSeek-V3 router (``topk_method: noaux_tc`` with one group, so
+    that the group step is the identity): ``s = sigmoid(x W_g)`` in float32
+    over all experts; chosen are the ``k`` largest of ``s + bias`` (the
+    learned ``e_score_correction_bias`` selects and never weighs); weights
+    ``s[chosen] / (sum s[chosen] + 1e-20) * scale``. -> (weights, indices),
+    each [T, k]."""
+    scores = jax.nn.sigmoid(
+        jnp.dot(x, gate_wg, preferred_element_type=jnp.float32))
+    _, top_idx = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
+    return top_vals / (jnp.sum(top_vals, -1, keepdims=True) + 1e-20) * scale, \
+        top_idx
+
+
 def moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, valid=None,
-            force_einsum=False):
-    """x: [T, D]; gate_wg: [D, E]; w1/w3: [E, D, F]; w2: [E, F, D];
-    ``valid``: [T] bool, None for all. Returns [T, D], zero where not valid.
+            force_einsum=False, scoring="softmax", score_bias=None,
+            routed_scale=1.0, shared=None, experts_held=None):
+    """x: [T, D]; gate_wg: [D, E]; w1/w3: [E_held, D, F]; w2: [E_held, F, D]
+    (``E_held`` is E unless ``experts_held`` says otherwise); ``valid``: [T]
+    bool, None for all. Returns [T, D], zero where not valid.
+
+    ``scoring``: ``"softmax"`` (Mixtral, Mellum2: softmax, top-k,
+    renormalised) or ``"sigmoid"`` (``sigmoid_router`` with ``score_bias``
+    [E] and ``routed_scale``). ``shared``: ``(w1, w2, w3)`` of a dense SwiGLU
+    every valid token takes beside its routed experts, or None.
+    ``experts_held``: ``(first, count)`` of the router's E columns whose
+    experts the weights hold, None for all (module docstring).
 
     Inference uses LOSSLESS capacity C = T: no token is ever dropped. The
     training-side capacity_factor machinery (moe/sharded_moe.py) does not
@@ -55,15 +95,37 @@ def moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, valid=None,
     with jax.named_scope("moe_ffn"):
         # single routing implementation for both dispatch backends
         with jax.named_scope("moe_router"):
-            top_vals, top_idx = gg.topk_router(x, gate_wg, k)    # [T, k]
+            if scoring == "softmax":
+                top_vals, top_idx = gg.topk_router(x, gate_wg, k)    # [T, k]
+            elif scoring == "sigmoid":
+                top_vals, top_idx = sigmoid_router(x, gate_wg, score_bias, k,
+                                                   routed_scale)
+            else:
+                raise ValueError(f"unknown router scoring {scoring!r}")
             top_vals = jnp.where(valid[:, None], top_vals, 0.0)
+            if experts_held is not None:
+                first, E = experts_held
+                assert w1.shape[0] == E, "the weights hold experts_held"
+                # an expert that is not held becomes index E: past every
+                # group, as a padded slot's rows are
+                top_idx = top_idx - first
+                held = (top_idx >= 0) & (top_idx < E)
+                top_idx = jnp.where(held, top_idx, E)
+                top_vals = jnp.where(held, top_vals, 0.0)
         if not force_einsum and takes_kernel(
                 "moe_ffn_gmm", gg.is_supported(D, F),
                 f"dims ({D}, {F}) not 128-tileable for gmm"):
-            return gg.moe_ffn_gmm(x, top_vals, top_idx, w1, w2, w3,
-                                  n_experts=E, dtype=dtype, valid=valid,
-                                  interpret=pallas_interpret())
-        return _moe_ffn_einsum(x, top_vals, top_idx, valid, w1, w2, w3, dtype)
+            y = gg.moe_ffn_gmm(x, top_vals, top_idx, w1, w2, w3,
+                               n_experts=E, dtype=dtype, valid=valid,
+                               interpret=pallas_interpret())
+        else:
+            y = _moe_ffn_einsum(x, top_vals, top_idx, valid, w1, w2, w3, dtype)
+        if shared is None:
+            return y
+        with jax.named_scope("moe_shared"):
+            s1, s2, s3 = shared
+            h = (jax.nn.silu(x @ s1) * (x @ s3)) @ s2
+            return y + jnp.where(valid[:, None], h, 0).astype(dtype)
 
 
 def _moe_ffn_einsum(x, top_vals, top_idx, valid, w1, w2, w3, dtype):

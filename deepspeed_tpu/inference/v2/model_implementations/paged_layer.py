@@ -7,6 +7,11 @@ the paged cache, blocked_flash -> paged attention, logits_gather ->
 last-token logits). Every family's forward calls these and nothing else of
 the cache.
 
+Two kinds of page. A K and V pair (``_scatter_kv``, ``_paged_attention``),
+and a page of ONE leaf, a latent row a token (``_scatter_latent``,
+``latent_attention``: ``ragged/cache_groups.py`` ``leaves=1``), which is read
+once for the scores and the values.
+
 The layout. The state manager hands a forward stacked pools ``[L, NB+1,
 KV, bs, Dh]`` (an ``(int8, scale)`` pair when ``kv_dtype="int8"``), the last
 page of every layer being that layer's trash page, which absorbs the writes
@@ -93,6 +98,32 @@ def _quantize_kv_rows(x):
     return q.reshape(x.shape), scale.reshape(x.shape[:-1])
 
 
+def _write_slots(block_tables, seen, q_len, Q, block_size, trash):
+    """(page, slot in the page), each [S*Q, 1], of a dispatch's ``[S, Q]``
+    token slots; a padded slot goes to slot 0 of the ``trash`` page."""
+    pos = seen[:, None] + jnp.arange(Q)[None, :]              # [S, Q]
+    valid = jnp.arange(Q)[None, :] < q_len[:, None]
+    blk = jnp.take_along_axis(block_tables, pos // block_size, axis=1,
+                              mode="clip")
+    # every leading dim is indexed — (block, head, slot) per [Dh] row, values
+    # [S*Q, KV, Dh] — so the scatter writes whole rows in the pool's own
+    # layout. Leaving the head dim a slice between two indexed dims made the
+    # chip's compiler re-lay the WHOLE pool out around the scatter.
+    bi = jnp.where(valid, blk, trash).reshape(-1, 1)          # [S*Q, 1]
+    si = jnp.where(valid, pos % block_size, 0).reshape(-1, 1)
+    return bi, si
+
+
+def _scatter_latent(pool, rows, block_tables, seen, q_len, block_size, trash):
+    """Write [S, Q, W] new latent rows into the one-leaf pool [NB, 1, bs, W]
+    via block tables: one whole row a token, padded slots to the ``trash``
+    page, indexed as ``_scatter_kv`` indexes (``_write_slots``)."""
+    S, Q, W = rows.shape
+    bi, si = _write_slots(block_tables, seen, q_len, Q, block_size, trash)
+    return pool.at[bi, jnp.zeros((1, 1), jnp.int32), si].set(
+        rows.reshape(S * Q, 1, W).astype(pool.dtype))
+
+
 def _scatter_kv(k_pool, v_pool, k, v, block_tables, seen, q_len, block_size,
                 trash):
     """Write [S, Q, KV, Dh] new KVs into the [NB, KV, bs, Dh] pool via block
@@ -107,16 +138,7 @@ def _scatter_kv(k_pool, v_pool, k, v, block_tables, seen, q_len, block_size,
     k_pool, k_scale = _pool_parts(k_pool)
     v_pool, v_scale = _pool_parts(v_pool)
     S, Q = k.shape[:2]
-    pos = seen[:, None] + jnp.arange(Q)[None, :]              # [S, Q]
-    valid = jnp.arange(Q)[None, :] < q_len[:, None]
-    blk = jnp.take_along_axis(block_tables, pos // block_size, axis=1,
-                              mode="clip")
-    # every leading dim is indexed — (block, head, slot) per [Dh] row, values
-    # [S*Q, KV, Dh] — so the scatter writes whole rows in the pool's own
-    # layout. Leaving the head dim a slice between two indexed dims made the
-    # chip's compiler re-lay the WHOLE pool out around the scatter.
-    bi = jnp.where(valid, blk, trash).reshape(-1, 1)          # [S*Q, 1]
-    si = jnp.where(valid, pos % block_size, 0).reshape(-1, 1)
+    bi, si = _write_slots(block_tables, seen, q_len, Q, block_size, trash)
     hi = jnp.arange(k.shape[2])[None, :]                      # [1, KV]
     if k_scale is not None:
         k, ks = _quantize_kv_rows(k)          # int8 [S,Q,KV,Dh], f32 [S,Q,KV]
@@ -147,8 +169,9 @@ def _paged_attention(q, k_pool, v_pool, block_tables, seen, block_size,
     if takes_kernel("paged_mha", pa.is_supported(q.shape, kp.shape),
                     f"q heads {tuple(q.shape[2:])} over pages "
                     f"{tuple(kp.shape[1:])} violate the kernel's tiling "
-                    f"(need H%KV==0, Dh<=256, block_size%8==0), "
-                    f"O(max_context) reads"):
+                    f"(need H%KV==0, Dh<=256, block_size%8==0; a walk of "
+                    f"live pages for Dh%128==0, else a grid over the "
+                    f"table's width), O(max_context) reads"):
         vp, vs = _pool_parts(v_pool)
         return pa.paged_mha(q, kp, vp, block_tables, seen, q_len,
                             k_scale=ks, v_scale=vs,
@@ -195,6 +218,48 @@ def _paged_attention_dense(q, k_pool, v_pool, block_tables, seen, block_size,
         logits = jnp.where(visible, logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(q_s.dtype)
         return jnp.einsum("krqs,skd->qkrd", probs, vals).reshape(Q, H, Dh)
+
+    return jax.vmap(one_seq)(q, block_tables, seen)
+
+
+# -- the read of a page of one leaf (a latent row a token) ---------------------
+
+def _latent_attention(q, pool, block_tables, seen, block_size, q_len,
+                      value_dim, softmax_scale):
+    """The absorbed read: every query head of q [S, Q, H, W] on the ONE
+    latent row a token of ``pool`` [NB, 1, bs, W], scores by the row's whole
+    width, values its first ``value_dim`` columns -> [S, Q, H, value_dim].
+    The Pallas walk ``paged_mla`` (a page crosses HBM once) when Pallas is on
+    and the shapes tile, the dense gather twin elsewhere."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    if takes_kernel("paged_mla",
+                    pa.mla_is_supported(q.shape, pool.shape, value_dim),
+                    f"q heads {tuple(q.shape[2:])} over latent pages "
+                    f"{tuple(pool.shape[1:])} violate the kernel's tiling "
+                    f"(need row width and value_dim%128==0, "
+                    f"block_size%8==0), O(max_context) reads"):
+        return pa.paged_mla(q, pool, block_tables, seen, q_len,
+                            value_dim=value_dim, softmax_scale=softmax_scale,
+                            interpret=pallas_interpret())
+    return _latent_attention_dense(q, pool, block_tables, seen, block_size,
+                                   value_dim, softmax_scale)
+
+
+def _latent_attention_dense(q, pool, block_tables, seen, block_size,
+                            value_dim, softmax_scale):
+    """Pure-XLA twin of ``paged_mla`` (gathers the full table)."""
+    S, Q, H, W = q.shape
+    MB = block_tables.shape[1]
+
+    def one_seq(q_s, bt_s, seen_s):
+        rows = pool[bt_s][:, 0].reshape(MB * block_size, W).astype(q_s.dtype)
+        logits = jnp.einsum("qhw,sw->hqs", q_s, rows).astype(jnp.float32) \
+            * softmax_scale
+        key_pos = jnp.arange(MB * block_size)[None, :]
+        qry_pos = (seen_s + jnp.arange(Q))[:, None]
+        logits = jnp.where(key_pos <= qry_pos, logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q_s.dtype)
+        return jnp.einsum("hqs,sv->qhv", probs, rows[:, :value_dim])
 
     return jax.vmap(one_seq)(q, block_tables, seen)
 

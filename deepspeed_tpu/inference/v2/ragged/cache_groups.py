@@ -9,6 +9,15 @@ one group. Further groups are either more paged groups (layers whose pages are
 allocated apart, with a ``window`` that lets pages be freed once every later
 query has left them behind) or one slot group (leaves of fixed size a
 sequence, addressed by a slot id: recurrent state).
+
+A paged group's page is two leaves, a K pool and a V pool, unless the group
+says ``leaves=1``: then it is ONE pool whose row a token (``head_dim`` wide,
+``kv_heads`` of them, 1 for a latent row) is read for the scores AND, its
+first ``value_dim`` columns, for the values. Latent (MLA) attention keeps such
+a row: the normalised latent beside the rotated position part, nothing a
+head. What works on a K and V pair and was not extended (the prefix cache,
+speculation's rollback, int8 pages, the page wire, the host tiers) refuses a
+one-leaf group by this field.
 """
 
 import dataclasses
@@ -25,6 +34,17 @@ class PagedGroup:
     # one, is then only a mask). An int: pages wholly before
     # ``seen - window`` are freed after each round.
     window: Optional[int] = None
+    # 2: a K pool and a V pool. 1: one pool whose rows are keys and, their
+    # first ``value_dim`` columns, values
+    leaves: int = 2
+    value_dim: Optional[int] = None
+
+    def __post_init__(self):
+        if self.leaves not in (1, 2):
+            raise ValueError("a paged group's page is 1 leaf or 2")
+        if (self.leaves == 1) != (self.value_dim is not None):
+            raise ValueError("value_dim belongs to a group of one leaf, "
+                             "and such a group states it")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -10,6 +10,11 @@ path scatters new KVs into the pool and gathers per-sequence views through
 block tables. One extra *trash block* (index ``num_blocks``) absorbs writes
 from padded token slots, keeping every scatter shape static for XLA.
 
+A group of ONE leaf (``leaves=1``, ``ragged/cache_groups.py``: a latent row a
+token) has the K pool alone: ``v_pool`` is None, ``fwd`` is a 1-tuple, and a
+pool's bytes are tokens x row width x itemsize. It takes fp pages and no host
+tier; swap-out and swap-in of a preempted sequence move its one leaf.
+
 Storage tiers (the long-context capacity axes):
 
 * ``kv_dtype="int8"`` stores the pools int8 with per-token fp32 scales in
@@ -79,18 +84,26 @@ class BlockedKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
                  head_dim, dtype="bf16", kv_dtype="fp", host_capacity=0,
-                 nvme_capacity=0, nvme_dir=None):
+                 nvme_capacity=0, nvme_dir=None, leaves=2):
         self.num_layers = num_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
+        self.leaves = leaves
         self.quantized = (kv_dtype == "int8")
         if kv_dtype not in ("fp", "int8"):
             raise ValueError(f"kv_dtype must be 'fp' or 'int8', got {kv_dtype!r}")
+        if leaves == 1:
+            for on, what in ((self.quantized, "kv_dtype int8"),
+                             (host_capacity, "host_kv_blocks (the host tier)"),
+                             (nvme_capacity, "nvme_kv_blocks (the NVMe tier)")):
+                if on:
+                    raise ValueError(f"{what} is not supported for a paged "
+                                     f"group of one leaf")
         self.dtype = jnp.int8 if self.quantized else _DTYPES.get(dtype, dtype)
         # +1 trash block for masked writes
         shape = (num_layers, num_blocks + 1, num_kv_heads, block_size, head_dim)
         self.k_pool = jnp.zeros(shape, self.dtype)
-        self.v_pool = jnp.zeros(shape, self.dtype)
+        self.v_pool = jnp.zeros(shape, self.dtype) if leaves == 2 else None
         if self.quantized:
             # one fp32 scale per (layer, block, kv head, token row); the
             # trailing (1, block_size) layout makes the kernel's scale tile a
@@ -164,10 +177,24 @@ class BlockedKVCache:
     def fwd_v(self):
         return (self.v_pool, self.v_scale) if self.quantized else self.v_pool
 
-    def update(self, k, v):
+    @property
+    def fwd(self):
+        """The group's entry of a forward's ``cache``: ``(K, V)``, or the one
+        leaf alone ``(pages,)``."""
+        return (self.fwd_k, self.fwd_v) if self.leaves == 2 else (self.k_pool,)
+
+    @property
+    def pool_bytes(self):
+        """Device bytes of the pools (trash page and scales included)."""
+        pools = (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
+        return sum(p.size * p.dtype.itemsize for p in pools if p is not None)
+
+    def update(self, k, v=None):
         """Swap in pools returned by the jitted forward (pairs when
-        quantized, mirroring ``fwd_k``/``fwd_v``)."""
-        if self.quantized:
+        quantized, mirroring ``fwd``)."""
+        if self.leaves == 1:
+            self.k_pool = k
+        elif self.quantized:
             (self.k_pool, self.k_scale) = k
             (self.v_pool, self.v_scale) = v
         else:
@@ -181,7 +208,8 @@ class BlockedKVCache:
         lands on the replica's devices, not device 0."""
         import jax
         self.k_pool = jax.device_put(self.k_pool, sharding)
-        self.v_pool = jax.device_put(self.v_pool, sharding)
+        if self.v_pool is not None:
+            self.v_pool = jax.device_put(self.v_pool, sharding)
         if self.quantized:
             self.k_scale = jax.device_put(self.k_scale, sharding)
             self.v_scale = jax.device_put(self.v_scale, sharding)
@@ -222,8 +250,10 @@ class BlockedKVCache:
     def _gather_pages(self, idx):
         """Dispatch gathers of the given block rows (and their scales) —
         all before any fetch, so the device->host copies pipeline."""
-        parts = [jnp.take(self.k_pool, idx, axis=1),
-                 jnp.take(self.v_pool, idx, axis=1)]
+        parts = [jnp.take(self.k_pool, idx, axis=1)]
+        if self.leaves == 1:
+            return tuple(parts)
+        parts.append(jnp.take(self.v_pool, idx, axis=1))
         if self.quantized:
             parts += [jnp.take(self.k_scale, idx, axis=1),
                       jnp.take(self.v_scale, idx, axis=1)]
@@ -233,6 +263,8 @@ class BlockedKVCache:
         """Bind host (or shipped device) page rows under the given ids."""
         self.k_pool = self.k_pool.at[:, idx].set(
             jnp.asarray(parts[0], self.dtype))
+        if self.leaves == 1:
+            return
         self.v_pool = self.v_pool.at[:, idx].set(
             jnp.asarray(parts[1], self.dtype))
         if self.quantized:
@@ -307,6 +339,11 @@ class BlockedKVCache:
     # scatter accepts whatever placement the transport delivered. Quantized
     # pools ship ``(int8, scale)`` pairs — the pytree flows through
     # device_put like a plain array.
+    def _refuse_one_leaf(self, what):
+        if self.leaves == 1:
+            raise ValueError(f"{what}: the page wire carries K and V pairs, "
+                             f"not a paged group of one leaf")
+
     def _pad_pages(self, blocks):
         """Pad a block-id list to the next power of two with trash-block
         reads/writes. Transfers bucket their shapes so the gather/scatter
@@ -325,6 +362,7 @@ class BlockedKVCache:
         ``[num_layers, bucket(len(blocks)), heads, block_size, head_dim]``
         (each a ``(data, scale)`` pair when quantized) — rows past
         ``len(blocks)`` are trash-block padding."""
+        self._refuse_one_leaf("export_blocks")
         idx = jnp.asarray(self._pad_pages(list(blocks)), jnp.int32)
         parts = self._gather_pages(idx)
         if self.quantized:
@@ -336,6 +374,7 @@ class BlockedKVCache:
         freshly allocated ids (refcount 1 via the allocator, evicting parked
         cached blocks first under pressure); padding rows scatter into the
         trash block. Returns the new ids in shipping order."""
+        self._refuse_one_leaf("import_blocks")
         k, ks = split_pages(k)
         v, vs = split_pages(v)
         if (ks is not None) != self.quantized:

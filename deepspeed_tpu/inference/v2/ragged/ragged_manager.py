@@ -2,8 +2,9 @@
 ``deepspeed/inference/v2/ragged/ragged_manager.py:19``): tracks live sequences
 and owns what they keep on the device between dispatches, as the model's
 cache groups declare it (``ragged/cache_groups.py``): the blocked KV cache of
-the ``"kv"`` group, further paged groups whose pages are freed behind a
-window, and a slot group of recurrent state."""
+the ``"kv"`` group (a K and a V pool, or ONE pool of latent rows where the
+group declares ``leaves=1``), further paged groups whose pages are freed
+behind a window, and a slot group of recurrent state."""
 
 import numpy as np
 
@@ -25,12 +26,14 @@ class DSStateManager:
                              "group \"kv\"")
         num_layers, num_kv_heads, head_dim = \
             primary.layers, primary.kv_heads, primary.head_dim
+        self.primary_group = primary
         self._init_further_groups(config, further)
+        self._refuse_for_one_leaf(config, groups)
         num_blocks = sm.num_kv_blocks
         if num_blocks is None:
             num_blocks = self._blocks_from_memory_budget(
                 num_layers, num_kv_heads, head_dim, kv,
-                kv_dtype=sm.kv_dtype)
+                kv_dtype=sm.kv_dtype, leaves=primary.leaves)
         self.kv_cache = BlockedKVCache(num_layers, num_blocks, kv.block_size,
                                        num_kv_heads, head_dim, kv.cache_dtype,
                                        kv_dtype=sm.kv_dtype,
@@ -38,7 +41,8 @@ class DSStateManager:
                                        nvme_capacity=getattr(
                                            sm, "nvme_kv_blocks", 0),
                                        nvme_dir=getattr(
-                                           sm, "nvme_kv_dir", "") or None)
+                                           sm, "nvme_kv_dir", "") or None,
+                                       leaves=primary.leaves)
         # block-granular prefix sharing (config_v2.py prefix_caching knob,
         # default off). None when disabled — every cache-path branch below
         # is a single attribute test, so the disabled path does zero
@@ -73,6 +77,26 @@ class DSStateManager:
         if self.slot_group is not None:
             logger.info(f"DSStateManager: group {self.slot_group.name!r}: "
                         f"{self.trash_slot} slots")
+
+    @staticmethod
+    def _refuse_for_one_leaf(config, groups):
+        """What works on a K and V pair and was not extended to a page of
+        one leaf is refused for a model that declares such a group: here
+        what the manager itself would build on it, in ``BlockedKVCache`` what
+        the pools would (int8 pages, the host tiers)."""
+        if all(g.leaves == 2 for g in groups if isinstance(g, PagedGroup)):
+            return
+        for on, option in (
+                (getattr(config, "prefix_caching", False), "prefix_caching"),
+                (config.speculative.enabled, "speculative.enabled")):
+            if on:
+                raise ValueError(f"{option} is not supported for a model with "
+                                 f"a paged cache group of one leaf")
+
+    @property
+    def one_leaf(self):
+        """Whether the ``"kv"`` group's page is one leaf (a latent row)."""
+        return self.primary_group.leaves == 1
 
     def _init_further_groups(self, config, further):
         """Paged groups beyond ``"kv"`` (an allocator and pools each) and
@@ -119,10 +143,11 @@ class DSStateManager:
                 width = -(-g.window // bs) + -(-sm.max_ragged_batch_size // bs) + 1
             else:
                 blocks = sm.num_kv_blocks or self._blocks_from_memory_budget(
-                    g.layers, g.kv_heads, g.head_dim, kv)
+                    g.layers, g.kv_heads, g.head_dim, kv, leaves=g.leaves)
                 width = -(-sm.max_context // bs)
             self.paged_groups[g.name] = (g, BlockedKVCache(
-                g.layers, blocks, bs, g.kv_heads, g.head_dim, kv.cache_dtype))
+                g.layers, blocks, bs, g.kv_heads, g.head_dim, kv.cache_dtype,
+                leaves=g.leaves))
             self.table_width[g.name] = width
 
     @property
@@ -152,11 +177,11 @@ class DSStateManager:
     # -- the cache and tables pytrees of a dispatch -------------------------
     def cache_view(self):
         """The donated ``cache`` argument of a forward: ``{"kv": (K, V)}``
-        and, for a model that declared them, the further groups' pools."""
-        kv = self.kv_cache
-        view = {"kv": (kv.fwd_k, kv.fwd_v)}
+        (``(pages,)`` for a group of one leaf) and, for a model that
+        declared them, the further groups' pools."""
+        view = {"kv": self.kv_cache.fwd}
         for name, (_, cache) in self.paged_groups.items():
-            view[name] = (cache.fwd_k, cache.fwd_v)
+            view[name] = cache.fwd
         if self.slot_group is not None:
             view[self.slot_group.name] = self.slot_pools
         return view
@@ -248,7 +273,7 @@ class DSStateManager:
 
     @staticmethod
     def _blocks_from_memory_budget(num_layers, num_kv_heads, head_dim, kv,
-                                   kv_dtype="fp"):
+                                   kv_dtype="fp", leaves=2):
         """Size the pool from device memory (the reference derives block count
         from a reserved memory fraction, ``ragged_manager.py`` memory_config):
         ~60% of the device's memory limit, fallback 1 GiB when unknown.
@@ -262,8 +287,8 @@ class DSStateManager:
         else:
             elt_bytes = np.dtype(
                 "float32" if kv.cache_dtype == "fp32" else "uint16").itemsize
-        bytes_per_block = int(2 * num_layers * kv.block_size * num_kv_heads
-                              * head_dim * elt_bytes)  # K + V pools
+        bytes_per_block = int(leaves * num_layers * kv.block_size
+                              * num_kv_heads * head_dim * elt_bytes)  # K + V pools
         try:
             from deepspeed_tpu import telemetry
             stats = telemetry.sample_memory("kv_cache_budget") or {}
@@ -355,14 +380,19 @@ class DSStateManager:
                  "nvme_kv_demotions": hs.get("nvme_demotions", 0)}
         if self.prefix_cache is not None:
             stats.update(self.prefix_cache.stats())
-        if self.has_further_groups:
-            # occupancy per group; "kv" repeats the device census above
+        if self.has_further_groups or self.one_leaf:
+            # occupancy per group; "kv" repeats the device census above.
+            # ``bytes``: the group's pools on the device, every leaf
             groups = {"kv": {"total": total, "free": free,
-                             "occupancy": occupancy}}
-            for name, (_, cache) in self.paged_groups.items():
+                             "occupancy": occupancy,
+                             "leaves": self.primary_group.leaves,
+                             "bytes": self.kv_cache.pool_bytes}}
+            for name, (g, cache) in self.paged_groups.items():
                 groups[name] = {"total": cache.num_blocks,
                                 "free": cache.free_blocks,
                                 "occupancy": cache.occupancy,
+                                "leaves": g.leaves,
+                                "bytes": cache.pool_bytes,
                                 "freed_by_window": self.window_pages_freed}
             if self.slot_group is not None:
                 groups[self.slot_group.name] = {
@@ -483,9 +513,9 @@ class DSStateManager:
             raise ValueError(f"rollback of untracked sequence {uid}")
         if n_tokens <= 0:
             return
-        if self.has_further_groups:
+        if self.has_further_groups or self.one_leaf:
             raise ValueError("rollback is not supported for a model with "
-                             "more than the one paged cache group")
+                             "more than the one paged cache group of K and V")
         assert seq.in_flight_tokens == 0, "cannot roll back mid-forward"
         assert not seq.is_swapped, "cannot roll back a swapped sequence"
         bs = self.kv_block_size
@@ -557,11 +587,12 @@ class DSStateManager:
                 for uid, chain in chains.items()}
 
     def _refuse_page_wire(self, what):
-        if self.has_further_groups:
+        if self.has_further_groups or self.one_leaf:
             raise ValueError(
                 f"page {what} is not supported for a model with more than "
-                f"the one paged cache group: the wire carries \"kv\" pages "
-                f"only, not window pages or recurrent state")
+                f"the one paged cache group of K and V: the wire carries "
+                f"\"kv\" pairs only, not window pages, recurrent state or "
+                f"a page of one leaf")
 
     def export_sequence_pages(self, uid):
         """Detach ``uid``'s KV pages for shipping to another engine's pool

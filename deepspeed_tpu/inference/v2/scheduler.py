@@ -148,6 +148,10 @@ class SplitFuseScheduler:
         # layers) and for padded token slots
         self.expert_rows = 0
         self.expert_rows_padded = 0
+        # for a model whose "kv" group is one leaf (latent rows), the sum of
+        # the same spans' ``latent_pages``: pages held after each dispatch's
+        # allocation
+        self.latent_pages = 0
         # device_sampling=True (default) fuses temperature/top-k/top-p and
         # the categorical draw into the decode step on the accelerator: the
         # host receives one int32 per sequence instead of a [S, vocab] float
@@ -776,6 +780,7 @@ class SplitFuseScheduler:
         self.state_slots += self._engine.last_state_slots
         self.expert_rows += self._engine.last_expert_rows
         self.expert_rows_padded += self._engine.last_expert_rows_padded
+        self.latent_pages += self._engine.last_latent_pages
         return (uids, chunks, ids, logits, t_fwd, was_prefilling,
                 sched_tokens, rnd)
 

@@ -191,7 +191,9 @@ def moe_ffn_gmm(x, top_vals, top_idx, w1, w2, w3, *, n_experts, dtype,
     x [T, D]; w1/w3 [E, D, F]; w2 [E, F, D] -> [T, D]. ``valid`` [T] bool
     (None: all): a token that is not valid takes no expert rows (its rows
     sort past every expert's group, which the grouped GEMM never visits) and
-    gets zeros.
+    gets zeros. So does a single row whose ``top_idx`` is ``n_experts``: an
+    expert the caller does not hold (``moe_layer.moe_ffn``'s
+    ``experts_held``).
 
     SPMD: tokens shard over the active mesh's data axes (dp AND ep — under
     expert parallelism the token batch is split across the expert world, the
@@ -316,5 +318,8 @@ def _moe_ffn_gmm_local(x, top_vals, top_idx, valid, w1, w2, w3, *, n_experts,
     with jax.named_scope("moe_unsort"):
         inv = jnp.argsort(order, stable=True)
         y = jnp.take(y, inv, axis=0).reshape(T, k, D)
-        y = jnp.where(valid[:, None, None], y.astype(jnp.float32), 0.0)
+        # rows no group held (a padded slot's; an expert index of E) were
+        # never written
+        written = valid[:, None] & (top_idx < E)
+        y = jnp.where(written[..., None], y.astype(jnp.float32), 0.0)
         return jnp.sum(y * top_vals[..., None], axis=1).astype(dtype)

@@ -37,6 +37,17 @@ k/v pools [NB, KV, bs, Dh]; block_tables [S, MB]; seen [S]. Output matches q.
 GQA runs natively: each KV head attends its whole ``rep = H // KV``
 query-head group (``rep * Q`` rows) against the trip's keys.
 
+Latent pages (``paged_mla``, the kernel's name in a trace): a group of one
+leaf keeps ONE row a token and layer (``[NB, 1, bs, W]``: the normalised
+latent's ``value_dim`` columns, then the rotated position part), read for the
+scores by its whole width and for the values by its first ``value_dim``
+columns; every query head sits on that one row (``rep = H``). It is a mode of
+the same walk: a page crosses HBM once into ONE buffer, the values are a
+lane-aligned slice of the keys in VMEM, and the second grid axis steps over
+tiles of the ``H * Q`` query rows (a ``[1, 512]`` chunk has 16,384 of them,
+more than VMEM holds beside their accumulator), each tile walking the row's
+live pages anew. ``W`` is any multiple of 128.
+
 int8 KV (``k_scale``/``v_scale`` given): pools are int8 with per-token fp32
 scales in side pools [NB, KV, 1, bs]. The pages take the same walk, so their
 HBM reads stay int8-sized and the dequant fuses into the flash loop in VMEM.
@@ -141,10 +152,18 @@ def _finish(l_ref, acc_ref, dtype):
 
 
 def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
-                 row_tile, **mask):
-    # ``scales``: a sequence's (ks, vs) rows when the pages are int8
-    (q_ref, k_hbm, v_hbm, *scales, o_ref, k_buf, v_buf, sems, slot_ref,
-     m_scr, l_scr, acc_scr) = refs
+                 row_tile, latent=None, **mask):
+    if latent is None:
+        # ``scales``: a sequence's (ks, vs) rows when the pages are int8
+        (q_ref, k_hbm, v_hbm, *scales, o_ref, k_buf, v_buf, sems, slot_ref,
+         m_scr, l_scr, acc_scr) = refs
+        sources = ((k_hbm, k_buf), (v_hbm, v_buf))
+    else:
+        # ``latent``: the row's value columns. One row a token in ONE pool;
+        # the second grid axis is a tile of the query rows
+        (q_ref, k_hbm, o_ref, k_buf, sems, slot_ref, m_scr, l_scr,
+         acc_scr) = refs
+        sources = ((k_hbm, k_buf),)
     s, hg = pl.program_id(0), pl.program_id(1)
     n_hg = pl.num_programs(1)
     step = s * n_hg + hg
@@ -163,9 +182,11 @@ def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
 
         def one(p, _):
             page = bt_ref[seq, first + p]
-            for t, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            # a latent page has one "head", whatever row tile the step is at
+            head0 = head_group * heads if latent is None else 0
+            for t, (hbm, buf) in enumerate(sources):
                 act(pltpu.make_async_copy(
-                    hbm.at[page, pl.ds(head_group * heads, heads)],
+                    hbm.at[page, pl.ds(head0, heads)],
                     buf.at[slot, p], sems.at[slot, t]))
             return 0
 
@@ -179,8 +200,8 @@ def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
     def _first():
         # leftovers in a slot that no copy of a trip filled are multiplied by
         # a masked (zero) weight: they have to be finite from the start
-        k_buf[...] = jnp.zeros_like(k_buf)
-        v_buf[...] = jnp.zeros_like(v_buf)
+        for _, buf in sources:
+            buf[...] = jnp.zeros_like(buf)
         slot_ref[0] = 0
         each_copy(0, 0, 0, 0, start)
 
@@ -206,6 +227,14 @@ def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
                       jnp.where(more, trip + 1, 0), 1 - slot, start)
 
         each_copy(s, hg, trip, slot, wait)
+
+        if latent is not None:
+            k = k_buf[slot, :, 0].reshape(keys, k_buf.shape[-1])
+            _flash_update(q_ref[0, 0], k, k[:, :latent], None, None,
+                          m_scr.at[0], l_scr.at[0], acc_scr.at[0],
+                          key0=trip * keys, row0=hg * row_tile,
+                          seen_s=seen_ref[s], **mask)
+            return 0
 
         def head(h, _):
             k = k_buf[slot, :, h].reshape(keys, dh)
@@ -422,6 +451,98 @@ def _grid_call(qt, k_pool, v_pool, block_tables, seen, q_len, k_scale,
         name="paged_attention",
         interpret=interpret,
     )(block_tables, seen, q_len, jcap, *inputs)
+
+
+def paged_mla(q, pool, block_tables, seen, q_len, *, value_dim,
+              softmax_scale, interpret=False):
+    """Attention of every query head over ONE latent row a token.
+
+    q [S, Q, H, W]: a head's query in the row's own columns (the latent part
+    absorbed through ``W_UK``, then the rotated position part, zeros in any
+    padding); ``pool`` [NB, 1, bs, W]: the one pool of a group of one leaf.
+    Returns [S, Q, H, value_dim]: ``softmax(q . row) @ row[:value_dim]``, for
+    the caller to take through ``W_UV``."""
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.registry import sharded_kernel_call
+
+    # a mode of the paged walk: the tuning table and the dispatch counters
+    # know it as ``paged_mha``, the trace as ``paged_mla``
+    block_config = registry.resolve_block_config(
+        "paged_mha", {"bs": pool.shape[2], "dh": q.shape[-1]}, q.dtype)
+
+    def call(q_, bt_, sn_, ql_, pool_):
+        return _paged_mla_local(q_, pool_, bt_, sn_, ql_,
+                                value_dim=value_dim,
+                                softmax_scale=softmax_scale,
+                                interpret=interpret)
+
+    # sequences shard over the data axes; the one latent row is every
+    # head's, so the pool stays whole on each shard
+    return sharded_kernel_call(
+        call, [q, block_tables, seen, q_len, pool],
+        [("data", None, None, None), ("data", None), ("data",), ("data",),
+         (None, None, None, None)],
+        ("data", None, None, None), name="paged_mha",
+        block_config=block_config)
+
+
+def _paged_mla_local(q, pool, block_tables, seen, q_len, *, value_dim,
+                     softmax_scale, interpret=False):
+    S, Q, H, W = q.shape
+    bs = pool.shape[2]
+    rows = H * Q
+    # [S, Q, H, W] -> [S, 1, H*Q, W]: a head's queries together, so that a
+    # row's chunk position is ``row % Q``
+    qt = q.transpose(0, 2, 1, 3).reshape(S, 1, rows, W)
+    _, row_tile, pages = _walk_plan(
+        rows, 1, bs, W, qt.dtype.itemsize, pool.dtype.itemsize,
+        block_tables.shape[1])
+    tile = lambda width: pl.BlockSpec(
+        (1, 1, row_tile, width), lambda s, r, bt, sn, ql: (s, 0, r, 0),
+        memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, rows // row_tile),
+        in_specs=[tile(W), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile(value_dim),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, 1, bs, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 1)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((1, row_tile, LANES), jnp.float32),
+            pltpu.VMEM((1, row_tile, LANES), jnp.float32),
+            pltpu.VMEM((1, row_tile, value_dim), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _walk_kernel, bs=bs, pages=pages, heads=1, row_tile=row_tile,
+        latent=value_dim, q_tokens=Q, window=None,
+        scale=softmax_scale)
+    with jax.named_scope("paged_mla"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((S, 1, rows, value_dim), qt.dtype),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+            name="paged_mla",
+            interpret=interpret,
+        )(block_tables.astype(jnp.int32), seen.astype(jnp.int32),
+          q_len.astype(jnp.int32), qt, pool)
+    return out.reshape(S, H, Q, value_dim).transpose(0, 2, 1, 3)
+
+
+def mla_is_supported(q_shape, pool_shape, value_dim):
+    """The latent walk copies pages by hand: the pool's rows fill lane
+    tiles, the values are a lane-aligned run of their first columns, and
+    more than ``_MAX_ROW_TILE`` query rows divide into tiles of 8s."""
+    S, Q, H, W = q_shape
+    rows = H * Q
+    NB, heads, bs, width = pool_shape
+    return (W == width and width % LANES == 0 and heads == 1
+            and value_dim % LANES == 0 and value_dim <= width
+            and bs % 8 == 0
+            and (rows <= _MAX_ROW_TILE or rows % 8 == 0))
 
 
 def is_supported(q_shape, pool_shape):

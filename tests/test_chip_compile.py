@@ -128,6 +128,45 @@ def test_paged_attention_mistral_geometry(for_tpu, one_chip, seqs, q_tokens,
     _compile(fn, _paged_args(one_chip, seqs, q_tokens, int8, table))
 
 
+@pytest.mark.parametrize("seqs,q_tokens", [
+    (64, 1), (1, 512), (1, 16), (1, 128)],
+    ids=["decode64", "chunk512", "chunk16", "chunk128"])
+def test_paged_mla_kanana2_geometry(for_tpu, one_chip, seqs, q_tokens):
+    """The latent walk at the benchmark's Kanana-2 cell: 32 query heads on a
+    row of 640 columns (512 latent + 64 rotated + padding) whose first 512
+    are the values, over a 320-slot table; a chunk's 16,384 query rows in
+    tiles of 512 on the grid."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = sds((2049, 1, PAGE, 640), jnp.bfloat16)
+    q = sds((seqs, q_tokens, 32, 640), jnp.bfloat16)
+    assert pa.mla_is_supported(q.shape, pool.shape, 512)
+
+    def fn(q, bt, seen, q_len, pool):
+        return pa.paged_mla(q, pool, bt, seen, q_len, value_dim=512,
+                            softmax_scale=192 ** -0.5)
+
+    compiled = _compile(fn, (q, sds((seqs, 320), jnp.int32), sds((seqs,), jnp.int32),
+                             sds((seqs,), jnp.int32), pool))
+    assert "paged_mla" in compiled.as_text()
+
+
+def test_paged_mla_refuses_a_row_that_does_not_fill_its_lane_tiles(for_tpu, one_chip):
+    """A 576-wide row (the latent's 512 + 64 as they are) is not copied by
+    hand: HBM's (8, 128) tiles hold it in 640 lanes and Mosaic refuses the
+    slice, so ``mla_is_supported`` sends it to the dense twin and the model
+    pads its row to 640."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool, q = sds((2049, 1, PAGE, 576), jnp.bfloat16), sds((64, 1, 32, 576), jnp.bfloat16)
+    assert not pa.mla_is_supported(q.shape, pool.shape, 512)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(lambda q, bt, sn, ql, p: pa._paged_mla_local(
+            q, p, bt, sn, ql, value_dim=512, softmax_scale=0.07)).lower(
+                q, sds((64, 320), jnp.int32), sds((64,), jnp.int32),
+                sds((64,), jnp.int32), pool).compile()
+
+
 def test_paged_attention_walk_under_dp2_tp2(for_tpu, topo):
     """The cells' [64, 8] decode dispatch across four chips: rows over dp,
     KV heads (the pools' second dim) over tp, so each kernel walks 32 rows'
@@ -212,6 +251,7 @@ def _cell_program(name, rows, one_chip):
     from benchmark import harness, weights
     from benchmark.drivers import serve_phi4flash
     from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    from deepspeed_tpu.models.kanana2 import Kanana2Config, Kanana2ForCausalLM
     from deepspeed_tpu.models.mellum2 import Mellum2Config, Mellum2ForCausalLM
     from deepspeed_tpu.models.mistral import MistralForCausalLM, mistral_config
     from deepspeed_tpu.models.phi4flash import (Phi4FlashConfig,
@@ -224,6 +264,11 @@ def _cell_program(name, rows, one_chip):
             **cfg["assumed"]["sizes"]))
     elif cfg["driver"] == "serve_mellum2":
         model = Mellum2ForCausalLM(Mellum2Config.from_hf(cfg, dtype=jnp.bfloat16))
+    elif cfg["driver"] == "serve_kanana2":
+        share = cfg["experts_held"]
+        model = Kanana2ForCausalLM(Kanana2Config.from_hf(
+            cfg, dtype=jnp.bfloat16, n_routed_experts=cfg["n_routed_experts_published"],
+            experts_held=(share["first"], share["count"])))
     else:
         model = MistralForCausalLM(mistral_config(dtype=jnp.bfloat16, **{
             k: cfg[k] for k in (
@@ -253,15 +298,18 @@ def _cell_program(name, rows, one_chip):
 @pytest.mark.parametrize("name,rows,bucket,kernels", [
     ("mistral-7b-l16", 64, 64, 1), ("mistral-7b-l16", 3, 4, 1),
     ("phi4-mini-flash", 64, 64, 5), ("phi4-mini-flash", 3, 4, 5),
-    ("mellum2-l12", 64, 64, 48)],
-    ids=["mistral64", "mistral4", "phi4flash64", "phi4flash4", "mellum2-64"])
+    ("mellum2-l12", 64, 64, 48), ("kanana2-l12-ep8", 64, 64, 45)],
+    ids=["mistral64", "mistral4", "phi4flash64", "phi4flash4", "mellum2-64",
+         "kanana2-64"])
 def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, name, rows,
                                              bucket, kernels):
     """The WHOLE ragged forward of a decode round, [64, 1] and [4, 1], as the
     benchmark's serving cells dispatch it: every layer at the published
     widths, the paged kernel (and phi4flash's scan; for mellum2 the paged
     kernel and the three grouped GEMMs in each of 12 layers, over 512 expert
-    rows of which a padded row takes none) at one token a row."""
+    rows of which a padded row takes none; for kanana2 the latent walk in 12
+    layers and the grouped GEMMs over the 16 experts held in 11) at one token
+    a row."""
     forward, cfg, shapes = _cell_program(name, rows, one_chip)
     assert shapes[2].shape == (bucket, 1)                     # the tokens
     compiled = forward.lower(cfg, *shapes).compile()

@@ -246,3 +246,101 @@ def test_walk_int8_pages_with_scales(Q):
     assert np.isfinite(np.asarray(out_k)).all()
     np.testing.assert_allclose(valid_rows(out_k, q_len),
                                valid_rows(out_d, q_len), atol=2e-4, rtol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# ``paged_mla``: the walk's mode for a page of ONE leaf (a latent row a token:
+# read once, keys by its whole width, values by its first ``value_dim``
+# columns, every query head on the one row). Poisoned as above.
+# ---------------------------------------------------------------------------
+
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
+    _latent_attention_dense)
+from deepspeed_tpu.ops.pallas.paged_attention import mla_is_supported, paged_mla
+
+MLA = dict(W=256, value_dim=128, bs=16, scale=0.09)
+
+
+def make_mla_case(live, Q=1, H=4, MB=64, q_len=None, dtype=jnp.float32, seed=0):
+    """One row a ``live`` entry: its context ends in its ``live``-th page;
+    a ``q_len`` of 0 is a padded row on the trash page."""
+    W, bs = MLA["W"], MLA["bs"]
+    S, NB = len(live), sum(live) + 2
+    poison, trash = NB - 2, NB - 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    q = jax.random.normal(ks[0], (S, Q, H, W), dtype)
+    pool = jax.random.normal(ks[1], (NB, 1, bs, W), dtype)
+    rng = np.random.default_rng(seed)
+    q_len = np.full((S,), Q, np.int32) if q_len is None else np.asarray(q_len, np.int32)
+    pages = rng.permutation(NB - 2)
+    bt = np.full((S, MB), poison, np.int32)
+    seen = np.zeros((S,), np.int32)
+    for i, n in enumerate(live):
+        if q_len[i] == 0:
+            bt[i] = trash
+            continue
+        bt[i, :n], pages = pages[:n], pages[n:]
+        seen[i] = rng.integers(max((n - 1) * bs + 1 - q_len[i], 0), n * bs - q_len[i] + 1)
+    return (q, pool, jnp.asarray(bt), jnp.asarray(seen), jnp.asarray(q_len)), poison
+
+
+def check_mla(case, poison, atol=2e-4, rtol=1e-3):
+    q, pool, bt, seen, q_len = case
+    out_k = paged_mla(q, pool.at[poison].set(jnp.nan), bt, seen, q_len,
+                      value_dim=MLA["value_dim"],
+                      softmax_scale=MLA["scale"], interpret=True)
+    out_d = _latent_attention_dense(q, pool.at[poison].set(0), bt, seen, MLA["bs"],
+                                    MLA["value_dim"], MLA["scale"])
+    assert out_k.shape == q.shape[:3] + (MLA["value_dim"],)
+    assert np.isfinite(np.asarray(out_k, np.float32)).all(), \
+        "the walk read past a row's live pages, or left a padded row undefined"
+    assert valid_rows(out_k, q_len).size
+    np.testing.assert_allclose(valid_rows(out_k, q_len).astype(np.float32),
+                               valid_rows(out_d, q_len).astype(np.float32),
+                               atol=atol, rtol=rtol)
+
+
+@pytest.mark.parametrize("Q", [1, 8])
+def test_mla_ragged_lengths_a_trash_page_and_padded_slots(Q):
+    """Rows of 1..40 live pages beside a padded row (``q_len`` 0, the trash
+    page) and, for a chunk, rows shorter than the chunk bucket."""
+    q_len = [Q, max(Q - 3, 1), 0, Q, 1]
+    check_mla(*make_mla_case([1, 7, 1, 40, 13], Q=Q, q_len=q_len))
+
+
+def test_mla_first_token_and_a_table_of_one_live_page():
+    case, poison = make_mla_case([1, 1], Q=1)
+    q, pool, bt, seen, q_len = case
+    check_mla((q, pool, bt, jnp.zeros_like(seen), q_len), poison)
+
+
+def test_mla_row_tiles_on_the_grid():
+    """A chunk whose ``heads x Q`` query rows pass one tile (512): the second
+    grid axis steps over tiles of them, each walking the pages anew."""
+    from deepspeed_tpu.ops.pallas.paged_attention import _walk_plan
+    rows = 8 * 128
+    assert _walk_plan(rows, 1, MLA["bs"], MLA["W"], 4, 4, 64)[1] == 512 < rows
+    check_mla(*make_mla_case([9], Q=128, H=8, q_len=[100]))
+
+
+def test_mla_a_chunk_past_its_rows_live_pages_sees_its_own_keys_only():
+    """A chunk that starts a sequence (``seen`` 0): a query sees the keys up
+    to itself and none of the rest of the chunk's page."""
+    case, poison = make_mla_case([1, 2], Q=8, q_len=[8, 5])
+    q, pool, bt, seen, q_len = case
+    check_mla((q, pool, bt, jnp.zeros_like(seen), q_len), poison)
+
+
+def test_mla_bf16():
+    check_mla(*make_mla_case([5, 30, 2], Q=1, dtype=jnp.bfloat16), atol=3e-2, rtol=3e-2)
+
+
+def test_mla_is_supported():
+    ok = lambda q, pool, v=128: mla_is_supported(q, pool, v)
+    assert ok((64, 1, 32, 640), (10, 1, 64, 640), 512)
+    assert ok((1, 512, 32, 640), (10, 1, 64, 640), 512)
+    assert not ok((64, 1, 32, 576), (10, 1, 64, 576), 512)        # 4.5 lane tiles
+    assert not ok((4, 1, 4, 256), (10, 2, 16, 256))               # one row, not heads
+    assert not ok((4, 1, 4, 256), (10, 1, 12, 256))               # block size
+    assert not ok((4, 1, 4, 256), (10, 1, 16, 256), 192)          # values off the lanes
+    assert not ok((4, 1, 4, 384), (10, 1, 16, 256))               # q is not the row's width

@@ -283,6 +283,96 @@ def test_no_layer_of_the_pool_is_sliced_out_and_written_back(family):
     assert scans == (1 if "layers" in params else 0)
 
 
+# -- a page of one leaf: the latent write and read ------------------------------
+
+def _latent_case(S=3, Q=4, H=4, r=128, dr=16, dn=32, dv=32, bs=8, MB=6, seed=0):
+    rng = np.random.default_rng(seed)
+    W = 256
+    n = lambda *s: jnp.asarray(rng.normal(size=s) / np.sqrt(s[0]), jnp.float32)
+    NB = S * MB + 1
+    tables = jnp.asarray(rng.permutation(NB - 1).reshape(S, MB), jnp.int32)
+    seen = jnp.asarray([0, 5, 2 * bs + 1][:S], jnp.int32)
+    q_len = jnp.asarray([Q, 1, Q - 1][:S], jnp.int32)
+    # the pages as a forward leaves them: every visible token's row written
+    pool = jnp.zeros((NB, 1, bs, W), jnp.float32)
+    ctx = jnp.asarray(rng.normal(size=(S, MB * bs, r + dr)), jnp.float32)
+    rows = jnp.concatenate([ctx, jnp.zeros((S, MB * bs, W - r - dr))], -1)
+    pool = pool.at[tables].set(rows.reshape(S, MB, 1, bs, W))
+    return dict(pool=pool, tables=tables, seen=seen, q_len=q_len, ctx=ctx, bs=bs, W=W,
+                q_nope=jnp.asarray(rng.normal(size=(S, Q, H, dn)), jnp.float32),
+                q_pe=jnp.asarray(rng.normal(size=(S, Q, H, dr)), jnp.float32),
+                w_uk=n(r, H, dn), w_uv=n(r, H, dv), r=r, dr=dr, scale=(dn + dr) ** -0.5)
+
+
+def _first_form(c):
+    """Every head's keys and values up-projected from the latent, a plain
+    masked softmax a row: what the absorbed read has to equal."""
+    k_nope = jnp.einsum("stc,chd->sthd", c["ctx"][..., :c["r"]], c["w_uk"])
+    v = jnp.einsum("stc,chd->sthd", c["ctx"][..., :c["r"]], c["w_uv"])
+    s = (jnp.einsum("sqhd,sthd->shqt", c["q_nope"], k_nope)
+         + jnp.einsum("sqhr,str->shqt", c["q_pe"], c["ctx"][..., c["r"]:])) * c["scale"]
+    Q, T = s.shape[2], s.shape[3]
+    qpos = c["seen"][:, None] + jnp.arange(Q)[None, :]
+    s = jnp.where(jnp.arange(T)[None, None, None, :] <= qpos[:, None, :, None], s, -jnp.inf)
+    return jnp.einsum("shqt,sthd->sqhd", jax.nn.softmax(s, -1), v)
+
+
+def _absorbed(c, read):
+    q_lat = jnp.einsum("sqhd,chd->sqhc", c["q_nope"], c["w_uk"])
+    S, Q, H, _ = q_lat.shape
+    q_row = jnp.concatenate(
+        [q_lat, c["q_pe"], jnp.zeros((S, Q, H, c["W"] - c["r"] - c["dr"]))], -1)
+    o_lat = read(q_row, c["pool"], c["tables"], c["seen"], c["bs"], c["q_len"], c["r"],
+                 c["scale"])
+    return jnp.einsum("sqhc,chd->sqhd", o_lat, c["w_uv"])
+
+
+def _real(x, q_len):
+    return np.asarray(x)[np.arange(x.shape[1])[None, :] < np.asarray(q_len)[:, None]]
+
+
+@pytest.mark.parametrize("Q", [4, 1], ids=["chunk", "decode"])
+@pytest.mark.parametrize("disable,record", [
+    (False, None), (True, ("paged_mla", "fallback", "no_tpu"))],
+    ids=["kernel", "pallas-disabled"])
+def test_the_absorbed_read_is_the_first_form(dispatch, monkeypatch, disable, record, Q):
+    """Absorbed through the kernel and through its dense twin, a chunk's
+    rows and decode rows alike, equals the first form."""
+    if disable:
+        monkeypatch.setenv("DS_TPU_DISABLE_PALLAS", "1")
+    c = _latent_case(Q=Q)
+    want = _real(_first_form(c), c["q_len"])
+    assert _traces_kernel(lambda p: _absorbed(dict(c, pool=p), paged_layer._latent_attention),
+                          c["pool"]) == (not disable)
+    got = _absorbed(c, paged_layer._latent_attention)
+    np.testing.assert_allclose(_real(got, c["q_len"]), want, atol=2e-5)
+    assert (record in dispatch()) == disable
+
+
+def test_a_latent_read_the_kernel_cannot_tile_falls_back_with_a_record(dispatch):
+    c = _latent_case(bs=12)
+    got = _absorbed(c, paged_layer._latent_attention)
+    np.testing.assert_allclose(_real(got, c["q_len"]), _real(_first_form(c), c["q_len"]),
+                               atol=2e-5)
+    assert ("paged_mla", "fallback", "unsupported_shape") in dispatch()
+
+
+def test_the_latent_write_is_one_row_a_token_and_padding_goes_to_the_trash_page():
+    rng = np.random.default_rng(0)
+    S, Q, W, bs, NB = 2, 4, 256, 4, 6
+    pool = jnp.zeros((NB + 1, 1, bs, W), jnp.float32)
+    rows = jnp.asarray(rng.normal(size=(S, Q, W)), jnp.float32)
+    tables = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
+    seen, q_len = jnp.asarray([3, 0], jnp.int32), jnp.asarray([4, 2], jnp.int32)
+    out = np.asarray(paged_layer._scatter_latent(pool, rows, tables, seen, q_len, bs, NB))
+    np.testing.assert_array_equal(out[0, 0, 3], np.asarray(rows[0, 0]))      # token 3
+    np.testing.assert_array_equal(out[1, 0, :3], np.asarray(rows[0, 1:]))    # tokens 4-6
+    np.testing.assert_array_equal(out[3, 0, :2], np.asarray(rows[1, :2]))
+    written = np.zeros((NB + 1, bs), bool)
+    written[0, 3] = written[1, :3] = written[3, :2] = written[NB, 0] = True
+    assert not out[:, 0][~written].any(), "a padded slot wrote outside the trash page"
+
+
 # -- the logits gather --------------------------------------------------------
 
 def test_a_row_of_no_tokens_reads_position_zero():
