@@ -127,6 +127,10 @@ class InferenceEngineV2:
             kvc.k_pool.shape[2] * kvc.k_pool.shape[4]
             * kvc.k_pool.dtype.itemsize) if self._state.one_leaf else 0
         self.last_latent_pages = 0
+        # of the last round's dispatches, those that held a row whose
+        # temperature is above 0: their sampler sorted every row's
+        # vocabulary, the others' took the argmax (``sampling.py``)
+        self.last_dispatches_sorted = 0
         # postmortem-bundle collector (telemetry/flightrec.py): the newest
         # engine's host-side KV pool stats ride every bundle — pure host
         # reads, so collection is safe even from an abnormal path
@@ -280,7 +284,9 @@ class InferenceEngineV2:
         shared chain cache; the scheduler calls ``commit_prefix`` after
         accept/rollback). ``sample``: a callable dispatched on each
         dispatch's logits and rows behind its forward (the on-device
-        sampler); its result is returned in the logits' place."""
+        sampler); it returns what goes in the logits' place, and how many of
+        the rows sample (``sampled_rows`` on the dispatch's span: 0 where
+        the sampler took the argmax and sorted nothing)."""
         lengths = [len(t) for t in batch_tokens]
         verdict = self.can_schedule(batch_uids, lengths)
         if not verdict.success:
@@ -298,7 +304,7 @@ class InferenceEngineV2:
         self.last_window_pages_freed = self.last_state_slots = 0
         self.last_live_pages = self.last_table_slots = 0
         self.last_expert_rows = self.last_expert_rows_padded = 0
-        self.last_latent_pages = 0
+        self.last_latent_pages = self.last_dispatches_sorted = 0
         for rows, min_seqs, min_tokens in dispatch_rows(
                 lengths, short_row_tokens(verify_k)):
             # explicit begin/end, and the host-to-device copies as arguments
@@ -375,7 +381,9 @@ class InferenceEngineV2:
                     jnp.asarray(arrays["seen"]), tables)
             self._state.cache_update(cache)
             if sample is not None:
-                out = sample(out, rows)
+                out, sampled_rows = sample(out, rows)
+                self.last_dispatches_sorted += sampled_rows > 0
+                sp.set(sampled_rows=sampled_rows)
             sp.end()
             parts.append((rows, out))
 
@@ -426,7 +434,10 @@ class InferenceEngineV2:
         with the five per-row parameter vectors of a dispatch's ``rows``
         packed into two host arrays of the logits' padded row count, so that
         the jit fast path moves them — per-dispatch host time, not device
-        math, bounds a fleet stepping several schedulers per round."""
+        math, bounds a fleet stepping several schedulers per round — beside
+        the number of ``rows`` whose temperature is above 0 (any, and the
+        sampler sorts every row's vocabulary; none, and it takes the
+        argmax: ``sampling.py``)."""
         # arbitrary Python-int seeds (the host sampler accepted any) fold
         # deterministically into the int31 space PRNGKey wants
         seeds = [int(s) & 0x7FFFFFFF for s in seeds]
@@ -440,7 +451,8 @@ class InferenceEngineV2:
             iparams[0, :n] = [top_ks[i] for i in rows]
             iparams[1, :n] = [seeds[i] for i in rows]
             iparams[2, :n] = [positions[i] for i in rows]
-            return sampler(logits, fparams, iparams)
+            return (sampler(logits, fparams, iparams),
+                    int(np.count_nonzero(fparams[0] > 0.0)))
         return sample
 
     def put(self, batch_uids: List[int],

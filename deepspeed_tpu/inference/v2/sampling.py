@@ -10,10 +10,19 @@ device; the host receives only ``[S]`` int32 token ids.
 
 Per-row (per-request) parameters are traced values, so one compiled program
 serves every mix of greedy/sampled requests — no retrace when a new request
-arrives with a different temperature. Determinism: each row draws from
-``fold_in(PRNGKey(seed), position)``, so a (seed, position) pair always
-yields the same token, independent of batch composition — the same contract
-the host sampler in ``scheduler.py`` provides.
+arrives with a different temperature. One program, two arms: each entry
+branches ONCE for the whole dispatch (``_dispatch_sample``), on whether any
+of its rows has a temperature above 0. Where none has (padded rows carry
+0.0), the ids are the rows' argmax and the device sorts, scans and draws
+nothing; where one has, every row goes through ``_row_sample``, whose
+select gives a greedy row that same argmax. The branch sits above the
+``vmap`` because a ``cond`` under ``vmap`` lowers to a select, which runs
+both arms: sorting a ``[64, 200064]`` dispatch whose result no row read was
+the largest device operation of a decode round (PERF.md, PR 38).
+Determinism: each row draws from ``fold_in(PRNGKey(seed), position)``, so a
+(seed, position) pair always yields the same token, independent of batch
+composition — the same contract the host sampler in ``scheduler.py``
+provides.
 
 Semantics mirror ``SplitFuseScheduler._sample`` (greedy at temperature 0;
 top-k keeps values >= the kth largest; top-p keeps the smallest set with
@@ -50,6 +59,16 @@ def _row_sample(logits, temp, top_k, top_p, seed, position):
     return jnp.where(temp <= 0.0, greedy, sampled)
 
 
+def _dispatch_sample(sample_all, logits, temps):
+    """The ids of one dispatch: ``sample_all()`` (every row through
+    ``_row_sample``) where any row of ``temps`` samples, else the argmax
+    over the vocabulary and nothing more. The ids are the same either way:
+    a row of temperature 0 gets its argmax from ``_row_sample`` too."""
+    return jax.lax.cond(
+        jnp.any(temps > 0.0), sample_all,
+        lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32))
+
+
 @jax.jit
 def sample_rows(logits, temps, top_ks, top_ps, seeds, positions):
     """Vectorized per-row sampling.
@@ -62,8 +81,9 @@ def sample_rows(logits, temps, top_ks, top_ps, seeds, positions):
     Returns ``[S]`` int32 token ids (still on device; the caller transfers
     4*S bytes instead of 4*S*V).
     """
-    return jax.vmap(_row_sample)(logits, temps, top_ks, top_ps, seeds,
-                                 positions)
+    return _dispatch_sample(
+        lambda: jax.vmap(_row_sample)(logits, temps, top_ks, top_ps, seeds,
+                                      positions), logits, temps)
 
 
 @jax.jit
@@ -94,8 +114,9 @@ def verify_rows_packed(logits, fparams, iparams):
                                      last_pos - (k - 1) + c)
         )(lg, cols)
 
-    return jax.vmap(row)(logits, fparams[0], iparams[0], fparams[1],
-                         iparams[1], iparams[2])
+    return _dispatch_sample(
+        lambda: jax.vmap(row)(logits, fparams[0], iparams[0], fparams[1],
+                              iparams[1], iparams[2]), logits, fparams[0])
 
 
 @jax.jit
@@ -107,5 +128,7 @@ def sample_rows_packed(logits, fparams, iparams):
     instead of five; on CPU fleets stepping several schedulers per round
     the per-dispatch host time is the serving bottleneck, not the math.
     """
-    return jax.vmap(_row_sample)(logits, fparams[0], iparams[0], fparams[1],
-                                 iparams[1], iparams[2])
+    return _dispatch_sample(
+        lambda: jax.vmap(_row_sample)(logits, fparams[0], iparams[0],
+                                      fparams[1], iparams[1], iparams[2]),
+        logits, fparams[0])

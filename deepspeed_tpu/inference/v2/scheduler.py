@@ -152,6 +152,11 @@ class SplitFuseScheduler:
         # the same spans' ``latent_pages``: pages held after each dispatch's
         # allocation
         self.latent_pages = 0
+        # the dispatches whose ``serving/dispatch`` span reads
+        # ``sampled_rows`` above 0: they held a row whose temperature is
+        # above 0, so the device sampler sorted every row's vocabulary; in
+        # the others it took the argmax and sorted nothing
+        self.dispatches_sorted = 0
         # device_sampling=True (default) fuses temperature/top-k/top-p and
         # the categorical draw into the decode step on the accelerator: the
         # host receives one int32 per sequence instead of a [S, vocab] float
@@ -781,6 +786,7 @@ class SplitFuseScheduler:
         self.expert_rows += self._engine.last_expert_rows
         self.expert_rows_padded += self._engine.last_expert_rows_padded
         self.latent_pages += self._engine.last_latent_pages
+        self.dispatches_sorted += self._engine.last_dispatches_sorted
         return (uids, chunks, ids, logits, t_fwd, was_prefilling,
                 sched_tokens, rnd)
 

@@ -11,6 +11,8 @@ time may hold it, so the topology is described inside a module-scoped fixture
 (never while a module is imported) and every compile happens in this process.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -416,3 +418,21 @@ def test_same_program_same_topology_same_cache_key(topo, tmp_path,
         for k, v in saved.items():
             jax.config.update(k, v)
         cc.reset_cache()
+
+
+# the smallest and the largest vocabulary of the serving cells (16 s each:
+# the sorting arm is what takes the compiler long)
+@pytest.mark.parametrize("vocab", [32000, 200064])
+def test_the_sampler_keeps_its_branch_and_sorts_in_one_arm_only(for_tpu, one_chip, vocab):
+    """``sample_rows_packed`` at a serving cell's ``[64, vocabulary]``: the
+    chip's compiler keeps the dispatch's ``conditional`` (it could have
+    flattened it to a select, which runs both arms), and the sort lies in an
+    arm's computation, not in the entry's."""
+    from deepspeed_tpu.inference.v2.sampling import sample_rows_packed
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = sample_rows_packed.lower(
+        sds((64, vocab), jnp.float32), sds((2, 64), jnp.float32),
+        sds((3, 64), jnp.int32)).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert len(re.findall(r" conditional\(", entry)) == 1
+    assert " sort(" in text and " sort(" not in entry

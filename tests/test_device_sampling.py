@@ -10,7 +10,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.sampling import sample_rows
+from deepspeed_tpu.inference.v2.sampling import (
+    _row_sample, sample_rows, sample_rows_packed, verify_rows_packed)
 
 
 def _rows(v=97, s=4, seed=0):
@@ -18,11 +19,15 @@ def _rows(v=97, s=4, seed=0):
         np.random.default_rng(seed).normal(size=(s, v)).astype(np.float32))
 
 
-def _call(logits, temps, top_ks, top_ps, seeds, positions):
-    return np.asarray(sample_rows(
-        logits, jnp.asarray(temps, jnp.float32),
-        jnp.asarray(top_ks, jnp.int32), jnp.asarray(top_ps, jnp.float32),
-        jnp.asarray(seeds, jnp.int32), jnp.asarray(positions, jnp.int32)))
+def _params(temps, top_ks, top_ps, seeds, positions):
+    """The five per-row vectors as ``sample_rows`` takes them."""
+    return (jnp.asarray(temps, jnp.float32), jnp.asarray(top_ks, jnp.int32),
+            jnp.asarray(top_ps, jnp.float32), jnp.asarray(seeds, jnp.int32),
+            jnp.asarray(positions, jnp.int32))
+
+
+def _call(logits, *params):
+    return np.asarray(sample_rows(logits, *_params(*params)))
 
 
 def test_greedy_rows_are_argmax():
@@ -94,6 +99,115 @@ def test_rows_independent_of_batch_composition():
                      [[5, 0, 0, 3][i]], [[1.0, 1.0, 0.7, 1.0][i]],
                      [[21, 22, 23, 24][i]], [[0, 4, 9, 2][i]])
         assert int(solo[0]) == int(batch[i])
+
+
+# -- one branch a dispatch: all greedy takes the argmax, any sampled row sorts ---
+
+VERIFY_K = 3
+
+
+def _entry_logits(entry, s, seed, v=97):
+    shape = (s, VERIFY_K, v) if entry == "verify_rows_packed" else (s, v)
+    return jnp.asarray(
+        np.random.default_rng(seed).normal(size=shape).astype(np.float32))
+
+
+def _entry_args(entry, logits, temps, top_ks, top_ps, seeds, positions):
+    """(jitted entry, its arguments) for rows of these parameters; a verify
+    row's ``positions`` is the stream position of its LAST column."""
+    if entry == "sample_rows":
+        return sample_rows, (logits,) + _params(temps, top_ks, top_ps, seeds,
+                                                positions)
+    fn = {"sample_rows_packed": sample_rows_packed,
+          "verify_rows_packed": verify_rows_packed}[entry]
+    return fn, (logits, jnp.asarray([temps, top_ps], jnp.float32),
+                jnp.asarray([top_ks, seeds, positions], jnp.int32))
+
+
+def _unbranched(entry, logits, temps, top_ks, top_ps, seeds, positions):
+    """``vmap(_row_sample)`` with no branch above it: what every entry
+    returned before it had two arms."""
+    *args, pos = _params(temps, top_ks, top_ps, seeds, positions)
+    if entry != "verify_rows_packed":
+        return np.asarray(jax.vmap(_row_sample)(logits, *args, pos))
+    cols = [jax.vmap(_row_sample)(logits[:, c], *args,
+                                  pos - (VERIFY_K - 1) + c)
+            for c in range(VERIFY_K)]
+    return np.stack([np.asarray(c) for c in cols], axis=1)
+
+
+ENTRIES = ("sample_rows", "sample_rows_packed", "verify_rows_packed")
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_all_greedy_dispatch_with_padded_rows_is_the_argmax(entry):
+    """Three greedy requests (whatever their top-k and top-p) and the zero
+    rows ``_packed_sampler`` pads with: the argmax of every row, and of
+    every column of a verify row."""
+    logits = _entry_logits(entry, s=8, seed=20)
+    n = 3
+    pad = lambda xs: list(xs) + [0] * (8 - n)
+    fn, args = _entry_args(entry, logits, pad([0.0] * n), pad([5, 0, 1]),
+                           pad([0.3, 1.0, 0.9]), pad([3, 4, 5]),
+                           pad([7, 0, 2]))
+    np.testing.assert_array_equal(np.asarray(fn(*args)),
+                                  np.argmax(np.asarray(logits), -1))
+
+
+def _primitives(jaxpr):
+    """Names of every equation of ``jaxpr`` and of the jaxprs inside it."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_entry_branches_once_and_its_argmax_arm_neither_sorts_nor_draws(entry):
+    """The CPU cannot time the arms; it can read the program: ONE ``cond``
+    at the top level (under a ``vmap`` it would be a select, and both arms
+    would run), none below it, and an argmax arm with no sort, no scan and
+    no random bits, beside a sampling arm that has all three."""
+    logits = _entry_logits(entry, s=4, seed=21)
+    fn, args = _entry_args(entry, logits, [0.0] * 4, [0] * 4, [1.0] * 4,
+                           [0] * 4, [0] * 4)
+    top = jax.make_jaxpr(fn)(*args).jaxpr
+    jitted, = top.eqns
+    body = jitted.params["jaxpr"].jaxpr
+    conds = [e for e in body.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    assert _primitives(body).count("cond") == 1, "a cond below the top level"
+    # index 0 is the arm of a false predicate: no row samples
+    argmax_arm, sort_arm = (_primitives(b.jaxpr)
+                            for b in conds[0].params["branches"])
+    costly = {"sort", "cumsum", "random_bits"}
+    assert "argmax" in argmax_arm and not costly & set(argmax_arm), argmax_arm
+    assert costly <= set(sort_arm), sort_arm
+    # nothing of the sampler's is left outside the arms
+    assert not costly & {e.primitive.name for e in body.eqns}
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_one_sampled_row_leaves_every_row_the_id_it_has_alone(entry):
+    """A dispatch of greedy rows with ONE sampled row takes the sorting arm:
+    every row gets the id ``vmap(_row_sample)`` gives it (what the entry
+    returned before it branched) and the id it gets in a dispatch of its
+    own: the greedy rows the argmax arm's, the sampled row the other's."""
+    logits = _entry_logits(entry, s=4, seed=22)
+    params = ([0.0, 0.0, 1.3, 0.0], [0, 4, 6, 0], [1.0, 0.5, 0.8, 1.0],
+              [31, 32, 33, 34], [5, 9, 3, 0])
+    fn, args = _entry_args(entry, logits, *params)
+    batch = np.asarray(fn(*args))
+    np.testing.assert_array_equal(batch, _unbranched(entry, logits, *params))
+    for i in range(4):
+        fn, args = _entry_args(entry, logits[i:i + 1],
+                               *([p[i]] for p in params))
+        np.testing.assert_array_equal(np.asarray(fn(*args))[0], batch[i])
+    greedy = [0, 1, 3]
+    np.testing.assert_array_equal(
+        batch[greedy], np.argmax(np.asarray(logits), -1)[greedy])
 
 
 @pytest.fixture(scope="module")

@@ -205,6 +205,49 @@ def test_live_pages_and_table_slots_sum_to_the_schedulers_counters(served):
     assert 0 < sched.live_pages < sched.table_slots
 
 
+def test_dispatches_sorted_counts_the_dispatch_spans_that_held_a_sampled_row(tmp_path):
+    """``sampled_rows`` on a ``serving/dispatch`` span is the dispatch's rows
+    of temperature > 0: 0 where the device sampler took the argmax. The
+    scheduler's ``dispatches_sorted`` is the count of spans above 0: none
+    over an all-greedy run, and in a mixed one the dispatches that held the
+    sampled request: one in every round from its admission to its finish."""
+    cfg, sched = _scheduler()
+    rng = np.random.default_rng(7)
+    prompt = lambda n: rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+
+    def greedy():
+        sched.submit(1, prompt(21), max_new_tokens=4)
+        sched.submit(2, prompt(9), max_new_tokens=5, top_k=3, top_p=0.5)
+        sched.run_to_completion()
+    spans, _ = _captured(tmp_path / "greedy", greedy)
+    dispatches = _named(spans, "serving/dispatch")
+    assert len(dispatches) == sched.dispatches > 0
+    assert all(s[3]["sampled_rows"] == 0 for s in dispatches)
+    assert sched.dispatches_sorted == 0
+
+    def mixed():
+        sched.submit(21, prompt(21), max_new_tokens=6)
+        sched.submit(22, prompt(9), max_new_tokens=5, temperature=0.8, seed=4)
+        for _ in range(3):
+            sched.step()
+        sched.submit(23, prompt(20), max_new_tokens=3)
+        sched.run_to_completion()
+    before = sched.dispatches
+    spans, _ = _captured(tmp_path / "mixed", mixed)
+    dispatches = _named(spans, "serving/dispatch")
+    assert len(dispatches) == sched.dispatches - before
+    held = [s for s in dispatches if s[3]["sampled_rows"] > 0]
+    assert sched.dispatches_sorted == len(held)
+    first, = (s[3]["round"] for s in _named(spans, "serving/admit")
+              if s[3]["uid"] == 22)
+    last, = (s[3]["round"] for s in _named(spans, "serving/finish")
+             if s[3]["uid"] == 22)
+    assert [s[3]["round"] for s in held] == list(range(first, last + 1))
+    assert all(s[3]["sampled_rows"] == 1 for s in held)
+    # the greedy rows dispatched beside it, alone or after it, sorted nothing
+    assert 0 < len(held) < len(dispatches)
+
+
 def test_cancel_and_context_roof_mark_finish_with_their_reason(tmp_path):
     cfg, sched = _scheduler(max_context=16)
     rng = np.random.default_rng(5)
