@@ -94,13 +94,20 @@ class InferenceEngineV2:
         # number of forwards it took): the ``round`` every span of a serving
         # round carries (the scheduler reads it before composing)
         self.round = 0
+        # dispatches so far (one per forward, never reset): the ``dispatch``
+        # that ``serving/build``, ``serving/dispatch`` and the spans inside
+        # it carry, which ties the host's work for one dispatch to the
+        # device runs it caused
+        self.dispatch = 0
+        # the (sequence bucket, chunk bucket, verify_k) dispatched so far:
+        # the dispatch that is first of its shape (``first_seen`` on its
+        # span) is the one that traced, compiled or loaded a program
+        self._shapes_seen = set()
         # [sequence bucket, chunk bucket] of each dispatch of the last round
         self.last_batch_shapes = []
         # of the last round's dispatches, summed: the pages of the "kv"
-        # group its rows' contexts reach (what the paged kernel walks) and
-        # the slots of the block tables it was handed (rows x width)
+        # group its rows' contexts reach (what the paged kernel walks)
         self.last_live_pages = 0
-        self.last_table_slots = 0
         # of the last round's dispatches, summed (zero for a model of one
         # paged group): pages its windows freed, slots of state held
         self.last_window_pages_freed = 0
@@ -302,16 +309,17 @@ class InferenceEngineV2:
         parts, self.last_batch_shapes = DispatchedRound(), []
         further = self._state.has_further_groups
         self.last_window_pages_freed = self.last_state_slots = 0
-        self.last_live_pages = self.last_table_slots = 0
+        self.last_live_pages = 0
         self.last_expert_rows = self.last_expert_rows_padded = 0
         self.last_latent_pages = self.last_dispatches_sorted = 0
         for rows, min_seqs, min_tokens in dispatch_rows(
                 lengths, short_row_tokens(verify_k)):
-            # explicit begin/end, and the host-to-device copies as arguments
-            # of the jitted call: tracing a new batch shape inside ``with``
-            # blocks cost set-up 0.07 s a shape more on the chip (PERF.md,
-            # PR 27)
-            sp = tm.span_begin("serving/build", round=rnd, seqs=len(rows))
+            # explicit begin/end everywhere below: tracing a new batch shape
+            # inside ``with`` blocks cost set-up 0.07 s a shape more on the
+            # chip (PERF.md, PR 27)
+            n = self.dispatch
+            sp = tm.span_begin("serving/build", round=rnd, dispatch=n,
+                               seqs=len(rows))
             wrapper = RaggedBatchWrapper(sm.max_ragged_sequence_count,
                                          sm.max_ragged_batch_size,
                                          self._max_blocks_per_seq,
@@ -338,11 +346,9 @@ class InferenceEngineV2:
             tables = {"kv": arrays["block_tables"]}
             if further:
                 tables.update(self._state.group_tables(seqs, seq_bucket))
-                self._note_further_groups(sp, seqs, seq_bucket)
+                self._note_further_groups(sp, seqs)
             self.last_batch_shapes.append((seq_bucket, chunk_bucket))
-            table_slots = seq_bucket * self._max_blocks_per_seq
             self.last_live_pages += live_pages
-            self.last_table_slots += table_slots
             if self._expert_fanout[0]:
                 took, padded = expert_rows(real_tokens, *self._expert_fanout)
                 self.last_expert_rows += took
@@ -360,33 +366,52 @@ class InferenceEngineV2:
                    real_tokens=real_tokens,
                    padded_slots=seq_bucket * chunk_bucket,
                    context_tokens=context_tokens,
-                   live_pages=live_pages, table_slots=table_slots)
+                   live_pages=live_pages)
             sp.end()
 
-            # fwd_k/fwd_v are (int8, scale) pairs when kv_dtype="int8" — they
-            # flow through the jitted forwards as pytree leaves
-            sp = tm.span_begin("serving/dispatch", round=rnd)
-            # the cache (named groups of pools, donated) is threaded from
-            # one dispatch of the round to the next
+            sp = tm.span_begin("serving/dispatch", round=rnd, dispatch=n)
+            part = tm.span_begin("serving/dispatch/h2d", round=rnd, dispatch=n)
+            host = [arrays["tokens"], arrays["q_len"], arrays["seen"],
+                    *tables.values()]
+            tokens, q_len, seen = map(jnp.asarray, host[:3])
             tables = {name: jnp.asarray(t) for name, t in tables.items()}
+            part.set(arrays=len(host), bytes=sum(a.nbytes for a in host))
+            part.end()
+            # fwd_k/fwd_v are (int8, scale) pairs when kv_dtype="int8" — they
+            # flow through the jitted forwards as pytree leaves. The cache
+            # (named groups of pools, donated) is threaded from one dispatch
+            # of the round to the next
+            part = tm.span_begin("serving/dispatch/forward", round=rnd,
+                                 dispatch=n)
             if verify_k is not None:
                 out, cache = self._verify_forward(
                     self._model_config, self._params, self._state.cache_view(),
-                    jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
-                    jnp.asarray(arrays["seen"]), tables, int(verify_k))
+                    tokens, q_len, seen, tables, int(verify_k))
             else:
                 out, cache = self._ragged_forward(
                     self._model_config, self._params, self._state.cache_view(),
-                    jnp.asarray(arrays["tokens"]), jnp.asarray(arrays["q_len"]),
-                    jnp.asarray(arrays["seen"]), tables)
+                    tokens, q_len, seen, tables)
             self._state.cache_update(cache)
+            part.end()
+            programs = 1
             if sample is not None:
+                part = tm.span_begin("serving/dispatch/sample", round=rnd,
+                                     dispatch=n)
                 out, sampled_rows = sample(out, rows)
+                part.end()
+                programs = 2
                 self.last_dispatches_sorted += sampled_rows > 0
                 sp.set(sampled_rows=sampled_rows)
+            shape = (seq_bucket, chunk_bucket, verify_k)
+            first_seen = int(shape not in self._shapes_seen)
+            if first_seen:
+                self._shapes_seen.add(shape)
+            sp.set(programs=programs, first_seen=first_seen)
             sp.end()
+            self.dispatch = n + 1
             parts.append((rows, out))
 
+            sp = tm.span_begin("serving/post_forward", round=rnd, dispatch=n)
             for i in rows:
                 seq = self._state.get_sequence(batch_uids[i])
                 seq.post_forward()
@@ -397,22 +422,20 @@ class InferenceEngineV2:
                     # concurrent requests sharing a prefix hit as early as
                     # possible
                     self._state.commit_cached_blocks(seq)
+            sp.end()
         self.round = rnd + 1
         return parts
 
-    def _note_further_groups(self, sp, seqs, seq_bucket):
+    def _note_further_groups(self, sp, seqs):
         """On a dispatch's ``serving/build`` span, for a model with further
         cache groups: slots of state and pages held after this dispatch's
         allocation, the pages the windows freed since the last dispatch
         (the previous round's retire), and each further paged group's
-        ``<name>_live_pages`` of the rows ``seqs`` beside the
-        ``<name>_table_slots`` of its table."""
+        ``<name>_live_pages`` of the rows ``seqs``."""
         census = self._state.census()
         for name in self._state.paged_groups:
             census[name + "_live_pages"] = sum(
                 len(seq.group_blocks.get(name, ())) for seq in seqs)
-            census[name + "_table_slots"] = \
-                seq_bucket * self._state.table_width[name]
         freed = self._state.window_pages_freed - self._window_freed_reported
         self._window_freed_reported += freed
         self.last_window_pages_freed += freed

@@ -22,12 +22,14 @@ and at its admission), never per round (pinned by
 tests/test_serving_observability.py).
 
 Whatever ``enabled`` says, each round opens ``telemetry.span``s
-(``serving/round`` > ``serving/compose``, the engine's ``serving/build`` +
-``dispatch`` once per dispatch of the round, its one ``fetch``,
+(``serving/round`` > ``serving/compose``, the engine's ``serving/build``,
+``dispatch`` (> ``dispatch/h2d``, ``dispatch/forward``, ``dispatch/sample``)
+and ``post_forward`` once per dispatch of the round, its one ``fetch``,
 ``serving/retire``) and marks each request's ``serving/admit`` /
 ``first_token`` / ``finish``: profiler annotations that cost about a
 microsecond with no profiler session and never sync. All carry the engine's
-``round``, one value per ``step()``. docs/OBSERVABILITY.md lists their
+``round``, one value per ``step()``; the engine's per-dispatch spans also its
+``dispatch``, one value per forward. docs/OBSERVABILITY.md lists their
 attributes.
 """
 
@@ -131,11 +133,9 @@ class SplitFuseScheduler:
         self.dispatches = 0
         self.real_tokens = 0
         self.padded_slots = 0
-        # the sums of the same spans' ``live_pages`` and ``table_slots``:
-        # pages of the "kv" group the dispatched rows' contexts reach (the
-        # paged kernel's work) and slots of the block tables handed to it
+        # the sum of the same spans' ``live_pages``: pages of the "kv" group
+        # the dispatched rows' contexts reach (the paged kernel's work)
         self.live_pages = 0
-        self.table_slots = 0
         # for a model with further cache groups (ragged/cache_groups.py),
         # the sums of the same spans' ``window_pages_freed`` and
         # ``state_slots``: pages the windows gave back, and slots of
@@ -780,7 +780,6 @@ class SplitFuseScheduler:
         self.real_tokens += sched_tokens
         self.padded_slots += sum(s * q for s, q in shapes)
         self.live_pages += self._engine.last_live_pages
-        self.table_slots += self._engine.last_table_slots
         self.window_pages_freed += self._engine.last_window_pages_freed
         self.state_slots += self._engine.last_state_slots
         self.expert_rows += self._engine.last_expert_rows

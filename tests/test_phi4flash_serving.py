@@ -302,12 +302,19 @@ def test_spans_carry_the_groups_and_their_sums_are_the_counters(served, tmp_path
     spans = _captured(tmp_path, run)
     builds = [a for n, _, a in spans if n == "serving/build"]
     assert len(builds) == sched.dispatches - before[2] > 0
+    state = sched._engine._state
+    # tokens, lengths, positions; the "kv" table; the ring's table and base;
+    # the slot ids: every group's tables are copied for a dispatch
+    copies = [a for n, _, a in spans if n == "serving/dispatch/h2d"]
+    assert [a["dispatch"] for a in copies] == [a["dispatch"] for a in builds]
+    assert all(a["arrays"] == 3 + 1 + 2 * len(state.paged_groups) + 1 == 7
+               for a in copies)
     for a in builds:
         assert 1 <= a["state_slots"] <= 2 and a["global_pages"] > 0
         assert a["state_slots"] <= a["window_pages"] <= a["state_slots"] * 7
         # the ring's walk is its live pages, never its table's width
         assert a["seqs"] <= a["window_live_pages"] <= a["window_pages"]
-        assert a["window_live_pages"] < a["window_table_slots"] < a["table_slots"]
+        assert a["window_live_pages"] < a["seq_bucket"] * state.table_width["window"]
         assert a["seqs"] <= a["live_pages"] <= a["global_pages"]
     assert sum(a["window_pages_freed"] for a in builds) == \
         sched.window_pages_freed - before[0] > 0

@@ -23,7 +23,8 @@ from deepspeed_tpu.models.mistral import MistralForCausalLM, tiny_mistral_config
 from deepspeed_tpu.parallel import groups
 from deepspeed_tpu.parallel.topology import MeshTopology
 
-PHASES = ("compose", "build", "dispatch", "fetch", "retire")
+PHASES = ("compose", "build", "dispatch", "post_forward", "fetch", "retire")
+CHILDREN = ("h2d", "forward", "sample")
 
 
 @pytest.fixture(autouse=True)
@@ -55,7 +56,7 @@ def _captured(trace_dir, run):
     return sorted(spans, key=lambda s: s[1]), out
 
 
-def _scheduler(max_context=64):
+def _scheduler(max_context=64, speculative=False):
     cfg = tiny_mistral_config()
     model = MistralForCausalLM(cfg)
     ids = np.zeros((1, 8), np.int32)
@@ -64,7 +65,8 @@ def _scheduler(max_context=64):
         "state_manager": {"max_ragged_sequence_count": 4,
                           "max_ragged_batch_size": 16,
                           "max_context": max_context, "num_kv_blocks": 48},
-        "kv_cache": {"block_size": 8, "cache_dtype": "fp32"}})
+        "kv_cache": {"block_size": 8, "cache_dtype": "fp32"},
+        "speculative": {"enabled": speculative, "max_draft_tokens": 4}})
     return cfg, SplitFuseScheduler(engine)
 
 
@@ -88,7 +90,7 @@ def served(tmp_path_factory):
         return sched
     before = (sched.rounds, sched.real_tokens, sched.padded_slots,
               sched.prefill_tokens_executed, sched.dispatches,
-              sched.live_pages, sched.table_slots)
+              sched.live_pages)
     spans, _ = _captured(tmp_path_factory.mktemp("serve"), run)
     return spans, sched, before
 
@@ -131,15 +133,16 @@ def test_phase_spans_lie_inside_their_round_and_carry_it(served):
         if s[0].startswith("serving/") and s[0] != "serving/round":
             assert any(a <= s[1] and s[2] <= b for _, a, b, _ in rounds), s
     # the phases of a round do not overlap, in the order the round runs
-    # them: compose, a build and a dispatch per dispatch, ONE fetch, retire
+    # them: compose, a build, a dispatch and the rows' bookkeeping per
+    # dispatch, ONE fetch, retire
     for _, a, b, attrs in rounds:
         order = [s for s in spans if s[0] != "serving/round"
                  and s[0][8:] in PHASES and s[3]["round"] == attrs["round"]]
         assert all(x[2] <= y[1] for x, y in zip(order, order[1:]))
         names = [s[0][8:] for s in order]
         k = names.count("build")
-        assert names == ["compose"] + ["build", "dispatch"] * k + \
-            ["fetch", "retire"], names
+        assert names == ["compose"] + \
+            ["build", "dispatch", "post_forward"] * k + ["fetch", "retire"], names
     assert any(len([s for s in _named(spans, "serving/build")
                     if s[3]["round"] == n]) > 1 for n in numbers), \
         "no round of this run took more than one dispatch"
@@ -187,22 +190,139 @@ def test_sums_over_spans_equal_the_schedulers_counters(served):
 
 
 def test_live_pages_and_table_slots_sum_to_the_schedulers_counters(served):
-    """What the paged kernel walks beside what its grid used to step over:
-    a dispatch's rows reach ``ceil((seen + new) / block)`` pages each, and
-    its tables hold ``sequence bucket x width`` slots."""
+    """What the paged kernel walks: a dispatch's rows reach
+    ``ceil((seen + new) / block)`` pages each, none past its table (whose
+    slots nothing counts any more: the kernel's grid stopped stepping over
+    them in PR 31)."""
     spans, sched, before = served
     builds = _named(spans, "serving/build")
     total = lambda key: sum(s[3][key] for s in builds)
     assert sched.live_pages - before[5] == total("live_pages")
-    assert sched.table_slots - before[6] == total("table_slots")
     width = sched._engine._max_blocks_per_seq
     for _, _, _, a in builds:
-        assert a["table_slots"] == a["seq_bucket"] * width
+        assert "table_slots" not in a
         # every row reaches a page; none reaches past its table
         assert a["seqs"] <= a["live_pages"] <= a["seqs"] * width
         assert a["live_pages"] * sched._engine._state.kv_block_size >= \
             a["real_tokens"] + a["context_tokens"]
-    assert 0 < sched.live_pages < sched.table_slots
+    assert sched.live_pages > 0
+
+
+def test_dispatch_index_is_unique_and_rises_by_one(served):
+    """``dispatch`` is the engine's count of forwards: one value a
+    ``serving/dispatch``, the same on the build before it, on the spans
+    inside it and on the rows' bookkeeping behind it."""
+    spans, sched, _ = served
+    dispatches = _named(spans, "serving/dispatch")
+    numbers = [s[3]["dispatch"] for s in dispatches]
+    assert numbers == list(range(numbers[0], numbers[0] + len(dispatches)))
+    assert numbers[-1] == sched._engine.dispatch - 1
+    for name in ("serving/build", "serving/post_forward", "serving/dispatch/h2d",
+                 "serving/dispatch/forward", "serving/dispatch/sample"):
+        assert [s[3]["dispatch"] for s in _named(spans, name)] == numbers, name
+    builds = _named(spans, "serving/build")
+    for build, disp, post in zip(builds, dispatches,
+                                 _named(spans, "serving/post_forward")):
+        assert build[3]["round"] == disp[3]["round"] == post[3]["round"]
+        assert build[2] <= disp[1] and disp[2] <= post[1]
+    # a round's fetch follows the bookkeeping of its last dispatch
+    for fetch in _named(spans, "serving/fetch"):
+        last = [s for s in _named(spans, "serving/post_forward")
+                if s[3]["round"] == fetch[3]["round"]][-1]
+        assert last[2] <= fetch[1]
+
+
+def test_dispatch_children_lie_inside_it_in_order_and_carry_its_ids(served):
+    spans, _, _ = served
+    dispatches = _named(spans, "serving/dispatch")
+    assert dispatches
+    for _, a, b, attrs in dispatches:
+        inside = [s for s in spans if s[0].startswith("serving/dispatch/")
+                  and s[3]["dispatch"] == attrs["dispatch"]]
+        assert [s[0][17:] for s in inside] == list(CHILDREN)
+        assert all(a <= s[1] and s[2] <= b for s in inside)
+        assert all(x[2] <= y[1] for x, y in zip(inside, inside[1:]))
+        assert all(s[3]["round"] == attrs["round"] for s in inside)
+    # and no child lies outside every dispatch
+    for s in spans:
+        if s[0].startswith("serving/dispatch/"):
+            assert any(a <= s[1] and s[2] <= b for _, a, b, _ in dispatches), s
+
+
+def test_h2d_counts_the_arrays_and_bytes_copied_for_a_dispatch(served):
+    """Tokens ``[S, C]``, lengths and positions ``[S]`` and the one group's
+    block table ``[S, width]``, all int32."""
+    spans, sched, _ = served
+    width = sched._engine._max_blocks_per_seq
+    shapes = {s[3]["dispatch"]: (s[3]["seq_bucket"], s[3]["chunk_bucket"])
+              for s in _named(spans, "serving/build")}
+    copies = _named(spans, "serving/dispatch/h2d")
+    assert copies
+    for _, _, _, a in copies:
+        rows, chunk = shapes[a["dispatch"]]
+        assert a["arrays"] == 4
+        assert a["bytes"] == 4 * (rows * chunk + 2 * rows + rows * width)
+
+
+@pytest.mark.parametrize("path, programs", [("device_sampler", 2), ("put", 1)])
+def test_programs_counts_the_executables_a_dispatch_enqueued(path, programs, tmp_path):
+    """The forward, and the sampler where one is dispatched behind it: what
+    the device's ``XLA Modules`` line shows for the dispatch."""
+    cfg, sched = _scheduler()
+    rng = np.random.default_rng(11)
+    prompt = lambda n: rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+
+    def run():
+        if path == "put":       # logits to the host: no sampler on the device
+            sched._engine.put([1, 2], [prompt(9), prompt(1)])
+        else:
+            sched.submit(1, prompt(9), max_new_tokens=3)
+            sched.run_to_completion()
+    spans, _ = _captured(tmp_path, run)
+    dispatches = _named(spans, "serving/dispatch")
+    assert dispatches and all(s[3]["programs"] == programs for s in dispatches)
+    sampled = _named(spans, "serving/dispatch/sample")
+    assert len(sampled) == (len(dispatches) if programs == 2 else 0)
+    assert ("sampled_rows" in dispatches[0][3]) == (programs == 2)
+    assert len(_named(spans, "serving/dispatch/forward")) == len(dispatches)
+
+
+@pytest.mark.parametrize("speculative", [False, True])
+def test_first_seen_is_one_exactly_once_a_shape(speculative, tmp_path):
+    """The dispatch that is first of its (sequence bucket, chunk bucket,
+    verify_k) on this engine names itself: it traced, compiled or loaded the
+    program. A verify forward is another program than the plain forward of
+    the same buckets."""
+    cfg, sched = _scheduler(speculative=speculative)
+    rng = np.random.default_rng(13)
+    prompt = lambda n: np.tile(rng.integers(0, cfg.vocab_size, 3), n)[:n].astype(np.int32)
+
+    def run():
+        if speculative:     # the plain forward at the buckets [1, 16]
+            sched._engine.put([9], [prompt(16)])
+            sched._engine.flush(9)
+        sched.submit(1, prompt(21), max_new_tokens=4)
+        sched.submit(2, prompt(9), max_new_tokens=6)
+        sched.run_to_completion()
+        known = len(sched._engine._shapes_seen)
+        sched.submit(3, prompt(12), max_new_tokens=3)   # no shape it brings is new
+        sched.run_to_completion()
+        return known
+    spans, known = _captured(tmp_path, run)
+    shapes = {s[3]["dispatch"]: (s[3]["seq_bucket"], s[3]["chunk_bucket"])
+              for s in _named(spans, "serving/build")}
+    dispatches = _named(spans, "serving/dispatch")
+    seen, verify = set(), 0
+    for _, _, _, a in dispatches:
+        if shapes[a["dispatch"]] in seen:
+            verify += a["first_seen"]     # these buckets, with another verify_k
+        else:
+            assert a["first_seen"] == 1, a
+        seen.add(shapes[a["dispatch"]])
+    assert verify == speculative      # the verify forward at [1, 16]
+    assert sum(a["first_seen"] for _, _, _, a in dispatches) == known \
+        == len(sched._engine._shapes_seen) == len(seen) + verify >= 2
+    assert known < len(dispatches)
 
 
 def test_dispatches_sorted_counts_the_dispatch_spans_that_held_a_sampled_row(tmp_path):
@@ -315,8 +435,17 @@ def test_spans_without_a_session_or_telemetry_grow_no_state():
                 sp.set(seqs=1, prefill_tokens=5, decode_rows=0, long_rows=0)
             with telemetry.span("serving/build", round=rnd) as sp:
                 sp.set(real_tokens=5, padded_slots=32)
-            with telemetry.span("serving/dispatch", round=rnd):
-                pass
+            sp = telemetry.span_begin("serving/dispatch", round=rnd, dispatch=rnd)
+            for child in CHILDREN:
+                part = telemetry.span_begin("serving/dispatch/" + child,
+                                            round=rnd, dispatch=rnd)
+                part.set(arrays=4, bytes=1024)
+                assert part._tm is None
+                part.end()
+            sp.set(programs=2, first_seen=0, sampled_rows=0)
+            sp.end()
+            telemetry.span_begin("serving/post_forward", round=rnd,
+                                 dispatch=rnd, rows=1).end()
             with telemetry.span("serving/fetch", round=rnd, what="ids"):
                 pass
             sp = telemetry.span_begin("serving/retire", round=rnd)
@@ -346,6 +475,9 @@ def test_no_span_ever_waits_for_the_device(enabled, monkeypatch):
         assert stats["serving/admit"][0] == 1
         assert {"serving/round", "serving/compose", "serving/dispatch",
                 "serving/fetch", "serving/retire", "fwd", "step"} <= set(stats)
+        for name in ("dispatch", "dispatch/h2d", "dispatch/forward",
+                     "dispatch/sample", "post_forward"):
+            assert stats["serving/" + name][0] == sched.dispatches, name
     else:
         assert stats == {}
 
