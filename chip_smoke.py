@@ -507,7 +507,7 @@ def _serve_once(p, cfg, model, params, probes, kv_dtype):
     logits probes, the decode program's text. Returns (notes, probe logits)."""
     import jax
 
-    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2, engine_v2
     from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 
     engine = InferenceEngineV2(model, params, config={
@@ -537,7 +537,7 @@ def _serve_once(p, cfg, model, params, probes, kv_dtype):
              "requests_warm_s": round(warm_s, 3),
              "compile_s": round(cold_s - warm_s, 3),
              "probe_s": round(probe_s, 3),
-             "programs_compiled": engine._ragged_forward._cache_size(),
+             "programs_compiled": engine_v2.packed_forward._cache_size(),
              "host_sync_count": engine.host_sync_count,
              "peak_bytes": peak_bytes(),
              "first_request_tokens": tokens[0][:8]}

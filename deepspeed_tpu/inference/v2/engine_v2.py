@@ -8,11 +8,11 @@ control for an external scheduler (DeepSpeed-MII's SplitFuse role);
 """
 
 import dataclasses
+import functools
 from typing import Iterable, List, Tuple
 
 import numpy as np
 import jax
-import jax.numpy as jnp
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
@@ -20,7 +20,7 @@ from deepspeed_tpu.inference.v2.model_implementations.moe_layer import (
     expert_rows)
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
-    RaggedBatchWrapper, dispatch_rows, short_row_tokens)
+    RaggedBatchWrapper, dispatch_rows, pack, short_row_tokens, unpack)
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -29,6 +29,20 @@ class SchedulingResult:
     """Admission verdict (reference ``scheduling_utils.py``)."""
     success: bool
     reason: str = "ok"
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 6), donate_argnums=(4,))
+def packed_forward(forward_fn, cfg, layout, params, cache, packed, verify_k):
+    """The program of a dispatch, for every family: a dispatch's host arrays
+    arrive as the ONE int32 buffer ``ragged_wrapper.pack`` made, are sliced
+    back out by its ``layout`` (tokens, lengths, positions, then the cache
+    groups' tables) and go to the family's ``forward_fn(cfg, params, cache,
+    tokens, q_len, seen, tables)``, its verify forward where ``verify_k`` is
+    set. ``cache`` is donated; the family's own jit is inlined."""
+    tables = unpack(layout, packed)
+    tokens, q_len, seen = (tables.pop(n) for n in ("tokens", "q_len", "seen"))
+    extra = () if verify_k is None else (verify_k,)
+    return forward_fn(cfg, params, cache, tokens, q_len, seen, tables, *extra)
 
 
 class DispatchedRound(list):
@@ -343,7 +357,7 @@ class InferenceEngineV2:
                 seqs.append(seq)
             arrays = wrapper.build(min_seqs, min_tokens)
             seq_bucket, chunk_bucket = arrays["tokens"].shape
-            tables = {"kv": arrays["block_tables"]}
+            tables = {"kv": arrays.pop("block_tables")}
             if further:
                 tables.update(self._state.group_tables(seqs, seq_bucket))
                 self._note_further_groups(sp, seqs)
@@ -371,11 +385,17 @@ class InferenceEngineV2:
 
             sp = tm.span_begin("serving/dispatch", round=rnd, dispatch=n)
             part = tm.span_begin("serving/dispatch/h2d", round=rnd, dispatch=n)
-            host = [arrays["tokens"], arrays["q_len"], arrays["seen"],
-                    *tables.values()]
-            tokens, q_len, seen = map(jnp.asarray, host[:3])
-            tables = {name: jnp.asarray(t) for name, t in tables.items()}
-            part.set(arrays=len(host), bytes=sum(a.nbytes for a in host))
+            # ONE transfer a dispatch: a transfer costs the host the same
+            # whatever it carries, so the arrays cross as one buffer that
+            # the program slices by ``layout``. It goes to the jitted call as
+            # the numpy array it is: the call's fast path moves it for a
+            # third of what ``jnp.asarray`` takes first (PERF.md, PR 40)
+            fields = {**arrays, **tables}    # tokens, q_len, seen, tables
+            if len(fields) != len(arrays) + len(tables):
+                raise ValueError(f"a cache group's table is named as one of "
+                                 f"the batch's own arrays: {list(tables)}")
+            layout, packed = pack(fields)
+            part.set(arrays=1, bytes=packed.nbytes)
             part.end()
             # fwd_k/fwd_v are (int8, scale) pairs when kv_dtype="int8" — they
             # flow through the jitted forwards as pytree leaves. The cache
@@ -383,14 +403,10 @@ class InferenceEngineV2:
             # of the round to the next
             part = tm.span_begin("serving/dispatch/forward", round=rnd,
                                  dispatch=n)
-            if verify_k is not None:
-                out, cache = self._verify_forward(
-                    self._model_config, self._params, self._state.cache_view(),
-                    tokens, q_len, seen, tables, int(verify_k))
-            else:
-                out, cache = self._ragged_forward(
-                    self._model_config, self._params, self._state.cache_view(),
-                    tokens, q_len, seen, tables)
+            out, cache = packed_forward(
+                self._ragged_forward if verify_k is None
+                else self._verify_forward, self._model_config, layout,
+                self._params, self._state.cache_view(), packed, verify_k)
             self._state.cache_update(cache)
             part.end()
             programs = 1

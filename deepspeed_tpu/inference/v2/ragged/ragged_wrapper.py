@@ -19,7 +19,10 @@ dispatch's token slots are, padding included, and a decode row has one. A
 verify round's rows carry ``[last] + drafts`` and go as ``[D, max(8, k)]``.
 """
 
+import math
+
 import numpy as np
+from jax import lax
 
 #: floor of the width of a verify round's short class (``short_row_tokens``).
 #: A plain round's short class is one token wide and does not read this.
@@ -51,6 +54,38 @@ def dispatch_rows(lengths, short):
     alone = [([i], 1, LONE_ROW_TOKENS) for i, n in enumerate(lengths)
              if n > short]
     return ([(together, 4, short)] if together else []) + alone
+
+
+def pack(fields):
+    """A dispatch's host arrays as ONE flat int32 buffer, so that they cross
+    to the device in one transfer: ``fields`` is ``{name: array}`` in the
+    order the program takes them (tokens, lengths, positions, then every
+    cache group's tables as the state manager gives them). Returns
+    ``(layout, packed)``: ``layout`` the ``(name, shape)`` of each field in
+    order, which ``unpack`` slices by (every width is fixed for an engine, so
+    it is a function of the dispatch's two buckets), ``packed`` a fresh
+    buffer (a transfer may read it after the call returns). An array of
+    another dtype is refused by name, not cast."""
+    for name, a in fields.items():
+        if a.dtype != np.int32:
+            raise TypeError(f"dispatch array {name!r} is {a.dtype}, not int32: "
+                            "it cannot join the packed buffer")
+    layout = tuple((name, a.shape) for name, a in fields.items())
+    return layout, np.concatenate([a.ravel() for a in fields.values()])
+
+
+def unpack(layout, packed):
+    """``{name: array}`` of ``pack``'s fields back out of the flat buffer,
+    by static slices: inside a program (``packed`` traced) they fuse into
+    their consumers."""
+    fields, at = {}, 0
+    for name, shape in layout:
+        n = math.prod(shape)
+        fields[name] = lax.slice(packed, (at,), (at + n,)).reshape(shape)
+        at += n
+    if at != packed.shape[0]:
+        raise ValueError(f"layout holds {at} values, the buffer {packed.shape[0]}")
+    return fields
 
 
 class RaggedBatchWrapper:

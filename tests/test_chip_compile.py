@@ -243,15 +243,18 @@ class _Captured(Exception):
     pass
 
 
-def _cell_program(name, rows, one_chip):
-    """(forward, cfg, arguments as shapes on ``one_chip``) of the dispatch
-    ``rows`` decoding sequences make in the engine of the benchmark's
-    configuration ``name``: the configuration's widths, layers and dtypes
-    (weights as shapes only), its engine limits, a small page pool."""
+def _cell_program(name, rows, one_chip, monkeypatch):
+    """(layout, the lowered program) of the dispatch ``rows`` decoding
+    sequences make in the engine of the benchmark's configuration ``name``,
+    as the engine enqueues it (``engine_v2.packed_forward``: the packed
+    buffer sliced, then the family's forward), its arguments as shapes on
+    ``one_chip``: the configuration's widths, layers and dtypes (weights as
+    shapes only), its engine limits, a small page pool."""
     import json
     import os
     from benchmark import harness, weights
     from benchmark.drivers import serve_phi4flash
+    from deepspeed_tpu.inference.v2 import engine_v2
     from deepspeed_tpu.inference.v2.engine_factory import build_engine
     from deepspeed_tpu.models.kanana2 import Kanana2Config, Kanana2ForCausalLM
     from deepspeed_tpu.models.mellum2 import Mellum2Config, Mellum2ForCausalLM
@@ -283,18 +286,19 @@ def _cell_program(name, rows, one_chip):
     engine = build_engine(model, params, dict(
         cfg["engine"], state_manager=dict(cfg["engine"]["state_manager"],
                                           num_kv_blocks=256)))
-    forward, got = engine._ragged_forward, []
+    program, got = engine_v2.packed_forward, []
 
     def spy(*args):
         got.extend(args)
         raise _Captured
 
-    engine._ragged_forward = spy
+    monkeypatch.setattr(engine_v2, "packed_forward", spy)
     with pytest.raises(_Captured):
         engine.put(list(range(rows)), [np.zeros(1, np.int32)] * rows)
+    forward, cfg, layout, *arrays, verify_k = got
     shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one_chip), tuple(got[1:]))
-    return forward, got[0], shapes
+        a.shape, a.dtype, sharding=one_chip), tuple(arrays))
+    return layout, program.lower(forward, cfg, layout, *shapes, verify_k)
 
 
 @pytest.mark.parametrize("name,rows,bucket,kernels", [
@@ -303,8 +307,8 @@ def _cell_program(name, rows, one_chip):
     ("mellum2-l12", 64, 64, 48), ("kanana2-l12-ep8", 64, 64, 45)],
     ids=["mistral64", "mistral4", "phi4flash64", "phi4flash4", "mellum2-64",
          "kanana2-64"])
-def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, name, rows,
-                                             bucket, kernels):
+def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
+                                             name, rows, bucket, kernels):
     """The WHOLE ragged forward of a decode round, [64, 1] and [4, 1], as the
     benchmark's serving cells dispatch it: every layer at the published
     widths, the paged kernel (and phi4flash's scan; for mellum2 the paged
@@ -312,10 +316,9 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, name, rows,
     rows of which a padded row takes none; for kanana2 the latent walk in 12
     layers and the grouped GEMMs over the 16 experts held in 11) at one token
     a row."""
-    forward, cfg, shapes = _cell_program(name, rows, one_chip)
-    assert shapes[2].shape == (bucket, 1)                     # the tokens
-    compiled = forward.lower(cfg, *shapes).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= kernels
+    layout, lowered = _cell_program(name, rows, one_chip, monkeypatch)
+    assert dict(layout)["tokens"] == (bucket, 1)
+    assert lowered.compile().as_text().count("tpu_custom_call") >= kernels
 
 
 @pytest.mark.parametrize("tokens,k,experts,d,f,dtype,grad", [

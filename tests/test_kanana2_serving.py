@@ -22,6 +22,7 @@ import pytest
 from benchmark.references import kanana2 as reference
 from deepspeed_tpu.inference.v2.engine_factory import (
     build_engine, resolve_cache_groups, resolve_forward_fn, resolve_verify_fn)
+from deepspeed_tpu.inference.v2 import engine_v2
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.model_implementations import moe_layer
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
@@ -487,7 +488,7 @@ def test_scheduler_serves_and_its_counters_equal_the_spans_sums(served, tmp_path
             assert sched._requests[u].generated[0] == int(np.argmax(want[u][len(p) - 1]))
 
 
-def test_a_program_lowers_one_function_a_layer_kind(served):
+def test_a_program_lowers_one_function_a_layer_kind(served, monkeypatch):
     """The layers of a dispatch call TWO lowered functions, the dense layer
     and the expert layer (``kanana2._layer`` is a jit of its own with a
     static ``dense``), not one inlined copy a layer."""
@@ -496,7 +497,7 @@ def test_a_program_lowers_one_function_a_layer_kind(served):
     deep = dataclasses.replace(cfg, num_hidden_layers=5)
     engine = build_engine(Kanana2ForCausalLM(deep),
                           Kanana2ForCausalLM(deep).init_params(jax.random.PRNGKey(1)), ENGINE)
-    forward, got = engine._ragged_forward, []
+    program, got = engine_v2.packed_forward, []
 
     class Captured(Exception):
         pass
@@ -505,12 +506,12 @@ def test_a_program_lowers_one_function_a_layer_kind(served):
         got.extend(args)
         raise Captured
 
-    engine._ragged_forward = spy
+    monkeypatch.setattr(engine_v2, "packed_forward", spy)
     with pytest.raises(Captured):
         engine.put([0, 1], [np.zeros(1, np.int32)] * 2)
-    text = forward.lower(*got).as_text()
+    text = program.lower(*got).as_text()
     assert len(set(re.findall(r"func\.func private @(_layer\w*)", text))) == 2
     assert len(re.findall(r"call @_layer", text)) == 5
     # W_UK and W_UV were cut out of kv_b_proj when the engine was built
-    attn = got[1]["layers_1"]["self_attn"]
+    attn = got[3]["layers_1"]["self_attn"]
     assert "kv_b_proj" not in attn and attn["w_uk"].shape == (128, 4, 32) == attn["w_uv"].shape

@@ -23,6 +23,7 @@ import pytest
 from benchmark.references import mellum2 as reference
 from deepspeed_tpu.inference.v2.engine_factory import (
     build_engine, resolve_cache_groups, resolve_forward_fn, resolve_verify_fn)
+from deepspeed_tpu.inference.v2 import engine_v2
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models import mellum2 as model_file
@@ -391,7 +392,7 @@ def test_scheduler_serves_and_its_counters_equal_the_spans_sums(served, tmp_path
             assert first == int(np.argmax(want[u][len(p) - 1]))
 
 
-def test_a_program_lowers_one_function_a_layer_type(served):
+def test_a_program_lowers_one_function_a_layer_type(served, monkeypatch):
     """The eight layers of a dispatch call TWO lowered functions, one a layer
     type (``mellum2._layer`` is a jit of its own and the layer's place in its
     pool a traced value), not eight inlined copies: what a program costs to
@@ -401,7 +402,7 @@ def test_a_program_lowers_one_function_a_layer_type(served):
     import re
     cfg = served[0]
     engine = _engine(served)
-    forward, got = engine._ragged_forward, []
+    program, got = engine_v2.packed_forward, []
 
     class Captured(Exception):
         pass
@@ -410,10 +411,10 @@ def test_a_program_lowers_one_function_a_layer_type(served):
         got.extend(args)
         raise Captured
 
-    engine._ragged_forward = spy
+    monkeypatch.setattr(engine_v2, "packed_forward", spy)
     with pytest.raises(Captured):
         engine.put([0, 1], [np.zeros(1, np.int32)] * 2)
-    text = forward.lower(*got).as_text()
+    text = program.lower(*got).as_text()
     layers = re.findall(r"func\.func private @(_layer\w*)", text)
     assert len(layers) == len(set(cfg.layer_types)) == 2
     assert len(re.findall(r"call @_layer", text)) == cfg.num_hidden_layers == 8

@@ -304,11 +304,15 @@ def test_spans_carry_the_groups_and_their_sums_are_the_counters(served, tmp_path
     assert len(builds) == sched.dispatches - before[2] > 0
     state = sched._engine._state
     # tokens, lengths, positions; the "kv" table; the ring's table and base;
-    # the slot ids: every group's tables are copied for a dispatch
+    # the slot ids: every group's tables cross for a dispatch, seven arrays
+    # in ONE transfer
     copies = [a for n, _, a in spans if n == "serving/dispatch/h2d"]
     assert [a["dispatch"] for a in copies] == [a["dispatch"] for a in builds]
-    assert all(a["arrays"] == 3 + 1 + 2 * len(state.paged_groups) + 1 == 7
-               for a in copies)
+    assert all(a["arrays"] == 1 for a in copies)
+    width = sched._engine._max_blocks_per_seq + state.table_width["window"]
+    for a, b in zip(copies, builds):
+        rows, chunk = b["seq_bucket"], b["chunk_bucket"]
+        assert a["bytes"] == 4 * (rows * chunk + 2 * rows + rows * width + 2 * rows)
     for a in builds:
         assert 1 <= a["state_slots"] <= 2 and a["global_pages"] > 0
         assert a["state_slots"] <= a["window_pages"] <= a["state_slots"] * 7

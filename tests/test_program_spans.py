@@ -250,8 +250,9 @@ def test_dispatch_children_lie_inside_it_in_order_and_carry_its_ids(served):
 
 
 def test_h2d_counts_the_arrays_and_bytes_copied_for_a_dispatch(served):
-    """Tokens ``[S, C]``, lengths and positions ``[S]`` and the one group's
-    block table ``[S, width]``, all int32."""
+    """ONE transfer a dispatch (``ragged_wrapper.pack``), holding tokens
+    ``[S, C]``, lengths and positions ``[S]`` and the one group's block table
+    ``[S, width]``, all int32."""
     spans, sched, _ = served
     width = sched._engine._max_blocks_per_seq
     shapes = {s[3]["dispatch"]: (s[3]["seq_bucket"], s[3]["chunk_bucket"])
@@ -260,7 +261,7 @@ def test_h2d_counts_the_arrays_and_bytes_copied_for_a_dispatch(served):
     assert copies
     for _, _, _, a in copies:
         rows, chunk = shapes[a["dispatch"]]
-        assert a["arrays"] == 4
+        assert a["arrays"] == 1
         assert a["bytes"] == 4 * (rows * chunk + 2 * rows + rows * width)
 
 

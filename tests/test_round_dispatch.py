@@ -16,10 +16,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeed_tpu.inference.v2 import InferenceEngineV2
+from deepspeed_tpu.inference.v2 import InferenceEngineV2, engine_v2
+from deepspeed_tpu.inference.v2.engine_v2 import packed_forward
 from deepspeed_tpu.inference.v2.model_implementations.llama import ragged_forward
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
-    RaggedBatchWrapper, dispatch_rows, short_row_tokens)
+    RaggedBatchWrapper, dispatch_rows, short_row_tokens, unpack)
 from deepspeed_tpu.inference.v2.sampling import sample_rows_packed
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -97,20 +98,22 @@ def _warm_pass(engine):
 
 
 class _Spy:
-    """Stands in for the engine's ragged forward: keeps each dispatch's
-    arrays and logits, and a copy of the pools as they were when the round
-    began (the forward donates them)."""
+    """Stands in for the engine's program of a dispatch: keeps each
+    dispatch's arrays, as its packed buffer holds them, and logits, and a
+    copy of the pools as they were when the round began (the program donates
+    them)."""
 
     def __init__(self, engine):
         self.engine, self.rounds = engine, {}
 
-    def __call__(self, cfg, params, cache, tokens, q_len, seen, tables):
+    def __call__(self, forward_fn, cfg, layout, params, cache, packed, verify_k):
         rnd = self.rounds.setdefault(self.engine.round, {"dispatches": []})
         if not rnd["dispatches"]:
             rnd["pools"] = jax.tree.map(jnp.copy, cache)
-        out = ragged_forward(cfg, params, cache, tokens, q_len, seen, tables)
-        rnd["dispatches"].append(tuple(np.asarray(a) for a in
-                                       (tokens, q_len, seen, tables["kv"], out[0])))
+        host = unpack(layout, np.asarray(packed))
+        out = packed_forward(forward_fn, cfg, layout, params, cache, packed, verify_k)
+        rnd["dispatches"].append(tuple(np.asarray(a) for a in (
+            host["tokens"], host["q_len"], host["seen"], host["kv"], out[0])))
         return out
 
 
@@ -122,18 +125,20 @@ def mixed_run(served):
     cfg, model, params, prompts = served
     engine = _engine(model, params)
     warm = _warm_pass(engine)
-    compiled = lambda: (ragged_forward._cache_size(), sample_rows_packed._cache_size())
+    compiled = lambda: (packed_forward._cache_size(), sample_rows_packed._cache_size())
     before = compiled()
-    spy = engine._ragged_forward = _Spy(engine)
+    spy = _Spy(engine)
     sched = SplitFuseScheduler(engine)
     shapes, rnd = set(), 0
-    while rnd == 0 or sched.has_work:
-        for uid, r in enumerate(REQUESTS):
-            if r[6] == rnd:
-                _submit(sched, uid, prompts[uid])
-        sched.step()
-        shapes.update(engine.last_batch_shapes)
-        rnd += 1
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(engine_v2, "packed_forward", spy)
+        while rnd == 0 or sched.has_work:
+            for uid, r in enumerate(REQUESTS):
+                if r[6] == rnd:
+                    _submit(sched, uid, prompts[uid])
+            sched.step()
+            shapes.update(engine.last_batch_shapes)
+            rnd += 1
     assert rnd > max(r[6] for r in REQUESTS)
     new_programs = tuple(b - a for a, b in zip(before, compiled()))
     return engine, sched, spy, warm, shapes, new_programs
@@ -200,7 +205,7 @@ def test_the_recipe_leaves_one_forward_program_a_shape(served):
     jax.clear_caches()
     warm = _warm_pass(engine)
     assert warm == _family()
-    assert ragged_forward._cache_size() == len(warm)
+    assert packed_forward._cache_size() == len(warm)
     assert sample_rows_packed._cache_size() == len({s for s, _ in warm})
 
 
