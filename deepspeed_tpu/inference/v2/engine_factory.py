@@ -13,7 +13,10 @@ speculation or page export yet. mellum2 (Mellum2: window and full attention
 layers over two paged groups, sparse experts in every layer) likewise, and
 kanana2 (Kanana-2, a DeepSeek-V3 tree: latent attention over ONE paged group
 of one leaf, sigmoid-routed experts beside a shared one, of which the tree may
-hold a share).
+hold a share), and keye_vl2 (Keye-VL-2.0's language model: learned sparse
+attention, every query reading the ``topk`` cached tokens its indexer picks,
+over one paged group whose page keeps the indexer's key beside K and V;
+softmax-routed experts of which the tree may hold a share).
 
 A family is three things, resolved here: its ragged forward, its verify
 forward (or None) and its cache groups (``ragged/cache_groups.py``); a fourth
@@ -36,14 +39,14 @@ _IMPLEMENTATION = {"llama": "llama", "mistral": "llama", "qwen2": "llama",
                    "mixtral": "mixtral", "falcon": "parallel_block",
                    "phi": "parallel_block", "opt": "opt",
                    "phi4flash": "phi4flash", "mellum2": "mellum2",
-                   "kanana2": "kanana2"}
+                   "kanana2": "kanana2", "keye_vl2": "keye_vl2"}
 
 #: families ``build_engine`` serves from an in-tree model and tree
 SERVED_FAMILIES = tuple(_IMPLEMENTATION)
 #: families ``build_hf_engine`` loads from a checkpoint directory
 SUPPORTED_FAMILIES = tuple(
     f for f in SERVED_FAMILIES
-    if f not in ("phi4flash", "mellum2", "kanana2"))  # no HF converter
+    if f not in ("phi4flash", "mellum2", "kanana2", "keye_vl2"))  # no HF converter
 
 #: the one place a config class names its family; any other is a llama tree
 _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
@@ -51,7 +54,8 @@ _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
                      "OPTConfig": "opt",
                      "Phi4FlashConfig": "phi4flash",
                      "Mellum2Config": "mellum2",
-                     "Kanana2Config": "kanana2"}
+                     "Kanana2Config": "kanana2",
+                     "KeyeVL2Config": "keye_vl2"}
 
 
 def _implementation(model, family):

@@ -45,6 +45,15 @@ def packed_forward(forward_fn, cfg, layout, params, cache, packed, verify_k):
     return forward_fn(cfg, params, cache, tokens, q_len, seen, tables, *extra)
 
 
+def selected_tokens(seen, new, topk):
+    """What a row of ``new`` tokens behind ``seen`` cached ones reads of a
+    layer under learned sparse attention, from the lengths alone: the sum
+    over its new tokens of ``min(position + 1, topk)``."""
+    full = min(max(seen + new - topk, 0), new)       # tokens at topk or past
+    short = new - full                               # positions seen .. < topk
+    return full * topk + short * seen + short * (short + 1) // 2
+
+
 class DispatchedRound(list):
     """What one round left on the device: [(rows, out)] per dispatch, ``rows``
     indexing the round's uids and ``out`` that dispatch's padded
@@ -148,6 +157,20 @@ class InferenceEngineV2:
             kvc.k_pool.shape[2] * kvc.k_pool.shape[4]
             * kvc.k_pool.dtype.itemsize) if self._state.one_leaf else 0
         self.last_latent_pages = 0
+        # where the "kv" group's page keeps an indexer's key beside K and V
+        # (learned sparse attention): the bytes of a token's key in one
+        # layer, the tokens a query reads at most (the model's
+        # ``index_topk``), and of the last round's dispatches, summed: the
+        # pages held after each one's allocation, the rows whose context
+        # passes ``index_topk`` (they score, select and read sparsely), and
+        # the (token, layer) reads of selected tokens, from the lengths alone
+        self._index_row_bytes = (
+            kvc.i_pool.shape[4] * kvc.i_pool.dtype.itemsize
+            if self._state.indexed else 0)
+        self._index_topk = getattr(cfg, "index_topk", 0) or 0
+        self.last_index_pages = 0
+        self.last_sparse_rows = 0
+        self.last_selected_tokens = 0
         # of the last round's dispatches, those that held a row whose
         # temperature is above 0: their sampler sorted every row's
         # vocabulary, the others' took the argmax (``sampling.py``)
@@ -326,6 +349,8 @@ class InferenceEngineV2:
         self.last_live_pages = 0
         self.last_expert_rows = self.last_expert_rows_padded = 0
         self.last_latent_pages = self.last_dispatches_sorted = 0
+        self.last_index_pages = self.last_sparse_rows = 0
+        self.last_selected_tokens = 0
         for rows, min_seqs, min_tokens in dispatch_rows(
                 lengths, short_row_tokens(verify_k)):
             # explicit begin/end everywhere below: tracing a new batch shape
@@ -376,6 +401,22 @@ class InferenceEngineV2:
                 self.last_latent_pages += held
                 sp.set(latent_pages=held,
                        latent_row_bytes=self._latent_row_bytes)
+            if self._index_row_bytes:
+                # from the rows' lengths, in a pass of their own: a family
+                # without an index leaf does nothing a row for them
+                held = kv.num_blocks - kv.free_blocks
+                topk = self._index_topk
+                sparse_rows = sum(s.seen_tokens + s.in_flight_tokens > topk
+                                  for s in seqs)
+                selected = self._model_config.num_hidden_layers * sum(
+                    selected_tokens(s.seen_tokens, s.in_flight_tokens, topk)
+                    for s in seqs)
+                self.last_index_pages += held
+                self.last_sparse_rows += sparse_rows
+                self.last_selected_tokens += selected
+                sp.set(index_pages=held,
+                       index_row_bytes=self._index_row_bytes,
+                       sparse_rows=sparse_rows, selected_tokens=selected)
             sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
                    real_tokens=real_tokens,
                    padded_slots=seq_bucket * chunk_bucket,

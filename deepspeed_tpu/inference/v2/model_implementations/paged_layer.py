@@ -12,6 +12,12 @@ and a page of ONE leaf, a latent row a token (``_scatter_latent``,
 ``latent_attention``: ``ragged/cache_groups.py`` ``leaves=1``), which is read
 once for the scores and the values.
 
+A K and V pair may keep an indexer's key beside it (``index_dim``): learned
+sparse attention (``dsa_attention``) writes it (``_scatter_index``), scores a
+query against every cached key of its row (``_index_scores``), finds the
+score of its ``topk``-th largest (``_select``: a threshold, never a sort) and
+reads K and V at the tokens at or above it (``_sparse_attention``).
+
 The layout. The state manager hands a forward stacked pools ``[L, NB+1,
 KV, bs, Dh]`` (an ``(int8, scale)`` pair when ``kv_dtype="int8"``), the last
 page of every layer being that layer's trash page, which absorbs the writes
@@ -183,10 +189,12 @@ def _paged_attention(q, k_pool, v_pool, block_tables, seen, block_size,
 
 
 def _paged_attention_dense(q, k_pool, v_pool, block_tables, seen, block_size,
-                           window=None, softmax_scale=None):
+                           window=None, softmax_scale=None, keep=None):
     """Pure-XLA reference path (gathers the full table; numerics twin of the
     Pallas kernel — including the fused-dequant int8 path, which it
-    reproduces as gather-then-dequantize with broadcast scales)."""
+    reproduces as gather-then-dequantize with broadcast scales). ``keep``
+    [S, Q, MB * bs] bool: the keys a query may read beside the causal rule
+    (``_sparse_attention``)."""
     k_pool, k_scale = _pool_parts(k_pool)
     v_pool, v_scale = _pool_parts(v_pool)
     S, Q, H, Dh = q.shape
@@ -195,7 +203,7 @@ def _paged_attention_dense(q, k_pool, v_pool, block_tables, seen, block_size,
     scale = 1.0 / (Dh ** 0.5) if softmax_scale is None else softmax_scale
     MB = block_tables.shape[1]
 
-    def one_seq(q_s, bt_s, seen_s):
+    def one_seq(q_s, bt_s, seen_s, keep_s=None):
         keys, vals = k_pool[bt_s], v_pool[bt_s]       # [MB, KV, bs, Dh]
         if k_scale is not None:
             # scale rows [MB, KV, 1, bs] -> per-token column [MB, KV, bs, 1]
@@ -215,10 +223,14 @@ def _paged_attention_dense(q, k_pool, v_pool, block_tables, seen, block_size,
         visible = key_pos <= qry_pos
         if window:
             visible = visible & (key_pos > qry_pos - window)
+        if keep_s is not None:
+            visible = visible & keep_s
         logits = jnp.where(visible, logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(q_s.dtype)
         return jnp.einsum("krqs,skd->qkrd", probs, vals).reshape(Q, H, Dh)
 
+    if keep is not None:
+        return jax.vmap(one_seq)(q, block_tables, seen, keep)
     return jax.vmap(one_seq)(q, block_tables, seen)
 
 
@@ -262,6 +274,133 @@ def _latent_attention_dense(q, pool, block_tables, seen, block_size,
         return jnp.einsum("hqs,sv->qhv", probs, rows[:, :value_dim])
 
     return jax.vmap(one_seq)(q, block_tables, seen)
+
+
+# -- learned sparse attention: a K and V pair with an indexer's key ------------
+
+def _scatter_index(pool, keys, block_tables, seen, q_len, block_size, trash):
+    """Write [S, Q, Di] new index keys into the index pool [NB, 1, bs, W]
+    (``W`` the key's width padded to whole lane tiles, zeros behind the key),
+    one row a token under the page and slot its K and V take."""
+    pad = pool.shape[-1] - keys.shape[-1]
+    rows = jnp.pad(keys, ((0, 0), (0, 0), (0, pad))) if pad else keys
+    return _scatter_latent(pool, rows, block_tables, seen, q_len, block_size,
+                           trash)
+
+
+def _index_scores(q_idx, w_idx, pool, block_tables, seen, block_size, q_len):
+    """The index score of every query on every cached token of its row:
+    ``I[s, t, n] = sum_j w_idx[s, t, j] ReLU(q_idx[s, t, j] . key[s, n])`` for
+    q_idx [S, Q, Hi, Di], w_idx [S, Q, Hi] float32 and the index pool [NB, 1,
+    bs, W] -> [S, Q, MB * bs] float32, ``-inf`` where token ``n`` lies behind
+    query ``t`` (``n > seen + t``). Products in the pool's dtype with float32
+    accumulation, the sum over heads in float32. The Pallas walk
+    ``paged_index_scores`` (a live page crosses HBM once) when Pallas is on
+    and the shapes tile, the dense gather twin elsewhere."""
+    from deepspeed_tpu.ops.pallas import sparse_index as si
+    if takes_kernel("paged_index_scores",
+                    si.scores_is_supported(q_idx.shape, pool.shape),
+                    f"indexer heads {tuple(q_idx.shape[2:])} over index pages "
+                    f"{tuple(pool.shape[1:])} violate the kernel's tiling "
+                    f"(need row width%128==0, block_size%8==0, a chunk of 1 "
+                    f"or a multiple of 8), O(max_context) reads"):
+        return si.paged_index_scores(q_idx, w_idx, pool, block_tables, seen,
+                                     q_len, interpret=pallas_interpret())
+    return _index_scores_dense(q_idx, w_idx, pool, block_tables, seen,
+                               block_size)
+
+
+def _index_scores_dense(q_idx, w_idx, pool, block_tables, seen, block_size):
+    """Pure-XLA twin of ``paged_index_scores`` (gathers the full table)."""
+    S, Q, Hi, Di = q_idx.shape
+    MB = block_tables.shape[1]
+
+    def one_seq(q_s, w_s, bt_s, seen_s):
+        keys = pool[bt_s][:, 0].reshape(MB * block_size, -1)[:, :Di]
+        dots = jnp.einsum("qhd,nd->qhn", q_s.astype(keys.dtype), keys,
+                          preferred_element_type=jnp.float32)
+        scores = jnp.sum(jax.nn.relu(dots) * w_s[:, :, None], axis=1)
+        key_pos = jnp.arange(MB * block_size)[None, :]
+        qry_pos = (seen_s + jnp.arange(Q))[:, None]
+        return jnp.where(key_pos <= qry_pos, scores, -jnp.inf)
+
+    return jax.vmap(one_seq)(q_idx, w_idx.astype(jnp.float32), block_tables,
+                             seen)
+
+
+def _select(scores, topk, seen):
+    """The selection as a threshold: ``tau`` [S, Q] float32, the ``topk``-th
+    largest of each query's ``scores`` [S, Q, N] (``-inf`` where the query
+    sees fewer than ``topk`` tokens: it then reads them all), so that the
+    tokens a query reads are those with ``scores >= tau``: exactly the
+    ``topk`` of largest score unless scores tie at ``tau``. Never a sort: the
+    Pallas kernel ``topk_threshold`` settles the threshold's 32 bits one at a
+    time by counting in VMEM; the twin asks ``jax.lax.top_k``."""
+    from deepspeed_tpu.ops.pallas import sparse_index as si
+    if takes_kernel("topk_threshold", si.threshold_is_supported(scores.shape),
+                    f"scores {tuple(scores.shape[1:])} violate the kernel's "
+                    f"tiling (need a context%128==0)"):
+        visible = seen[:, None] + jnp.arange(scores.shape[1])[None, :] + 1
+        return si.topk_threshold(scores, visible.astype(jnp.int32), topk,
+                                 interpret=pallas_interpret())
+    return _select_dense(scores, topk)
+
+
+def _select_dense(scores, topk):
+    """Pure-XLA twin of ``topk_threshold``: the last of ``jax.lax.top_k``."""
+    return jax.lax.top_k(scores, topk)[0][..., -1]
+
+
+def _sparse_attention(q, k_pool, v_pool, scores, tau, block_tables, seen,
+                      block_size, q_len):
+    """Grouped-query attention of q [S, Q, H, Dh] over the cached tokens of
+    its row whose index score is at or above the query's threshold (``scores``
+    [S, Q, MB * bs], ``tau`` [S, Q]: one set a query token, shared by every
+    head): the paged walk over every live page under the per-query mask
+    ``scores >= tau`` (``paged_mha``'s ``select``), else the dense twin."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    if takes_kernel("paged_mha",
+                    pa.is_supported(q.shape, k_pool.shape)
+                    and pa.select_is_supported(q.shape, k_pool.shape),
+                    f"q heads {tuple(q.shape[2:])} over pages "
+                    f"{tuple(k_pool.shape[1:])} violate the masked walk's "
+                    f"tiling (need Dh%128==0, a chunk of 1 or a multiple of "
+                    f"8), O(max_context) reads"):
+        return pa.paged_mha(q, k_pool, v_pool, block_tables, seen, q_len,
+                            select=(scores, tau),
+                            interpret=pallas_interpret())
+    return _paged_attention_dense(q, k_pool, v_pool, block_tables, seen,
+                                  block_size, keep=scores >= tau[..., None])
+
+
+def dsa_attention(q, q_idx, w_idx, k_pool, v_pool, i_pool, block_tables, seen,
+                  block_size, q_len, topk):
+    """Learned sparse attention's read, after the write: q [S, Q, H, Dh] on
+    the ``topk`` cached tokens of its row that the indexer's scores pick
+    (``q_idx`` [S, Q, Hi, Di] and ``w_idx`` [S, Q, Hi] against the index
+    keys of ``i_pool``). One rule by shape: a table that holds at most
+    ``topk`` tokens, or a dispatch none of whose rows passes ``topk``
+    (``seen + new <= topk``: the selection is the identity), takes
+    ``_paged_attention`` and computes no score; any other dispatch scores,
+    selects and reads sparsely, its short rows reading all they see."""
+    def dense():
+        with jax.named_scope("dsa_read"):
+            return _paged_attention(q, k_pool, v_pool, block_tables, seen,
+                                    block_size, q_len)
+
+    def sparse():
+        with jax.named_scope("dsa_index"):
+            scores = _index_scores(q_idx, w_idx, i_pool, block_tables, seen,
+                                   block_size, q_len)
+        with jax.named_scope("dsa_select"):
+            tau = _select(scores, topk, seen)
+        with jax.named_scope("dsa_read"):
+            return _sparse_attention(q, k_pool, v_pool, scores, tau,
+                                     block_tables, seen, block_size, q_len)
+
+    if block_tables.shape[1] * block_size <= topk:
+        return dense()
+    return jax.lax.cond(jnp.all(seen + q_len <= topk), dense, sparse)
 
 
 # -- the logits gather --------------------------------------------------------

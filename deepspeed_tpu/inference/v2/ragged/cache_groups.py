@@ -18,6 +18,14 @@ a row: the normalised latent beside the rotated position part, nothing a
 head. What works on a K and V pair and was not extended (the prefix cache,
 speculation's rollback, int8 pages, the page wire, the host tiers) refuses a
 one-leaf group by this field.
+
+A K and V pair may keep a THIRD leaf beside it, ``index_dim`` columns a token
+and layer in one head of its own: the key of a learned indexer, which scores
+every cached token of a sequence for each query and so picks the tokens whose
+K and V are read (learned sparse attention). It lives in the same pages, under
+the same allocator and the same table as its token's K and V, and is
+allocated, freed, preempted and resumed with them; what was not extended to
+it is refused by this field as for a group of one leaf (``kv_pair``).
 """
 
 import dataclasses
@@ -38,6 +46,9 @@ class PagedGroup:
     # first ``value_dim`` columns, values
     leaves: int = 2
     value_dim: Optional[int] = None
+    # columns of a further leaf of one head beside K and V (an indexer's key
+    # a token and layer); None: no such leaf
+    index_dim: Optional[int] = None
 
     def __post_init__(self):
         if self.leaves not in (1, 2):
@@ -45,6 +56,16 @@ class PagedGroup:
         if (self.leaves == 1) != (self.value_dim is not None):
             raise ValueError("value_dim belongs to a group of one leaf, "
                              "and such a group states it")
+        if self.index_dim is not None and (self.leaves != 2 or self.window):
+            raise ValueError("an index leaf stands beside a K and V pair "
+                             "whose pages live as long as the sequence")
+
+    @property
+    def kv_pair(self):
+        """Whether a page is a K and a V leaf and nothing else: what the
+        prefix cache, speculation's rollback, int8 pages, the page wire and
+        the host tiers work on."""
+        return self.leaves == 2 and self.index_dim is None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -15,6 +15,12 @@ token) has the K pool alone: ``v_pool`` is None, ``fwd`` is a 1-tuple, and a
 pool's bytes are tokens x row width x itemsize. It takes fp pages and no host
 tier; swap-out and swap-in of a preempted sequence move its one leaf.
 
+A K and V pair with an index leaf (``index_dim``: an indexer's key a token
+and layer, ``cache_groups.py``) has a third pool ``i_pool`` ``[num_layers,
+num_blocks + 1, 1, block_size, index_dim]`` under the same block ids: ``fwd``
+is ``(K, V, index)``, swap-out and swap-in move all three, and it takes fp
+pages, no host tier and no page wire, as a group of one leaf does.
+
 Storage tiers (the long-context capacity axes):
 
 * ``kv_dtype="int8"`` stores the pools int8 with per-token fp32 scales in
@@ -84,7 +90,7 @@ class BlockedKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
                  head_dim, dtype="bf16", kv_dtype="fp", host_capacity=0,
-                 nvme_capacity=0, nvme_dir=None, leaves=2):
+                 nvme_capacity=0, nvme_dir=None, leaves=2, index_dim=None):
         self.num_layers = num_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
@@ -92,18 +98,22 @@ class BlockedKVCache:
         self.quantized = (kv_dtype == "int8")
         if kv_dtype not in ("fp", "int8"):
             raise ValueError(f"kv_dtype must be 'fp' or 'int8', got {kv_dtype!r}")
-        if leaves == 1:
+        self.kv_pair = leaves == 2 and index_dim is None
+        if not self.kv_pair:
+            kind = "of one leaf" if leaves == 1 else "with an index leaf"
             for on, what in ((self.quantized, "kv_dtype int8"),
                              (host_capacity, "host_kv_blocks (the host tier)"),
                              (nvme_capacity, "nvme_kv_blocks (the NVMe tier)")):
                 if on:
                     raise ValueError(f"{what} is not supported for a paged "
-                                     f"group of one leaf")
+                                     f"group {kind}")
         self.dtype = jnp.int8 if self.quantized else _DTYPES.get(dtype, dtype)
         # +1 trash block for masked writes
         shape = (num_layers, num_blocks + 1, num_kv_heads, block_size, head_dim)
         self.k_pool = jnp.zeros(shape, self.dtype)
         self.v_pool = jnp.zeros(shape, self.dtype) if leaves == 2 else None
+        self.i_pool = None if index_dim is None else jnp.zeros(
+            (num_layers, num_blocks + 1, 1, block_size, index_dim), self.dtype)
         if self.quantized:
             # one fp32 scale per (layer, block, kv head, token row); the
             # trailing (1, block_size) layout makes the kernel's scale tile a
@@ -179,20 +189,25 @@ class BlockedKVCache:
 
     @property
     def fwd(self):
-        """The group's entry of a forward's ``cache``: ``(K, V)``, or the one
-        leaf alone ``(pages,)``."""
+        """The group's entry of a forward's ``cache``: ``(K, V)``, ``(K, V,
+        index)``, or the one leaf alone ``(pages,)``."""
+        if self.i_pool is not None:
+            return (self.k_pool, self.v_pool, self.i_pool)
         return (self.fwd_k, self.fwd_v) if self.leaves == 2 else (self.k_pool,)
 
     @property
     def pool_bytes(self):
         """Device bytes of the pools (trash page and scales included)."""
-        pools = (self.k_pool, self.v_pool, self.k_scale, self.v_scale)
+        pools = (self.k_pool, self.v_pool, self.i_pool, self.k_scale,
+                 self.v_scale)
         return sum(p.size * p.dtype.itemsize for p in pools if p is not None)
 
-    def update(self, k, v=None):
+    def update(self, k, v=None, index=None):
         """Swap in pools returned by the jitted forward (pairs when
         quantized, mirroring ``fwd``)."""
-        if self.leaves == 1:
+        if self.i_pool is not None:
+            self.k_pool, self.v_pool, self.i_pool = k, v, index
+        elif self.leaves == 1:
             self.k_pool = k
         elif self.quantized:
             (self.k_pool, self.k_scale) = k
@@ -210,6 +225,8 @@ class BlockedKVCache:
         self.k_pool = jax.device_put(self.k_pool, sharding)
         if self.v_pool is not None:
             self.v_pool = jax.device_put(self.v_pool, sharding)
+        if self.i_pool is not None:
+            self.i_pool = jax.device_put(self.i_pool, sharding)
         if self.quantized:
             self.k_scale = jax.device_put(self.k_scale, sharding)
             self.v_scale = jax.device_put(self.v_scale, sharding)
@@ -254,6 +271,8 @@ class BlockedKVCache:
         if self.leaves == 1:
             return tuple(parts)
         parts.append(jnp.take(self.v_pool, idx, axis=1))
+        if self.i_pool is not None:
+            parts.append(jnp.take(self.i_pool, idx, axis=1))
         if self.quantized:
             parts += [jnp.take(self.k_scale, idx, axis=1),
                       jnp.take(self.v_scale, idx, axis=1)]
@@ -267,6 +286,9 @@ class BlockedKVCache:
             return
         self.v_pool = self.v_pool.at[:, idx].set(
             jnp.asarray(parts[1], self.dtype))
+        if self.i_pool is not None:
+            self.i_pool = self.i_pool.at[:, idx].set(
+                jnp.asarray(parts[2], self.dtype))
         if self.quantized:
             self.k_scale = self.k_scale.at[:, idx].set(
                 jnp.asarray(parts[2], jnp.float32))
@@ -340,9 +362,10 @@ class BlockedKVCache:
     # pools ship ``(int8, scale)`` pairs — the pytree flows through
     # device_put like a plain array.
     def _refuse_one_leaf(self, what):
-        if self.leaves == 1:
+        if not self.kv_pair:
             raise ValueError(f"{what}: the page wire carries K and V pairs, "
-                             f"not a paged group of one leaf")
+                             f"not a paged group of one leaf or one with an "
+                             f"index leaf")
 
     def _pad_pages(self, blocks):
         """Pad a block-id list to the next power of two with trash-block

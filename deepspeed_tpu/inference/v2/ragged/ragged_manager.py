@@ -2,8 +2,9 @@
 ``deepspeed/inference/v2/ragged/ragged_manager.py:19``): tracks live sequences
 and owns what they keep on the device between dispatches, as the model's
 cache groups declare it (``ragged/cache_groups.py``): the blocked KV cache of
-the ``"kv"`` group (a K and a V pool, or ONE pool of latent rows where the
-group declares ``leaves=1``), further paged groups whose pages are freed
+the ``"kv"`` group (a K and a V pool, a third pool of an indexer's keys in
+the same pages where the group declares ``index_dim``, or ONE pool of latent
+rows where it declares ``leaves=1``), further paged groups whose pages are freed
 behind a window, and a slot group of recurrent state."""
 
 import numpy as np
@@ -33,7 +34,8 @@ class DSStateManager:
         if num_blocks is None:
             num_blocks = self._blocks_from_memory_budget(
                 num_layers, num_kv_heads, head_dim, kv,
-                kv_dtype=sm.kv_dtype, leaves=primary.leaves)
+                kv_dtype=sm.kv_dtype, leaves=primary.leaves,
+                index_dim=primary.index_dim)
         self.kv_cache = BlockedKVCache(num_layers, num_blocks, kv.block_size,
                                        num_kv_heads, head_dim, kv.cache_dtype,
                                        kv_dtype=sm.kv_dtype,
@@ -42,7 +44,8 @@ class DSStateManager:
                                            sm, "nvme_kv_blocks", 0),
                                        nvme_dir=getattr(
                                            sm, "nvme_kv_dir", "") or None,
-                                       leaves=primary.leaves)
+                                       leaves=primary.leaves,
+                                       index_dim=primary.index_dim)
         # block-granular prefix sharing (config_v2.py prefix_caching knob,
         # default off). None when disabled — every cache-path branch below
         # is a single attribute test, so the disabled path does zero
@@ -84,19 +87,33 @@ class DSStateManager:
         one leaf is refused for a model that declares such a group: here
         what the manager itself would build on it, in ``BlockedKVCache`` what
         the pools would (int8 pages, the host tiers)."""
-        if all(g.leaves == 2 for g in groups if isinstance(g, PagedGroup)):
+        odd = [g for g in groups if isinstance(g, PagedGroup) and not g.kv_pair]
+        if not odd:
             return
+        kind = "of one leaf" if odd[0].leaves == 1 else "with an index leaf"
         for on, option in (
                 (getattr(config, "prefix_caching", False), "prefix_caching"),
                 (config.speculative.enabled, "speculative.enabled")):
             if on:
                 raise ValueError(f"{option} is not supported for a model with "
-                                 f"a paged cache group of one leaf")
+                                 f"a paged cache group {kind}")
 
     @property
     def one_leaf(self):
         """Whether the ``"kv"`` group's page is one leaf (a latent row)."""
         return self.primary_group.leaves == 1
+
+    @property
+    def indexed(self):
+        """Whether the ``"kv"`` group's page keeps an indexer's key beside
+        K and V."""
+        return self.primary_group.index_dim is not None
+
+    @property
+    def _kv_pair_only(self):
+        """Whether the model keeps the one paged group of K and V and nothing
+        else: what rollback and the page wire work on."""
+        return not self.has_further_groups and self.primary_group.kv_pair
 
     def _init_further_groups(self, config, further):
         """Paged groups beyond ``"kv"`` (an allocator and pools each) and
@@ -177,7 +194,8 @@ class DSStateManager:
     # -- the cache and tables pytrees of a dispatch -------------------------
     def cache_view(self):
         """The donated ``cache`` argument of a forward: ``{"kv": (K, V)}``
-        (``(pages,)`` for a group of one leaf) and, for a model that
+        (``(K, V, index)`` with an index leaf, ``(pages,)`` for a group of one
+        leaf) and, for a model that
         declared them, the further groups' pools."""
         view = {"kv": self.kv_cache.fwd}
         for name, (_, cache) in self.paged_groups.items():
@@ -273,7 +291,7 @@ class DSStateManager:
 
     @staticmethod
     def _blocks_from_memory_budget(num_layers, num_kv_heads, head_dim, kv,
-                                   kv_dtype="fp", leaves=2):
+                                   kv_dtype="fp", leaves=2, index_dim=None):
         """Size the pool from device memory (the reference derives block count
         from a reserved memory fraction, ``ragged_manager.py`` memory_config):
         ~60% of the device's memory limit, fallback 1 GiB when unknown.
@@ -287,8 +305,8 @@ class DSStateManager:
         else:
             elt_bytes = np.dtype(
                 "float32" if kv.cache_dtype == "fp32" else "uint16").itemsize
-        bytes_per_block = int(leaves * num_layers * kv.block_size
-                              * num_kv_heads * head_dim * elt_bytes)  # K + V pools
+        bytes_per_block = int(num_layers * kv.block_size * elt_bytes * (
+            leaves * num_kv_heads * head_dim + (index_dim or 0)))  # K + V pools
         try:
             from deepspeed_tpu import telemetry
             stats = telemetry.sample_memory("kv_cache_budget") or {}
@@ -380,12 +398,13 @@ class DSStateManager:
                  "nvme_kv_demotions": hs.get("nvme_demotions", 0)}
         if self.prefix_cache is not None:
             stats.update(self.prefix_cache.stats())
-        if self.has_further_groups or self.one_leaf:
+        if not self._kv_pair_only:
             # occupancy per group; "kv" repeats the device census above.
             # ``bytes``: the group's pools on the device, every leaf
             groups = {"kv": {"total": total, "free": free,
                              "occupancy": occupancy,
-                             "leaves": self.primary_group.leaves,
+                             "leaves": self.primary_group.leaves
+                             + self.indexed,
                              "bytes": self.kv_cache.pool_bytes}}
             for name, (g, cache) in self.paged_groups.items():
                 groups[name] = {"total": cache.num_blocks,
@@ -513,7 +532,7 @@ class DSStateManager:
             raise ValueError(f"rollback of untracked sequence {uid}")
         if n_tokens <= 0:
             return
-        if self.has_further_groups or self.one_leaf:
+        if not self._kv_pair_only:
             raise ValueError("rollback is not supported for a model with "
                              "more than the one paged cache group of K and V")
         assert seq.in_flight_tokens == 0, "cannot roll back mid-forward"
@@ -587,12 +606,12 @@ class DSStateManager:
                 for uid, chain in chains.items()}
 
     def _refuse_page_wire(self, what):
-        if self.has_further_groups or self.one_leaf:
+        if not self._kv_pair_only:
             raise ValueError(
                 f"page {what} is not supported for a model with more than "
                 f"the one paged cache group of K and V: the wire carries "
-                f"\"kv\" pairs only, not window pages, recurrent state or "
-                f"a page of one leaf")
+                f"\"kv\" pairs only, not window pages, recurrent state, "
+                f"a page of one leaf or an index leaf")
 
     def export_sequence_pages(self, uid):
         """Detach ``uid``'s KV pages for shipping to another engine's pool

@@ -152,6 +152,12 @@ class SplitFuseScheduler:
         # the same spans' ``latent_pages``: pages held after each dispatch's
         # allocation
         self.latent_pages = 0
+        # for a model whose pages keep an indexer's key (learned sparse
+        # attention), the sums of the same spans' ``index_pages``,
+        # ``sparse_rows`` and ``selected_tokens``
+        self.index_pages = 0
+        self.sparse_rows = 0
+        self.selected_tokens = 0
         # the dispatches whose ``serving/dispatch`` span reads
         # ``sampled_rows`` above 0: they held a row whose temperature is
         # above 0, so the device sampler sorted every row's vocabulary; in
@@ -785,6 +791,9 @@ class SplitFuseScheduler:
         self.expert_rows += self._engine.last_expert_rows
         self.expert_rows_padded += self._engine.last_expert_rows_padded
         self.latent_pages += self._engine.last_latent_pages
+        self.index_pages += self._engine.last_index_pages
+        self.sparse_rows += self._engine.last_sparse_rows
+        self.selected_tokens += self._engine.last_selected_tokens
         self.dispatches_sorted += self._engine.last_dispatches_sorted
         return (uids, chunks, ids, logits, t_fwd, was_prefilling,
                 sched_tokens, rnd)

@@ -48,6 +48,16 @@ tiles of the ``H * Q`` query rows (a ``[1, 512]`` chunk has 16,384 of them,
 more than VMEM holds beside their accumulator), each tile walking the row's
 live pages anew. ``W`` is any multiple of 128.
 
+A selection (``select=(scores, tau)``: learned sparse attention). A query
+reads only the keys whose index score is at or above its threshold
+(``scores`` [S, Q, MB * bs] float32, ``tau`` [S, Q]; one set a query token,
+shared by every head). It is a mode of the same walk over every live page:
+the trip's ``[Q, pages * bs]`` slab of scores rides the trip's buffers (a
+copy by hand beside the pages for a chunk; a row's whole scores through the
+pipeline for a ``[D, 1]`` dispatch), becomes a bias of 0 or ``NEG_INF`` once a
+trip and is added to every head's score tile. ``pages`` is cut to a divisor
+of the table's width, so that no slab overruns the scores.
+
 int8 KV (``k_scale``/``v_scale`` given): pools are int8 with per-token fp32
 scales in side pools [NB, KV, 1, bs]. The pages take the same walk, so their
 HBM reads stay int8-sized and the dequant fuses into the flash loop in VMEM.
@@ -103,10 +113,11 @@ def _walk_plan(rows, kv, bs, dh, q_itemsize, pool_itemsize, table_width):
 
 
 def _flash_update(q, k, v, ks, vs, m_ref, l_ref, acc_ref, *, key0, row0,
-                  seen_s, q_tokens, scale, window):
+                  seen_s, q_tokens, scale, window, bias=None):
     """One online-softmax update of the rows ``[row0, row0 + len(q))`` of a
     KV head's query group against the keys ``[key0, key0 + len(k))``.
-    ``ks`` / ``vs``: the keys' ``[1, len(k)]`` scale rows for int8 pages."""
+    ``ks`` / ``vs``: the keys' ``[1, len(k)]`` scale rows for int8 pages.
+    ``bias``: 0 or ``NEG_INF`` a (row, key), a selection's mask."""
     if ks is not None:
         # int8 page tiles dequantize HERE, in VMEM — fp KV never exists in
         # HBM. The QK dot runs on the raw int8 values (widened to the q
@@ -124,6 +135,8 @@ def _flash_update(q, k, v, ks, vs, m_ref, l_ref, acc_ref, *, key0, row0,
     if window is not None:  # Mistral-style sliding window
         visible = jnp.logical_and(visible, kpos > seen_s + qi - window)
     sij = jnp.where(visible, sij, NEG_INF)
+    if bias is not None:
+        sij = sij + bias
 
     m_prev = m_ref[:, :1]
     l_prev = l_ref[:, :1]
@@ -152,8 +165,18 @@ def _finish(l_ref, acc_ref, dtype):
 
 
 def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
-                 row_tile, latent=None, **mask):
-    if latent is None:
+                 row_tile, latent=None, select=False, **mask):
+    sel_hbm = sel_buf = None
+    if select:
+        # ``sc_ref``: the scores, a row's whole [trips, keys] in VMEM for a
+        # dispatch of one token a row, else in HBM with slabs copied by hand
+        (q_ref, k_hbm, v_hbm, sc_ref, tau_ref, o_ref, k_buf, v_buf, sems,
+         slot_ref, m_scr, l_scr, acc_scr, *slab) = refs
+        scales = []
+        sources = ((k_hbm, k_buf), (v_hbm, v_buf))
+        if slab:
+            sel_hbm, (sel_buf, sel_sems) = sc_ref, slab
+    elif latent is None:
         # ``scales``: a sequence's (ks, vs) rows when the pages are int8
         (q_ref, k_hbm, v_hbm, *scales, o_ref, k_buf, v_buf, sems, slot_ref,
          m_scr, l_scr, acc_scr) = refs
@@ -192,6 +215,11 @@ def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
 
         jax.lax.fori_loop(0, jnp.minimum(live_pages(seq) - first, pages),
                           one, 0)
+        if sel_hbm is not None:
+            # the trip's slab of scores: [Q, keys] at the trip's first key
+            act(pltpu.make_async_copy(
+                sel_hbm.at[seq, :, pl.ds(pl.multiple_of(first * bs, keys), keys)],
+                sel_buf.at[slot], sel_sems.at[slot]))
 
     start = lambda copy: copy.start()
     wait = lambda copy: copy.wait()
@@ -236,6 +264,16 @@ def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
                           seen_s=seen_ref[s], **mask)
             return 0
 
+        bias = None
+        if select:
+            q_tokens = mask["q_tokens"]
+            sel = sc_ref[0, pl.ds(trip, 1)] if sel_hbm is None \
+                else sel_buf[slot]                       # [q_tokens, keys]
+            bias = jnp.where(sel >= tau_ref[0], 0.0, NEG_INF)
+            if q_tokens > 1 and row_tile > q_tokens:
+                # a tile's rows are whole heads' queries: row % q_tokens
+                bias = jnp.concatenate([bias] * (row_tile // q_tokens), 0)
+
         def head(h, _):
             k = k_buf[slot, :, h].reshape(keys, dh)
             v = v_buf[slot, :, h].reshape(keys, dh)
@@ -243,10 +281,15 @@ def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
                 if scales else (None, None)
             for row0 in range(0, rows, row_tile):
                 tile = pl.ds(row0, row_tile)
+                more = {}
+                if bias is not None:
+                    # a tile inside one head's queries takes their rows
+                    first = row0 % bias.shape[0]
+                    more["bias"] = bias[first:first + row_tile]
                 _flash_update(q_ref[0, h, tile], k, v, ks, vs,
                               m_scr.at[h, tile], l_scr.at[h, tile],
                               acc_scr.at[h, tile], key0=trip * keys,
-                              row0=row0, seen_s=seen_ref[s], **mask)
+                              row0=row0, seen_s=seen_ref[s], **mask, **more)
             return 0
 
         # traced once, laid out ``heads`` times: the heads' multiplications
@@ -285,8 +328,10 @@ def _grid_kernel(bt_ref, seen_ref, qlen_ref, jcap_ref, *refs, bs, nb_grid,
 
 def paged_mha(q, k_pool, v_pool, block_tables, seen, q_len, *,
               k_scale=None, v_scale=None, softmax_scale=None, window=None,
-              interpret=False):
+              select=None, interpret=False):
     """Blocked-flash attention over paged KV. See module docstring for shapes.
+    ``select``: ``(scores [S, Q, MB * bs] float32, tau [S, Q])``, a query
+    reading only the keys with ``scores >= tau`` (fp pages, no window).
 
     SPMD: routed through the kernel dispatcher — sequences (the ``S`` batch
     dim of q/block_tables/seen/q_len) shard over the active mesh's data axes;
@@ -307,12 +352,13 @@ def paged_mha(q, k_pool, v_pool, block_tables, seen, q_len, *,
     block_config = registry.resolve_block_config(
         "paged_mha", {"bs": k_pool.shape[2], "dh": q.shape[-1]}, q.dtype)
 
-    def call(q_, kp_, vp_, bt_, sn_, ql_, *scales):
-        ks_, vs_ = scales if quantized else (None, None)
+    def call(q_, kp_, vp_, bt_, sn_, ql_, *more):
+        ks_, vs_ = more if quantized else (None, None)
         return _paged_mha_local(q_, kp_, vp_, bt_, sn_, ql_,
                                 k_scale=ks_, v_scale=vs_,
                                 softmax_scale=softmax_scale, window=window,
-                                interpret=interpret)
+                                interpret=interpret,
+                                **({"select": more} if select else {}))
 
     def accept(shard_shapes):
         (_, _, h, _), (_, kv, _, _) = shard_shapes[0], shard_shapes[1]
@@ -324,6 +370,11 @@ def paged_mha(q, k_pool, v_pool, block_tables, seen, q_len, *,
     if quantized:
         inputs += [k_scale, v_scale]
         roles += [(None, "head", None, None), (None, "head", None, None)]
+    if select:
+        assert not quantized and not window, \
+            "a selection reads fp pages and has no window"
+        inputs += list(select)
+        roles += [("data", None, None), ("data", None)]
     return sharded_kernel_call(
         call, inputs, roles,
         ("data", None, "head", None), accept=accept, name="paged_mha",
@@ -332,7 +383,7 @@ def paged_mha(q, k_pool, v_pool, block_tables, seen, q_len, *,
 
 def _paged_mha_local(q, k_pool, v_pool, block_tables, seen, q_len, *,
                      k_scale=None, v_scale=None, softmax_scale=None,
-                     window=None, interpret=False):
+                     window=None, select=None, interpret=False):
     S, Q, H, Dh = q.shape
     KV, bs = k_pool.shape[1:3]
     rep = H // KV
@@ -345,21 +396,30 @@ def _paged_mha_local(q, k_pool, v_pool, block_tables, seen, q_len, *,
                 else Dh ** -0.5)
     # Mosaic copies by hand only out of arrays whose rows fill a lane tile
     call = _walk_call if Dh % LANES == 0 else _grid_call
+    more = {"select": select} if select else {}
     with jax.named_scope("paged_attention"):
         out = call(qt, k_pool, v_pool, block_tables.astype(jnp.int32),
                    seen.astype(jnp.int32), q_len.astype(jnp.int32),
-                   k_scale, v_scale, mask, interpret)
+                   k_scale, v_scale, mask, interpret, **more)
     return out.reshape(S, KV, rep, Q, Dh).transpose(0, 3, 1, 2, 4) \
               .reshape(S, Q, H, Dh)
 
 
 def _walk_call(qt, k_pool, v_pool, block_tables, seen, q_len, k_scale,
-               v_scale, mask, interpret):
+               v_scale, mask, interpret, select=None):
     S, KV, rows, Dh = qt.shape
     bs = k_pool.shape[2]
     heads, row_tile, pages = _walk_plan(
         rows, KV, bs, Dh, qt.dtype.itemsize, k_pool.dtype.itemsize,
         block_tables.shape[1])
+    if select:
+        # a trip's slab of scores must lie inside the table's width, and a
+        # tile's rows be whole heads' queries or a part of one head's
+        Q = mask["q_tokens"]
+        pages = max(p for p in range(1, pages + 1)
+                    if block_tables.shape[1] % p == 0)
+        row_tile = max(t for t in range(8 if Q > 1 else 1, row_tile + 1)
+                       if rows % t == 0 and (t % Q == 0 or Q % t == 0))
     q_spec = pl.BlockSpec((1, heads, rows, Dh),
                           lambda s, h, bt, sn, ql: (s, h, 0, 0),
                           memory_space=pltpu.VMEM)
@@ -379,6 +439,25 @@ def _walk_call(qt, k_pool, v_pool, block_tables, seen, q_len, k_scale,
                                   lambda s, h, bt, sn, ql: (s, h, 0, 0),
                                   memory_space=pltpu.VMEM)] * 2
 
+    slab = []
+    if select:
+        scores, tau = select
+        Q, keys = mask["q_tokens"], pages * bs
+        whole = lambda *block: pl.BlockSpec(
+            block, lambda s, h, bt, sn, ql: (s,) + (0,) * (len(block) - 1),
+            memory_space=pltpu.VMEM)
+        if Q == 1:
+            # a row's whole scores, a trip a row
+            inputs.append(scores.reshape(S, -1, keys))
+            in_specs.append(whole(1, scores.shape[-1] // keys, keys))
+        else:
+            inputs.append(scores)
+            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+            slab = [pltpu.VMEM((2, Q, keys), jnp.float32),
+                    pltpu.SemaphoreType.DMA((2,))]
+        inputs.append(tau.astype(jnp.float32)[..., None])
+        in_specs.append(whole(1, Q, 1))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, KV // heads),
@@ -393,10 +472,11 @@ def _walk_call(qt, k_pool, v_pool, block_tables, seen, q_len, k_scale,
             pltpu.VMEM((heads, rows, LANES), jnp.float32),
             pltpu.VMEM((heads, rows, LANES), jnp.float32),
             pltpu.VMEM((heads, rows, Dh), jnp.float32),
-        ],
+        ] + slab,
     )
     kernel = functools.partial(_walk_kernel, bs=bs, pages=pages, heads=heads,
-                               row_tile=row_tile, **mask)
+                               row_tile=row_tile, **mask,
+                               **({"select": True} if select else {}))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -543,6 +623,14 @@ def mla_is_supported(q_shape, pool_shape, value_dim):
             and value_dim % LANES == 0 and value_dim <= width
             and bs % 8 == 0
             and (rows <= _MAX_ROW_TILE or rows % 8 == 0))
+
+
+def select_is_supported(q_shape, pool_shape):
+    """The walk under a selection copies pages and slabs of scores by hand:
+    rows that fill lane tiles, and a chunk whose score slab has whole
+    sublane tiles (or one token a row)."""
+    S, Q, H, Dh = q_shape
+    return Dh % LANES == 0 and (Q == 1 or Q % 8 == 0)
 
 
 def is_supported(q_shape, pool_shape):

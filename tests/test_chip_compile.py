@@ -169,6 +169,42 @@ def test_paged_mla_refuses_a_row_that_does_not_fill_its_lane_tiles(for_tpu, one_
                 sds((64,), jnp.int32), pool).compile()
 
 
+@pytest.mark.parametrize("seqs,q_tokens", [
+    (32, 1), (4, 1), (1, 512), (1, 16), (1, 128)],
+    ids=["decode32", "decode4", "chunk512", "chunk16", "chunk128"])
+def test_learned_sparse_attention_keye_vl2_geometry(for_tpu, one_chip, seqs, q_tokens):
+    """The three kernels of learned sparse attention at the benchmark's
+    Keye-VL-2.0 cell: 16 indexer heads of 64 over index rows of 128 columns,
+    a 704-slot table (45,056 tokens), the 2,048th largest of each query's
+    scores, and 32 query heads of 128 walking K and V pages under that
+    selection; a [D, 1] decode dispatch and a [1, C] chunk."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    from deepspeed_tpu.ops.pallas import sparse_index as si
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    table, context = 704, 704 * PAGE
+    pool = sds((2049, 4, PAGE, 128), jnp.bfloat16)
+    index_pool = sds((2049, 1, PAGE, 128), jnp.bfloat16)
+    q = sds((seqs, q_tokens, 32, 128), jnp.bfloat16)
+    q_idx = sds((seqs, q_tokens, 16, 64), jnp.bfloat16)
+    w_idx = sds((seqs, q_tokens, 16), jnp.float32)
+    scores = sds((seqs, q_tokens, context), jnp.float32)
+    bt, row = sds((seqs, table), jnp.int32), sds((seqs,), jnp.int32)
+    assert si.scores_is_supported(q_idx.shape, index_pool.shape)
+    assert si.threshold_is_supported(scores.shape)
+    assert pa.is_supported(q.shape, pool.shape) and pa.select_is_supported(q.shape, pool.shape)
+
+    compiled = _compile(lambda q_, w_, bt_, sn, ql, p: si.paged_index_scores(
+        q_, w_, p, bt_, sn, ql), (q_idx, w_idx, bt, row, row, index_pool))
+    assert "paged_index_scores" in compiled.as_text()
+    compiled = _compile(lambda x, vis: si.topk_threshold(x, vis, 2048),
+                        (scores, sds((seqs, q_tokens), jnp.int32)))
+    assert "topk_threshold" in compiled.as_text()
+    compiled = _compile(lambda q_, k, v, bt_, sn, ql, x, tau: pa.paged_mha(
+        q_, k, v, bt_, sn, ql, select=(x, tau)),
+        (q, pool, pool, bt, row, row, scores, sds((seqs, q_tokens), jnp.float32)))
+    assert "paged_attention" in compiled.as_text()
+
+
 def test_paged_attention_walk_under_dp2_tp2(for_tpu, topo):
     """The cells' [64, 8] decode dispatch across four chips: rows over dp,
     KV heads (the pools' second dim) over tp, so each kernel walks 32 rows'

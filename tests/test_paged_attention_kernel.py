@@ -344,3 +344,159 @@ def test_mla_is_supported():
     assert not ok((4, 1, 4, 256), (10, 1, 12, 256))               # block size
     assert not ok((4, 1, 4, 256), (10, 1, 16, 256), 192)          # values off the lanes
     assert not ok((4, 1, 4, 384), (10, 1, 16, 256))               # q is not the row's width
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention: the walk under a selection, the index scores over
+# paged keys, and the threshold that stands for the selection
+# (``ops/pallas/sparse_index.py``). Slots past a row's live count point at a
+# page of NaN, as above.
+# ---------------------------------------------------------------------------
+
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (  # noqa: E402
+    _index_scores_dense, _select_dense)
+from deepspeed_tpu.ops.pallas import sparse_index  # noqa: E402
+from deepspeed_tpu.ops.pallas.paged_attention import select_is_supported  # noqa: E402
+
+
+def _selection(case, topk, seed=0):
+    """Random scores of every (query, key) and the threshold ``top_k`` gives,
+    ``-inf`` behind each query as the index scores have it."""
+    q, kp, _, bt, seen, _ = case
+    S, Q, N = q.shape[0], q.shape[1], bt.shape[1] * kp.shape[2]
+    scores = jax.random.normal(jax.random.PRNGKey(100 + seed), (S, Q, N), jnp.float32)
+    behind = jnp.arange(N)[None, None, :] > (seen[:, None] + jnp.arange(Q)[None, :])[..., None]
+    scores = jnp.where(behind, -jnp.inf, scores)
+    return scores, _select_dense(scores, topk)
+
+
+def check_select_walk(case, poison, topk, atol=2e-4, rtol=1e-3):
+    q, kp, vp, bt, seen, q_len = case
+    scores, tau = _selection(case, topk)
+    nan = lambda pool: pool.at[poison].set(jnp.nan)
+    out_k = paged_mha(q, nan(kp), nan(vp), bt, seen, q_len, select=(scores, tau),
+                      interpret=True)
+    zero = lambda pool: pool.at[poison].set(0)
+    out_d = _paged_attention_dense(q, zero(kp), zero(vp), bt, seen, kp.shape[2],
+                                   keep=scores >= tau[..., None])
+    plain = _paged_attention_dense(q, zero(kp), zero(vp), bt, seen, kp.shape[2])
+    assert np.isfinite(np.asarray(out_k, np.float32)).all()
+    got, want = valid_rows(out_k, q_len), valid_rows(out_d, q_len)
+    np.testing.assert_allclose(got.astype(np.float32), want.astype(np.float32),
+                               atol=atol, rtol=rtol)
+    return float(np.abs(want - valid_rows(plain, q_len)).max())
+
+
+@pytest.mark.parametrize("live", ["one", "a_trip", "a_trip_and_one", "table"])
+def test_select_walk_live_pages_at_the_trip_boundaries(live):
+    MB = 256
+    P = _pages_a_trip(1, 4, MB)
+    n = {"one": 1, "a_trip": P, "a_trip_and_one": P + 1, "table": MB}[live]
+    moved = check_select_walk(*make_walk_case([n, 2 * P + 3, n], MB=MB, seed=n), topk=96)
+    assert moved > 1e-2            # the selection is not the identity on the long row
+
+
+@pytest.mark.parametrize("Q", [1, 8, 64, 384])
+def test_select_walk_query_tokens(Q):
+    """A [D, 1] dispatch (a row's whole scores through the pipeline), chunks
+    whose tiles hold several heads' queries, and a chunk walked in row tiles
+    (a slab of scores copied beside the pages, shared by the tiles)."""
+    assert select_is_supported((2, Q, 16, 256), (9, 4, 32, 256))
+    check_select_walk(*make_walk_case([max(3, Q // 32), 40], Q=Q, seed=Q), topk=200)
+
+
+def test_select_walk_padded_rows_and_a_row_that_reads_all_it_sees():
+    case, poison = make_walk_case([5, 1, 70, 1, 9], Q=8, q_len=[8, 0, 3, 0, 1])
+    check_select_walk(case, poison, topk=100)      # rows 0 and 4 see fewer than 100
+    assert not select_is_supported((2, 4, 16, 256), (9, 4, 32, 256))   # a chunk of 4
+    assert not select_is_supported((2, 8, 16, 64), (9, 4, 32, 64))     # narrow heads
+
+
+def make_index_case(live, Q=1, Hi=4, Di=64, W=128, bs=32, MB=64, seed=0, dtype=jnp.float32):
+    S, NB = len(live), sum(live) + 2
+    poison = NB - 2
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    pool = jax.random.normal(ks[0], (NB, 1, bs, W), dtype).at[..., Di:].set(0)
+    q = jax.random.normal(ks[1], (S, Q, Hi, Di), dtype)
+    w = jax.random.normal(ks[2], (S, Q, Hi), jnp.float32)
+    rng = np.random.default_rng(seed)
+    pages = rng.permutation(NB - 2)
+    bt = np.full((S, MB), poison, np.int32)
+    seen = np.zeros((S,), np.int32)
+    for i, n in enumerate(live):
+        bt[i, :n], pages = pages[:n], pages[n:]
+        seen[i] = rng.integers(max((n - 1) * bs + 1 - Q, 0), n * bs - Q + 1)
+    return (q, w, pool, jnp.asarray(bt), jnp.asarray(seen),
+            jnp.full((S,), Q, jnp.int32)), poison
+
+
+def check_index(case, poison, atol=2e-4):
+    q, w, pool, bt, seen, q_len = case
+    got = sparse_index.paged_index_scores(q, w, pool.at[poison].set(jnp.nan), bt, seen, q_len,
+                                          interpret=True)
+    want = _index_scores_dense(q, w, pool.at[poison].set(0), bt, seen, pool.shape[2])
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert not np.isnan(got).any(), "the walk read past a row's live pages"
+    assert (np.isfinite(got) == np.isfinite(want)).all()
+    np.testing.assert_allclose(got[np.isfinite(want)], want[np.isfinite(want)], atol=atol,
+                               rtol=1e-3)
+
+
+@pytest.mark.parametrize("Q", [1, 8, 64])
+def test_index_scores_live_pages_at_the_step_boundaries(Q):
+    """Rows that end in a step's first page, its last, the next step's first
+    and the table's last; steps past a row's live pages copy nothing and
+    read ``-inf``."""
+    MB, bs, W = 512, 32, 128
+    P = sparse_index._pages_a_step(MB, bs, W, 4, Q)
+    assert 1 < P < MB
+    check_index(*make_index_case([max(1, Q // bs), P, P + 1, MB, 2 * P + 1], Q=Q, MB=MB,
+                                 seed=Q))
+
+
+def test_index_scores_bf16_products_accumulate_in_float32():
+    case, poison = make_index_case([3, 20], Q=8, dtype=jnp.bfloat16, seed=5)
+    q, w, pool, bt, seen, q_len = case
+    got = sparse_index.paged_index_scores(q, w, pool, bt, seen, q_len, interpret=True)
+    assert got.dtype == jnp.float32
+    f32 = lambda a: a.astype(jnp.float32)
+    want = _index_scores_dense(f32(q), w, f32(pool), bt, seen, pool.shape[2])
+    fin = np.isfinite(np.asarray(want))
+    # bfloat16 x bfloat16 is exact in float32: only the order of the sums differs
+    np.testing.assert_allclose(np.asarray(got)[fin], np.asarray(want)[fin], atol=1e-4)
+    assert sparse_index.scores_is_supported(q.shape, pool.shape)
+    assert not sparse_index.scores_is_supported((2, 4, 4, 64), pool.shape)     # a chunk of 4
+    assert not sparse_index.scores_is_supported(q.shape, (9, 1, 32, 64))       # half a tile
+
+
+@pytest.mark.parametrize("rows,N,topk", [((4, 1), 1024, 100), ((1, 16), 2048, 700),
+                                         ((3, 8), 384, 5), ((32, 1), 4096, 2048)])
+def test_the_threshold_is_the_topk_th_largest_bit_for_bit(rows, N, topk):
+    """Against ``jax.lax.top_k`` on the same float32 scores: rows of unequal
+    visible length (``-inf`` behind), negative and positive scores, zeros
+    that tie, a row that sees fewer than ``topk``."""
+    S, Q = rows
+    rng = np.random.default_rng(N + topk)
+    x = rng.normal(size=(S, Q, N)).astype(np.float32) * 10 ** rng.uniform(-3, 3, (S, Q, 1))
+    x[..., ::7] = 0.0                                   # exact ties, some at the threshold
+    x[0, 0, ::3] = -0.0
+    visible = rng.integers(1, N + 1, (S, Q))
+    visible[0, 0], visible[-1, -1] = min(topk - 1, N), N
+    x[np.arange(N)[None, None, :] >= visible[..., None]] = -np.inf
+    got = sparse_index.topk_threshold(jnp.asarray(x), jnp.asarray(visible, jnp.int32), topk,
+                                      interpret=True)
+    want = np.asarray(_select_dense(jnp.asarray(x), topk))
+    assert (np.asarray(got) == want).all()
+    assert np.isneginf(np.asarray(got)[0, 0]) and np.isfinite(np.asarray(got)[-1, -1])
+    assert sparse_index.threshold_is_supported(x.shape)
+    assert not sparse_index.threshold_is_supported((1, 1, 200))
+
+
+def test_the_float_keys_keep_the_order_of_the_floats():
+    x = jnp.asarray([-np.inf, -3e38, -1.5, -1e-30, -0.0, 0.0, 1e-30, 2.5, 3e38, np.inf],
+                    jnp.float32)
+    keys = np.asarray(sparse_index._to_key(x))
+    assert (np.diff(keys.astype(np.int64)) > 0).all()
+    assert keys[0] == sparse_index._KEY_NEG_INF
+    back = np.asarray(sparse_index._from_key(jnp.asarray(keys)))
+    assert (back == np.asarray(x)).all() and np.signbit(back[4]) and not np.signbit(back[5])

@@ -407,3 +407,146 @@ def test_the_llama_forward_refuses_unstacked_layers():
     stacked = LlamaForCausalLM(dataclasses.replace(model.config,
                                                    scan_layers=True))
     assert ef.resolve_verify_fn(stacked).__name__ == "ragged_forward_verify"
+
+
+# -- learned sparse attention: an indexer's key beside K and V -------------------
+
+def _dsa_case(S=3, Q=8, H=4, KV=2, Dh=128, Hi=16, Di=16, W=128, bs=8, MB=16, ctx=None,
+              seed=0):
+    """Pools as a forward leaves them and a dispatch's queries: row 0 short
+    (it sees at most ``Q + 2`` tokens), the others deep into their tables."""
+    rng = np.random.default_rng(seed)
+    NB = S * MB + 1
+    r = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    tables = jnp.asarray(rng.permutation(NB - 1).reshape(S, MB), jnp.int32)
+    ctx = ctx or [Q + 2, MB * bs - 3, MB * bs // 2 + 1][:S]
+    return dict(
+        k_pool=r(NB, KV, bs, Dh), v_pool=r(NB, KV, bs, Dh),
+        i_pool=r(NB, 1, bs, W).at[..., Di:].set(0), tables=tables, bs=bs,
+        q_len=jnp.full((S,), Q, jnp.int32), seen=jnp.asarray(ctx, jnp.int32) - Q,
+        q=r(S, Q, H, Dh), q_idx=r(S, Q, Hi, Di), w_idx=r(S, Q, Hi))
+
+
+def _chosen(scores, topk):
+    """{(row, query): the set ``jax.lax.top_k`` picks of the visible keys}."""
+    scores = np.asarray(scores)
+    _, idx = jax.lax.top_k(jnp.asarray(scores), topk)
+    idx = np.asarray(idx)
+    return {(s, t): {int(n) for n in idx[s, t] if np.isfinite(scores[s, t, n])}
+            for s in range(scores.shape[0]) for t in range(scores.shape[1])}
+
+
+@pytest.mark.parametrize("Q", [8, 1], ids=["chunk", "decode"])
+@pytest.mark.parametrize("disable", [False, True], ids=["kernel", "pallas-disabled"])
+def test_the_selected_set_is_top_ks_on_the_same_scores(dispatch, monkeypatch, disable, Q):
+    """Scores through the kernel and through the twin agree; the threshold
+    (settled bit by bit in the kernel, the last of ``top_k`` in the twin) is
+    the SAME float32, and ``scores >= tau`` among the visible keys is exactly
+    the set ``jax.lax.top_k`` picks: ``topk`` tokens a query, all it sees
+    where it sees fewer."""
+    if disable:
+        monkeypatch.setenv("DS_TPU_DISABLE_PALLAS", "1")
+    c, topk = _dsa_case(Q=Q), 12
+    args = (c["q_idx"], c["w_idx"], c["i_pool"], c["tables"], c["seen"], c["bs"], c["q_len"])
+    assert _traces_kernel(lambda p: paged_layer._index_scores(*args[:2], p, *args[3:]),
+                          c["i_pool"]) == (not disable)
+    scores = paged_layer._index_scores(*args)
+    twin = paged_layer._index_scores_dense(*args[:6])
+    seen_keys = np.isfinite(np.asarray(twin))
+    assert (np.isfinite(np.asarray(scores)) == seen_keys).all()
+    visible = np.asarray(c["seen"])[:, None] + np.arange(Q)[None, :] + 1
+    assert (seen_keys.sum(-1) == visible).all()                      # the causal rule
+    np.testing.assert_allclose(np.asarray(scores)[seen_keys], np.asarray(twin)[seen_keys],
+                               atol=1e-5)
+    tau = paged_layer._select(twin, topk, c["seen"])
+    assert _traces_kernel(lambda x: paged_layer._select(x, topk, c["seen"]), twin) \
+        == (not disable)
+    assert (np.asarray(tau) == np.asarray(paged_layer._select_dense(twin, topk))).all()
+    keep = np.asarray(twin >= tau[..., None]) & seen_keys
+    want = _chosen(twin, topk)
+    for (s, t), members in want.items():
+        assert set(np.flatnonzero(keep[s, t])) == members
+        assert len(members) == min(topk, visible[s, t])
+    records = dispatch()
+    for kernel in ("paged_index_scores", "topk_threshold"):
+        assert ((kernel, "fallback", "no_tpu") in records) == disable
+
+
+@pytest.mark.parametrize("Q", [8, 1], ids=["chunk", "decode"])
+@pytest.mark.parametrize("disable", [False, True], ids=["kernel", "pallas-disabled"])
+def test_the_sparse_read_is_a_softmax_over_the_selected_set(dispatch, monkeypatch, disable, Q):
+    """The masked walk and its dense twin against a plain softmax over the
+    tokens ``top_k`` picks, heads of one KV group sharing the set."""
+    if disable:
+        monkeypatch.setenv("DS_TPU_DISABLE_PALLAS", "1")
+    c, topk = _dsa_case(Q=Q), 12
+    S, _, H, Dh = c["q"].shape
+    KV, MB, bs = c["k_pool"].shape[1], c["tables"].shape[1], c["bs"]
+    scores = paged_layer._index_scores_dense(c["q_idx"], c["w_idx"], c["i_pool"], c["tables"],
+                                             c["seen"], bs)
+    tau = paged_layer._select_dense(scores, topk)
+    got = paged_layer._sparse_attention(c["q"], c["k_pool"], c["v_pool"], scores, tau,
+                                        c["tables"], c["seen"], bs, c["q_len"])
+    flat = lambda pool: np.asarray(pool[c["tables"]]).transpose(0, 1, 3, 2, 4) \
+        .reshape(S, MB * bs, KV, Dh)
+    keys, vals = flat(c["k_pool"]), flat(c["v_pool"])
+    for (s, t), members in _chosen(scores, topk).items():
+        at = sorted(members)
+        for h in range(H):
+            logits = keys[s, at, h // (H // KV)] @ np.asarray(c["q"])[s, t, h] / np.sqrt(Dh)
+            p = np.exp(logits - logits.max())
+            want = (p / p.sum()) @ vals[s, at, h // (H // KV)]
+            np.testing.assert_allclose(np.asarray(got)[s, t, h], want, atol=3e-5)
+    assert (("paged_mha", "fallback", "no_tpu") in dispatch()) == disable
+
+
+def test_a_dispatch_none_of_whose_rows_passes_topk_is_the_plain_paged_read(dispatch):
+    """``seen + new <= topk`` on every row: the selection is the identity and
+    is not computed; the output is ``_paged_attention``'s, bit for bit. One
+    row past ``topk`` and the dispatch scores, selects and reads sparsely, its
+    short rows reading all they see."""
+    c = _dsa_case(ctx=[10, 37, 29])
+    args = (c["q"], c["q_idx"], c["w_idx"], c["k_pool"], c["v_pool"], c["i_pool"], c["tables"],
+            c["seen"], c["bs"], c["q_len"])
+    plain = paged_layer._paged_attention(c["q"], c["k_pool"], c["v_pool"], c["tables"],
+                                         c["seen"], c["bs"], c["q_len"])
+    short = paged_layer.dsa_attention(*args, 40)
+    assert (np.asarray(short) == np.asarray(plain)).all()
+    # a table that holds at most topk tokens never traces the indexer at all
+    text = str(jax.make_jaxpr(lambda q: paged_layer.dsa_attention(q, *args[1:], 128))(c["q"]))
+    assert "paged_index_scores" not in text and "topk_threshold" not in text
+    sparse = paged_layer.dsa_attention(*args, 20)
+    assert np.abs(np.asarray(sparse)[1:] - np.asarray(plain)[1:]).max() > 1e-3
+    np.testing.assert_allclose(np.asarray(sparse)[0], np.asarray(plain)[0], atol=2e-5)
+
+
+def test_sparse_reads_the_kernels_cannot_tile_fall_back_with_a_record(dispatch):
+    c = _dsa_case(Q=4, bs=12, MB=6, W=128)                 # a chunk of 4, a block of 12
+    c["i_pool"] = c["i_pool"][..., :64]                    # a row that fills no lane tile
+    got = paged_layer.dsa_attention(c["q"], c["q_idx"], c["w_idx"], c["k_pool"], c["v_pool"],
+                                    c["i_pool"], c["tables"], c["seen"], c["bs"], c["q_len"], 12)
+    scores = paged_layer._index_scores_dense(c["q_idx"], c["w_idx"], c["i_pool"], c["tables"],
+                                             c["seen"], c["bs"])
+    want = paged_layer._paged_attention_dense(
+        c["q"], c["k_pool"], c["v_pool"], c["tables"], c["seen"], c["bs"],
+        keep=scores >= paged_layer._select_dense(scores, 12)[..., None])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    records = dispatch()
+    assert ("paged_index_scores", "fallback", "unsupported_shape") in records
+    assert ("topk_threshold", "fallback", "unsupported_shape") in records
+    assert ("paged_mha", "fallback", "unsupported_shape") in records
+
+
+def test_the_index_write_is_one_padded_row_a_token_beside_its_k_and_v():
+    bs, W, Di = 4, 128, 16
+    pool = jnp.zeros((7, 1, bs, W), jnp.float32)
+    keys = jnp.arange(2 * 3 * Di, dtype=jnp.float32).reshape(2, 3, Di) + 1
+    tables = jnp.asarray([[2, 5], [4, 1]], jnp.int32)
+    out = paged_layer._scatter_index(pool, keys, tables, jnp.asarray([3, 0]),
+                                     jnp.asarray([3, 1]), bs, trash=6)
+    out = np.asarray(out)
+    np.testing.assert_array_equal(out[2, 0, 3, :Di], np.asarray(keys)[0, 0])   # token 3
+    np.testing.assert_array_equal(out[5, 0, :2, :Di], np.asarray(keys)[0, 1:])  # tokens 4, 5
+    np.testing.assert_array_equal(out[4, 0, 0, :Di], np.asarray(keys)[1, 0])
+    assert not out[..., Di:].any() and not out[1].any() and not out[4, 0, 1:].any()
+    assert out[6, 0, 0].any() and not out[6, 0, 1:].any()         # padding: the trash page
