@@ -16,7 +16,7 @@ import time
 
 import numpy as np
 
-from benchmark import harness, traffic, weights
+from benchmark import harness, tails, traffic, weights
 
 
 def buckets(lo, hi):
@@ -28,6 +28,8 @@ def buckets(lo, hi):
 
 
 class Driver:
+    gaps = ()                             # set-up's rounds count none
+
     def __init__(self, cell, seed, rec, devices=None, seconds=0.0):
         import jax
         import jax.numpy as jnp
@@ -139,7 +141,7 @@ class Driver:
                     self.tokens_out += 1
             req["n"] += new
         attrs.update(prefill_tokens=prefill, decode_rows=decode_rows, context_tokens=context,
-                     seqs=len(before))
+                     seqs=len(before), gaps=len(self.gaps))
         for uid in done:
             req = self.active.pop(uid)
             if self.measuring and req["in_window"] is not None:
@@ -170,6 +172,8 @@ class Driver:
             req["in_window"] = False          # their first token came in set-up
         n_started = len(self.active)
         sent_before = sum(getattr(self, "cursor", []))
+        n_spans = len(rec.spans)
+        no_first_token_at_close = None
         t0 = time.perf_counter()
         t = t0
         if self.load["loop"] == "open":
@@ -185,6 +189,8 @@ class Driver:
                 if now >= seconds and not pending:
                     self.measuring = False    # gaps and tokens count inside the window only
                     waiting = any(r["n"] == 0 for r in self.active.values())
+                    if no_first_token_at_close is None:
+                        no_first_token_at_close = sum(r["n"] == 0 for r in self.active.values())
                     if not waiting or now >= seconds + mix["drain_first_tokens_s"]:
                         break
                 if self.sched.has_work:
@@ -206,11 +212,28 @@ class Driver:
             attempted = n_started + sum(self.cursor) - sent_before
             generator_late_ms = 0.0
         elapsed = t_end - t0
-        rounds = rec.named("round")
+        # what the tail stands on, from the window's own gaps and rounds
+        # (benchmark/tails.py); computed here, once the window has closed
+        rounds, gap_round, n_gaps = [], [], 0
+        for name, a, b, at in rec.spans[n_spans:]:
+            if name == "round" and a < t0 + seconds:
+                gap_round += [len(rounds)] * (at["gaps"] - n_gaps)
+                n_gaps = at["gaps"]
+                rounds.append((round(b - t0, 5), round(1e3 * (b - a), 3),
+                               at["prefill_tokens"], at["decode_rows"]))
+        gaps_ms = [round(1e3 * g, 3) for g in self.gaps]
+        # what the result's line says of the window beside its metrics
+        tail = {"gaps": len(gaps_ms), **tails.describe(gaps_ms, gap_round, rounds),
+                "rounds": len(rounds),
+                "in_rounds_share": sum(ms for _, ms, _, _ in rounds) / 1e3 / elapsed,
+                "no_first_token_at_close": no_first_token_at_close,
+                "generator_late_ms": generator_late_ms}
         with open(os.path.join(out_dir, "window.json"), "w") as f:
             json.dump({"ttft_s": self.ttft, "gaps_ms_p50": 1e3 * (harness.percentile(self.gaps, 50) or 0),
-                       "rounds": len(rounds), "finished": len(self.finished),
-                       "generator_late_ms": generator_late_ms}, f)
+                       "finished": len(self.finished), **tail,
+                       "gaps_ms": gaps_ms, "gap_round": gap_round,
+                       "round_fields": ["end_s", "ms", "prefill_tokens", "decode_rows"],
+                       "rounds": rounds}, f)
         p = harness.percentile
         return {"ttft_p90_s": p(self.ttft, 90),
                 "token_gap_p99_ms": 1e3 * p(self.gaps, 99) if self.gaps else None,
@@ -220,7 +243,7 @@ class Driver:
                 "tokens_out": self.tokens_out, "gaps": len(self.gaps),
                 "token_gap_p50_ms": 1e3 * p(self.gaps, 50) if self.gaps else None,
                 "generator_late_ms": generator_late_ms,
-                "programs_warmed": self.programs_warmed}
+                "programs_warmed": self.programs_warmed, "tail": tail}
 
     def release(self):
         import jax

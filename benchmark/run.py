@@ -79,6 +79,8 @@ def run_cell(cell, seed, seconds, trace, devices, device_info, t_start, out_dir)
 
     result = {"correct": correct, "attempted": int(facts["attempted"]),
               "failed": int(facts["failed"]), "metrics": {}, "device": device}
+    if "tail" in facts:               # a serving window: what its tail stood on (benchmark/tails.py)
+        result["window"] = facts["tail"]
     if not trace:
         facts = dict(facts, setup_s=setup_s)
         for m in cell.end_to_end:
@@ -100,6 +102,8 @@ def run_cell(cell, seed, seconds, trace, devices, device_info, t_start, out_dir)
                 result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
         for note in ctx["notes"]:
             print(note, flush=True)
+    # every number compared beside its limit: last in the line, last on standard error
+    result["compared"] = {name: {"value": value, "limit": lim} for name, value, lim in checks}
     for line in report:
         print(line, file=sys.stderr, flush=True)
     return result

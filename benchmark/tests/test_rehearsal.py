@@ -12,7 +12,7 @@ import time
 import numpy as np
 import pytest
 
-from benchmark import harness, run
+from benchmark import harness, run, tails
 from benchmark.tests.conftest import TINY
 
 CELLS = ["gpt2-tiny.train-tiny", "mistral-tiny.chat-tiny", "mistral-tiny.closed-tiny"]
@@ -33,6 +33,28 @@ def test_cell_end_to_end(name, tiny_bench, cpu_device, tmp_path, capsys):
     out, err = capsys.readouterr()
     assert "setup_breakdown_s" in out and "compared " in out
     assert err.strip().splitlines()[-1].startswith("reference took")
+    # every number compared beside its limit, last in the result's line
+    assert list(result)[-1] == "compared" and len(result["compared"]) >= 2
+    assert all(c["value"] <= c["limit"] for c in result["compared"].values())
+    if name.startswith("mistral"):
+        # what the tail stood on (benchmark/tails.py), and the window's own gaps and rounds
+        tail = result["window"]
+        with open(tmp_path / "window.json") as f:
+            dump = json.load(f)
+        assert tail["gaps"] == len(dump["gaps_ms"]) == len(dump["gap_round"]) > 0
+        assert tail["token_gap_p99_rank"] == tails.p99_rank(tail["gaps"])
+        high, low = tail["token_gap_plateau_ms"]
+        p99 = result["metrics"]["token_gap_p99_ms"]["value"]
+        assert high >= round(p99, 3) >= low > 0
+        assert tail["stall_rounds"] == len(tails.stall_rounds(dump["rounds"])) >= 0
+        assert dump["round_fields"] == ["end_s", "ms", "prefill_tokens", "decode_rows"]
+        assert len(dump["rounds"]) == tail["rounds"]
+        # a gap ends in the round that gave its token: one a decode row
+        per_round = [dump["gap_round"].count(i) for i in range(len(dump["rounds"]))]
+        assert per_round == [r[3] for r in dump["rounds"]]
+        assert tails.describe(dump["gaps_ms"], dump["gap_round"], dump["rounds"]) == \
+            {k: dump[k] for k in ("token_gap_p99_rank", "token_gap_plateau_ms", "token_gap_on_plateau",
+                                  "stall_rounds", "token_gap_p99_with_6_stalls_ms")}
     if name.startswith("gpt2"):
         with open(tmp_path / "step_times.json") as f:
             steps = json.load(f)

@@ -83,7 +83,10 @@ def test_the_real_cell_loads_through_the_harness_and_keeps_the_catalog_numbers()
     assert {m["name"] for m in cell.end_to_end} == {"serve_tokens_per_s", "setup_s"}
     per_layer = {m["name"] for m in cell.per_layer}
     # ``round_ms.decode`` reads rounds WITHOUT prompt tokens: this traffic has none
-    assert per_layer == {"mla_attn_roofline.serve", "mla_device_share.serve"}
+    assert per_layer == {"mla_attn_roofline.serve", "mla_device_share.serve",
+                         # PR 39's four: what the host did for each dispatch
+                         "host_exposed_ms.serve", "host_prelaunch_ms.serve",
+                         "fetch_tail_ms.serve", "dispatch_host_ms.serve"}
     for name in per_layer:
         with open(os.path.join(cell.metrics_dir, name + ".json")) as f:
             spec = json.load(f)
