@@ -13,6 +13,7 @@ from typing import Iterable, List, Tuple
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
@@ -31,16 +32,24 @@ class SchedulingResult:
     reason: str = "ok"
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 6), donate_argnums=(4,))
-def packed_forward(forward_fn, cfg, layout, params, cache, packed, verify_k):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 7), donate_argnums=(4,))
+def packed_forward(forward_fn, cfg, layout, params, cache, packed, kept,
+                   verify_k):
     """The program of a dispatch, for every family: a dispatch's host arrays
     arrive as the ONE int32 buffer ``ragged_wrapper.pack`` made, are sliced
-    back out by its ``layout`` (tokens, lengths, positions, then the cache
-    groups' tables) and go to the family's ``forward_fn(cfg, params, cache,
-    tokens, q_len, seen, tables)``, its verify forward where ``verify_k`` is
-    set. ``cache`` is donated; the family's own jit is inlined."""
+    back out by its ``layout`` (tokens, lengths, positions, the tokens'
+    sources, then the cache groups' tables) and go to the family's
+    ``forward_fn(cfg, params, cache, tokens, q_len, seen, tables)``, its
+    verify forward where ``verify_k`` is set. A row whose source is not -1
+    takes its one new token from ``kept`` at that place: the id the round
+    before sampled, which the host may not have fetched yet
+    (``sampling.sample_rows_packed`` left it there). ``cache`` is donated;
+    the family's own jit is inlined."""
     tables = unpack(layout, packed)
-    tokens, q_len, seen = (tables.pop(n) for n in ("tokens", "q_len", "seen"))
+    tokens, q_len, seen, src = (tables.pop(n) for n in
+                                ("tokens", "q_len", "seen", "src"))
+    tokens = tokens.at[:, 0].set(
+        jnp.where(src < 0, tokens[:, 0], kept[jnp.maximum(src, 0)]))
     extra = () if verify_k is None else (verify_k,)
     return forward_fn(cfg, params, cache, tokens, q_len, seen, tables, *extra)
 
@@ -58,7 +67,12 @@ class DispatchedRound(list):
     """What one round left on the device: [(rows, out)] per dispatch, ``rows``
     indexing the round's uids and ``out`` that dispatch's padded
     [S-bucket, ...] device array. ``InferenceEngineV2.host_fetch`` lands it
-    as ONE array with the rows in the order the round listed them."""
+    as ONE array with the rows in the order the round listed them.
+    ``round`` is the engine's count of the round that made it."""
+
+    def __init__(self, round):
+        super().__init__()
+        self.round = round
 
 
 class InferenceEngineV2:
@@ -126,6 +140,12 @@ class InferenceEngineV2:
         # the dispatch that is first of its shape (``first_seen`` on its
         # span) is the one that traced, compiled or loaded a program
         self._shapes_seen = set()
+        # the ids the last round's samplers drew, left on the device for the
+        # next round's forward (``packed_forward``'s ``kept``): one place a
+        # row of a round, so its length is the engine's limit whatever the
+        # round's buckets; and where each uid's id lies in it
+        self._kept_ids = jnp.zeros((sm.max_ragged_sequence_count,), jnp.int32)
+        self._kept_at = {}
         # [sequence bucket, chunk bucket] of each dispatch of the last round
         self.last_batch_shapes = []
         # of the last round's dispatches, summed: the pages of the "kv"
@@ -202,8 +222,10 @@ class InferenceEngineV2:
         tm = telemetry.get_telemetry()
         if tm.enabled:
             tm.count("host_sync", what=what)
-        with tm.span("serving/fetch", round=self.round - 1, what=what):
-            if not isinstance(value, DispatchedRound):
+        a_round = isinstance(value, DispatchedRound)
+        with tm.span("serving/fetch", what=what,
+                     round=value.round if a_round else self.round - 1):
+            if not a_round:
                 return jax.device_get(value)
             # the dispatches' padded arrays land as they are, in one
             # transfer: slicing or concatenating them on the device would
@@ -300,6 +322,12 @@ class InferenceEngineV2:
             return SchedulingResult(False, "no free state slot")
         return SchedulingResult(True)
 
+    def can_admit(self) -> bool:
+        """Whether a sequence this engine does not track yet could take its
+        first token now: a page of every paged group, a slot of state, a
+        place among the tracked sequences."""
+        return self.can_schedule([None], [1]).success
+
     def get_remaining_block_capacity(self, uid: int) -> int:
         seq = self._state.get_sequence(uid)
         if seq is None:
@@ -309,7 +337,8 @@ class InferenceEngineV2:
     # -- serving (reference engine_v2.py:107) ------------------------------
     def _forward_device(self, batch_uids: List[int],
                         batch_tokens: List[np.ndarray],
-                        verify_k: int = None, defer_commit=(), sample=None):
+                        verify_k: int = None, defer_commit=(), sample=None,
+                        device_rows=()):
         """Run one round's rows through the ragged forward, dispatched by
         chunk-length class (``dispatch_rows``): the rows of one new token
         together as [D, 1] (a verify round's short rows as [D, max(8, k)]),
@@ -330,7 +359,10 @@ class InferenceEngineV2:
         dispatch's logits and rows behind its forward (the on-device
         sampler); it returns what goes in the logits' place, and how many of
         the rows sample (``sampled_rows`` on the dispatch's span: 0 where
-        the sampler took the argmax and sorted nothing)."""
+        the sampler took the argmax and sorted nothing). ``device_rows``:
+        the rows whose one new token is the id this engine's last round
+        sampled for the same uid and left on the device; what
+        ``batch_tokens`` holds for them is not read."""
         lengths = [len(t) for t in batch_tokens]
         verdict = self.can_schedule(batch_uids, lengths)
         if not verdict.success:
@@ -343,7 +375,10 @@ class InferenceEngineV2:
         sm = self._config.state_manager
         kv = self._state.kv_cache
         caching = self._state.prefix_cache is not None
-        parts, self.last_batch_shapes = DispatchedRound(), []
+        if device_rows and caching:
+            raise RuntimeError("the prefix cache digests a block's token ids: "
+                               "every row's tokens have to be the host's")
+        parts, self.last_batch_shapes = DispatchedRound(rnd), []
         further = self._state.has_further_groups
         self.last_window_pages_freed = self.last_state_slots = 0
         self.last_live_pages = 0
@@ -377,8 +412,10 @@ class InferenceEngineV2:
                     context_tokens += seq.seen_tokens
                 live_pages += -(-(seq.seen_tokens + len(toks))
                                 // self._state.kv_block_size)
-                wrapper.insert_sequence(uid, np.asarray(toks, np.int32),
-                                        seq.seen_tokens, seq.kv_blocks)
+                wrapper.insert_sequence(
+                    uid, np.asarray(toks, np.int32), seq.seen_tokens,
+                    seq.kv_blocks,
+                    self._kept_at[uid] if i in device_rows else -1)
                 seqs.append(seq)
             arrays = wrapper.build(min_seqs, min_tokens)
             seq_bucket, chunk_bucket = arrays["tokens"].shape
@@ -431,7 +468,7 @@ class InferenceEngineV2:
             # the program slices by ``layout``. It goes to the jitted call as
             # the numpy array it is: the call's fast path moves it for a
             # third of what ``jnp.asarray`` takes first (PERF.md, PR 40)
-            fields = {**arrays, **tables}    # tokens, q_len, seen, tables
+            fields = {**arrays, **tables}   # tokens, q_len, seen, src, tables
             if len(fields) != len(arrays) + len(tables):
                 raise ValueError(f"a cache group's table is named as one of "
                                  f"the batch's own arrays: {list(tables)}")
@@ -447,7 +484,8 @@ class InferenceEngineV2:
             out, cache = packed_forward(
                 self._ragged_forward if verify_k is None
                 else self._verify_forward, self._model_config, layout,
-                self._params, self._state.cache_view(), packed, verify_k)
+                self._params, self._state.cache_view(), packed,
+                self._kept_ids, verify_k)
             self._state.cache_update(cache)
             part.end()
             programs = 1
@@ -481,6 +519,9 @@ class InferenceEngineV2:
                     self._state.commit_cached_blocks(seq)
             sp.end()
         self.round = rnd + 1
+        # the samplers wrote row i's id at place i (``_packed_sampler``)
+        self._kept_at = {uid: i for i, uid in enumerate(batch_uids)} \
+            if sample is not None and verify_k is None else {}
         return parts
 
     def _note_further_groups(self, sp, seqs):
@@ -507,9 +548,8 @@ class InferenceEngineV2:
             return None
         return self._state.take_slot(self._state.get_or_create_sequence(uid))
 
-    @staticmethod
-    def _packed_sampler(sampler, temperatures, top_ks, top_ps, seeds,
-                        positions):
+    def _packed_sampler(self, sampler, temperatures, top_ks, top_ps, seeds,
+                        positions, keep=False):
         """``sample(logits, rows)``: ``sampler(logits, fparams, iparams)``
         with the five per-row parameter vectors of a dispatch's ``rows``
         packed into two host arrays of the logits' padded row count, so that
@@ -517,7 +557,9 @@ class InferenceEngineV2:
         math, bounds a fleet stepping several schedulers per round — beside
         the number of ``rows`` whose temperature is above 0 (any, and the
         sampler sorts every row's vocabulary; none, and it takes the
-        argmax: ``sampling.py``)."""
+        argmax: ``sampling.py``). ``keep``: the sampler also writes row i of
+        the round's id at place i of the ids kept on the device (a fourth
+        line of ``iparams``; a padded row's place is past the end)."""
         # arbitrary Python-int seeds (the host sampler accepted any) fold
         # deterministically into the int31 space PRNGKey wants
         seeds = [int(s) & 0x7FFFFFFF for s in seeds]
@@ -527,12 +569,18 @@ class InferenceEngineV2:
             fparams = np.zeros((2, s_max), np.float32)
             fparams[0, :n] = [temperatures[i] for i in rows]
             fparams[1, :n] = [top_ps[i] for i in rows]
-            iparams = np.zeros((3, s_max), np.int32)
+            iparams = np.zeros((3 + keep, s_max), np.int32)
             iparams[0, :n] = [top_ks[i] for i in rows]
             iparams[1, :n] = [seeds[i] for i in rows]
             iparams[2, :n] = [positions[i] for i in rows]
-            return (sampler(logits, fparams, iparams),
-                    int(np.count_nonzero(fparams[0] > 0.0)))
+            if keep:
+                iparams[3, :n] = rows
+                iparams[3, n:] = len(self._kept_ids)
+                ids, self._kept_ids = sampler(logits, fparams, iparams,
+                                              self._kept_ids)
+            else:
+                ids = sampler(logits, fparams, iparams)
+            return ids, int(np.count_nonzero(fparams[0] > 0.0))
         return sample
 
     def put(self, batch_uids: List[int],
@@ -544,7 +592,7 @@ class InferenceEngineV2:
     def put_sampled_device(self, batch_uids: List[int],
                            batch_tokens: List[np.ndarray],
                            temperatures, top_ks, top_ps, seeds,
-                           positions):
+                           positions, device_rows=()):
         """``put_sampled`` without the final host fetch: returns the
         round's dispatches as a ``DispatchedRound`` of [S-bucket] int32 ids
         on the DEVICE, leaving the forwards + samplers dispatched
@@ -552,16 +600,20 @@ class InferenceEngineV2:
         two-phase scheduler step (``step_begin``/``step_finish``) uses this
         to keep several replicas' forwards in flight at once — the fleet's
         cross-replica overlap — fetching each result only when retiring
-        tokens."""
+        tokens. ``device_rows`` are the rows whose token is the id the last
+        round sampled, still on the device (``_forward_device``): a
+        scheduler that runs ahead dispatches a round before it has fetched
+        the one before."""
         from deepspeed_tpu.inference.v2.sampling import sample_rows_packed
         # the PADDED [S-bucket] ids come back: a device-side ids[:n] would
         # compile one slice program per distinct live count (n is not
         # bucketed), a cold ~10ms stall every time a request finishes.
         # ``host_fetch`` reads rows < n on the host.
         return self._forward_device(
-            batch_uids, batch_tokens, sample=self._packed_sampler(
+            batch_uids, batch_tokens, device_rows=device_rows,
+            sample=self._packed_sampler(
                 sample_rows_packed, temperatures, top_ks, top_ps, seeds,
-                positions))
+                positions, keep=True))
 
     def put_sampled(self, batch_uids: List[int],
                     batch_tokens: List[np.ndarray],

@@ -59,8 +59,9 @@ def dispatch_rows(lengths, short):
 def pack(fields):
     """A dispatch's host arrays as ONE flat int32 buffer, so that they cross
     to the device in one transfer: ``fields`` is ``{name: array}`` in the
-    order the program takes them (tokens, lengths, positions, then every
-    cache group's tables as the state manager gives them). Returns
+    order the program takes them (tokens, lengths, positions, the tokens'
+    sources, then every cache group's tables as the state manager gives
+    them). Returns
     ``(layout, packed)``: ``layout`` the ``(name, shape)`` of each field in
     order, which ``unpack`` slices by (every width is fixed for an engine, so
     it is a function of the dispatch's two buckets), ``packed`` a fresh
@@ -99,9 +100,12 @@ class RaggedBatchWrapper:
         self.clear()
 
     def clear(self):
-        self._rows = []  # (uid, tokens, seen, blocks)
+        self._rows = []  # (uid, tokens, seen, blocks, src)
 
-    def insert_sequence(self, uid, tokens, seen_tokens, kv_blocks):
+    def insert_sequence(self, uid, tokens, seen_tokens, kv_blocks, src=-1):
+        """``src``: where the row's one new token lies among the ids the
+        round before left on the device (``engine_v2.packed_forward`` reads
+        it there), -1 where ``tokens`` holds it."""
         if len(self._rows) >= self.max_seqs:
             raise ValueError(f"batch already holds {self.max_seqs} sequences")
         if len(tokens) > self.max_q:
@@ -109,7 +113,7 @@ class RaggedBatchWrapper:
         if len(kv_blocks) > self.max_blocks:
             raise ValueError(f"sequence needs {len(kv_blocks)} blocks > table width "
                              f"{self.max_blocks}")
-        self._rows.append((uid, list(tokens), seen_tokens, list(kv_blocks)))
+        self._rows.append((uid, list(tokens), seen_tokens, list(kv_blocks), src))
 
     @property
     def current_sequences(self):
@@ -117,11 +121,11 @@ class RaggedBatchWrapper:
 
     @property
     def current_tokens(self):
-        return sum(len(t) for _, t, _, _ in self._rows)
+        return sum(len(row[1]) for row in self._rows)
 
     @property
     def uids(self):
-        return [u for u, _, _, _ in self._rows]
+        return [row[0] for row in self._rows]
 
     def build(self, min_seqs=4, min_tokens=1):
         """Pad to the static [S, Q] / [S, MB] device layout.
@@ -135,7 +139,7 @@ class RaggedBatchWrapper:
         while S < len(self._rows):
             S *= 2
         S = min(S, self.max_seqs)
-        longest = max((len(t) for _, t, _, _ in self._rows), default=1)
+        longest = max((len(row[1]) for row in self._rows), default=1)
         Q = min_tokens
         while Q < longest:
             Q *= 2
@@ -144,11 +148,13 @@ class RaggedBatchWrapper:
         tokens = np.zeros((S, Q), np.int32)
         q_len = np.zeros((S,), np.int32)
         seen = np.zeros((S,), np.int32)
+        src = np.full((S,), -1, np.int32)
         block_tables = np.full((S, self.max_blocks), self.trash_block, np.int32)
-        for i, (_, toks, sn, blocks) in enumerate(self._rows):
+        for i, (_, toks, sn, blocks, at) in enumerate(self._rows):
             tokens[i, :len(toks)] = toks
             q_len[i] = len(toks)
             seen[i] = sn
+            src[i] = at
             block_tables[i, :len(blocks)] = blocks
-        return {"tokens": tokens, "q_len": q_len, "seen": seen,
+        return {"tokens": tokens, "q_len": q_len, "seen": seen, "src": src,
                 "block_tables": block_tables}

@@ -30,6 +30,8 @@ cumulative probability >= top_p, always including the top token; top-p is
 computed over the already-top-k-masked distribution).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -119,16 +121,25 @@ def verify_rows_packed(logits, fparams, iparams):
                               iparams[1], iparams[2]), logits, fparams[0])
 
 
-@jax.jit
-def sample_rows_packed(logits, fparams, iparams):
+@functools.partial(jax.jit, donate_argnums=(3,))
+def sample_rows_packed(logits, fparams, iparams, kept):
     """``sample_rows`` with the five per-row parameter vectors packed into
     two host arrays — ``fparams`` ``[2, S]`` float32 (temps, top_ps) and
-    ``iparams`` ``[3, S]`` int32 (top_ks, seeds, positions) — unpacked
-    inside the trace. Two host->device transfers per decode dispatch
-    instead of five; on CPU fleets stepping several schedulers per round
-    the per-dispatch host time is the serving bottleneck, not the math.
+    ``iparams`` ``[4, S]`` int32 (top_ks, seeds, positions, and where each
+    row's id goes in ``kept``) — unpacked inside the trace. Two
+    host->device transfers per decode dispatch instead of five; on CPU
+    fleets stepping several schedulers per round the per-dispatch host time
+    is the serving bottleneck, not the math.
+
+    Returns ``(ids, kept)``: the ``[S]`` ids for the host's one fetch a
+    round, and ``kept`` (donated; its length fixed by the engine's limits,
+    whatever ``S``) with each row's id written at ``iparams[3]``, a place
+    past its end for a padded row, which writes nothing: the next round's
+    forward reads a decode row's token there (``engine_v2.packed_forward``)
+    before the host has it.
     """
-    return _dispatch_sample(
+    ids = _dispatch_sample(
         lambda: jax.vmap(_row_sample)(logits, fparams[0], iparams[0],
                                       fparams[1], iparams[1], iparams[2]),
         logits, fparams[0])
+    return ids, kept.at[iparams[3]].set(ids, mode="drop")

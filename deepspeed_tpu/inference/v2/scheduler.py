@@ -28,11 +28,19 @@ and ``post_forward`` once per dispatch of the round, its one ``fetch``,
 ``serving/retire``) and marks each request's ``serving/admit`` /
 ``first_token`` / ``finish``: profiler annotations that cost about a
 microsecond with no profiler session and never sync. All carry the engine's
-``round``, one value per ``step()``; the engine's per-dispatch spans also its
+``round``: compose, build, dispatch and post_forward the round they
+dispatch, fetch and retire the round they fetch, ``serving/round`` the round
+whose result the ``step()`` returns; the engine's per-dispatch spans also its
 ``dispatch``, one value per forward. docs/OBSERVABILITY.md lists their
 attributes.
+
+``step()`` runs ahead by one round where no request could have joined the
+next round anyway: it composes and dispatches round n + 1 before it fetches
+round n, so the device never waits for the host between them
+(``_propose_ahead`` has the rule, docs/SERVING.md the whole of it).
 """
 
+import collections
 import dataclasses
 import time
 from typing import Dict, List, Optional
@@ -87,6 +95,12 @@ class _Request:
     # here draws at position ``len(generated) + pos_offset`` — the exact
     # position the uninterrupted stream would use (bit-exact recovery)
     pos_offset: int = 0
+    # what rounds dispatched and not yet retired will have done: prompt
+    # tokens run and tokens sampled. ``prefill_pos`` and ``generated`` move
+    # at retire, so they show a caller what ``step()`` has returned and
+    # nothing of a round in flight; composing counts both
+    flying_prefill: int = 0
+    flying_new: int = 0
     done: bool = False
     preempted: bool = False  # KV host-swapped out (scheduler preemption)
     # perf_counter timestamps: submit_ts and first_sched_ts always (0.0 =
@@ -98,6 +112,20 @@ class _Request:
     @property
     def prefilling(self):
         return self.prefill_pos < len(self.prompt)
+
+    @property
+    def to_prefill(self):
+        """Prompt tokens no round has been dispatched for yet."""
+        return len(self.prompt) - self.prefill_pos - self.flying_prefill
+
+
+#: a round dispatched and not yet fetched (``step_begin``'s handle): its rows
+#: and chunks, the ids (or logits) on the device, when it was dispatched,
+#: which rows were mid-prompt, its tokens, the engine's ``round``, the tokens
+#: each row will have sampled (0 or 1), and whether it was dispatched ahead
+_Pending = collections.namedtuple(
+    "_Pending", "uids chunks ids logits t_fwd was_prefilling sched_tokens "
+                "rnd news ahead")
 
 
 class SplitFuseScheduler:
@@ -163,6 +191,16 @@ class SplitFuseScheduler:
         # above 0, so the device sampler sorted every row's vocabulary; in
         # the others it took the argmax and sorted nothing
         self.dispatches_sorted = 0
+        # run-ahead: rounds composed and dispatched before the round before
+        # them was fetched, their rows whose token came from the device (the
+        # sums of the ``serving/compose`` spans' ``ahead``, ``ahead_rows``),
+        # and of those rows the ones that had ended (an eos in the round
+        # before, a cancel) and rode the round for nothing
+        self.rounds_ahead = 0
+        self.ahead_rows = 0
+        self.ahead_rows_dropped = 0
+        # the round dispatched ahead, ``step_finish``'s to fetch next
+        self._flying = None
         # device_sampling=True (default) fuses temperature/top-k/top-p and
         # the categorical draw into the decode step on the accelerator: the
         # host receives one int32 per sequence instead of a [S, vocab] float
@@ -370,7 +408,9 @@ class SplitFuseScheduler:
         blocks — device-resident or host-swapped — and records the terminal
         ``serving/e2e_s`` + ``req/cancel`` lane, so cancellation never leaks
         blocks or silently drops the worst latencies from replay
-        percentiles. Call between steps (the scheduler is synchronous).
+        percentiles. Call between steps (the scheduler is synchronous). A
+        request riding a round in flight is cancelled like any other: the
+        retire of that round drops its id (``ahead_rows_dropped``).
         Returns True iff a live request was cancelled."""
         r = self._requests.get(uid)
         if r is None or r.done:
@@ -453,7 +493,9 @@ class SplitFuseScheduler:
 
     @property
     def has_work(self):
-        return any(not r.done for r in self._requests.values())
+        """A request unfinished, or a round in flight still to be fetched."""
+        return self._flying is not None or \
+            any(not r.done for r in self._requests.values())
 
     @staticmethod
     def _mark_finish(r, reason, rnd):
@@ -462,19 +504,50 @@ class SplitFuseScheduler:
         telemetry.span("serving/finish", uid=r.uid, round=rnd,
                        new_tokens=len(r.generated), reason=reason).end()
 
-    def _compose(self):
+    def _compose(self, ahead=False):
         """Pick (uids, token-chunks) for one forward under the budget.
 
         Decodes (1 token) first — they bound tail latency; leftover budget
-        is split across pending prefills (the SplitFuse chunking)."""
+        is split across pending prefills (the SplitFuse chunking).
+
+        ``ahead``: the round before this one is still in flight, and the
+        rows count what it will have done (``flying_prefill``,
+        ``flying_new``). A decode row that rode it has its token on the
+        device: its row is in the third value returned (empty otherwise). A
+        row that ends in flight by count, or at the context roof, is left
+        out, and nothing is evicted. The fourth value is then whether this
+        round is CLOSED: a request submitted from now on could take nothing
+        of it, as this method would compose it after the retire (None when
+        not ``ahead``). Closed is: every sequence slot taken, or the token
+        budget spent, or no page or slot of state for a sequence the engine
+        does not track yet; and never where this is not what ``_propose``
+        would compose after the retire (a sequence on the host to resume, a
+        row at the roof to evict, pages a row ending in flight gives back
+        that a chunk here was cut for)."""
         max_ctx = self._engine._config.state_manager.max_context
         tm = telemetry.get_telemetry()
         uids, chunks, budget = [], [], self._budget
+        device_rows = set()
+        # ahead: a row in flight ends (its pages and its slot are free once
+        # it is retired); ``_propose`` would compose another round
+        ending = differs = False
         for r in list(self._requests.values()):
-            if r.done or r.prefilling or r.preempted or len(uids) >= self._max_seqs:
+            if r.done:
                 continue
-            pos = len(r.prompt) + len(r.generated)
+            if r.preempted:
+                differs = True
+                continue
+            if r.to_prefill > 0 or len(uids) >= self._max_seqs:
+                continue
+            n_new = len(r.generated) + r.flying_new
+            pos = len(r.prompt) + n_new
+            if r.flying_new and (n_new >= r.max_new_tokens or pos >= max_ctx):
+                ending = True
+                continue
             if pos >= max_ctx:
+                if ahead:
+                    differs = True
+                    continue
                 # context capacity reached: retire with what it has — the
                 # request can never schedule again and must not wedge others.
                 # This IS the request's terminal event: record e2e latency
@@ -496,8 +569,11 @@ class SplitFuseScheduler:
                 continue
             if budget < 1:
                 break
-            nxt = r.generated[-1]
-            chunk = [nxt]
+            if r.flying_new:
+                device_rows.add(len(uids))
+                chunk = [0]          # not read: the token is on the device
+            else:
+                chunk = [r.generated[-1]]
             if self._spec:
                 # drafts bounded by the verify width, the row's remaining
                 # token quota (emitting past max_new is wasted work), the
@@ -514,13 +590,15 @@ class SplitFuseScheduler:
             chunks.append(np.asarray(chunk, np.int32))
             budget -= len(chunk)
         for r in self._requests.values():
-            if r.done or not r.prefilling or r.preempted or r.uid in uids:
+            if r.done or r.to_prefill < 1 or r.preempted or r.uid in uids:
                 continue
             if len(uids) >= self._max_seqs or budget < 1:
                 break
             room, _ = self._engine.query(r.uid, budget,
                                          self._engine.free_blocks)
-            take = min(budget, room, len(r.prompt) - r.prefill_pos)
+            take = min(budget, room, r.to_prefill)
+            if ending and take < min(budget, r.to_prefill):
+                differs = True       # cut for pages the retire gives back
             if take < 1:
                 continue
             if self._prefix_caching and r.prefill_pos == 0 and \
@@ -542,11 +620,17 @@ class SplitFuseScheduler:
                 if matched:
                     r.prefill_pos = matched
                     self.prefill_tokens_saved += matched
-                    take = min(budget, room, len(r.prompt) - r.prefill_pos)
+                    take = min(budget, room, r.to_prefill)
+            at = r.prefill_pos + r.flying_prefill
             uids.append(r.uid)
-            chunks.append(r.prompt[r.prefill_pos:r.prefill_pos + take])
+            chunks.append(r.prompt[at:at + take])
             budget -= take
-        return uids, chunks
+        if not ahead:
+            return uids, chunks, device_rows, None
+        closed = not differs and (
+            len(uids) >= self._max_seqs or budget < 1
+            or not (ending or self._engine.can_admit()))
+        return uids, chunks, device_rows, closed
 
     def _try_resume(self):
         """Swap preempted sequences back in (oldest first) while device
@@ -636,7 +720,7 @@ class SplitFuseScheduler:
         returns the rows the shrink loop dropped and the sequences
         preempted (0 or 1), for the ``serving/compose`` span."""
         self._try_resume()
-        uids, chunks = self._compose()
+        uids, chunks, _, _ = self._compose()
         if not uids:
             # nothing composable but preempted work pending and unresumable:
             # that's starvation too (e.g. a request whose resume needs more
@@ -689,11 +773,43 @@ class SplitFuseScheduler:
         self._starved = 0
         return uids, chunks, shrunk, 0
 
+    def _propose_ahead(self):
+        """Round n + 1's (uids, chunks, rows whose token is on the device)
+        while round n is in flight, or None: the round is then composed as
+        ever, after n has been retired and the caller has had its turn to
+        submit. Not None only where the composition is CLOSED (``_compose``)
+        and is what ``_propose`` would return after the retire with nothing
+        more submitted: no sequence to resume, nothing to shrink, preempt or
+        shed. So no request is admitted a round later for it. Changes
+        nothing."""
+        uids, chunks, device_rows, closed = self._compose(ahead=True)
+        if not (uids and closed and self._engine.can_schedule(
+                uids, [len(c) for c in chunks]).success):
+            return None
+        return uids, chunks, device_rows
+
     def step(self):
-        """One scheduling round + forward. Returns uids finished this round."""
-        with telemetry.span("serving/round", round=self._engine.round):
-            pending = self.step_begin()
-            return self.step_finish(pending) if pending is not None else []
+        """One scheduling round + forward. Returns uids finished this round.
+
+        Where it can, it first composes and dispatches the NEXT round, then
+        fetches and retires this one: that round stays in flight and is the
+        next call's to return (at most one beyond the one being fetched).
+        Whether it can is decided round by round (``_propose_ahead``), and
+        never for a scheduler that speculates (the accept walk decides the
+        next chunk), samples on the host, hands sequences off at retire
+        (``on_finish``) or caches prefixes (a block's digest needs the ids).
+        A caller sees the round returned and nothing of the one in flight."""
+        pending, self._flying = self._flying, None
+        with telemetry.span("serving/round", round=pending.rnd if pending
+                            else self._engine.round):
+            if pending is None:
+                pending = self._begin()
+            if pending is None:
+                return []
+            if self._device_sampling and not self._spec and \
+                    not self._prefix_caching and self.on_finish is None:
+                self._flying = self._begin(ahead=True)
+            return self.step_finish(pending)
 
     def step_begin(self):
         """Compose + dispatch one round WITHOUT fetching the result.
@@ -703,7 +819,15 @@ class SplitFuseScheduler:
         asynchronously dispatched in between — a fleet stepping N replicas
         begins them all, then finishes them all, so the forwards run
         concurrently across submeshes instead of serializing on each
-        replica's host fetch. ``step()`` is the fused single-replica form."""
+        replica's host fetch. ``step()`` is the fused single-replica form,
+        and the only one that runs ahead; a round it left in flight is the
+        round begun here."""
+        pending, self._flying = self._flying, None
+        return pending or self._begin()
+
+    def _begin(self, ahead=False):
+        """``step_begin``'s work; ``ahead``: while the round before is in
+        flight, and only if ``_propose_ahead`` has a round for it."""
         tm = telemetry.get_telemetry()
         enabled = tm.enabled
         rnd = self._engine.round
@@ -711,15 +835,21 @@ class SplitFuseScheduler:
         sched_tokens = prefill_tokens = 0
         was_prefilling = []
         with tm.span("serving/compose", round=rnd) as sp:
-            uids, chunks, shrunk, preempted = self._propose()
+            shrunk = preempted = 0
+            if ahead:
+                uids, chunks, device_rows = \
+                    self._propose_ahead() or ([], [], ())
+            else:
+                uids, chunks, shrunk, preempted = self._propose()
+                device_rows = ()
             if enabled:
                 t_fwd = _now()
             for row, uid in enumerate(uids):
                 r = self._requests[uid]
                 n = len(chunks[row])
                 sched_tokens += n
-                was_prefilling.append(r.prefilling)
-                if r.prefilling:
+                was_prefilling.append(r.to_prefill > 0)
+                if was_prefilling[row]:
                     prefill_tokens += n
                 if r.first_sched_ts == 0.0:
                     r.first_sched_ts = _now()
@@ -743,18 +873,21 @@ class SplitFuseScheduler:
             sp.set(seqs=len(uids), prefill_tokens=prefill_tokens,
                    decode_rows=len(uids) - sum(was_prefilling),
                    long_rows=sum(len(c) > short for c in chunks),
-                   shrunk=shrunk, preempted=preempted)
+                   shrunk=shrunk, preempted=preempted,
+                   ahead=int(ahead and bool(uids)),
+                   ahead_rows=len(device_rows))
         if not uids:
             return None
+        reqs = [self._requests[u] for u in uids]
         if self._spec:
-            reqs = [self._requests[u] for u in uids]
             # each row's LAST verify column samples at: the next stream
             # position after the chunk for decode rows (len(generated)
             # counts chunk[0], drafts follow), the first generated position
             # for prefill rows (mid-prompt rows discard their ids anyway)
-            positions = [len(r.generated) + r.pos_offset if r.prefilling
+            positions = [len(r.generated) + r.pos_offset if prefilling
                          else len(r.generated) + len(c) - 1 + r.pos_offset
-                         for r, c in zip(reqs, chunks)]
+                         for r, c, prefilling in
+                         zip(reqs, chunks, was_prefilling)]
             # rows that can roll back must not commit prefix-cache blocks
             # until the accept walk ran (a rejected draft in the chain
             # cache would poison every future match)
@@ -768,14 +901,15 @@ class SplitFuseScheduler:
                 positions=positions, k_max=self._kmax, defer_commit=defer)
             logits = None
         elif self._device_sampling:
-            reqs = [self._requests[u] for u in uids]
             ids = self._engine.put_sampled_device(
                 uids, chunks,
                 temperatures=[r.temperature for r in reqs],
                 top_ks=[r.top_k for r in reqs],
                 top_ps=[r.top_p for r in reqs],
                 seeds=[r.seed for r in reqs],
-                positions=[len(r.generated) + r.pos_offset for r in reqs])
+                positions=[len(r.generated) + r.flying_new + r.pos_offset
+                           for r in reqs],
+                device_rows=device_rows)
             logits = None
         else:
             logits = self._engine.put(uids, chunks)
@@ -795,14 +929,26 @@ class SplitFuseScheduler:
         self.sparse_rows += self._engine.last_sparse_rows
         self.selected_tokens += self._engine.last_selected_tokens
         self.dispatches_sorted += self._engine.last_dispatches_sorted
-        return (uids, chunks, ids, logits, t_fwd, was_prefilling,
-                sched_tokens, rnd)
+        self.rounds_ahead += ahead
+        self.ahead_rows += len(device_rows)
+        # what this round will have done once retired: a prompt's tokens,
+        # and a token sampled by every row but one mid-prompt and a
+        # re-admitted row's last chunk (its sample is discarded)
+        news = []
+        for r, c, prefilling in zip(reqs, chunks, was_prefilling):
+            if prefilling:
+                r.flying_prefill += len(c)
+            news.append(int(not (r.to_prefill or r.generated))
+                        if prefilling else 1)
+            r.flying_new += news[-1]
+        return _Pending(uids, chunks, ids, logits, t_fwd, was_prefilling,
+                        sched_tokens, rnd, news, ahead)
 
     def step_finish(self, pending):
         """Fetch a dispatched round's sampled ids and retire tokens /
         finished requests. Returns uids finished this round."""
         (uids, chunks, ids, logits, t_fwd, was_prefilling, sched_tokens,
-         rnd) = pending
+         rnd, news, ahead) = pending
         tm = telemetry.get_telemetry()
         # t_fwd == 0.0 means telemetry was off at dispatch; recording phases
         # against a zero anchor would be garbage, so the round stays dark
@@ -827,7 +973,17 @@ class SplitFuseScheduler:
         n_decode_rows = decode_committed = drafted = accepted = occ_cols = 0
         for row, uid in enumerate(uids):
             r = self._requests[uid]
-            if r.prefilling:
+            r.flying_new -= news[row]
+            if was_prefilling[row]:
+                r.flying_prefill -= len(chunks[row])
+            if r.done:
+                # ended while this round was in flight (an eos in the round
+                # before, a cancel): it rode the round for nothing. Its id
+                # is dropped; its pages went at its end, which the device's
+                # in-order queue makes safe (docs/SERVING.md)
+                self.ahead_rows_dropped += ahead
+                continue
+            if was_prefilling[row]:
                 self.prefill_tokens_executed += len(chunks[row])
                 r.prefill_pos += len(chunks[row])
                 if r.prefilling:

@@ -351,9 +351,13 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
     kernel and the three grouped GEMMs in each of 12 layers, over 512 expert
     rows of which a padded row takes none; for kanana2 the latent walk in 12
     layers and the grouped GEMMs over the 16 experts held in 11) at one token
-    a row."""
+    a row, read from the host's buffer or, by the row's source, from the ids
+    the round before left on the device (the program's last array, one
+    place a row of the engine's 64)."""
     layout, lowered = _cell_program(name, rows, one_chip, monkeypatch)
     assert dict(layout)["tokens"] == (bucket, 1)
+    assert dict(layout)["src"] == (bucket,)
+    assert lowered.in_avals[0][-1].shape == (64,)
     assert lowered.compile().as_text().count("tpu_custom_call") >= kernels
 
 
@@ -463,15 +467,17 @@ def test_same_program_same_topology_same_cache_key(topo, tmp_path,
 # the sorting arm is what takes the compiler long)
 @pytest.mark.parametrize("vocab", [32000, 200064])
 def test_the_sampler_keeps_its_branch_and_sorts_in_one_arm_only(for_tpu, one_chip, vocab):
-    """``sample_rows_packed`` at a serving cell's ``[64, vocabulary]``: the
-    chip's compiler keeps the dispatch's ``conditional`` (it could have
-    flattened it to a select, which runs both arms), and the sort lies in an
-    arm's computation, not in the entry's."""
+    """``sample_rows_packed`` at a serving cell's ``[64, vocabulary]``, with
+    the ids it keeps on the device for the next round's forward (one place
+    a row of the engine's 64): the chip's compiler keeps the dispatch's
+    ``conditional`` (it could have flattened it to a select, which runs
+    both arms), and the sort lies in an arm's computation, not in the
+    entry's."""
     from deepspeed_tpu.inference.v2.sampling import sample_rows_packed
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     text = sample_rows_packed.lower(
         sds((64, vocab), jnp.float32), sds((2, 64), jnp.float32),
-        sds((3, 64), jnp.int32)).compile().as_text()
+        sds((4, 64), jnp.int32), sds((64,), jnp.int32)).compile().as_text()
     entry = text[text.index("\nENTRY "):]
     assert len(re.findall(r" conditional\(", entry)) == 1
     assert " sort(" in text and " sort(" not in entry

@@ -118,10 +118,15 @@ def _entry_args(entry, logits, temps, top_ks, top_ps, seeds, positions):
     if entry == "sample_rows":
         return sample_rows, (logits,) + _params(temps, top_ks, top_ps, seeds,
                                                 positions)
-    fn = {"sample_rows_packed": sample_rows_packed,
-          "verify_rows_packed": verify_rows_packed}[entry]
-    return fn, (logits, jnp.asarray([temps, top_ps], jnp.float32),
-                jnp.asarray([top_ks, seeds, positions], jnp.int32))
+    fparams = jnp.asarray([temps, top_ps], jnp.float32)
+    if entry == "verify_rows_packed":
+        return verify_rows_packed, (
+            logits, fparams, jnp.asarray([top_ks, seeds, positions], jnp.int32))
+    # the ids for the host; what it keeps on the device has a test of its own
+    rows = list(range(len(temps)))
+    return (lambda *args: sample_rows_packed(*args)[0]), (
+        logits, fparams, jnp.asarray([top_ks, seeds, positions, rows], jnp.int32),
+        jnp.zeros(len(rows), jnp.int32))
 
 
 def _unbranched(entry, logits, temps, top_ks, top_ps, seeds, positions):
@@ -208,6 +213,24 @@ def test_one_sampled_row_leaves_every_row_the_id_it_has_alone(entry):
     greedy = [0, 1, 3]
     np.testing.assert_array_equal(
         batch[greedy], np.argmax(np.asarray(logits), -1)[greedy])
+
+
+def test_packed_sampler_keeps_each_rows_id_at_its_place_on_the_device():
+    """``sample_rows_packed`` returns the ids twice: ``[S]`` for the host's
+    fetch, and written into the buffer the next round's forward reads, each
+    at the place ``iparams[3]`` gives; a padded row's place is past the end
+    and writes nothing, and a place no row names keeps what it held. The
+    buffer's length is the engine's, whatever the dispatch's ``S``."""
+    logits = _entry_logits("sample_rows_packed", s=4, seed=23)
+    kept = jnp.arange(100, 106, dtype=jnp.int32)
+    iparams = jnp.asarray([[0] * 4, [0] * 4, [0] * 4, [5, 2, 6, 6]], jnp.int32)
+    ids, after = sample_rows_packed(logits, jnp.zeros((2, 4), jnp.float32),
+                                    iparams, kept)
+    ids, after = np.asarray(ids), np.asarray(after)
+    np.testing.assert_array_equal(ids, np.argmax(np.asarray(logits), -1))
+    assert after.shape == (6,) and after.dtype == np.int32
+    assert after[5] == ids[0] and after[2] == ids[1]
+    np.testing.assert_array_equal(after[[0, 1, 3, 4]], [100, 101, 103, 104])
 
 
 @pytest.fixture(scope="module")

@@ -439,10 +439,10 @@ def test_scheduler_serves_and_its_counters_equal_the_spans_sums(served, tmp_path
     lengths = []
     program = engine_v2.packed_forward
 
-    def spy(forward_fn, cfg_, layout, params_, cache, packed, verify_k):
+    def spy(forward_fn, cfg_, layout, params_, cache, packed, kept, verify_k):
         fields = engine_v2.unpack(layout, jnp.asarray(packed))
         lengths.append((np.asarray(fields["seen"]), np.asarray(fields["q_len"])))
-        return program(forward_fn, cfg_, layout, params_, cache, packed, verify_k)
+        return program(forward_fn, cfg_, layout, params_, cache, packed, kept, verify_k)
 
     def run():
         for u, p in prompts.items():

@@ -2,8 +2,11 @@
 (``ragged_wrapper.pack``) and the program of a dispatch
 (``engine_v2.packed_forward``) slices them back out by the layout before it
 calls the family's forward. Every family goes through it: one paged group
-(llama: 4 arrays), a ring and a slot list beside it (phi4flash: 7), two paged
-groups (mellum2: 6), one group of one leaf (kanana2: 4), the verify forward.
+(llama: 5 arrays), a ring and a slot list beside it (phi4flash: 8), two paged
+groups (mellum2: 7), one group of one leaf (kanana2: 5), the verify forward.
+A row's source is one of the arrays: where it is not -1 the program takes
+the row's token from the ids the round before left on the device (a round
+dispatched ahead of the fetch, ``SplitFuseScheduler.step``).
 
 What is pinned: pack -> unpack gives every array back exactly; a served run
 emits, bit for bit, what it emits when each dispatch hands the family's
@@ -27,11 +30,11 @@ LIMITS = {"state_manager": {"max_ragged_sequence_count": 4, "max_ragged_batch_si
           "kv_cache": {"block_size": 4, "cache_dtype": "fp32"}}
 
 #: family -> (host arrays a dispatch packs, the tables' names in order)
-FAMILIES = {"llama": (4, ["kv"]),
-            "phi4flash": (7, ["kv", "window", "window_base", "state"]),
-            "mellum2": (6, ["kv", "window", "window_base"]),
-            "kanana2": (4, ["kv"]),
-            "llama-verify": (4, ["kv"])}
+FAMILIES = {"llama": (5, ["kv"]),
+            "phi4flash": (8, ["kv", "window", "window_base", "state"]),
+            "mellum2": (7, ["kv", "window", "window_base"]),
+            "kanana2": (5, ["kv"]),
+            "llama-verify": (5, ["kv"])}
 
 
 def _build(family):
@@ -87,11 +90,16 @@ def _serve(engine, prompts, new_tokens=6):
     return {u: np.asarray(ids).tolist() for u, ids in sched.results().items()}, shapes
 
 
-def _separate_arrays(forward_fn, cfg, layout, params, cache, packed, verify_k):
+def _separate_arrays(forward_fn, cfg, layout, params, cache, packed, kept, verify_k):
     """The dispatch as it went before the buffer: every array a device array
-    of its own, handed to the family's forward."""
-    tables = {name: jnp.asarray(a)
+    of its own, handed to the family's forward; a token the round before
+    left on the device is taken from there on the host."""
+    tables = {name: np.array(a)
               for name, a in unpack(layout, np.asarray(packed)).items()}
+    src = tables.pop("src")
+    tables["tokens"][:, 0] = np.where(src < 0, tables["tokens"][:, 0],
+                                      np.asarray(kept)[np.maximum(src, 0)])
+    tables = {name: jnp.asarray(a) for name, a in tables.items()}
     tokens, q_len, seen = (tables.pop(n) for n in ("tokens", "q_len", "seen"))
     extra = () if verify_k is None else (verify_k,)
     return forward_fn(cfg, params, cache, tokens, q_len, seen, tables, *extra)
@@ -115,9 +123,9 @@ def test_a_served_run_through_the_packed_buffer(family, monkeypatch):
 
     program = engine_v2.packed_forward
 
-    def recording_program(forward_fn, cfg, layout, params, cache, packed, verify_k):
+    def recording_program(forward_fn, cfg, layout, params, cache, packed, kept, verify_k):
         dispatched.append((layout, np.asarray(packed), verify_k))
-        return program(forward_fn, cfg, layout, params, cache, packed, verify_k)
+        return program(forward_fn, cfg, layout, params, cache, packed, kept, verify_k)
 
     monkeypatch.setattr(engine_v2, "pack", recording_pack)
     monkeypatch.setattr(engine_v2, "packed_forward", recording_program)
@@ -129,7 +137,7 @@ def test_a_served_run_through_the_packed_buffer(family, monkeypatch):
     verify_ks = set()
     for (fields, layout, packed), (got_layout, got_packed, verify_k) in zip(
             packed_fields, dispatched):
-        assert list(fields) == ["tokens", "q_len", "seen"] + table_names
+        assert list(fields) == ["tokens", "q_len", "seen", "src"] + table_names
         assert len(fields) == arrays
         assert layout == got_layout == tuple((n, a.shape) for n, a in fields.items())
         assert packed.dtype == np.int32 and packed.ndim == 1
@@ -187,9 +195,9 @@ def test_each_dispatch_of_a_round_reads_a_buffer_of_its_own(monkeypatch):
 
     program = engine_v2.packed_forward
 
-    def recording_program(forward_fn, cfg, layout, params, cache, packed, verify_k):
+    def recording_program(forward_fn, cfg, layout, params, cache, packed, kept, verify_k):
         seen.append((layout, packed))
-        return program(forward_fn, cfg, layout, params, cache, packed, verify_k)
+        return program(forward_fn, cfg, layout, params, cache, packed, kept, verify_k)
 
     monkeypatch.setattr(engine_v2, "pack", recording_pack)
     monkeypatch.setattr(engine_v2, "packed_forward", recording_program)

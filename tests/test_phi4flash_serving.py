@@ -303,16 +303,16 @@ def test_spans_carry_the_groups_and_their_sums_are_the_counters(served, tmp_path
     builds = [a for n, _, a in spans if n == "serving/build"]
     assert len(builds) == sched.dispatches - before[2] > 0
     state = sched._engine._state
-    # tokens, lengths, positions; the "kv" table; the ring's table and base;
-    # the slot ids: every group's tables cross for a dispatch, seven arrays
-    # in ONE transfer
+    # tokens, lengths, positions, the tokens' sources; the "kv" table; the
+    # ring's table and base; the slot ids: every group's tables cross for a
+    # dispatch, eight arrays in ONE transfer
     copies = [a for n, _, a in spans if n == "serving/dispatch/h2d"]
     assert [a["dispatch"] for a in copies] == [a["dispatch"] for a in builds]
     assert all(a["arrays"] == 1 for a in copies)
     width = sched._engine._max_blocks_per_seq + state.table_width["window"]
     for a, b in zip(copies, builds):
         rows, chunk = b["seq_bucket"], b["chunk_bucket"]
-        assert a["bytes"] == 4 * (rows * chunk + 2 * rows + rows * width + 2 * rows)
+        assert a["bytes"] == 4 * (rows * chunk + 3 * rows + rows * width + 2 * rows)
     for a in builds:
         assert 1 <= a["state_slots"] <= 2 and a["global_pages"] > 0
         assert a["state_slots"] <= a["window_pages"] <= a["state_slots"] * 7

@@ -81,7 +81,7 @@ def served(tmp_path_factory):
     sched.run_to_completion()         # compile outside the capture
 
     def run():
-        sched.submit(11, prompt(21), max_new_tokens=4)
+        sched.submit(11, prompt(37), max_new_tokens=4)
         sched.submit(12, prompt(9), max_new_tokens=6)
         for _ in range(3):
             sched.step()
@@ -117,27 +117,48 @@ def test_request_yields_admit_first_token_finish_with_one_uid(served):
 
 
 def test_phase_spans_lie_inside_their_round_and_carry_it(served):
-    spans, _, _ = served
+    """Every span names the round it works for: compose, build, dispatch and
+    post_forward the round they DISPATCH, which is the ``step()``'s own or,
+    run ahead, the next one; fetch and retire the round they fetch, which
+    is the round ``serving/round`` names: the one whose result the
+    ``step()`` returns."""
+    spans, sched, _ = served
     rounds = _named(spans, "serving/round")
     assert len(rounds) >= 6
     numbers = [r[3]["round"] for r in rounds]
     assert numbers == list(range(numbers[0], numbers[0] + len(rounds))), \
         "one round number per step(), whatever the number of dispatches"
+    assert sched.rounds_ahead > 0, "no round of this run was dispatched ahead"
+    ahead = {s[3]["round"] for s in _named(spans, "serving/compose")
+             if s[3]["ahead"]}
     for _, a, b, attrs in rounds:
         inside = [s for s in spans if a <= s[1] and s[2] <= b
                   and s[0] != "serving/round"]
-        assert {s[0] for s in inside} >= {"serving/" + p for p in PHASES}
-        assert all(s[3]["round"] == attrs["round"] for s in inside), inside
+        n = attrs["round"]
+        assert {s[0] for s in inside} >= {"serving/fetch", "serving/retire"}
+        for s in inside:
+            if s[0] in ("serving/fetch", "serving/retire"):
+                assert s[3]["round"] == n, s
+            elif s[3]["round"] != n:
+                # dispatched under round n: a round ahead, or a look ahead
+                # that composed nothing
+                assert s[3]["round"] == n + 1, s
+                assert n + 1 in ahead or \
+                    (s[0] == "serving/compose" and s[3]["seqs"] == 0), s
+            else:
+                assert n not in ahead, s
     # and no phase span lies outside every round
     for s in spans:
         if s[0].startswith("serving/") and s[0] != "serving/round":
             assert any(a <= s[1] and s[2] <= b for _, a, b, _ in rounds), s
     # the phases of a round do not overlap, in the order the round runs
     # them: compose, a build, a dispatch and the rows' bookkeeping per
-    # dispatch, ONE fetch, retire
-    for _, a, b, attrs in rounds:
+    # dispatch, ONE fetch, retire (a look ahead that dispatched nothing
+    # left a compose of no sequences before it)
+    for n in numbers:
         order = [s for s in spans if s[0] != "serving/round"
-                 and s[0][8:] in PHASES and s[3]["round"] == attrs["round"]]
+                 and s[0][8:] in PHASES and s[3]["round"] == n
+                 and not (s[0] == "serving/compose" and s[3]["seqs"] == 0)]
         assert all(x[2] <= y[1] for x, y in zip(order, order[1:]))
         names = [s[0][8:] for s in order]
         k = names.count("build")
@@ -160,6 +181,8 @@ def test_build_counts_real_tokens_within_padded_slots(served):
     # a round's dispatches together carry what the round composed: the rows
     # of one token in one [D, 1] batch, every other row alone in a [1, C] one
     for _, _, _, c in _named(spans, "serving/compose"):
+        if not c["seqs"]:
+            continue                  # a look ahead that dispatched nothing
         mine = [a for _, _, _, a in builds if a["round"] == c["round"]]
         total = lambda key: sum(a[key] for a in mine)
         assert c["seqs"] == total("seqs")
@@ -182,7 +205,7 @@ def test_sums_over_spans_equal_the_schedulers_counters(served):
     assert sched.real_tokens - before[1] == total("real_tokens")
     assert sched.padded_slots - before[2] == total("padded_slots")
     prefill = sum(s[3]["prefill_tokens"] for s in _named(spans, "serving/compose"))
-    assert sched.prefill_tokens_executed - before[3] == prefill == 21 + 9 + 12
+    assert sched.prefill_tokens_executed - before[3] == prefill == 37 + 9 + 12
     retired = _named(spans, "serving/retire")
     assert sum(s[3]["new_tokens"] for s in retired) == 4 + 6 + 3
     assert sum(s[3]["finished"] for s in retired) == 3
@@ -251,8 +274,8 @@ def test_dispatch_children_lie_inside_it_in_order_and_carry_its_ids(served):
 
 def test_h2d_counts_the_arrays_and_bytes_copied_for_a_dispatch(served):
     """ONE transfer a dispatch (``ragged_wrapper.pack``), holding tokens
-    ``[S, C]``, lengths and positions ``[S]`` and the one group's block table
-    ``[S, width]``, all int32."""
+    ``[S, C]``, lengths, positions and the tokens' sources ``[S]`` and the
+    one group's block table ``[S, width]``, all int32."""
     spans, sched, _ = served
     width = sched._engine._max_blocks_per_seq
     shapes = {s[3]["dispatch"]: (s[3]["seq_bucket"], s[3]["chunk_bucket"])
@@ -262,7 +285,7 @@ def test_h2d_counts_the_arrays_and_bytes_copied_for_a_dispatch(served):
     for _, _, _, a in copies:
         rows, chunk = shapes[a["dispatch"]]
         assert a["arrays"] == 1
-        assert a["bytes"] == 4 * (rows * chunk + 2 * rows + rows * width)
+        assert a["bytes"] == 4 * (rows * chunk + 3 * rows + rows * width)
 
 
 @pytest.mark.parametrize("path, programs", [("device_sampler", 2), ("put", 1)])

@@ -99,21 +99,26 @@ def _warm_pass(engine):
 
 class _Spy:
     """Stands in for the engine's program of a dispatch: keeps each
-    dispatch's arrays, as its packed buffer holds them, and logits, and a
-    copy of the pools as they were when the round began (the program donates
-    them)."""
+    dispatch's arrays, as its packed buffer holds them (a token the round
+    before left on the device taken from there, as the program takes it),
+    and logits, and a copy of the pools as they were when the round began
+    (the program donates them)."""
 
     def __init__(self, engine):
-        self.engine, self.rounds = engine, {}
+        self.engine, self.rounds, self.device_tokens = engine, {}, 0
 
-    def __call__(self, forward_fn, cfg, layout, params, cache, packed, verify_k):
+    def __call__(self, forward_fn, cfg, layout, params, cache, packed, kept, verify_k):
         rnd = self.rounds.setdefault(self.engine.round, {"dispatches": []})
         if not rnd["dispatches"]:
             rnd["pools"] = jax.tree.map(jnp.copy, cache)
         host = unpack(layout, np.asarray(packed))
-        out = packed_forward(forward_fn, cfg, layout, params, cache, packed, verify_k)
+        src, tokens = np.asarray(host["src"]), np.array(host["tokens"])
+        tokens[:, 0] = np.where(src < 0, tokens[:, 0],
+                                np.asarray(kept)[np.maximum(src, 0)])
+        self.device_tokens += int(np.sum(src >= 0))
+        out = packed_forward(forward_fn, cfg, layout, params, cache, packed, kept, verify_k)
         rnd["dispatches"].append(tuple(np.asarray(a) for a in (
-            host["tokens"], host["q_len"], host["seen"], host["kv"], out[0])))
+            tokens, host["q_len"], host["seen"], host["kv"], out[0])))
         return out
 
 
@@ -185,6 +190,7 @@ def test_each_dispatch_matches_the_round_as_one_rectangle(mixed_run):
         split += len(rnd["dispatches"]) > 1
         compared += len(expected)
     assert split >= 5 and compared >= 50, (split, compared)
+    assert spy.device_tokens > 0, "no round of this run was dispatched ahead"
 
 
 def test_dispatched_shapes_are_the_ones_warm_up_reaches(mixed_run):
