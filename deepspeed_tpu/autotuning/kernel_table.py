@@ -330,14 +330,16 @@ def resolve(kernel, dims, dtype, device_kind=None, path=None, validate=None):
     return cfg, "tuned"
 
 
-def save_table(path, device_kind, entries, generated_by, extra=None):
+def save_table(path, device_kind, entries, generated_by, extra=None,
+               sort=True):
     """Write a table doc atomically. ``entries`` maps bucket keys to
-    ``{"blocks": {...}, ...provenance...}``."""
+    ``{"blocks": {...}, ...provenance...}``; ``sort=False`` keeps their
+    order (a sweep of some kernels leaves the rest of the file as it is)."""
     doc = {
         "format_version": FORMAT_VERSION,
         "device_kind": normalize_device_kind(device_kind),
         "generated_by": generated_by,
-        "entries": dict(sorted(entries.items())),
+        "entries": dict(sorted(entries.items()) if sort else entries),
     }
     if extra:
         doc.update(extra)

@@ -35,6 +35,11 @@ GRID_STEP_SECONDS = 5e-7  # per-grid-step dispatch overhead for the proxy
 
 GMM_TUNING_EXPERTS = 4  # experts of the moe_ffn_gmm tuning program
 
+FLASH_TUNING_B, FLASH_TUNING_H = 2, 4  # the flash_mha tuning program's
+FLASH_TUNING_BH = FLASH_TUNING_B * FLASH_TUNING_H
+
+FLASH_MATMULS = 9  # [tq, tk, dh] products of a forward (2), dq (3), dkv (4)
+
 GMM_CANDIDATES_A_GEMM = 4  # tilings swept for each of the FFN's GEMM shapes
 
 #: per-chip HBM bandwidth (bytes/s) for the roofline proxy denominator
@@ -120,8 +125,15 @@ def grid_steps(kernel, dims, config):
     """Analytic grid-step count at tuning-harness batch/head sizes — the
     dispatch-overhead term of the proxy score."""
     if kernel == "flash_mha":
+        # the tuning program is causal: a block above the diagonal is a step
+        # that computes nothing and fetches nothing
+        from deepspeed_tpu.ops.pallas.flash_attention import _block_visible
         bq, bk = config["block_q"], config["block_k"]
-        return 2 * 4 * (dims["tq"] // bq) * (dims["tk"] // bk)
+        return FLASH_TUNING_BH * sum(
+            _block_visible(iq, ik, causal=True, window=None, bq=bq, bk=bk,
+                           off=dims["tk"] - dims["tq"])
+            for iq in range(dims["tq"] // bq)
+            for ik in range(dims["tk"] // bk))
     if kernel == "quantized_matmul":
         bm = min(config["block_m"], dims["m"])
         return ((dims["m"] // bm) * (dims["n"] // config["block_n"])
@@ -188,9 +200,9 @@ def build_program(kernel, dims, dtype, config):
     cfg = dict(config) if config else None
     if kernel == "flash_mha":
         from deepspeed_tpu.ops.pallas.flash_attention import flash_mha
-        B, H = 2, 4
-        qkv = tuple(jax.ShapeDtypeStruct((B, dims["tq"], H, dims["dh"]),
-                                         dtype) for _ in range(3))
+        qkv = tuple(jax.ShapeDtypeStruct(
+            (FLASH_TUNING_B, dims["tq"], FLASH_TUNING_H, dims["dh"]), dtype)
+            for _ in range(3))
 
         def loss(q, k, v):
             return jnp.sum(flash_mha(q, k, v, causal=True, block_config=cfg)
@@ -330,8 +342,23 @@ def proxy_score(kernel, dims, dtype, config, cost, device_kind):
     bw = _HBM_BYTES_PER_S.get(slug, _HBM_BYTES_PER_S["tpu_v5e"])
     flops = float(cost.get("flops", 0.0) or 0.0)
     nbytes = float(cost.get("bytes accessed", 0.0) or 0.0)
+    if kernel == "flash_mha":
+        flops += flash_logit_flops(dims, config)
     return (flops / peak + nbytes / bw
             + grid_steps(kernel, dims, config) * GRID_STEP_SECONDS)
+
+
+def flash_logit_flops(dims, config):
+    """The causal tuning program's products over the logits its blocks and
+    the walk's tiles inside them compute (``visible_share``). XLA's cost
+    analysis gives a Pallas custom-call the same cost whatever its blocks,
+    so without this the score could only prefer the fewest grid steps, and a
+    block that skips nothing scored like one that skips half."""
+    from deepspeed_tpu.ops.pallas.flash_attention import visible_share
+    tq, tk = dims["tq"], dims["tk"]
+    bq, bk = config["block_q"], config["block_k"]
+    share = visible_share(tq, tk, bq, bk, True, None)
+    return FLASH_MATMULS * 2.0 * tq * tk * dims["dh"] * FLASH_TUNING_BH * share
 
 
 def roofline_compute_seconds(flops, bytes_accessed, device_kind="tpu_v5e"):

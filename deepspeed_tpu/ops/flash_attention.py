@@ -143,9 +143,10 @@ def mha(q, k, v, bias=None, causal=True, softmax_scale=None, window=None,
             if orig_t is not None:
                 out = out[:, :orig_t]
             # named so remat policies can choose to save attention outputs
-            # (see activation_checkpointing "dots" policy) — recomputing the
-            # flash kernel in backward doubles its cost for no memory win
-            # beyond the [B,T,H,Dh] output itself
+            # (see activation_checkpointing "dots" policy): the projection
+            # behind reads it in backward. What the flash BACKWARD reads is
+            # named inside the kernel's custom_vjp (RESIDUAL_NAMES there);
+            # this name alone never kept the forward kernel from rerunning
             return jax.ad_checkpoint.checkpoint_name(out, "flash_attn_out")
         q, k, v, bias, segment_ids = orig  # fall back on the UNpadded inputs
         if orig_t is not None:

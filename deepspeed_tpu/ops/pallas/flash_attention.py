@@ -27,6 +27,7 @@ are int32 [B, Tq] / [B, Tk]; attention is masked where they differ.
 import functools
 
 import jax
+import jax.ad_checkpoint  # jax 0.9 removed the lazy `jax.ad_checkpoint` attr
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -35,6 +36,10 @@ NEG_INF = -1e9  # finite: -inf poisons fully-masked softmax rows
 
 LANES = 128     # TPU lane width; m/l scratch rows are broadcast across lanes
 SUBLANES = 8    # TPU sublane count; kv segment-id rows are sublane-replicated
+
+#: ``checkpoint_name``s of the custom_vjp's residuals that only the forward
+#: kernel can make (output and log-sum-exp; q, k, v are slices of a saved dot)
+RESIDUAL_NAMES = ("flash_attn_res_out", "flash_attn_res_lse")
 
 
 def _largest_divisor(n, candidates):
@@ -201,29 +206,157 @@ def _clamp_q(iq, ik, **kw):
     return jnp.clip(iq, lo, hi)
 
 
-def _mask_logits(s, iq, ik, qseg_ref, kseg_ref, *, causal, window, bq, bk, off):
-    """Apply causal / sliding-window / segment masking to a [bq, bk] logit
-    block. Position masks are built from iotas (no HBM mask tensors); segment
-    ids arrive lane-replicated (q: [bq, LANES]) and sublane-replicated
-    (kv: [SUBLANES, bk]) so the comparison lowers to cheap VPU broadcasts."""
+# ---------------------------------------------------------------------------
+# the walk inside a block
+#
+# A grid step holds a [bq, bk] block, as large as the chip's timing wants
+# (the whole sequence at gpt2's 1024). Where the block IS the sequence and an
+# edge crosses it, the kernels walk it in [sq, sk] sub-tiles: for a tile of
+# query rows only the key tiles a (query, key) pair can be visible in, the
+# position mask only on the tiles an edge crosses. Every bound is then a
+# Python int and the walk unrolls. With several blocks a sequence a block is
+# one tile, skipped or run whole as ``_block_visible`` says: a walk whose
+# bounds follow ``program_id`` (loops of a traced trip count) took the v5e
+# twice the whole block's time (docs/AUTOTUNING.md).
+# ---------------------------------------------------------------------------
+
+#: preferred (query rows, keys) sub-tile of each kernel's walk, as timed on
+#: the v5e at gpt2's [12, 16, 1024, 64] (docs/AUTOTUNING.md); a block the
+#: size does not divide is walked whole
+_TILES = {"fwd": (128, 256), "dq": (256, 256), "dkv": (128, 256)}
+
+
+def _tiles(kernel, tq, tk, bq, bk, causal, window):
+    """A kernel's tile at these blocks: the block itself unless it is the
+    whole sequence and an edge crosses it."""
+    if (bq, bk) != (tq, tk) or not (causal or window is not None):
+        return bq, bk
+    sq, sk = _TILES[kernel]
+    return (sq if bq % sq == 0 else bq), (sk if bk % sk == 0 else bk)
+
+
+def _ordered(a, b, c, d, n):
+    clip = lambda x, lo, hi: max(lo, min(x, hi))
+    a = clip(a, 0, n)
+    d = clip(d, a, n)
+    b = clip(b, a, d)
+    return a, b, clip(c, b, d), d
+
+
+def _k_ranges(row0, col0, n, *, sq, sk, causal, window):
+    """Key tiles ``u`` (keys ``col0 + u * sk ..``, ``n`` of them) that query
+    rows ``row0 .. row0 + sq`` (``off`` added) can see, as ``(a, b, c, d)``:
+    ``[a, b)`` the window's left edge crosses, ``[b, c)`` are visible whole,
+    ``[c, d)`` the diagonal crosses; outside ``[a, d)`` nothing is visible.
+    Where both edges cross one tile it lies in a masked range. Python ints."""
+    x = row0 - col0
+    a = b = 0
+    c = d = n
+    if window is not None:
+        a = (x - window + 1) // sk
+        b = (x - window + sq - 1) // sk + 1
+    if causal:
+        c = (x + 1) // sk
+        d = (x + sq - 1) // sk + 1
+    return _ordered(a, b, c, d, n)
+
+
+def _q_ranges(col0, row0, n, *, sq, sk, causal, window):
+    """:func:`_k_ranges` seen from keys ``col0 .. col0 + sk``: query tiles
+    ``t`` (rows ``row0 + t * sq ..``, ``off`` added): ``[a, b)`` the diagonal
+    crosses, ``[b, c)`` whole, ``[c, d)`` the window's edge crosses."""
+    y = col0 - row0
+    a = b = 0
+    c = d = n
+    if causal:
+        a = y // sq
+        b = (y + sk + sq - 2) // sq
+    if window is not None:
+        c = (y + window) // sq
+        d = (y + sk + window + sq - 2) // sq
+    return _ordered(a, b, c, d, n)
+
+
+def visible_share(tq, tk, bq, bk, causal, window):
+    """Share of the [tq, tk] square whose logits the forward computes at
+    these blocks (static, from shapes; the backward kernels' share is the
+    same while every kernel's rows divide one keys' tile, as ``_TILES``'
+    do): 1.0 without an edge, 0.625 for a causal 1024 x 1024 in one block
+    walked in tiles of 256 keys."""
+    sq, sk = _tiles("fwd", tq, tk, bq, bk, causal, window)
+    tiles = 0
+    for iq in range(tq // bq):
+        for ik in range(tk // bk):
+            for t in range(bq // sq):
+                a, _, _, d = _k_ranges(iq * bq + t * sq + tk - tq, ik * bk,
+                                       bk // sk, sq=sq, sk=sk, causal=causal,
+                                       window=window)
+                tiles += d - a
+    return tiles * sq * sk / (tq * tk)
+
+
+def _walk(ranges, step, carry):
+    """``step(i, carry, edge)`` over the tiles of a walk, unrolled; ``None``
+    for a block that is one tile, run whole (the grid step's
+    ``_block_visible`` was its bound)."""
+    if ranges is None:
+        return step(0, carry, edge=True)
+    a, b, c, d = ranges
+    for i in range(a, d):
+        carry = step(i, carry, edge=not b <= i < c)
+    return carry
+
+
+def _when(cond, fn):
+    """``pl.when`` that a static condition decides at trace time."""
+    if isinstance(cond, bool):
+        if cond:
+            fn()
+    else:
+        pl.when(cond)(fn)
+
+
+def _tile_logits(q, k, rows, cols, thr, diff, bias_ref, qseg_ref, kseg_ref, *,
+                 scale, edge, causal, window, sk):
+    """Masked, scaled logits of one [sq, sk] tile. ``thr`` is the tile's first
+    key minus its first query row (``off`` added), ``diff`` the tile's
+    ``row - column`` iota: a pair is causal-visible iff ``diff >= thr`` and
+    inside the window iff ``diff < thr + window``; only a tile an edge
+    crosses (``edge``) builds that mask. Segment ids arrive lane-replicated
+    (q: [bq, LANES]) and sublane-replicated (kv: [SUBLANES, bk]) so the
+    comparison lowers to cheap VPU broadcasts."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if bias_ref is not None:
+        s = s + bias_ref[0, 0, rows, cols].astype(jnp.float32)
     mask = None
     if qseg_ref is not None:
         # pltpu.repeat, not jnp.tile: tile lowers through a shape cast that
         # older Mosaic rejects ("unsupported shape cast")
-        qs = pltpu.repeat(qseg_ref[0], bk // LANES, 1)     # [bq, bk]
-        ks = kseg_ref[0][:1, :]                            # [1, bk]
-        mask = qs == ks
-    if causal or window is not None:
-        qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        kpos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        pm = None
+        qs = pltpu.repeat(qseg_ref[0, rows, :], sk // LANES, 1)   # [sq, sk]
+        mask = qs == kseg_ref[0, :1, cols]                        # [1, sk]
+    if edge:
         if causal:
-            pm = qpos + off >= kpos
+            pm = diff >= thr
+            mask = pm if mask is None else mask & pm
         if window is not None:
-            wm = kpos > qpos + off - window
-            pm = wm if pm is None else pm & wm
-        mask = pm if mask is None else mask & pm
+            wm = diff < thr + window
+            mask = wm if mask is None else mask & wm
     return s if mask is None else jnp.where(mask, s, NEG_INF)
+
+
+def _diff_iota(sq, sk, causal, window):
+    if not causal and window is None:
+        return None
+    return (jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1))
+
+
+def _block_ids(nq, nk, q_axis, k_axis):
+    """A block's place; 0 as a Python int on an axis of one block, so that
+    the walk's bounds are static there."""
+    return (0 if nq == 1 else pl.program_id(q_axis),
+            0 if nk == 1 else pl.program_id(k_axis))
 
 
 def _unpack_refs(refs, n_fixed, has_bias, has_seg):
@@ -271,58 +404,79 @@ def _seg_specs(bq, bk, order="qk", clamp=None):
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, causal, scale, window, bq, bk, nk, off,
+def _fwd_kernel(*refs, causal, scale, window, bq, bk, nq, nk, off, tiles,
                 has_bias, has_seg):
     (q_ref, k_ref, v_ref), bias_ref, qseg_ref, kseg_ref, rest = _unpack_refs(
         refs, 3, has_bias, has_seg)
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
-    iq, ik = pl.program_id(2), pl.program_id(3)
+    iq, ik = _block_ids(nq, nk, 2, 3)
+    sq, sk = tiles
+    dh = q_ref.shape[-1]
 
-    @pl.when(ik == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    should_run = _block_visible(iq, ik, causal=causal, window=window,
-                                bq=bq, bk=bk, off=off)
-
-    @pl.when(should_run)
-    def _body():
-        q = q_ref[0, 0]                                   # [bq, dh]
-        k = k_ref[0, 0]                                   # [bk, dh]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * scale                                     # [bq, bk]
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        s = _mask_logits(s, iq, ik, qseg_ref, kseg_ref, causal=causal,
-                         window=window, bq=bq, bk=bk, off=off)
-
-        m_prev = m_scr[:, :1]                             # [bq, 1]
-        l_prev = l_scr[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)                            # [bq, bk] f32
-        l_cur = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-
-        m_scr[...] = jnp.broadcast_to(m_cur, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_cur, l_scr.shape)
-        pv = jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, 0],
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha + pv
-
-    @pl.when(ik == nk - 1)
-    def _finish():
-        l = l_scr[:, :1]
+    def finish(rows, m, l, acc):
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+        o_ref[0, 0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
         # LSE rows are replicated across the LANES minor dim: Mosaic requires
         # the last two block dims be (8k, 128m)-aligned, so a [bq] vector
         # output is stored as [bq, LANES] (same layout as jax's own kernel).
-        lse = m_scr[:, :1] + jnp.log(jnp.maximum(l_scr[:, :1], 1e-30))
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+        lse = m + jnp.log(jnp.maximum(l, 1e-30))
+        lse_ref[0, 0, rows, :] = jnp.broadcast_to(lse, (lse.shape[0], LANES))
+
+    if nk > 1:
+        @pl.when(ik == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def _body():
+        diff = _diff_iota(sq, sk, causal, window)
+        for t in range(bq // sq):
+            rows = pl.ds(t * sq, sq)
+            row0 = iq * bq + t * sq + off
+            q = q_ref[0, 0, rows, :]                      # [sq, dh]
+
+            def step(u, carry, edge):
+                m_prev, l_prev, acc = carry
+                cols = pl.ds(u * sk, sk)
+                s = _tile_logits(q, k_ref[0, 0, cols, :], rows, cols,
+                                 ik * bk + u * sk - row0, diff, bias_ref,
+                                 qseg_ref, kseg_ref, scale=scale, edge=edge,
+                                 causal=causal, window=window, sk=sk)
+                m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_cur)
+                p = jnp.exp(s - m_cur)                    # [sq, sk] f32
+                l_cur = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[0, 0, cols, :],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return m_cur, l_cur, acc * alpha + pv
+
+            if nk == 1:     # the block is the row's every key: no scratch
+                carry = (jnp.full((sq, 1), NEG_INF, jnp.float32),
+                         jnp.zeros((sq, 1), jnp.float32),
+                         jnp.zeros((sq, dh), jnp.float32))
+            else:
+                carry = (m_scr[rows, :1], l_scr[rows, :1], acc_scr[rows, :])
+            m, l, acc = _walk(
+                _k_ranges(row0, ik * bk, bk // sk, sq=sq, sk=sk,
+                          causal=causal, window=window)
+                if nq == nk == 1 else None, step, carry)
+            if nk == 1:
+                finish(rows, m, l, acc)
+            else:
+                m_scr[rows, :] = jnp.broadcast_to(m, (sq, LANES))
+                l_scr[rows, :] = jnp.broadcast_to(l, (sq, LANES))
+                acc_scr[rows, :] = acc
+
+    _when(_block_visible(iq, ik, causal=causal, window=window, bq=bq, bk=bk,
+                         off=off), _body)
+
+    if nk > 1:
+        @pl.when(ik == nk - 1)
+        def _finish():
+            finish(slice(None), m_scr[:, :1], l_scr[:, :1], acc_scr[...])
 
 
 def _bias_spec(bias, bq, bk, order="qk", clamp=None):
@@ -356,7 +510,10 @@ def _fwd(q, k, v, bias, segment_ids, causal, scale, window, interpret,
     vt = v.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                               window=window, bq=bq, bk=bk, nk=nk, off=tk - tq,
+                               window=window, bq=bq, bk=bk, nq=nq, nk=nk,
+                               off=tk - tq,
+                               tiles=_tiles("fwd", tq, tk, bq, bk, causal,
+                                            window),
                                has_bias=bias is not None,
                                has_seg=segment_ids is not None)
     kb = dict(causal=causal, window=window, bq=bq, bk=bk, nk=nk, off=tk - tq)
@@ -407,92 +564,135 @@ def _fwd(q, k, v, bias, segment_ids, causal, scale, window, interpret,
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(*refs, causal, scale, window, bq, bk, nk, off,
+def _bwd_dq_kernel(*refs, causal, scale, window, bq, bk, nq, nk, off, tiles,
                    has_bias, has_seg):
     ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, qseg_ref,
      kseg_ref, rest) = _unpack_refs(refs, 6, has_bias, has_seg)
     dq_ref, dq_scr = rest
-    iq, ik = pl.program_id(2), pl.program_id(3)
+    iq, ik = _block_ids(nq, nk, 2, 3)
+    sq, sk = tiles
+    dh = q_ref.shape[-1]
 
-    @pl.when(ik == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
+    if nk > 1:
+        @pl.when(ik == 0)
+        def _init():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    should_run = _block_visible(iq, ik, causal=causal, window=window,
-                                bq=bq, bk=bk, off=off)
-
-    @pl.when(should_run)
     def _body():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        s = _mask_logits(s, iq, ik, qseg_ref, kseg_ref, causal=causal,
-                         window=window, bq=bq, bk=bk, off=off)
-        lse = lse_ref[0, 0][:, :1]                        # [bq, 1] (lane-replicated)
-        p = jnp.exp(s - lse)                              # [bq, bk]
-        do = do_ref[0, 0].astype(jnp.float32)             # [bq, dh]
-        dp = jax.lax.dot_general(do, v_ref[0, 0].astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        delta = delta_ref[0, 0][:, :1]
-        ds = p * (dp - delta) * scale                     # [bq, bk]
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        diff = _diff_iota(sq, sk, causal, window)
+        for t in range(bq // sq):
+            rows = pl.ds(t * sq, sq)
+            row0 = iq * bq + t * sq + off
+            q = q_ref[0, 0, rows, :]
+            do = do_ref[0, 0, rows, :].astype(jnp.float32)      # [sq, dh]
+            lse = lse_ref[0, 0, rows, :1]                 # [sq, 1] (lane-replicated)
+            delta = delta_ref[0, 0, rows, :1]
 
-    @pl.when(ik == nk - 1)
-    def _finish():
-        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+            def step(u, dq, edge):
+                cols = pl.ds(u * sk, sk)
+                k = k_ref[0, 0, cols, :]
+                s = _tile_logits(q, k, rows, cols, ik * bk + u * sk - row0,
+                                 diff, bias_ref, qseg_ref, kseg_ref,
+                                 scale=scale, edge=edge, causal=causal,
+                                 window=window, sk=sk)
+                p = jnp.exp(s - lse)                      # [sq, sk]
+                dp = jax.lax.dot_general(
+                    do, v_ref[0, 0, cols, :].astype(jnp.float32),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds = p * (dp - delta) * scale             # [sq, sk]
+                return dq + jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+            dq = _walk(
+                _k_ranges(row0, ik * bk, bk // sk, sq=sq, sk=sk,
+                          causal=causal, window=window)
+                if nq == nk == 1 else None, step,
+                jnp.zeros((sq, dh), jnp.float32) if nk == 1
+                else dq_scr[rows, :])
+            if nk == 1:
+                dq_ref[0, 0, rows, :] = dq.astype(dq_ref.dtype)
+            else:
+                dq_scr[rows, :] = dq
+
+    _when(_block_visible(iq, ik, causal=causal, window=window, bq=bq, bk=bk,
+                         off=off), _body)
+
+    if nk > 1:
+        @pl.when(ik == nk - 1)
+        def _finish():
+            dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, causal, scale, window, bq, bk, nq, off,
+def _bwd_dkv_kernel(*refs, causal, scale, window, bq, bk, nq, nk, off, tiles,
                     has_bias, has_seg):
     ((q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, qseg_ref,
      kseg_ref, rest) = _unpack_refs(refs, 6, has_bias, has_seg)
     dk_ref, dv_ref, dk_scr, dv_scr = rest
-    ik, iq = pl.program_id(2), pl.program_id(3)
+    iq, ik = _block_ids(nq, nk, 3, 2)
+    sq, sk = tiles
+    dh = k_ref.shape[-1]
 
-    @pl.when(iq == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
+    if nq > 1:
+        @pl.when(iq == 0)
+        def _init():
+            dk_scr[...] = jnp.zeros_like(dk_scr)
+            dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    should_run = _block_visible(iq, ik, causal=causal, window=window,
-                                bq=bq, bk=bk, off=off)
-
-    @pl.when(should_run)
     def _body():
-        q = q_ref[0, 0]
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, 0].astype(jnp.float32)
-        s = _mask_logits(s, iq, ik, qseg_ref, kseg_ref, causal=causal,
-                         window=window, bq=bq, bk=bk, off=off)
-        lse = lse_ref[0, 0][:, :1]
-        p = jnp.exp(s - lse)                              # [bq, bk]
-        do = do_ref[0, 0].astype(jnp.float32)
-        # dV += P^T @ dO
-        dv_scr[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[0, 0].astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        delta = delta_ref[0, 0][:, :1]
-        ds = p * (dp - delta) * scale
-        # dK += dS^T @ Q
-        dk_scr[...] += jax.lax.dot_general(ds, q.astype(jnp.float32),
-                                           (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        diff = _diff_iota(sq, sk, causal, window)
+        for u in range(bk // sk):
+            cols = pl.ds(u * sk, sk)
+            col0 = ik * bk + u * sk
+            k = k_ref[0, 0, cols, :]
+            v = v_ref[0, 0, cols, :].astype(jnp.float32)
 
-    @pl.when(iq == nq - 1)
-    def _finish():
-        dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+            def step(t, carry, edge):
+                dk, dv = carry
+                rows = pl.ds(t * sq, sq)
+                q = q_ref[0, 0, rows, :]
+                s = _tile_logits(q, k, rows, cols,
+                                 col0 - (iq * bq + t * sq + off), diff,
+                                 bias_ref, qseg_ref, kseg_ref, scale=scale,
+                                 edge=edge, causal=causal, window=window,
+                                 sk=sk)
+                p = jnp.exp(s - lse_ref[0, 0, rows, :1])  # [sq, sk]
+                do = do_ref[0, 0, rows, :].astype(jnp.float32)
+                # dV += P^T @ dO
+                dv = dv + jax.lax.dot_general(
+                    p, do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                ds = p * (dp - delta_ref[0, 0, rows, :1]) * scale
+                # dK += dS^T @ Q
+                dk = dk + jax.lax.dot_general(
+                    ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return dk, dv
+
+            dk, dv = _walk(
+                _q_ranges(col0, iq * bq + off, bq // sq, sq=sq, sk=sk,
+                          causal=causal, window=window)
+                if nq == nk == 1 else None, step,
+                (jnp.zeros((sk, dh), jnp.float32),) * 2 if nq == 1
+                else (dk_scr[cols, :], dv_scr[cols, :]))
+            if nq == 1:
+                dk_ref[0, 0, cols, :] = dk.astype(dk_ref.dtype)
+                dv_ref[0, 0, cols, :] = dv.astype(dv_ref.dtype)
+            else:
+                dk_scr[cols, :] = dk
+                dv_scr[cols, :] = dv
+
+    _when(_block_visible(iq, ik, causal=causal, window=window, bq=bq, bk=bk,
+                         off=off), _body)
+
+    if nq > 1:
+        @pl.when(iq == nq - 1)
+        def _finish():
+            dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+            dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
 
 def _bwd(causal, scale, window, interpret, blocks, res, g):
@@ -546,7 +746,8 @@ def _bwd(causal, scale, window, interpret, blocks, res, g):
         [qspec, kspec, kspec, dospec, lspec, lspec], "qk", ck)
     dq_kernel = functools.partial(
         _bwd_dq_kernel, causal=causal, scale=scale, window=window,
-        bq=bq, bk=bk, nk=nk, off=tk - tq,
+        bq=bq, bk=bk, nq=nq, nk=nk, off=tk - tq,
+        tiles=_tiles("dq", tq, tk, bq, bk, causal, window),
         has_bias=bias is not None, has_seg=seg_args is not None)
     with jax.named_scope("flash_mha_bwd_dq"):
         dq = pl.pallas_call(
@@ -571,7 +772,8 @@ def _bwd(causal, scale, window, interpret, blocks, res, g):
         [qspec2, kspec2, kspec2, qspec2, lspec2, lspec2], "kq", cq)
     dkv_kernel = functools.partial(
         _bwd_dkv_kernel, causal=causal, scale=scale, window=window,
-        bq=bq, bk=bk, nq=nq, off=tk - tq,
+        bq=bq, bk=bk, nq=nq, nk=nk, off=tk - tq,
+        tiles=_tiles("dkv", tq, tk, bq, bk, causal, window),
         has_bias=bias is not None, has_seg=seg_args is not None)
     with jax.named_scope("flash_mha_bwd_dkv"):
         dk, dv = pl.pallas_call(
@@ -621,6 +823,12 @@ def _flash_fwd(q, k, v, bias, segment_ids, causal, scale, window, interpret,
                blocks):
     out, lse = _fwd(q, k, v, bias, segment_ids, causal, scale, window,
                     interpret, blocks)
+    # the backward's residuals by name, INSIDE the custom_vjp: a remat policy
+    # that saves these names (activation_checkpointing: "dots") keeps what
+    # the backward reads, and the forward kernel runs once a layer. A name
+    # on the output outside would save a value the backward never asks for.
+    out = jax.ad_checkpoint.checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = jax.ad_checkpoint.checkpoint_name(lse, RESIDUAL_NAMES[1])
     return out, (q, k, v, bias, segment_ids, out, lse)
 
 
@@ -693,6 +901,8 @@ def _dispatch_flash(q, k, v, bias, seg, causal, scale, window, interpret,
     else:
         block_config = _resolve_blocks(tq, tk, dh, q.dtype)
     blocks = (block_config.get("block_q"), block_config.get("block_k"))
+    registry.note_kernel_facts("flash_mha", visible_share=visible_share(
+        tq, tk, *blocks, causal, window))
 
     args = [q, k, v]
     in_roles = [("data", None, "head", None), ("data", None, "head", None),

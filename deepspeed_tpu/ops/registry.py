@@ -109,6 +109,7 @@ def takes_kernel(kernel, supported, detail):
 # ---------------------------------------------------------------------------
 
 _ACTIVE_BLOCK_CONFIGS = {}  # kernel name -> last-resolved BlockConfig
+_ACTIVE_KERNEL_FACTS = {}  # kernel name -> what else its last dispatch noted
 
 
 def resolve_block_config(kernel, dims, dtype, validate=None, ladder=None):
@@ -142,13 +143,22 @@ def note_block_config(kernel, cfg, reason="env_override"):
     return cfg
 
 
+def note_kernel_facts(kernel, **facts):
+    """What a kernel's dispatch knows from its shapes beside its blocks
+    (``flash_mha``: ``visible_share``, the share of the [tq, tk] square whose
+    logits its blocks and tiles compute), for ``active_kernel_configs``."""
+    _ACTIVE_KERNEL_FACTS[kernel] = facts
+
+
 def active_kernel_configs():
-    """kernel -> {knob: value..., "source": ...} for the last block config
-    each kernel dispatched with in this process (bench ``extra`` reporting)."""
+    """kernel -> {knob: value..., "source": ..., noted facts} for the last
+    block config each kernel dispatched with in this process (bench ``extra``
+    reporting)."""
     out = {}
     for k, cfg in sorted(_ACTIVE_BLOCK_CONFIGS.items()):
         d = cfg.as_dict()
         d["source"] = cfg.source
+        d.update(_ACTIVE_KERNEL_FACTS.get(k, {}))
         out[k] = d
     return out
 

@@ -58,20 +58,22 @@ def policy_by_name(name, checkpoint_in_cpu=False):
     reference's CPU checkpointing). ``policy="nothing"`` (no remat) takes
     precedence — there is nothing to offload if everything is saved."""
     cp = jax.checkpoint_policies
+    # the Pallas flash kernel is not a dot_general: what its backward reads
+    # of it (output and log-sum-exp, named inside its custom_vjp) is saved
+    # by name, on the device, beside the attention output that callers see
+    # — otherwise backward re-runs the whole attention kernel
+    from deepspeed_tpu.ops.pallas.flash_attention import RESIDUAL_NAMES
+    flash = cp.save_only_these_names("flash_attn_out", *RESIDUAL_NAMES)
     if checkpoint_in_cpu and name != "nothing":
-        # dots offload to pinned host; the flash output (not a dot_general)
-        # is saved on device — still skipping the backward recompute
-        return cp.save_from_both_policies(
-            cp.offload_dot_with_no_batch_dims("device", "pinned_host"),
-            cp.save_only_these_names("flash_attn_out"))
+        # dots offload to pinned host. Written out: save_from_both_policies
+        # takes booleans only and raises on the offload policy's answers
+        offload = cp.offload_dot_with_no_batch_dims("device", "pinned_host")
+        return lambda prim, *args, **params: (
+            flash(prim, *args, **params) or offload(prim, *args, **params))
     return {
         "everything": cp.nothing_saveable,
-        # projections saved via the dots rule; the Pallas flash kernel is not
-        # a dot_general, so its named output is saved explicitly — otherwise
-        # backward re-runs the whole attention kernel
         "dots": cp.save_from_both_policies(
-            cp.dots_with_no_batch_dims_saveable,
-            cp.save_only_these_names("flash_attn_out")),
+            cp.dots_with_no_batch_dims_saveable, flash),
         "nothing": cp.everything_saveable,
     }[name]
 

@@ -27,6 +27,28 @@ os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")  # chip-free host: libtpu
 # must not probe the GCP metadata server (30 HTTP retries per var)
 
 
+def keep_other_kernels(kernels, entries, report, generated_by, table_path,
+                       ranking_path):
+    """A sweep of some kernels replaces THEIR entries and rankings and keeps
+    every other kernel's as it is, in its place (each entry and each sweep
+    names its mode; the table's ``generated_by`` stays the full sweep's)."""
+    from deepspeed_tpu.autotuning import kernel_table
+    swept = lambda key: key.split("|", 1)[0] in kernels
+    old = kernel_table.load_table(path=table_path)
+    if old is not None:
+        entries = {**{k: entries.get(k, v) for k, v in old["entries"].items()
+                      if k in entries or not swept(k)}, **entries}
+        generated_by = old["generated_by"]
+    if os.path.exists(ranking_path):
+        with open(ranking_path) as f:
+            was = json.load(f)
+        kept = [dict(s, mode=s.get("mode", was.get("mode")))
+                for s in was.get("sweeps", []) if s["kernel"] not in kernels]
+        report = dict(report, sweeps=kept + [dict(s, mode=report["mode"])
+                                             for s in report["sweeps"]])
+    return entries, report, generated_by
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--mode", choices=("chip-free", "on-chip"),
@@ -60,9 +82,16 @@ def main(argv=None):
                                         iters=args.iters)
     device = report["device_kind"]
 
+    out = args.out or kernel_table.table_path(device)
+    generated_by = (f"scripts/tune_kernels.py --mode {args.mode}"
+                    + (f" --topology {args.topology}"
+                       if args.mode == "chip-free" else ""))
     os.makedirs(args.results_dir, exist_ok=True)
     ranking_path = os.path.join(args.results_dir,
                                 f"kernel_tuning_{device}.json")
+    if kernels:
+        entries, report, generated_by = keep_other_kernels(
+            kernels, entries, report, generated_by, out, ranking_path)
     with open(ranking_path, "w") as f:
         json.dump(report, f, indent=1)
     print(f"ranking -> {ranking_path} "
@@ -73,11 +102,8 @@ def main(argv=None):
         print("no feasible candidates — table NOT written", file=sys.stderr)
         return 1
 
-    out = args.out or kernel_table.table_path(device)
-    generated_by = (f"scripts/tune_kernels.py --mode {args.mode}"
-                    + (f" --topology {args.topology}"
-                       if args.mode == "chip-free" else ""))
-    kernel_table.save_table(out, device, entries, generated_by)
+    kernel_table.save_table(out, device, entries, generated_by,
+                            sort=not kernels)
     print(f"table -> {out} ({len(entries)} entries)")
     missing = [k for k in (kernels or kernel_table.KERNEL_KNOBS)
                if not any(key.startswith(f"{k}|") for key in entries)]

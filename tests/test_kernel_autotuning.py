@@ -276,7 +276,8 @@ def test_env_override_beats_table(tmp_path, monkeypatch):
     kernel_table.clear_cache()
     fa.flash_mha(q, k, v, causal=True, interpret=True)
     active = registry.active_kernel_configs()["flash_mha"]
-    assert active == {"block_q": 128, "block_k": 64, "source": "env"}
+    assert active == {"block_q": 128, "block_k": 64, "source": "env",
+                      "visible_share": 0.75}   # 3 of 4 blocks of [128, 64]
 
 
 @pytest.mark.parametrize("var,val,msg", [
@@ -360,6 +361,39 @@ def test_moe_candidates_and_grid_steps_go_by_gemm(d, f):
     assert steps[0] == min(steps)
 
 
+def test_flash_score_counts_the_visible_share(monkeypatch):
+    """The causal tuning program's grid steps and products are those of the
+    logits its blocks and tiles compute: a block that skips nothing no longer
+    scores like one that skips, and with the walk inside it the largest block
+    wins on its grid steps, as the chip's timing says."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    dims = {"tq": 1024, "tk": 1024, "dh": 64}
+    steps = lambda b: kernel_tuner.grid_steps(
+        "flash_mha", dims, {"block_q": b, "block_k": b})
+    assert [steps(b) for b in (1024, 512, 256)] == [8, 8 * 3, 8 * 10]
+
+    def score(b):
+        return kernel_tuner.proxy_score(
+            "flash_mha", dims, "bfloat16", {"block_q": b, "block_k": b},
+            {"flops": 1e6, "bytes accessed": 1e6}, "tpu_v5e")
+
+    products = 9 * 2 * 1024 * 1024 * 64 * kernel_tuner.FLASH_TUNING_BH
+    fixed = 1e6 / 197e12 + 1e6 / 819e9
+    monkeypatch.setattr(fa, "_TILES", {k: (256, 256) for k in fa._TILES})
+    assert fa.visible_share(1024, 1024, 1024, 1024, True, None) == 0.625
+    assert score(1024) == pytest.approx(
+        fixed + products * 0.625 / 197e12
+        + 8 * kernel_tuner.GRID_STEP_SECONDS, rel=1e-9)
+    assert score(1024) < score(512) < score(256) < score(128)
+    # a kernel that walked nothing: the whole-sequence block computes the
+    # whole square and loses to the block that skips a quarter of it
+    monkeypatch.setattr(fa, "_TILES", {k: (1024, 1024) for k in fa._TILES})
+    assert score(1024) == pytest.approx(
+        fixed + products / 197e12 + 8 * kernel_tuner.GRID_STEP_SECONDS,
+        rel=1e-9)
+    assert score(512) < score(1024)
+
+
 def test_chip_free_rank_orders_by_proxy_score():
     fake = _fake_compile_fn(score_of=lambda i: 1e9 * i)  # later = worse
     ranking, device = kernel_tuner.chip_free_rank(
@@ -399,6 +433,44 @@ def test_tune_writes_loadable_table(tmp_path, monkeypatch):
     for dims, dtype in kernel_table.BENCH_SHAPES["flash_mha"]:
         cfg, reason = kernel_table.resolve("flash_mha", dims, dtype)
         assert reason == "tuned" and cfg.source == "table"
+
+
+def test_sweep_of_some_kernels_keeps_the_other_entries(tmp_path):
+    """``tune_kernels.py --kernels flash_mha`` replaces flash_mha's entries
+    and rankings; every other entry stays byte for byte, in its place."""
+    import importlib.util
+    import json
+    import shutil
+    spec = importlib.util.spec_from_file_location(
+        "tune_kernels", os.path.join(os.path.dirname(__file__), "..",
+                                     "scripts", "tune_kernels.py"))
+    tune_kernels = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tune_kernels)
+    checked_in = kernel_table.table_path("tpu_v5e")
+    table, ranking = tmp_path / "tpu_v5e.json", tmp_path / "ranking.json"
+    shutil.copy(checked_in, table)
+    ranking.write_text(json.dumps({"mode": "chip-free", "sweeps": [
+        {"kernel": "paged_mha", "candidates": []},
+        {"kernel": "flash_mha", "candidates": ["old"]}]}))
+    old = kernel_table.load_table(path=str(table))
+    new = {k: dict(v, score=1.0) for k, v in old["entries"].items()
+           if k.startswith("flash_mha|")}
+    entries, report, generated_by = tune_kernels.keep_other_kernels(
+        ["flash_mha"], new, {"mode": "on-chip", "sweeps": [
+            {"kernel": "flash_mha", "candidates": ["new"]}]}, "this sweep",
+        str(table), str(ranking))
+    assert generated_by == old["generated_by"]
+    assert [(s["kernel"], s["mode"], s["candidates"])
+            for s in report["sweeps"]] == [
+        ("paged_mha", "chip-free", []), ("flash_mha", "on-chip", ["new"])]
+    kernel_table.save_table(str(table), "tpu_v5e", entries, generated_by,
+                            sort=False)
+    want = open(checked_in).read().splitlines()
+    got = table.read_text().splitlines()
+    assert len(got) == len(want)
+    changed = [b for a, b in zip(want, got) if a != b]
+    assert len(changed) == len(new) and all('"score": 1.0' in b
+                                            for b in changed)
 
 
 def test_onchip_rank_requires_tpu():

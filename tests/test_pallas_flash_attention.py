@@ -369,3 +369,134 @@ def test_mha_nonstandard_bias_falls_back_gracefully(monkeypatch):
     bias2d = jnp.zeros((200, 200))
     out = mod.mha(q, k, v, bias=bias2d, causal=True)
     assert_close(out, mha_reference(q, k, v, bias=bias2d, causal=True))
+
+
+# ---------------------------------------------------------------------------
+# the walk inside a block (sub-tiles up to the diagonal / the window's edge)
+# ---------------------------------------------------------------------------
+
+def _walk_case(tq=512, tk=512, blocks=(512, 512), tiles=(128, 128), H=2,
+               KV=2, causal=True, window=None, seg=False, bias=False):
+    return dict(tq=tq, tk=tk, blocks=blocks, tiles=tiles, H=H, KV=KV,
+                causal=causal, window=window, seg=seg, bias=bias)
+
+
+WALK_CASES = {
+    # nq = nk = 1: every bound of the walk is static
+    "causal-one-block": _walk_case(),
+    "causal-one-block-tiles-256x128": _walk_case(tiles=(256, 128)),
+    # several blocks: the bounds follow the block's place
+    "causal-blocks": _walk_case(blocks=(256, 256)),
+    "rect-tq-lt-tk": _walk_case(tq=256, blocks=(256, 256)),
+    "rect-one-q-block": _walk_case(tq=256, blocks=(256, 512)),
+    "window-narrower-than-a-tile": _walk_case(window=40),
+    "window-wider-than-a-block": _walk_case(blocks=(256, 256), window=300),
+    "window-non-causal": _walk_case(blocks=(256, 256), causal=False,
+                                    window=200),
+    "segment-ids": _walk_case(seg=True),
+    "segment-ids-blocks": _walk_case(blocks=(256, 256), seg=True),
+    "bias": _walk_case(blocks=(256, 256), bias=True),
+    "gqa": _walk_case(blocks=(512, 256), H=4, KV=2),
+    "non-causal": _walk_case(tq=256, blocks=(256, 256), causal=False),
+}
+
+
+def _tiles_touched(tq, tk, sq, sk, causal, window):
+    """Share of the square in [sq, sk] tiles that hold a visible pair, by
+    enumerating the position mask."""
+    qpos = np.arange(tq)[:, None] + (tk - tq)
+    kpos = np.arange(tk)[None, :]
+    mask = np.ones((tq, tk), bool)
+    if causal:
+        mask &= qpos >= kpos
+    if window is not None:
+        mask &= kpos > qpos - window
+    tiles = mask.reshape(tq // sq, sq, tk // sk, sk).any(axis=(1, 3))
+    return tiles.mean()
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES), ids=list(WALK_CASES))
+def test_walk_matches_reference(case, monkeypatch):
+    """Forward and all three gradients through the walk against the plain
+    reference, and the share of the square the walk says it computes against
+    the mask counted out."""
+    from deepspeed_tpu.ops import registry
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    c = WALK_CASES[case]
+    monkeypatch.setattr(fa, "_TILES", {k: c["tiles"] for k in fa._TILES})
+    tq, tk, H = c["tq"], c["tk"], c["H"]
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    q = jax.random.normal(ks[0], (1, tq, H, 64))
+    k = jax.random.normal(ks[1], (1, tk, c["KV"], 64))
+    v = jax.random.normal(ks[2], (1, tk, c["KV"], 64))
+    w = jax.random.normal(ks[3], (1, tq, H, 64))
+    kw = dict(causal=c["causal"], window=c["window"])
+    if c["seg"]:
+        kw["segment_ids"] = (jnp.arange(tq)[None] // 100).astype(jnp.int32)
+    if c["bias"]:
+        kw["bias"] = jax.random.normal(ks[4], (1, H, tq, tk)) * 0.5
+    blocks = dict(zip(("block_q", "block_k"), c["blocks"]))
+
+    def flash(q, k, v):
+        return fa.flash_mha(q, k, v, interpret=True, block_config=blocks, **kw)
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, **kw)
+
+    assert_close(flash(q, k, v), ref(q, k, v))
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        assert_close(a, b, atol=5e-3)
+    # sub-tiles where the block is the sequence and an edge crosses it, else
+    # the block is the tile
+    edge = c["causal"] or c["window"] is not None
+    walked = edge and c["blocks"] == (tq, tk)
+    share = registry.active_kernel_configs()["flash_mha"]["visible_share"]
+    assert share == _tiles_touched(
+        tq, tk, *(c["tiles"] if walked else c["blocks"]), c["causal"],
+        c["window"])
+    assert share == 1.0 if not edge else share <= 1.0
+
+
+def test_visible_share_at_the_training_shape(monkeypatch):
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    share = lambda *a: fa.visible_share(1024, 1024, *a)
+    assert share(1024, 1024, True, None) == 0.625          # keys in tiles of 256
+    assert {sk for _, sk in fa._TILES.values()} == {256}   # in all three kernels
+    assert share(512, 512, True, None) == 0.75     # several blocks: by block
+    assert share(1024, 1024, False, None) == 1.0   # no edge, nothing to walk
+    assert share(1024, 1024, True, 256) == 0.4375    # both edges
+    monkeypatch.setattr(fa, "_TILES", {k: (128, 128) for k in fa._TILES})
+    assert share(1024, 1024, True, None) == 0.5625
+    # a block no tile divides is walked whole
+    assert fa._tiles("dkv", 192, 320, 192, 320, True, None) == (192, 320)
+
+
+@pytest.mark.parametrize("scan_layers", [True, False],
+                         ids=["scan_layers", "layer_loop"])
+@pytest.mark.parametrize("policy,in_cpu,forwards_a_layer", [
+    ("dots", False, 1), ("dots", True, 1), ("everything", False, 2),
+    ("nothing", False, 1)])
+def test_forward_kernels_in_a_gradient(policy, in_cpu, forwards_a_layer,
+                                       scan_layers, monkeypatch):
+    """A policy that saves the kernel's residuals by name holds ONE
+    ``flash_mha_fwd`` a layer in the gradient's jaxpr; ``everything`` reruns
+    it, by its meaning."""
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    from deepspeed_tpu.runtime.activation_checkpointing import checkpointing
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.setitem(checkpointing._CONFIG, "policy", policy)
+    monkeypatch.setitem(checkpointing._CONFIG, "checkpoint_in_cpu", in_cpu)
+    cfg = GPT2Config.tiny(scan_layers=scan_layers)
+    model = GPT2LMHeadModel(cfg)
+    ids = jnp.zeros((1, 128), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))
+
+    def loss(p):
+        return jnp.sum(model.apply(p, ids).astype(jnp.float32))
+
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss))(params))
+    layers = 1 if scan_layers else cfg.n_layer
+    assert jaxpr.count("name=flash_mha_fwd") == forwards_a_layer * layers
+    assert jaxpr.count("name=flash_mha_bwd_dq") == layers
