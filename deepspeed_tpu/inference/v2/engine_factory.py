@@ -19,9 +19,11 @@ over one paged group whose page keeps the indexer's key beside K and V;
 softmax-routed experts of which the tree may hold a share).
 
 A family is three things, resolved here: its ragged forward, its verify
-forward (or None) and its cache groups (``ragged/cache_groups.py``); a fourth
-where its module has one, ``prepare_params(cfg, params)``, the tree as its
-forward reads it, made once when the engine is built.
+forward (or None) and its cache groups (``ragged/cache_groups.py``); two more
+where its module has them: ``prepare_params(cfg, params)``, the tree as its
+forward reads it, made once when the engine is built, and
+``dispatch_report(cfg, real_tokens)``, what a dispatch reports of the family
+beside the engine's and the cache groups' own counts.
 """
 
 import importlib
@@ -33,7 +35,7 @@ from deepspeed_tpu.utils.logging import logger
 
 #: a family is one row: its module under ``model_implementations``, which
 #: exports ``ragged_forward`` and, where the family has one,
-#: ``ragged_forward_verify``
+#: ``ragged_forward_verify``, ``prepare_params`` and ``dispatch_report``
 _IMPLEMENTATION = {"llama": "llama", "mistral": "llama", "qwen2": "llama",
                    "qwen": "llama", "internlm": "llama",  # llama trees (hf.py)
                    "mixtral": "mixtral", "falcon": "parallel_block",
@@ -115,6 +117,14 @@ def resolve_verify_fn(model, family=None):
                    None)
 
 
+def resolve_report_fn(model, family=None):
+    """The family's ``dispatch_report(cfg, real_tokens)``: what a dispatch
+    reports beyond what the engine and the cache groups say of it, as the two
+    mappings ``moe_layer.dispatch_report`` describes; ``None`` for a family
+    that exports none (no reporter, not a reporter of zeros)."""
+    return getattr(_implementation(model, family), "dispatch_report", None)
+
+
 def resolve_cache_groups(model):
     """What the model keeps per sequence between dispatches: its own
     ``cache_groups(config)`` where the stack is not homogeneous, else the one
@@ -132,4 +142,5 @@ def build_engine(model, params, engine_config=None, family=None):
     return InferenceEngineV2(model, params, engine_config,
                              forward_fn=resolve_forward_fn(model, family),
                              verify_fn=resolve_verify_fn(model, family),
-                             cache_groups=resolve_cache_groups(model))
+                             cache_groups=resolve_cache_groups(model),
+                             report_fn=resolve_report_fn(model, family))

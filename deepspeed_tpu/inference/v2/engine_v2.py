@@ -7,6 +7,7 @@ control for an external scheduler (DeepSpeed-MII's SplitFuse role);
 ``flush`` retires a sequence and frees its KV blocks.
 """
 
+import collections
 import dataclasses
 import functools
 from typing import Iterable, List, Tuple
@@ -17,8 +18,6 @@ import jax.numpy as jnp
 
 from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
-from deepspeed_tpu.inference.v2.model_implementations.moe_layer import (
-    expert_rows)
 from deepspeed_tpu.inference.v2.ragged.ragged_manager import DSStateManager
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
     RaggedBatchWrapper, dispatch_rows, pack, short_row_tokens, unpack)
@@ -54,15 +53,6 @@ def packed_forward(forward_fn, cfg, layout, params, cache, packed, kept,
     return forward_fn(cfg, params, cache, tokens, q_len, seen, tables, *extra)
 
 
-def selected_tokens(seen, new, topk):
-    """What a row of ``new`` tokens behind ``seen`` cached ones reads of a
-    layer under learned sparse attention, from the lengths alone: the sum
-    over its new tokens of ``min(position + 1, topk)``."""
-    full = min(max(seen + new - topk, 0), new)       # tokens at topk or past
-    short = new - full                               # positions seen .. < topk
-    return full * topk + short * seen + short * (short + 1) // 2
-
-
 class DispatchedRound(list):
     """What one round left on the device: [(rows, out)] per dispatch, ``rows``
     indexing the round's uids and ``out`` that dispatch's padded
@@ -88,12 +78,13 @@ class InferenceEngineV2:
             stack is not homogeneous, ``cache_groups``).
         params: trained parameter pytree.
         config: ``RaggedInferenceEngineConfig`` or dict.
-        forward_fn, verify_fn, cache_groups: what ``engine_factory``
-            resolved for the family; resolved here when left out.
+        forward_fn, verify_fn, cache_groups, report_fn: what
+            ``engine_factory`` resolved for the family; resolved here when
+            left out.
     """
 
     def __init__(self, model, params, config=None, forward_fn=None,
-                 verify_fn=None, cache_groups=None):
+                 verify_fn=None, cache_groups=None, report_fn=None):
         if not isinstance(config, RaggedInferenceEngineConfig):
             config = RaggedInferenceEngineConfig(config or {})
         self._config = config
@@ -110,8 +101,15 @@ class InferenceEngineV2:
         if cache_groups is None:
             from deepspeed_tpu.inference.v2.engine_factory import resolve_cache_groups
             cache_groups = resolve_cache_groups(model)
+        if report_fn is None:
+            from deepspeed_tpu.inference.v2.engine_factory import resolve_report_fn
+            report_fn = resolve_report_fn(model)
         self._ragged_forward = forward_fn
         self._verify_forward = verify_fn
+        # what a dispatch reports beyond the engine's own counts is said by
+        # the state manager, for the cache groups, and by the family's
+        # ``dispatch_report`` where its module exports one (docs/SERVING.md)
+        self._dispatch_report = report_fn
         if config.speculative.enabled and verify_fn is None:
             raise ValueError(
                 "speculative.enabled requires a verify forward; "
@@ -148,53 +146,13 @@ class InferenceEngineV2:
         self._kept_at = {}
         # [sequence bucket, chunk bucket] of each dispatch of the last round
         self.last_batch_shapes = []
-        # of the last round's dispatches, summed: the pages of the "kv"
-        # group its rows' contexts reach (what the paged kernel walks)
-        self.last_live_pages = 0
-        # of the last round's dispatches, summed (zero for a model of one
-        # paged group): pages its windows freed, slots of state held
-        self.last_window_pages_freed = 0
-        self.last_state_slots = 0
-        self._window_freed_reported = 0
-        # for a model with sparse experts (every layer of such a family has
-        # them): expert rows a real token takes through the stack, and of
-        # the last round's dispatches, summed, the rows that reached the
-        # expert GEMMs for real tokens and for padded slots
-        self._expert_fanout = (getattr(cfg, "num_experts_per_tok", 0) or 0,
-                               getattr(cfg, "num_expert_layers",
-                                       cfg.num_hidden_layers))
-        self.last_expert_rows = 0
-        self.last_expert_rows_padded = 0
-        # where the tree holds a share of the experts the router scores:
-        # (experts held, experts routed over), constant on every dispatch
-        held = getattr(cfg, "experts_held", None)
-        self._expert_share = (held[1], cfg.n_routed_experts) if held else None
-        # where the "kv" group's page is one leaf (a latent row a token):
-        # bytes of a token's row in one layer, and of the last round's
-        # dispatches, summed, the pages held after each one's allocation
-        kvc = self._state.kv_cache
-        self._latent_row_bytes = (
-            kvc.k_pool.shape[2] * kvc.k_pool.shape[4]
-            * kvc.k_pool.dtype.itemsize) if self._state.one_leaf else 0
-        self.last_latent_pages = 0
-        # where the "kv" group's page keeps an indexer's key beside K and V
-        # (learned sparse attention): the bytes of a token's key in one
-        # layer, the tokens a query reads at most (the model's
-        # ``index_topk``), and of the last round's dispatches, summed: the
-        # pages held after each one's allocation, the rows whose context
-        # passes ``index_topk`` (they score, select and read sparsely), and
-        # the (token, layer) reads of selected tokens, from the lengths alone
-        self._index_row_bytes = (
-            kvc.i_pool.shape[4] * kvc.i_pool.dtype.itemsize
-            if self._state.indexed else 0)
-        self._index_topk = getattr(cfg, "index_topk", 0) or 0
-        self.last_index_pages = 0
-        self.last_sparse_rows = 0
-        self.last_selected_tokens = 0
-        # of the last round's dispatches, those that held a row whose
-        # temperature is above 0: their sampler sorted every row's
-        # vocabulary, the others' took the argmax (``sampling.py``)
-        self.last_dispatches_sorted = 0
+        # of the last round's dispatches, summed: ``real_tokens``,
+        # ``padded_slots``, ``live_pages`` (pages of the "kv" group the rows'
+        # contexts reach: what the paged kernel walks), ``dispatches_sorted``
+        # (those with a row whose temperature is above 0: their sampler
+        # sorted every row's vocabulary, ``sampling.py``) and whatever the
+        # reporters said a dispatch adds
+        self.last_counts = collections.Counter()
         # postmortem-bundle collector (telemetry/flightrec.py): the newest
         # engine's host-side KV pool stats ride every bundle — pure host
         # reads, so collection is safe even from an abnormal path
@@ -380,12 +338,7 @@ class InferenceEngineV2:
                                "every row's tokens have to be the host's")
         parts, self.last_batch_shapes = DispatchedRound(rnd), []
         further = self._state.has_further_groups
-        self.last_window_pages_freed = self.last_state_slots = 0
-        self.last_live_pages = 0
-        self.last_expert_rows = self.last_expert_rows_padded = 0
-        self.last_latent_pages = self.last_dispatches_sorted = 0
-        self.last_index_pages = self.last_sparse_rows = 0
-        self.last_selected_tokens = 0
+        counts = self.last_counts = collections.Counter()
         for rows, min_seqs, min_tokens in dispatch_rows(
                 lengths, short_row_tokens(verify_k)):
             # explicit begin/end everywhere below: tracing a new batch shape
@@ -422,43 +375,24 @@ class InferenceEngineV2:
             tables = {"kv": arrays.pop("block_tables")}
             if further:
                 tables.update(self._state.group_tables(seqs, seq_bucket))
-                self._note_further_groups(sp, seqs)
             self.last_batch_shapes.append((seq_bucket, chunk_bucket))
-            self.last_live_pages += live_pages
-            if self._expert_fanout[0]:
-                took, padded = expert_rows(real_tokens, *self._expert_fanout)
-                self.last_expert_rows += took
-                self.last_expert_rows_padded += padded
-                sp.set(expert_rows=took, expert_rows_padded=padded)
-                if self._expert_share:
-                    sp.set(experts_held=self._expert_share[0],
-                           experts_routed_over=self._expert_share[1])
-            if self._latent_row_bytes:
-                held = kv.num_blocks - kv.free_blocks
-                self.last_latent_pages += held
-                sp.set(latent_pages=held,
-                       latent_row_bytes=self._latent_row_bytes)
-            if self._index_row_bytes:
-                # from the rows' lengths, in a pass of their own: a family
-                # without an index leaf does nothing a row for them
-                held = kv.num_blocks - kv.free_blocks
-                topk = self._index_topk
-                sparse_rows = sum(s.seen_tokens + s.in_flight_tokens > topk
-                                  for s in seqs)
-                selected = self._model_config.num_hidden_layers * sum(
-                    selected_tokens(s.seen_tokens, s.in_flight_tokens, topk)
-                    for s in seqs)
-                self.last_index_pages += held
-                self.last_sparse_rows += sparse_rows
-                self.last_selected_tokens += selected
-                sp.set(index_pages=held,
-                       index_row_bytes=self._index_row_bytes,
-                       sparse_rows=sparse_rows, selected_tokens=selected)
-            sp.set(seq_bucket=seq_bucket, chunk_bucket=chunk_bucket,
-                   real_tokens=real_tokens,
-                   padded_slots=seq_bucket * chunk_bucket,
-                   context_tokens=context_tokens,
-                   live_pages=live_pages)
+            # what the dispatch adds to the round's counts, and what rides
+            # on its span alone: a reporter answers with both, and neither
+            # is this method's to read
+            adds = {"real_tokens": real_tokens,
+                    "padded_slots": seq_bucket * chunk_bucket,
+                    "live_pages": live_pages}
+            rides = {"seq_bucket": seq_bucket, "chunk_bucket": chunk_bucket,
+                     "context_tokens": context_tokens}
+            reports = [self._state.dispatch_report(seqs)]
+            if self._dispatch_report is not None:
+                reports.append(
+                    self._dispatch_report(self._model_config, real_tokens))
+            for more_adds, more_rides in reports:
+                adds.update(more_adds)
+                rides.update(more_rides)
+            counts.update(adds)
+            sp.set(**adds, **rides)
             sp.end()
 
             sp = tm.span_begin("serving/dispatch", round=rnd, dispatch=n)
@@ -495,7 +429,7 @@ class InferenceEngineV2:
                 out, sampled_rows = sample(out, rows)
                 part.end()
                 programs = 2
-                self.last_dispatches_sorted += sampled_rows > 0
+                counts["dispatches_sorted"] += sampled_rows > 0
                 sp.set(sampled_rows=sampled_rows)
             shape = (seq_bucket, chunk_bucket, verify_k)
             first_seen = int(shape not in self._shapes_seen)
@@ -523,22 +457,6 @@ class InferenceEngineV2:
         self._kept_at = {uid: i for i, uid in enumerate(batch_uids)} \
             if sample is not None and verify_k is None else {}
         return parts
-
-    def _note_further_groups(self, sp, seqs):
-        """On a dispatch's ``serving/build`` span, for a model with further
-        cache groups: slots of state and pages held after this dispatch's
-        allocation, the pages the windows freed since the last dispatch
-        (the previous round's retire), and each further paged group's
-        ``<name>_live_pages`` of the rows ``seqs``."""
-        census = self._state.census()
-        for name in self._state.paged_groups:
-            census[name + "_live_pages"] = sum(
-                len(seq.group_blocks.get(name, ())) for seq in seqs)
-        freed = self._state.window_pages_freed - self._window_freed_reported
-        self._window_freed_reported += freed
-        self.last_window_pages_freed += freed
-        self.last_state_slots += census["state_slots"]
-        sp.set(window_pages_freed=freed, **census)
 
     def state_slot(self, uid: int):
         """The slot of recurrent state ``uid`` holds, taken now if it has

@@ -33,7 +33,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.model_implementations.llama import _rmsnorm
-from deepspeed_tpu.inference.v2.model_implementations.moe_layer import moe_ffn
+from deepspeed_tpu.inference.v2.model_implementations.moe_layer import (
+    dispatch_report, moe_ffn)  # the first: this family's export
 from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
     _pool_block_size, _scatter_index, _scatter_kv, dsa_attention, last_token,
     layer_rows, layer_trash, merge_layers, pool_pages_per_layer, real_slots,

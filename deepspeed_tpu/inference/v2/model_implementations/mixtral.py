@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models.llama import rotary_embed
 from deepspeed_tpu.inference.v2.model_implementations.llama import _rmsnorm
-from deepspeed_tpu.inference.v2.model_implementations.moe_layer import moe_ffn
+from deepspeed_tpu.inference.v2.model_implementations.moe_layer import (
+    dispatch_report, moe_ffn)  # the first: this family's export
 from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
     _paged_attention, _pool_block_size, _scatter_kv, last_token, layer_rows,
     layer_trash, merge_layers, pool_pages_per_layer, real_slots,

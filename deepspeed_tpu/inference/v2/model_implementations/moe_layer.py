@@ -1,6 +1,6 @@
 """The sparse-expert feed-forward layer of a ragged forward: the one place
 shared by every family that has one (``mixtral.py``, ``mellum2.py``,
-``kanana2.py``).
+``kanana2.py``, ``keye_vl2.py``).
 
 ``moe_ffn`` routes a flat batch of token slots (by default softmax over all
 experts, the ``k`` largest, renormalised: ``grouped_gemm.topk_router``; with
@@ -15,8 +15,8 @@ Padded token slots take no expert rows. A dispatch's token slots are
 not valid is sorted past every expert's group (the grouped GEMM never visits
 it) or has an all-zero dispatch row (the einsum), and its output is zero. With
 8 experts of 2 a token the padding was a few wasted rows a group; with 64 of 8
-it would be whole groups. ``expert_rows`` is what the engine's spans and the
-scheduler's counters say of it.
+it would be whole groups. ``dispatch_report`` is what the engine's spans and
+the scheduler's counters say of it.
 
 A share of the experts. ``experts_held = (first, count)`` says that ``w1`` /
 ``w2`` / ``w3`` hold the ``count`` experts from ``first`` on, of the router's
@@ -41,16 +41,27 @@ import jax.numpy as jnp
 from deepspeed_tpu.ops.registry import pallas_interpret, takes_kernel
 
 
-def expert_rows(real_tokens, k, layers):
-    """``(expert_rows, expert_rows_padded)`` of a dispatch of ``real_tokens``
-    real tokens through ``layers`` expert layers of ``k`` experts a token:
-    rows ROUTED for real tokens, and for the dispatch's padded slots. The
-    second is 0 however many those are, because ``moe_ffn`` sorts slots that
-    are not ``valid`` past every group. Under a share of the experts
-    (``experts_held``) the first is still every row the router chose, held
-    here or not: which of them land on this share's experts is data, known on
-    the device alone."""
-    return real_tokens * k * layers, 0
+def dispatch_report(cfg, real_tokens):
+    """What a dispatch of ``real_tokens`` real tokens reports of the expert
+    layers of a model of config ``cfg``: the family's module exports it
+    (``engine_factory.resolve_report_fn``), and the engine carries the two
+    mappings without reading them. Added to the round's counts:
+    ``expert_rows``, the rows ROUTED for real tokens (tokens x experts a
+    token x expert layers, every layer unless the config counts
+    ``num_expert_layers``), and ``expert_rows_padded``, those for the
+    dispatch's padded slots: 0 however many those are, because ``moe_ffn``
+    sorts slots that are not ``valid`` past every group. Under a share of the
+    experts (``experts_held``) the first is still every row the router chose,
+    held here or not (which of them land on this share's experts is data,
+    known on the device alone), and ``experts_held`` beside
+    ``experts_routed_over`` rides on the span alone."""
+    layers = getattr(cfg, "num_expert_layers", cfg.num_hidden_layers)
+    adds = {"expert_rows": real_tokens * cfg.num_experts_per_tok * layers,
+            "expert_rows_padded": 0}
+    held = getattr(cfg, "experts_held", None)
+    rides = {"experts_held": held[1],
+             "experts_routed_over": cfg.n_routed_experts} if held else {}
+    return adds, rides
 
 
 def sigmoid_router(x, gate_wg, bias, k, scale):

@@ -47,8 +47,11 @@ class PagedGroup:
     leaves: int = 2
     value_dim: Optional[int] = None
     # columns of a further leaf of one head beside K and V (an indexer's key
-    # a token and layer); None: no such leaf
+    # a token and layer); None: no such leaf. With it, the tokens a query
+    # reads at most (what the indexer picks): the state manager counts a
+    # dispatch's sparse rows and selected tokens by it
     index_dim: Optional[int] = None
+    index_topk: Optional[int] = None
 
     def __post_init__(self):
         if self.leaves not in (1, 2):
@@ -59,6 +62,9 @@ class PagedGroup:
         if self.index_dim is not None and (self.leaves != 2 or self.window):
             raise ValueError("an index leaf stands beside a K and V pair "
                              "whose pages live as long as the sequence")
+        if (self.index_dim is None) != (self.index_topk is None):
+            raise ValueError("index_topk belongs to a group with an index "
+                             "leaf, and such a group states it")
 
     @property
     def kv_pair(self):

@@ -16,6 +16,15 @@ from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDesc
 from deepspeed_tpu.utils.logging import logger
 
 
+def selected_tokens(seen, new, topk):
+    """What a row of ``new`` tokens behind ``seen`` cached ones reads of a
+    layer under learned sparse attention, from the lengths alone: the sum
+    over its new tokens of ``min(position + 1, topk)``."""
+    full = min(max(seen + new - topk, 0), new)       # tokens at topk or past
+    short = new - full                               # positions seen .. < topk
+    return full * topk + short * seen + short * (short + 1) // 2
+
+
 class DSStateManager:
 
     def __init__(self, config, groups):
@@ -67,6 +76,7 @@ class DSStateManager:
         if spec is not None and getattr(spec, "draft_page_divisor", 0) > 1:
             self.draft_pages = self.kv_cache.allocator.draft_pages(
                 spec.draft_page_divisor)
+        self._init_report()
         self._seqs = {}
         self.swap_outs = 0  # host swap tier counters (kv_cache swap_out/in)
         self.swap_ins = 0
@@ -183,13 +193,63 @@ class DSStateManager:
     def slots_in_use(self):
         return self.trash_slot - self.free_slots if self.slot_group else 0
 
-    def census(self):
-        """Slots of state and pages held by tracked sequences right now: the
-        ``"kv"`` group's and the further paged groups' together."""
-        held = lambda cache: cache.num_blocks - cache.free_blocks
-        return {"state_slots": self.slots_in_use,
-                "global_pages": held(self.kv_cache),
-                "window_pages": sum(held(c) for _, c in self.paged_groups.values())}
+    # -- what a dispatch reports of the groups -------------------------------
+    def _init_report(self):
+        """``dispatch_report``'s constants, from the ``"kv"`` group's
+        declaration and pools: the bytes of a token's latent row, or of its
+        indexer's key, in one layer."""
+        g, kvc = self.primary_group, self.kv_cache
+        self._report_rides = {}
+        if g.leaves == 1:
+            self._report_rides["latent_row_bytes"] = (
+                kvc.k_pool.shape[2] * kvc.k_pool.shape[4]
+                * kvc.k_pool.dtype.itemsize)
+        if g.index_dim is not None:
+            self._report_rides["index_row_bytes"] = \
+                kvc.i_pool.shape[4] * kvc.i_pool.dtype.itemsize
+        self._window_freed_reported = 0
+
+    def dispatch_report(self, seqs):
+        """What a dispatch of the sequences ``seqs`` (after their allocation)
+        reports of the cache groups: ``(adds, rides)``, what it adds to the
+        round's counts and what rides on its ``serving/build`` span alone.
+        The engine carries both and reads neither. The one K and V group
+        reports nothing, and what reports nothing a row costs nothing a row.
+
+        One leaf: ``latent_pages`` held now. An index leaf: ``index_pages``
+        held now, the ``sparse_rows`` whose context passes ``index_topk``
+        (they score, select and read sparsely) and the (token, layer) reads
+        of ``selected_tokens``, from the lengths alone. Further groups: added
+        are ``state_slots`` held and ``window_pages_freed`` since the last
+        dispatch (the round before's retire); ``global_pages`` and
+        ``window_pages`` held by tracked sequences and each further paged
+        group's ``<name>_live_pages`` of these rows ride."""
+        g, kvc = self.primary_group, self.kv_cache
+        held = kvc.num_blocks - kvc.free_blocks
+        adds, rides = {}, self._report_rides
+        if g.leaves == 1:
+            adds["latent_pages"] = held
+        elif g.index_dim is not None:
+            topk = g.index_topk
+            adds.update(
+                index_pages=held,
+                sparse_rows=sum(s.seen_tokens + s.in_flight_tokens > topk
+                                for s in seqs),
+                selected_tokens=g.layers * sum(
+                    selected_tokens(s.seen_tokens, s.in_flight_tokens, topk)
+                    for s in seqs))
+        if self.has_further_groups:
+            freed = self.window_pages_freed - self._window_freed_reported
+            self._window_freed_reported += freed
+            adds.update(window_pages_freed=freed,
+                        state_slots=self.slots_in_use)
+            rides = dict(rides, global_pages=held, window_pages=sum(
+                c.num_blocks - c.free_blocks
+                for _, c in self.paged_groups.values()))
+            for name in self.paged_groups:
+                rides[name + "_live_pages"] = sum(
+                    len(seq.group_blocks.get(name, ())) for seq in seqs)
+        return adds, rides
 
     # -- the cache and tables pytrees of a dispatch -------------------------
     def cache_view(self):

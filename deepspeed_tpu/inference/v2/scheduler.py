@@ -153,52 +153,19 @@ class SplitFuseScheduler:
         # without telemetry
         self.prefill_tokens_executed = 0
         self.prefill_tokens_saved = 0
-        # batch occupancy without a profile: rounds, the dispatches they
-        # took (the short class together, every other row alone), the tokens
-        # they carried and the [sequence bucket x chunk bucket] slots the
-        # engine padded them to (the sums of the ``serving/build`` spans)
-        self.rounds = 0
-        self.dispatches = 0
-        self.real_tokens = 0
-        self.padded_slots = 0
-        # the sum of the same spans' ``live_pages``: pages of the "kv" group
-        # the dispatched rows' contexts reach (the paged kernel's work)
-        self.live_pages = 0
-        # for a model with further cache groups (ragged/cache_groups.py),
-        # the sums of the same spans' ``window_pages_freed`` and
-        # ``state_slots``: pages the windows gave back, and slots of
-        # recurrent state held, summed over dispatches
-        self.window_pages_freed = 0
-        self.state_slots = 0
-        # for a model with sparse experts, the sums of the same spans'
-        # ``expert_rows`` and ``expert_rows_padded``: rows that reached the
-        # expert GEMMs for real tokens (tokens x experts a token x expert
-        # layers) and for padded token slots
-        self.expert_rows = 0
-        self.expert_rows_padded = 0
-        # for a model whose "kv" group is one leaf (latent rows), the sum of
-        # the same spans' ``latent_pages``: pages held after each dispatch's
-        # allocation
-        self.latent_pages = 0
-        # for a model whose pages keep an indexer's key (learned sparse
-        # attention), the sums of the same spans' ``index_pages``,
-        # ``sparse_rows`` and ``selected_tokens``
-        self.index_pages = 0
-        self.sparse_rows = 0
-        self.selected_tokens = 0
-        # the dispatches whose ``serving/dispatch`` span reads
-        # ``sampled_rows`` above 0: they held a row whose temperature is
-        # above 0, so the device sampler sorted every row's vocabulary; in
-        # the others it took the argmax and sorted nothing
-        self.dispatches_sorted = 0
-        # run-ahead: rounds composed and dispatched before the round before
-        # them was fetched, their rows whose token came from the device (the
-        # sums of the ``serving/compose`` spans' ``ahead``, ``ahead_rows``),
-        # and of those rows the ones that had ended (an eos in the round
-        # before, a cancel) and rode the round for nothing
-        self.rounds_ahead = 0
-        self.ahead_rows = 0
-        self.ahead_rows_dropped = 0
+        # batch occupancy without a profile, each count a plain attribute
+        # too (``sched.rounds``: ``__getattr__``, 0 until something adds to
+        # it). The scheduler's own: ``rounds``, the ``dispatches`` they took,
+        # and run-ahead's ``rounds_ahead`` (dispatched before the round
+        # before them was fetched), ``ahead_rows`` (their rows whose token
+        # came from the device) and ``ahead_rows_dropped`` (those of them
+        # that had ended, an eos or a cancel, and rode the round for
+        # nothing). The rest is the engine's ``last_counts`` summed over
+        # rounds, keys this file never names: the sums of the
+        # ``serving/build`` spans' ``real_tokens``, ``padded_slots``,
+        # ``live_pages`` and of what the model's cache groups and expert
+        # layer add (docs/SERVING.md, "What a dispatch reports")
+        self.counts = collections.Counter()
         # the round dispatched ahead, ``step_finish``'s to fetch next
         self._flying = None
         # device_sampling=True (default) fuses temperature/top-k/top-p and
@@ -269,6 +236,13 @@ class SplitFuseScheduler:
             getattr(engine._config, "slo_classes", None) or {})
         if self._slo_classes:
             telemetry.set_slo_classes(self._slo_classes)
+
+    def __getattr__(self, name):
+        """A count by its name (``self.counts``): 0 until a round adds to
+        it, so for a model none of whose dispatches reports it."""
+        if name.startswith("_") or "counts" not in self.__dict__:
+            raise AttributeError(name)
+        return self.counts[name]
 
     def submit(self, uid, prompt, max_new_tokens=16, eos_token_id=None,
                temperature=0.0, top_k=0, top_p=1.0, seed=None,
@@ -914,23 +888,10 @@ class SplitFuseScheduler:
         else:
             logits = self._engine.put(uids, chunks)
             ids = None
-        shapes = self._engine.last_batch_shapes
-        self.rounds += 1
-        self.dispatches += len(shapes)
-        self.real_tokens += sched_tokens
-        self.padded_slots += sum(s * q for s, q in shapes)
-        self.live_pages += self._engine.last_live_pages
-        self.window_pages_freed += self._engine.last_window_pages_freed
-        self.state_slots += self._engine.last_state_slots
-        self.expert_rows += self._engine.last_expert_rows
-        self.expert_rows_padded += self._engine.last_expert_rows_padded
-        self.latent_pages += self._engine.last_latent_pages
-        self.index_pages += self._engine.last_index_pages
-        self.sparse_rows += self._engine.last_sparse_rows
-        self.selected_tokens += self._engine.last_selected_tokens
-        self.dispatches_sorted += self._engine.last_dispatches_sorted
-        self.rounds_ahead += ahead
-        self.ahead_rows += len(device_rows)
+        self.counts.update(
+            self._engine.last_counts, rounds=1, rounds_ahead=int(ahead),
+            dispatches=len(self._engine.last_batch_shapes),
+            ahead_rows=len(device_rows))
         # what this round will have done once retired: a prompt's tokens,
         # and a token sampled by every row but one mid-prompt and a
         # re-admitted row's last chunk (its sample is discarded)
@@ -981,7 +942,7 @@ class SplitFuseScheduler:
                 # before, a cancel): it rode the round for nothing. Its id
                 # is dropped; its pages went at its end, which the device's
                 # in-order queue makes safe (docs/SERVING.md)
-                self.ahead_rows_dropped += ahead
+                self.counts["ahead_rows_dropped"] += ahead
                 continue
             if was_prefilling[row]:
                 self.prefill_tokens_executed += len(chunks[row])

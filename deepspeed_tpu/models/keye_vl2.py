@@ -232,4 +232,5 @@ class KeyeVL2ForCausalLM:
         pages the indexer's key, one head a token and layer."""
         from deepspeed_tpu.inference.v2.ragged.cache_groups import PagedGroup
         return (PagedGroup("kv", cfg.num_hidden_layers, cfg.num_key_value_heads,
-                           cfg.head_dim, index_dim=cfg.index_row_width),)
+                           cfg.head_dim, index_dim=cfg.index_row_width,
+                           index_topk=cfg.index_topk),)

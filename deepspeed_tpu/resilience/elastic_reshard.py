@@ -336,8 +336,10 @@ def run_elastic_drill(ckpt_dir, steps=6, fail_at_step=2, expand_at=4,
                       n_slices=2, hidden_dim=32, replan=False):
     """The in-process 8→4→8 drill (CPU, 8 forced host devices): train with
     a ``slice.lost`` fault armed mid-run, shrink to the surviving half,
-    re-expand, and compare the loss trajectory bitwise against a fault-free
-    full-world reference run. Returns the baseline payload consumed by
+    re-expand, and compare the loss trajectory against a fault-free
+    full-world reference run: bitwise at a restore step that runs on the
+    reference's world, within ``RESTORE_LOSS_MAX_ULPS`` at one that runs on
+    the survivors'. Returns the baseline payload consumed by
     ``perf_gate.py check_elastic_baseline`` and asserted by the e2e test.
     """
     import jax
@@ -401,13 +403,16 @@ def run_elastic_drill(ckpt_dir, steps=6, fail_at_step=2, expand_at=4,
         faults.reset()
 
     worlds = result["world_history"]
-    # bitwise identity is asserted AT each restore step (the replayed
-    # forward under the resharded mesh against the full-world reference) —
-    # steps after it may drift by ~1 ulp from the survivors' different
-    # gradient reduction order, which is trajectory continuity, not loss
-    restore_steps = [e["step"] for e in result["reshard_events"]]
-    bitwise = all(result["losses"][s] == ref_losses[s]
-                  for s in restore_steps if s in ref_losses)
+    # the replayed forward AT each restore step against the full-world
+    # reference, in float32 ulps: bitwise on the reference's own world (the
+    # re-expansion: the state came back exact); on the survivors' the same
+    # loss is summed over half the devices in another order, 0 or 1 ulp
+    events = {e["step"]: e for e in result["reshard_events"]}
+    restore_steps = list(events)
+    ulps = {s: _ulps32(result["losses"][s], ref_losses[s])
+            for s in restore_steps if s in ref_losses}
+    bitwise = all(n == 0 for s, n in ulps.items()
+                  if events[s]["world"] == worlds[0])
     traj_rel_err = max(
         abs(result["losses"][i] - ref_losses[i]) / max(abs(ref_losses[i]),
                                                        1e-12)
@@ -429,12 +434,25 @@ def run_elastic_drill(ckpt_dir, steps=6, fail_at_step=2, expand_at=4,
             controller.engine.state.opt_state),
         "restore_steps": restore_steps,
         "restore_loss_bitwise_equal": bool(bitwise),
+        "restore_loss_ulps": {str(s): n for s, n in sorted(ulps.items())},
         "trajectory_max_rel_err": traj_rel_err,
         "losses": {str(k): v for k, v in sorted(result["losses"].items())},
         "ref_losses": {str(k): v for k, v in sorted(ref_losses.items())},
         "replan_worlds": replan_calls,
     }
     return payload
+
+
+#: float32 ulps a restore step's loss may lie from the full-world reference's
+#: on another world (``scripts/perf_gate.py`` holds the record to the same)
+RESTORE_LOSS_MAX_ULPS = 4
+
+
+def _ulps32(a, b):
+    """Float32 values between ``a`` and ``b`` (both of one sign)."""
+    import struct
+    bits = struct.unpack("<2i", struct.pack("<2f", a, b))
+    return abs(bits[0] - bits[1])
 
 
 def _drill_compile_fn(fn, abstract):

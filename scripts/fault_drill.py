@@ -543,7 +543,8 @@ def emit_elastic_baseline(path):
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
-    from deepspeed_tpu.resilience.elastic_reshard import run_elastic_drill
+    from deepspeed_tpu.resilience.elastic_reshard import (
+        RESTORE_LOSS_MAX_ULPS, run_elastic_drill)
     workdir = tempfile.mkdtemp(prefix="elastic_baseline_")
     try:
         payload = run_elastic_drill(os.path.join(workdir, "uni"))
@@ -555,10 +556,13 @@ def emit_elastic_baseline(path):
     print(f"elastic baseline written to {path}: "
           f"worlds={payload['world_sequence']} "
           f"steps_lost={payload['steps_lost']} "
-          f"bitwise={payload['restore_loss_bitwise_equal']}")
+          f"bitwise={payload['restore_loss_bitwise_equal']} "
+          f"ulps={payload['restore_loss_ulps']}")
     ok = (payload["world_sequence"] == [8, 4, 8]
           and payload["steps_lost"] == 0
-          and payload["restore_loss_bitwise_equal"])
+          and payload["restore_loss_bitwise_equal"]
+          and max(payload["restore_loss_ulps"].values())
+          <= RESTORE_LOSS_MAX_ULPS)
     return 0 if ok else 1
 
 

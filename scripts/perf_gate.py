@@ -1564,10 +1564,13 @@ def check_speculate_baseline(baseline_path=None):
 #: elastic reshard drill acceptance for the checked-in baseline
 #: (onchip_results/elastic_drill_baseline.json, regenerated with
 #: ``scripts/fault_drill.py --emit-elastic-baseline``): the 8→4→8 CPU
-#: drill must lose zero steps, double-apply none, restore bitwise at every
-#: reshard, and keep each reshard leg under the wall-clock ceiling
+#: drill must lose zero steps, double-apply none, restore bitwise on the
+#: full world, and keep each reshard leg under the wall-clock ceiling
 ELASTIC_MAX_RESHARD_S = 30.0
 ELASTIC_WORLD_SEQUENCE = [8, 4, 8]
+# elastic_reshard.RESTORE_LOSS_MAX_ULPS (this file imports no jax): on the
+# survivors' world the restore step's loss is summed in another order
+ELASTIC_RESTORE_MAX_ULPS = 4
 ELASTIC_BASELINE_PATH = os.path.join(REPO_ROOT, "onchip_results",
                                      "elastic_drill_baseline.json")
 
@@ -1576,7 +1579,8 @@ def check_elastic_baseline(baseline_path=None):
     """Validate the checked-in elastic-reshard drill baseline: the recorded
     run shrank 8→4 on a mid-step slice loss and re-expanded 4→8
     (``world_sequence``), lost zero steps and double-applied none across
-    both reshards, restored the loss bitwise at every reshard step, kept
+    both reshards, restored the loss bitwise at the reshard step back on the
+    full world and within a few ulps on the survivors', kept
     the optimizer step count equal to the step budget, and each reshard
     leg's wall-seconds ratchets under :data:`ELASTIC_MAX_RESHARD_S`. Pure
     dict checks over recorded values (the drill itself needs jax + 8 CPU
@@ -1613,6 +1617,11 @@ def check_elastic_baseline(baseline_path=None):
         errors.append("elastic baseline: restore-step loss not bitwise "
                       "equal to the full-world reference — the universal "
                       "reshard-restore altered state")
+    ulps = max(doc.get("restore_loss_ulps", {}).values(), default=0)
+    if ulps > ELASTIC_RESTORE_MAX_ULPS:
+        errors.append(f"elastic baseline: a restore-step loss lies {ulps} "
+                      f"float32 ulps from the full-world reference "
+                      f"(ceiling {ELASTIC_RESTORE_MAX_ULPS})")
     if doc["final_optimizer_step"] != doc["steps"]:
         errors.append(
             f"elastic baseline: optimizer step count "

@@ -131,13 +131,20 @@ def drill_payload(tmp_path_factory):
 
 def test_drill_continues_on_survivors_bitwise(drill_payload):
     """(a) after the mid-step slice loss, training continues on the
-    4-device survivor mesh from the checkpointed step, the replayed
-    restore-step loss is bitwise identical to the full-world reference,
-    and the trajectory stays continuous."""
+    4-device survivor mesh from the checkpointed step, and the trajectory
+    stays continuous. The replayed restore-step loss is bitwise the
+    full-world reference's where it is reduced over the full world again
+    (the re-expansion: the state came back exact); over the four survivors
+    the same sum runs in another order, so there the claim is a few ulps
+    (it was 0 on the tree the drill was written on and is 1 on this one)."""
+    from deepspeed_tpu.resilience.elastic_reshard import RESTORE_LOSS_MAX_ULPS
     p = drill_payload
     assert p["world_sequence"][:2] == [8, 4]
     assert p["steps_lost"] == 0
     assert p["restore_loss_bitwise_equal"] is True
+    ulps = p["restore_loss_ulps"]
+    assert ulps[str(p["expand_at"])] == 0
+    assert 0 <= ulps[str(p["fail_at_step"])] <= RESTORE_LOSS_MAX_ULPS
     assert p["restore_steps"] == [p["fail_at_step"], p["expand_at"]]
     # every step of the trajectory within float32 reduction-order noise
     assert p["trajectory_max_rel_err"] < 1e-5

@@ -26,7 +26,8 @@ from benchmark.references import keye_vl2 as reference
 from deepspeed_tpu.inference.v2 import engine_v2
 from deepspeed_tpu.inference.v2.engine_factory import (
     build_engine, resolve_cache_groups, resolve_forward_fn, resolve_verify_fn)
-from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2, selected_tokens
+from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
+from deepspeed_tpu.inference.v2.ragged.ragged_manager import selected_tokens
 from deepspeed_tpu.inference.v2.model_implementations import moe_layer
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models import keye_vl2 as model_file

@@ -134,14 +134,18 @@ def test_int8_kernel_sliding_window():
 # int8 vs fp generation parity (the ISSUE's bit-parity generation gate)
 # ---------------------------------------------------------------------------
 
+def _prompts(cfg):
+    rng = np.random.default_rng(31)
+    prefix = rng.integers(0, cfg.vocab_size, 16).astype(np.int32)
+    return {uid: np.concatenate([prefix, rng.integers(
+        0, cfg.vocab_size, 6 + 5 * uid).astype(np.int32)]) for uid in range(3)}
+
+
 def _drive(cfg, model, params, kv_dtype, kw_fn, **engine_kw):
     engine = make_engine(cfg, model, params, kv_dtype=kv_dtype, **engine_kw)
     sched = SplitFuseScheduler(engine, token_budget=16)
-    rng = np.random.default_rng(31)
-    prefix = rng.integers(0, cfg.vocab_size, 16).astype(np.int32)
-    for uid in range(3):
-        tail = rng.integers(0, cfg.vocab_size, 6 + 5 * uid).astype(np.int32)
-        sched.submit(uid, np.concatenate([prefix, tail]), **kw_fn(uid))
+    for uid, prompt in _prompts(cfg).items():
+        sched.submit(uid, prompt, **kw_fn(uid))
     got = sched.run_to_completion()
     return {u: got[u].tolist() for u in got}, engine
 
@@ -157,17 +161,81 @@ def test_generation_parity_int8_vs_fp_greedy(served, eight_devices):
     assert engine._state.kv_cache.quantized
 
 
+def _draw(logits, temperature, top_k, seed, position, gap):
+    """``sampling._row_sample``'s draw from one row of host logits (top-k,
+    then the argmax of scaled logits plus the Gumbel noise of ``(seed,
+    position)``), and whether it is DECIDED by more than ``gap``: no move of
+    every logit by at most ``gap`` can change it, because fewer than
+    ``top_k`` other tokens can pass the drawn one, and no token that can be
+    among the ``top_k`` can reach its perturbed score."""
+    scaled = logits / temperature
+    slack = 2 * gap / temperature
+    kth = np.sort(scaled)[-top_k]
+    noise = np.asarray(jax.random.gumbel(jax.random.fold_in(
+        jax.random.PRNGKey(seed), position), scaled.shape, jnp.float32))
+    score = np.where(scaled >= kth, scaled + noise, -np.inf)
+    drawn = int(np.argmax(score))
+    stays = np.sum(scaled > scaled[drawn] - slack) - 1 < top_k
+    rivals = scaled >= kth - slack
+    rivals[drawn] = False
+    reach = np.max(np.where(rivals, scaled + noise, -np.inf))
+    return drawn, bool(stays and score[drawn] - reach > slack)
+
+
 def test_generation_parity_int8_vs_fp_sampled(served, eight_devices):
-    """Seeded per-request sampling: identical sampled ids at fixed seeds —
-    int8's logit perturbation must not cross any draw threshold here."""
+    """Seeded per-request sampling, int8 KV beside fp KV. int8 pages move
+    every logit a little, so a draw that lies nearer one of the sampler's
+    thresholds than that (the k-th largest logit, the runner-up's score) can
+    fall the other way: identical ids at seeds picked blind were luck, and
+    one request of three lost it from its first token on. What the pages can
+    promise under sampling: along the fp stream the int8 logits stay within
+    a bound of the fp ones (0.03 here, of a range of 1.0 over the
+    vocabulary), and a draw decided by more than their gap is the same draw.
+    So the test reads the fp logits, finds for each request a seed none of
+    whose draws lies within the gap of a threshold, and holds the
+    scheduler's sampled ids at THOSE seeds to be identical (the greedy test
+    above holds at any)."""
     cfg, model, params = served
+    temperature, top_k, new_tokens = 0.7, 8, 5
+    engines = [make_engine(cfg, model, params, kv_dtype=d) for d in ("fp", "int8")]
+
+    def feed(uid, tokens):          # -> the last token's logits, fp and int8
+        tokens = np.asarray(tokens, np.int32)
+        for start in range(0, len(tokens), 16):
+            got = [np.asarray(e.put([uid], [tokens[start:start + 16]])[0], np.float32)
+                   for e in engines]
+        return got
+
+    seeds, streams, draws, decided_draws = {}, {}, 0, 0
+    for uid, prompt in _prompts(cfg).items():
+        for tried, seed in enumerate(range(400 + 17 * uid, 700 + 17 * uid)):
+            fp, q = feed(1000 * uid + tried, prompt)
+            stream = []
+            for position in range(new_tokens):
+                gap = float(np.abs(q - fp).max())
+                assert gap <= 0.03
+                drawn, decided = _draw(fp, temperature, top_k, seed, position, gap + 1e-4)
+                draws, decided_draws = draws + 1, decided_draws + decided
+                if not decided:
+                    break
+                assert _draw(q, temperature, top_k, seed, position, 0.0)[0] == drawn
+                stream.append(drawn)
+                fp, q = feed(1000 * uid + tried, [drawn])
+            for e in engines:
+                e.flush(1000 * uid + tried)
+            if len(stream) == new_tokens:
+                seeds[uid], streams[uid] = seed, stream
+                break
+    assert len(seeds) == 3, "a seed with five decided draws within 300, a request"
+    assert 0.25 < decided_draws / draws < 0.75      # the gap is no formality
 
     def kw(uid):
-        return {"max_new_tokens": 5, "temperature": 0.7, "top_k": 8,
-                "seed": 400 + uid * 17}
+        return {"max_new_tokens": new_tokens, "temperature": temperature,
+                "top_k": top_k, "seed": seeds[uid]}
 
     fp, _ = _drive(cfg, model, params, "fp", kw)
     q, _ = _drive(cfg, model, params, "int8", kw)
+    assert fp == streams        # the draws read above ARE the scheduler's
     assert q == fp
 
 

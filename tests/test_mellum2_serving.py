@@ -191,7 +191,8 @@ def test_a_decode_round_advances_both_groups_and_a_padded_row_changes_nothing(se
     for _ in range(30):
         out = engine.put(uids, [ids[u][pos[u]:pos[u] + 1] for u in uids])
         assert engine.last_batch_shapes == [(4, 1)]
-        assert engine.last_expert_rows == rows * 2 * 8 and engine.last_expert_rows_padded == 0
+        assert engine.last_counts["expert_rows"] == rows * 2 * 8
+        assert engine.last_counts["expert_rows_padded"] == 0
         for u in uids:
             pos[u] += 1
             worst = max(worst, float(np.max(np.abs(out[u] - want[u][pos[u] - 1]))))

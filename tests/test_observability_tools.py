@@ -1302,6 +1302,16 @@ def test_perf_gate_elastic_baseline_ratchet(tmp_path):
     _, errs = pg.check_elastic_baseline(str(bad))
     assert any("bitwise" in e for e in errs)
 
+    from deepspeed_tpu.resilience.elastic_reshard import RESTORE_LOSS_MAX_ULPS
+    assert pg.ELASTIC_RESTORE_MAX_ULPS == RESTORE_LOSS_MAX_ULPS
+    good.write_text(json.dumps(dict(_elastic_payload(), restore_loss_ulps={
+        "2": RESTORE_LOSS_MAX_ULPS, "4": 0})))
+    assert pg.check_elastic_baseline(str(good))[1] == []
+    bad.write_text(json.dumps(dict(_elastic_payload(), restore_loss_ulps={
+        "2": RESTORE_LOSS_MAX_ULPS + 1, "4": 0})))
+    _, errs = pg.check_elastic_baseline(str(bad))
+    assert any("ulps" in e for e in errs)
+
     bad.write_text(json.dumps(_elastic_payload(opt_step=5)))
     _, errs = pg.check_elastic_baseline(str(bad))
     assert any("optimizer step count" in e for e in errs)

@@ -277,8 +277,14 @@ def test_offload_nvme_non_adam_raises():
 
 def test_simd_adam_speedup_over_scalar():
     """The AVX-512 Adam step must beat the unvectorized build >=3x (VERDICT:
-    vectorize the host step — the bottleneck under ZeRO-Offload). Both sides
-    are OpenMP-parallel, so the ratio isolates vectorization."""
+    vectorize the host step — the bottleneck under ZeRO-Offload). Measured
+    where neighbours cannot take it away: ONE thread on both sides and a
+    working set inside one core's cache (four arrays of 64 Ki floats, 1 MiB),
+    many repeats, the minimum. Over 2 Mi floats on every core, as this test
+    measured before, both sides wait for the memory bus as soon as anything
+    else runs on the machine, and the ratio read 1.1 beside five other test
+    workers (alone 4x; this way 4.5-5.4x alone and beside seven
+    memory-bound processes alike)."""
     import ctypes, time
     from deepspeed_tpu.ops.cpu_adam import _native
     lib = _native()
@@ -286,7 +292,7 @@ def test_simd_adam_speedup_over_scalar():
         pytest.skip("native lib unavailable")
     if not lib.ds_built_with_avx512():
         pytest.skip("library built without AVX-512")
-    n = 1 << 21
+    n = 1 << 16
     rng = np.random.default_rng(0)
     pf = lambda a: a.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
     p = rng.normal(size=n).astype(np.float32)
@@ -295,7 +301,7 @@ def test_simd_adam_speedup_over_scalar():
     v = (rng.normal(size=n) ** 2 * 0.01).astype(np.float32)
     args = (3, 1e-3, 0.9, 0.999, 1e-8, 0.01, 1, 1, pf(p), pf(g), pf(m), pf(v), n)
 
-    def bench(fn, iters=8):
+    def bench(fn, iters=300):
         # best-of-iters: the MIN is robust to CI load spikes (a mean would
         # absorb scheduler noise and flake the ratio)
         fn(*args)
@@ -306,14 +312,24 @@ def test_simd_adam_speedup_over_scalar():
             best = min(best, time.perf_counter() - t0)
         return best
 
-    for attempt in range(3):   # re-measure if a load spike still slips in
-        t_scalar = bench(lib.ds_adam_step_scalar)
-        t_simd = bench(lib.ds_adam_step)
-        if t_scalar / t_simd >= 3.0:
-            break
+    try:        # the library's OpenMP runtime (none: it runs one thread anyway)
+        omp = ctypes.CDLL("libgomp.so.1")
+        threads = omp.omp_get_max_threads()
+        omp.omp_set_num_threads(1)
+    except OSError:
+        omp = None
+    try:
+        for attempt in range(3):   # re-measure if a load spike still slips in
+            t_scalar = bench(lib.ds_adam_step_scalar)
+            t_simd = bench(lib.ds_adam_step)
+            if t_scalar / t_simd >= 3.0:
+                break
+    finally:
+        if omp is not None:
+            omp.omp_set_num_threads(threads)
     assert t_scalar / t_simd >= 3.0, (
         f"SIMD speedup only {t_scalar/t_simd:.1f}x "
-        f"(scalar {t_scalar*1e3:.1f}ms simd {t_simd*1e3:.1f}ms)")
+        f"(scalar {t_scalar*1e6:.1f}us simd {t_simd*1e6:.1f}us)")
 
 
 def test_offload_moment_mismatch_raises(tmp_path):

@@ -175,9 +175,16 @@ _Z3 = {"stage": 3, "stage3_param_persistence_threshold": 0}
 
 
 def test_qgz_loss_parity_feedback_tightens():
-    """qgZ tracks the fp32 psum baseline; error feedback must track it
-    STRICTLY tighter (measured: 0.042 no-feedback vs 0.031 with, int4
-    intra stage on the 8-way dp world)."""
+    """qgZ tracks the fp32 psum baseline within its two documented bounds
+    (measured 0.033 without feedback, 0.034 with: int4 intra stage on the
+    8-way dp world), and error feedback tightens what it promises to: the
+    ACCUMULATED quantization error. The loss cannot show that (six bf16 AdamW
+    steps on fresh batches leave the two runs 0.001 apart in either order,
+    and no nearer over 30 steps or other seeds), so no order between the two
+    losses is claimed. The sums can: the same local gradients reduced eight
+    times drift from eight times their fp32 sum by eight single-step errors
+    without the carry, and with it stay within one step's error however many
+    steps are summed (the carries telescope)."""
     _, l_ref = _train(dict(_BASE, zero_optimization=dict(_Z3)), steps=6)
     groups.reset()
     _, l_q = _train(dict(_BASE, zero_optimization=dict(
@@ -191,10 +198,28 @@ def test_qgz_loss_parity_feedback_tightens():
     div_fb = max(abs(a - b) for a, b in zip(l_fb, l_ref))
     assert div_q <= 0.2, (l_q, l_ref)
     assert div_fb <= 0.1, (l_fb, l_ref)          # the tighter documented bound
-    assert div_fb < div_q, (div_fb, div_q)
     # the carry is real: residual leaves are populated after stepping
     res = jax.tree.leaves(eng.state.qgz_residual)
     assert res and any(float(jnp.abs(r).max()) > 0 for r in res)
+
+    # the engine's own reduction, over gradients that do not change
+    plan, steps = eng._qgz_plan, 8
+    rng = np.random.default_rng(0)
+    acc = jax.tree.map(lambda a: jax.device_put(
+        rng.normal(size=a.shape).astype(a.dtype), a.sharding), eng.state.grad_acc)
+    want = [np.asarray(a, np.float32).sum(0) for a in jax.tree.leaves(acc)]
+    worst = lambda got, n: max(float(np.abs(np.asarray(g) - n * w).max())
+                               for g, w in zip(got, want))
+    one_step = worst(jax.tree.leaves(jax.jit(plan.reduce)(acc)), 1)
+    reduce_fb = jax.jit(lambda a, r: plan.reduce(a, residual=r,
+                                                 return_residual=True))
+    carry = jax.tree.map(jnp.zeros_like, eng.state.qgz_residual)
+    total = [np.zeros_like(w) for w in want]
+    for _ in range(steps):
+        out, carry = reduce_fb(acc, carry)
+        total = [t + np.asarray(o) for t, o in zip(total, jax.tree.leaves(out))]
+    assert one_step > 0.1                        # int4 does lose something
+    assert worst(total, steps) <= 1.5 * one_step, (worst(total, steps), one_step)
 
 
 def test_qgz_feedback_requires_quantized_gradients():

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu import telemetry
 from deepspeed_tpu.inference.v2 import InferenceEngineV2, engine_v2
 from deepspeed_tpu.inference.v2.engine_v2 import packed_forward
 from deepspeed_tpu.inference.v2.model_implementations.llama import ragged_forward
@@ -298,3 +299,126 @@ def test_put_returns_rows_in_the_order_given(served):
         alone = engine.put([u], [t])
         engine.flush(u)
         np.testing.assert_allclose(together[u], alone[0], rtol=2e-4, atol=2e-4)
+
+
+# -- what a dispatch reports ------------------------------------------------------
+# ``engine_v2.py`` and ``scheduler.py`` carry what the cache groups
+# (``DSStateManager.dispatch_report``) and the family's module
+# (``dispatch_report``, over ``moe_layer``) say of a dispatch, and read none of
+# it (docs/SERVING.md, "What a dispatch reports")
+
+@pytest.fixture
+def build_spans():
+    """``spans()``: the attributes of this test's ``serving/build`` spans."""
+    telemetry.reset()
+    telemetry.configure(enabled=True)
+    yield lambda: [e["args"] for e in telemetry.get_telemetry().trace_events
+                   if e["name"] == "serving/build"]
+    telemetry.configure(enabled=False)
+    telemetry.reset()
+
+
+def _serve(sched, vocab_size, requests=((14, 6, 0.0), (21, 5, 0.8), (35, 6, 0.0))):
+    rng = np.random.default_rng(3)
+    for uid, (n, n_new, temperature) in enumerate(requests):
+        sched.submit(uid, rng.integers(0, vocab_size, n).astype(np.int32),
+                     max_new_tokens=n_new, temperature=temperature, seed=5)
+    sched.run_to_completion()
+
+
+def test_a_reporter_nobody_knows_reaches_the_spans_and_the_scheduler(served, build_spans):
+    """A stand-in reporter adds a key no family has: it is on every
+    ``serving/build`` span and its sum is the scheduler's, a key that only
+    rides is on the spans and in no sum: ``engine_v2.py`` and
+    ``scheduler.py`` carry keys they have never heard of."""
+    cfg, model, params, _ = served
+    asked = []
+
+    def report(config, real_tokens):
+        asked.append((config, real_tokens))
+        return {"probe_pages": 3 + real_tokens}, {"probe_row_bytes": 96}
+
+    engine = InferenceEngineV2(model, params, report_fn=report, config={
+        "state_manager": {"max_ragged_sequence_count": MAX_SEQS,
+                          "max_ragged_batch_size": MAX_TOKENS,
+                          "max_context": 128, "num_kv_blocks": 96},
+        "kv_cache": {"block_size": 8, "cache_dtype": "fp32"}})
+    sched = SplitFuseScheduler(engine)
+    assert sched.probe_pages == 0
+    _serve(sched, cfg.vocab_size)
+    builds = build_spans()
+    assert builds and len(builds) == sched.dispatches == len(asked) > sched.rounds > 3
+    assert all(config is cfg for config, _ in asked)
+    assert [a["real_tokens"] for a in builds] == [n for _, n in asked]
+    assert all(a["probe_pages"] == 3 + a["real_tokens"] and a["probe_row_bytes"] == 96
+               for a in builds)
+    assert sched.probe_pages == sum(a["probe_pages"] for a in builds) \
+        == 3 * sched.dispatches + sched.real_tokens
+    assert type(sched.probe_pages) is int and "probe_row_bytes" not in sched.counts
+    assert engine.last_counts["probe_pages"] == sum(
+        a["probe_pages"] for a in builds if a["round"] == engine.round - 1)
+    with pytest.raises(AttributeError):
+        sched._no_such_private_name
+
+
+def _served_family(name):
+    """(model, params) of a served family's tiny preset, the expert families
+    that the benchmark serves as one share of eight under ``experts_held``."""
+    key = jax.random.PRNGKey(0)
+    if name == "llama":
+        model = LlamaForCausalLM(LlamaConfig.tiny(scan_layers=True, remat=False))
+        return model, model.init(key, {"input_ids": np.zeros((1, 8), np.int32)})["params"]
+    if name == "phi4flash":
+        from deepspeed_tpu.models.phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM
+        model = Phi4FlashForCausalLM(Phi4FlashConfig.tiny())
+    elif name == "mellum2":
+        from deepspeed_tpu.models.mellum2 import Mellum2Config, Mellum2ForCausalLM
+        model = Mellum2ForCausalLM(Mellum2Config.tiny())
+    elif name == "kanana2":
+        from deepspeed_tpu.models.kanana2 import Kanana2Config, Kanana2ForCausalLM
+        model = Kanana2ForCausalLM(Kanana2Config.tiny(experts_held=(4, 8)))
+    else:
+        from deepspeed_tpu.models.keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
+        model = KeyeVL2ForCausalLM(KeyeVL2Config.tiny(experts_held=(4, 2)))
+    return model, model.init_params(key)
+
+
+_EVERY_BUILD = {"round", "dispatch", "seqs", "seq_bucket", "chunk_bucket", "real_tokens",
+                "padded_slots", "context_tokens", "live_pages"}
+_FURTHER_GROUPS = {"window_pages_freed", "state_slots", "global_pages", "window_pages",
+                   "window_live_pages"}
+_EXPERTS = {"expert_rows", "expert_rows_padded"}
+_A_SHARE = {"experts_held", "experts_routed_over"}
+
+
+@pytest.mark.parametrize("family,beyond,summed", [
+    ("llama", set(), set()),
+    ("phi4flash", _FURTHER_GROUPS, {"window_pages_freed", "state_slots"}),
+    ("mellum2", _FURTHER_GROUPS | _EXPERTS, {"window_pages_freed", "state_slots"} | _EXPERTS),
+    ("kanana2", _EXPERTS | _A_SHARE | {"latent_pages", "latent_row_bytes"},
+     _EXPERTS | {"latent_pages"}),
+    ("keye_vl2", _EXPERTS | _A_SHARE | {"index_pages", "index_row_bytes", "sparse_rows",
+                                        "selected_tokens"},
+     _EXPERTS | {"index_pages", "sparse_rows", "selected_tokens"})])
+def test_the_build_spans_attributes_are_the_ones_the_benchmark_reads(
+        family, beyond, summed, build_spans):
+    """The SET of attribute names on ``serving/build`` a served family, as
+    the benchmark's readers take them from the trace (PERF.md section 3):
+    none dropped, none new, every one a plain int, and those a reporter adds
+    to the round's counts summed by the scheduler under the same name."""
+    from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    model, params = _served_family(family)
+    sched = SplitFuseScheduler(build_engine(model, params, {
+        "state_manager": {"max_ragged_sequence_count": 4, "max_ragged_batch_size": 16,
+                          "max_context": 128, "num_kv_blocks": 64},
+        "kv_cache": {"block_size": 4, "cache_dtype": "fp32"}}))
+    _serve(sched, model.config.vocab_size)
+    builds = build_spans()
+    assert builds and all(set(a) == _EVERY_BUILD | beyond for a in builds)
+    assert all(type(v) is int for a in builds for v in a.values())
+    summed = summed | {"real_tokens", "padded_slots", "live_pages"}
+    assert set(sched.counts) - {"ahead_rows_dropped"} == summed | {
+        "rounds", "dispatches", "rounds_ahead", "ahead_rows", "dispatches_sorted"}
+    for key in summed:
+        assert getattr(sched, key) == sum(a[key] for a in builds), key
+    assert sched.dispatches == len(builds) and sched.latent_pages == sched.counts["latent_pages"]

@@ -213,16 +213,21 @@ def test_greedy_parity_and_acceptance(served, eight_devices):
 
 def test_seeded_sampling_parity(served, eight_devices):
     """Seeded per-request sampling shares the (seed, position) stream: the
-    speculative run emits exactly the plain run's tokens (accepted drafts
-    are by construction the tokens plain decode would have drawn)."""
+    speculative run emits exactly the plain run's tokens, over rounds in
+    which verify chunks ran (an accepted draft is by construction the token
+    plain decode would have drawn; where a draft is rejected, the token in
+    its place is the verify sampler's draw at that column)."""
     cfg, model, params = served
     prompts = _repetitive_prompts(cfg, n=3, seed=2)
 
     def kw(uid):
         # low temperature: a random-weight tiny model rarely re-samples its
-        # own context at high temp, so the n-gram drafter would never fire
-        # and the verify path would go untested
-        return {"max_new_tokens": 8, "temperature": 0.2, "top_k": 12,
+        # own context at high temp. And twelve tokens, not eight: the n-gram
+        # drafter fires once a sampled token has occurred before, which in
+        # eight tokens after these prompts it never did (the test then
+        # compared two plain runs); in twelve it does on every prompt seed
+        # 1-10 at temperatures 0.1-0.4 (here 10 drafted, 1 accepted)
+        return {"max_new_tokens": 12, "temperature": 0.2, "top_k": 12,
                 "seed": 500 + uid * 7}
 
     off, _, _ = _run_sched(cfg, model, params, prompts, spec=False, kw_fn=kw)
@@ -231,6 +236,10 @@ def test_seeded_sampling_parity(served, eight_devices):
     assert on == off, "speculative sampling must share the seeded stream"
     assert sched.speculated_tokens > 0, \
         "sampled rows must actually run verify chunks"
+    assert sched.rejected_tokens > 0, \
+        "a rejected draft is replaced by the verify sampler's own draw"
+    assert sched.speculated_tokens == \
+        sched.accepted_tokens + sched.rejected_tokens
 
 
 def test_greedy_parity_mixed_random_prompts(served, eight_devices):
