@@ -16,7 +16,12 @@ of one leaf, sigmoid-routed experts beside a shared one, of which the tree may
 hold a share), and keye_vl2 (Keye-VL-2.0's language model: learned sparse
 attention, every query reading the ``topk`` cached tokens its indexer picks,
 over one paged group whose page keeps the indexer's key beside K and V;
-softmax-routed experts of which the tree may hold a share).
+softmax-routed experts of which the tree may hold a share), and longcat_flash
+(LongCat-Flash-Chat: shortcut-connected double layers, two latent attentions
+with a low-rank query and two dense FFNs each, beside one expert layer whose
+router's last columns are identity experts that compute nothing; one paged
+group of one leaf with two planes a layer, and a counter group the expert
+layers add to on the device).
 
 A family is three things, resolved here: its ragged forward, its verify
 forward (or None) and its cache groups (``ragged/cache_groups.py``); two more
@@ -41,14 +46,16 @@ _IMPLEMENTATION = {"llama": "llama", "mistral": "llama", "qwen2": "llama",
                    "mixtral": "mixtral", "falcon": "parallel_block",
                    "phi": "parallel_block", "opt": "opt",
                    "phi4flash": "phi4flash", "mellum2": "mellum2",
-                   "kanana2": "kanana2", "keye_vl2": "keye_vl2"}
+                   "kanana2": "kanana2", "keye_vl2": "keye_vl2",
+                   "longcat_flash": "longcat_flash"}
 
 #: families ``build_engine`` serves from an in-tree model and tree
 SERVED_FAMILIES = tuple(_IMPLEMENTATION)
 #: families ``build_hf_engine`` loads from a checkpoint directory
 SUPPORTED_FAMILIES = tuple(
     f for f in SERVED_FAMILIES
-    if f not in ("phi4flash", "mellum2", "kanana2", "keye_vl2"))  # no HF converter
+    if f not in ("phi4flash", "mellum2", "kanana2", "keye_vl2",
+                 "longcat_flash"))  # no HF converter
 
 #: the one place a config class names its family; any other is a llama tree
 _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
@@ -57,7 +64,8 @@ _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
                      "Phi4FlashConfig": "phi4flash",
                      "Mellum2Config": "mellum2",
                      "Kanana2Config": "kanana2",
-                     "KeyeVL2Config": "keye_vl2"}
+                     "KeyeVL2Config": "keye_vl2",
+                     "LongcatFlashConfig": "longcat_flash"}
 
 
 def _implementation(model, family):

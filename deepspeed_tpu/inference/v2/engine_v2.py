@@ -699,6 +699,16 @@ class InferenceEngineV2:
         window pages and the slot a preempted sequence took to the host."""
         return self._state.further_groups_fit_resume(uid)
 
+    def device_counters(self):
+        """{field: count} of the family's counter group
+        (``ragged/cache_groups.py``), {} where it declares none: one fetch
+        that waits for the dispatches in flight, for a caller OUTSIDE a round
+        (the benchmark when its window closes); no round fetches it. Counted
+        as a host sync where there is a group to fetch."""
+        if self._state.counter_group is not None:
+            self._host_sync_count += 1
+        return self._state.device_counters()
+
     @property
     def swap_stats(self):
         return {"swap_outs": self._state.swap_outs,

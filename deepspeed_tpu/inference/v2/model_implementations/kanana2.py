@@ -63,23 +63,24 @@ def prepare_params(cfg, params):
     return out
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _layer(cfg, dense, lp, x, pool, tables, seen, q_len, real, rope, trash):
-    """One decoder layer over x [S, Q, d] against the merged pool of latent
-    pages; ``tables`` and ``trash`` are this layer's. ``trash`` is a traced
-    scalar so that the layers of one kind share ONE traced and lowered
-    function (``mellum2._layer``)."""
+def absorbed_mla(cfg, scope, attn, project_q, h, x, pool, tables, seen, q_len,
+                 rope, trash):
+    """``x + Attn(h)`` of one latent attention over the merged pool, absorbed
+    (module docstring), and the pool with the new rows written: what this
+    family and LongCat-Flash (``longcat_flash.py``: a low-rank q, two of them
+    a layer) share. ``attn``: ``kv_a_proj``, ``kv_a_layernorm``, ``w_uk``,
+    ``w_uv``, ``o_proj``; ``project_q(h)`` -> [S, Q, H, nope + rope], traced
+    under ``mla_q``. The device scopes are ``<scope>/{mla_q,
+    mla_latent_write, mla_read, mla_out}``."""
     S, Q, _ = x.shape
     H, r = cfg.num_attention_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     W, bs = pool.shape[-1], pool.shape[2]
     eps, dt = cfg.rms_norm_eps, cfg.dtype
-    attn = lp["self_attn"]
     w_uk, w_uv = attn["w_uk"].astype(dt), attn["w_uv"].astype(dt)
-    h = _rmsnorm(x, lp["input_layernorm"]["scale"], eps)
-    with jax.named_scope("mla_attn"):
+    with jax.named_scope(scope):
         with jax.named_scope("mla_q"):
-            q = (h @ attn["q_proj"]["kernel"].astype(dt)).reshape(S, Q, H, dn + dr)
+            q = project_q(h)
             q_lat = jnp.einsum("sqhd,chd->sqhc", q[..., :dn], w_uk)
             q_row = jnp.concatenate(
                 [q_lat, rotary_apply(q[..., dn:], *rope),
@@ -97,6 +98,25 @@ def _layer(cfg, dense, lp, x, pool, tables, seen, q_len, real, rope, trash):
         with jax.named_scope("mla_out"):
             o = jnp.einsum("sqhc,chd->sqhd", o_lat, w_uv)
             x = x + o.reshape(S, Q, H * dv) @ attn["o_proj"]["kernel"].astype(dt)
+    return x, pool
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _layer(cfg, dense, lp, x, pool, tables, seen, q_len, real, rope, trash):
+    """One decoder layer over x [S, Q, d] against the merged pool of latent
+    pages; ``tables`` and ``trash`` are this layer's. ``trash`` is a traced
+    scalar so that the layers of one kind share ONE traced and lowered
+    function (``mellum2._layer``)."""
+    S, Q, _ = x.shape
+    H = cfg.num_attention_heads
+    eps, dt = cfg.rms_norm_eps, cfg.dtype
+    attn = lp["self_attn"]
+    h = _rmsnorm(x, lp["input_layernorm"]["scale"], eps)
+    x, pool = absorbed_mla(
+        cfg, "mla_attn", attn,
+        lambda h: (h @ attn["q_proj"]["kernel"].astype(dt)).reshape(
+            S, Q, H, cfg.qk_head_dim),
+        h, x, pool, tables, seen, q_len, rope, trash)
 
     h = _rmsnorm(x, lp["post_attention_layernorm"]["scale"], eps)
     if dense:

@@ -1,10 +1,11 @@
 """The sparse-expert feed-forward layer of a ragged forward: the one place
 shared by every family that has one (``mixtral.py``, ``mellum2.py``,
-``kanana2.py``, ``keye_vl2.py``).
+``kanana2.py``, ``keye_vl2.py``, ``longcat_flash.py``).
 
 ``moe_ffn`` routes a flat batch of token slots (by default softmax over all
 experts, the ``k`` largest, renormalised: ``grouped_gemm.topk_router``; with
-``scoring="sigmoid"`` the DeepSeek-V3 router, ``sigmoid_router``) and runs the
+``scoring="sigmoid"`` the DeepSeek-V3 router, ``sigmoid_router``; with
+``scoring="softmax_bias"`` LongCat-Flash's, ``softmax_bias_router``) and runs the
 chosen experts' SwiGLU: the ragged grouped GEMM (``ops/pallas/grouped_gemm.py``:
 rows sorted by expert, no capacity dimension) when Pallas is on and the dims
 tile, else the GShard dense dispatch-combine einsum below, which
@@ -28,17 +29,34 @@ visits it; in the einsum a zero dispatch row). A token none of whose experts
 are held gets the shared expert's output alone. Nothing stands in for the
 other shares or their exchange.
 
+Experts that compute nothing. ``zero_experts=n`` says that the router's LAST
+``n`` columns are identity experts (LongCat-Flash's ``zero_expert_type``
+``identity``): a row chosen there is a third outcome beside a group row and a
+row of another share. It is sorted past every group exactly as those are (no
+GEMM row), and its token gets ``(the sum of its zero picks' weights) x`` added.
+``experts_held`` keeps meaning a range of the REAL experts (the columns before
+the zero ones); the zero experts are every share's, so a sum over shares counts
+their term once. ``counts=True`` also returns what only the device knows of
+the dispatch, int32 ``COUNTS``: the rows routed for valid tokens, those that
+took a zero expert, those that landed on an expert held here, and the held
+experts with at least one row.
+
 Scopes for the device trace: everything here is under ``moe_ffn``; inside it
 the router under ``moe_router``, the sort and gather of rows under
 ``moe_sort``, each grouped GEMM under ``moe_ffn_gmm``, the unsort and the
 weighted sum of a token's ``k`` rows under ``moe_unsort``, a shared expert
-(every token's, dense) under ``moe_shared``.
+(every token's, dense) under ``moe_shared``, the zero experts' term under
+``moe_zero``, the counts under ``moe_counts``.
 """
 
 import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops.registry import pallas_interpret, takes_kernel
+
+
+#: what ``moe_ffn(counts=True)`` counts of one layer of one dispatch, in order
+COUNTS = ("routed_rows", "zero_rows", "held_rows", "experts_hit")
 
 
 def dispatch_report(cfg, real_tokens):
@@ -79,19 +97,36 @@ def sigmoid_router(x, gate_wg, bias, k, scale):
         top_idx
 
 
+def softmax_bias_router(x, gate_wg, bias, k, scale):
+    """LongCat-Flash's router: ``p = softmax(x W_r)`` in float32 over ALL
+    columns (the zero experts' among them); chosen are the ``k`` largest of
+    ``p + bias`` (``e_score_correction_bias`` selects and never weighs);
+    weights ``scale * p[chosen]``, NOT renormalised. -> (weights, indices),
+    each [T, k]."""
+    probs = jax.nn.softmax(
+        jnp.dot(x, gate_wg, preferred_element_type=jnp.float32), axis=-1)
+    _, top_idx = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+    return jnp.take_along_axis(probs, top_idx, axis=-1) * scale, top_idx
+
+
 def moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, valid=None,
             force_einsum=False, scoring="softmax", score_bias=None,
-            routed_scale=1.0, shared=None, experts_held=None):
+            routed_scale=1.0, shared=None, experts_held=None, zero_experts=0,
+            counts=False):
     """x: [T, D]; gate_wg: [D, E]; w1/w3: [E_held, D, F]; w2: [E_held, F, D]
     (``E_held`` is E unless ``experts_held`` says otherwise); ``valid``: [T]
     bool, None for all. Returns [T, D], zero where not valid.
 
     ``scoring``: ``"softmax"`` (Mixtral, Mellum2: softmax, top-k,
     renormalised) or ``"sigmoid"`` (``sigmoid_router`` with ``score_bias``
-    [E] and ``routed_scale``). ``shared``: ``(w1, w2, w3)`` of a dense SwiGLU
+    [E] and ``routed_scale``) or ``"softmax_bias"`` (``softmax_bias_router``
+    with the same two). ``shared``: ``(w1, w2, w3)`` of a dense SwiGLU
     every valid token takes beside its routed experts, or None.
     ``experts_held``: ``(first, count)`` of the router's E columns whose
     experts the weights hold, None for all (module docstring).
+    ``zero_experts``: how many of the router's last columns are identity
+    experts; ``E`` then counts the columns before them. ``counts``: return
+    ``(y, int32 [len(COUNTS)])`` (module docstring).
 
     Inference uses LOSSLESS capacity C = T: no token is ever dropped. The
     training-side capacity_factor machinery (moe/sharded_moe.py) does not
@@ -99,7 +134,7 @@ def moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, valid=None,
     """
     from deepspeed_tpu.ops.pallas import grouped_gemm as gg
     T, D = x.shape
-    E = gate_wg.shape[1]
+    E = gate_wg.shape[1] - zero_experts
     F = w1.shape[-1]
     if valid is None:
         valid = jnp.ones((T,), bool)
@@ -108,12 +143,21 @@ def moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, valid=None,
         with jax.named_scope("moe_router"):
             if scoring == "softmax":
                 top_vals, top_idx = gg.topk_router(x, gate_wg, k)    # [T, k]
-            elif scoring == "sigmoid":
-                top_vals, top_idx = sigmoid_router(x, gate_wg, score_bias, k,
-                                                   routed_scale)
+            elif scoring in ("sigmoid", "softmax_bias"):
+                route = sigmoid_router if scoring == "sigmoid" \
+                    else softmax_bias_router
+                top_vals, top_idx = route(x, gate_wg, score_bias, k,
+                                          routed_scale)
             else:
                 raise ValueError(f"unknown router scoring {scoring!r}")
             top_vals = jnp.where(valid[:, None], top_vals, 0.0)
+            if zero_experts:
+                # a zero expert's row becomes index E (of the real experts):
+                # past every group; its weight goes to the identity term
+                is_zero = top_idx >= E
+                zero_w = jnp.sum(jnp.where(is_zero, top_vals, 0.0), -1)
+                top_idx = jnp.where(is_zero, E, top_idx)
+                top_vals = jnp.where(is_zero, 0.0, top_vals)
             if experts_held is not None:
                 first, E = experts_held
                 assert w1.shape[0] == E, "the weights hold experts_held"
@@ -131,12 +175,24 @@ def moe_ffn(x, gate_wg, w1, w2, w3, *, k, dtype, valid=None,
                                interpret=pallas_interpret())
         else:
             y = _moe_ffn_einsum(x, top_vals, top_idx, valid, w1, w2, w3, dtype)
-        if shared is None:
+        if zero_experts:
+            with jax.named_scope("moe_zero"):
+                y = y + (zero_w[:, None] * x.astype(jnp.float32)).astype(dtype)
+        if shared is not None:
+            with jax.named_scope("moe_shared"):
+                s1, s2, s3 = shared
+                h = (jax.nn.silu(x @ s1) * (x @ s3)) @ s2
+                y = y + jnp.where(valid[:, None], h, 0).astype(dtype)
+        if not counts:
             return y
-        with jax.named_scope("moe_shared"):
-            s1, s2, s3 = shared
-            h = (jax.nn.silu(x @ s1) * (x @ s3)) @ s2
-            return y + jnp.where(valid[:, None], h, 0).astype(dtype)
+        with jax.named_scope("moe_counts"):
+            lands = valid[:, None] & (top_idx < E)        # on an expert held
+            rows = jnp.zeros((E,), jnp.int32).at[
+                jnp.where(lands, top_idx, E).reshape(-1)].add(1, mode="drop")
+            zero = jnp.sum(valid[:, None] & is_zero) if zero_experts else 0
+            return y, jnp.stack([
+                jnp.sum(valid) * k, zero, jnp.sum(rows), jnp.sum(rows > 0)
+            ]).astype(jnp.int32)
 
 
 def _moe_ffn_einsum(x, top_vals, top_idx, valid, w1, w2, w3, dtype):

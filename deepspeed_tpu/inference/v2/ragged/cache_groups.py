@@ -26,6 +26,17 @@ K and V are read (learned sparse attention). It lives in the same pages, under
 the same allocator and the same table as its token's K and V, and is
 allocated, freed, preempted and resumed with them; what was not extended to
 it is refused by this field as for a group of one leaf (``kv_pair``).
+
+A third kind beside the paged and the slot group is NOT a sequence's: a
+``CounterGroup``, a small int32 accumulator of named fields that the family's
+forward adds to, a dispatch (what only the device knows of a dispatch: which
+experts its rows landed on). The state manager holds the one array in
+``cache_view`` / ``cache_update``, donated and returned like a pool, and hands
+it out on demand (``device_counters``: one fetch outside any round, never a
+round's own). No sequence owns a part of it, so nothing that carries a
+sequence's state carries it: allocation, free, preemption and resume leave it
+as it is, and the prefix cache, the page wire and the host tiers (swap) have
+nothing of it to key, ship or spill. A family that declares none gets no leaf.
 """
 
 import dataclasses
@@ -80,6 +91,17 @@ class SlotGroup:
     # ((leaf name, shape of one sequence's slot with the layer axis first,
     # dtype name), ...); the pool is [layers, slots + 1, *shape[1:]]
     leaves: Tuple[Tuple[str, Tuple[int, ...], str], ...]
+
+
+@dataclasses.dataclass(frozen=True)
+class CounterGroup:
+    name: str
+    # the accumulator is int32 [len(fields)], a field a place
+    fields: Tuple[str, ...]
+
+    def __post_init__(self):
+        if not self.fields or len(set(self.fields)) != len(self.fields):
+            raise ValueError("a counter group names its fields, each once")
 
 
 def homogeneous(cfg):

@@ -5,11 +5,13 @@ cache groups declare it (``ragged/cache_groups.py``): the blocked KV cache of
 the ``"kv"`` group (a K and a V pool, a third pool of an indexer's keys in
 the same pages where the group declares ``index_dim``, or ONE pool of latent
 rows where it declares ``leaves=1``), further paged groups whose pages are freed
-behind a window, and a slot group of recurrent state."""
+behind a window, a slot group of recurrent state, and a counter group (an
+int32 accumulator the forward adds to, no sequence's: ``device_counters``)."""
 
 import numpy as np
 
-from deepspeed_tpu.inference.v2.ragged.cache_groups import PagedGroup, SlotGroup
+from deepspeed_tpu.inference.v2.ragged.cache_groups import (
+    CounterGroup, PagedGroup, SlotGroup)
 from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
 from deepspeed_tpu.inference.v2.ragged.prefix_cache import PrefixCache
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import DSSequenceDescriptor
@@ -30,7 +32,10 @@ class DSStateManager:
     def __init__(self, config, groups):
         self._config = config
         sm, kv = config.state_manager, config.kv_cache
-        primary, further = groups[0], groups[1:]
+        primary = groups[0]
+        counters = [g for g in groups[1:] if isinstance(g, CounterGroup)]
+        further = [g for g in groups[1:] if g not in counters]
+        self._init_counters(counters)
         if not isinstance(primary, PagedGroup) or primary.name != "kv":
             raise ValueError("a model's first cache group is the paged "
                              "group \"kv\"")
@@ -177,6 +182,28 @@ class DSStateManager:
                 leaves=g.leaves))
             self.table_width[g.name] = width
 
+    def _init_counters(self, declared):
+        """The counter group's accumulator, zeros: not a sequence's, so it is
+        no further group (no table, nothing to allocate, free or refuse)."""
+        if len(declared) > 1:
+            raise ValueError("one counter group a model")
+        self.counter_group = declared[0] if declared else None
+        self.counters = None
+        if declared:
+            import jax.numpy as jnp
+            self.counters = jnp.zeros((len(declared[0].fields),), jnp.int32)
+
+    def device_counters(self):
+        """{field: count} of the counter group since the engine was built, by
+        ONE fetch; {} for a model that declared none. It waits for every
+        dispatch in flight, so it is called outside a round (when a window
+        closes), never by ``step()``."""
+        if self.counter_group is None:
+            return {}
+        import jax
+        values = jax.device_get(self.counters)  # graftlint: allow[GL003] on demand and outside any round: the engine's accessor counts the sync; no serving/fetch span, which the readers pair with rounds
+        return dict(zip(self.counter_group.fields, (int(v) for v in values)))
+
     @property
     def has_further_groups(self):
         return bool(self.paged_groups) or self.slot_group is not None
@@ -255,13 +282,15 @@ class DSStateManager:
     def cache_view(self):
         """The donated ``cache`` argument of a forward: ``{"kv": (K, V)}``
         (``(K, V, index)`` with an index leaf, ``(pages,)`` for a group of one
-        leaf) and, for a model that
-        declared them, the further groups' pools."""
+        leaf) and, for a model that declared them, the further groups' pools
+        and the counter group's accumulator."""
         view = {"kv": self.kv_cache.fwd}
         for name, (_, cache) in self.paged_groups.items():
             view[name] = cache.fwd
         if self.slot_group is not None:
             view[self.slot_group.name] = self.slot_pools
+        if self.counter_group is not None:
+            view[self.counter_group.name] = self.counters
         return view
 
     def cache_update(self, view):
@@ -271,6 +300,8 @@ class DSStateManager:
             cache.update(*view[name])
         if self.slot_group is not None:
             self.slot_pools = view[self.slot_group.name]
+        if self.counter_group is not None:
+            self.counters = view[self.counter_group.name]
 
     def group_tables(self, seqs, n_rows):
         """The further groups' entries of a dispatch's ``tables`` for the

@@ -130,25 +130,28 @@ def test_paged_attention_mistral_geometry(for_tpu, one_chip, seqs, q_tokens,
     _compile(fn, _paged_args(one_chip, seqs, q_tokens, int8, table))
 
 
-@pytest.mark.parametrize("seqs,q_tokens", [
-    (64, 1), (1, 512), (1, 16), (1, 128)],
-    ids=["decode64", "chunk512", "chunk16", "chunk128"])
-def test_paged_mla_kanana2_geometry(for_tpu, one_chip, seqs, q_tokens):
+@pytest.mark.parametrize("seqs,q_tokens,heads,table", [
+    (64, 1, 32, 320), (1, 512, 32, 320), (1, 16, 32, 320), (1, 128, 32, 320),
+    (64, 1, 64, 144), (1, 512, 64, 144), (1, 8, 64, 144)],
+    ids=["decode64", "chunk512", "chunk16", "chunk128",
+         "longcat-decode64", "longcat-chunk512", "longcat-chunk8"])
+def test_paged_mla_kanana2_geometry(for_tpu, one_chip, seqs, q_tokens, heads, table):
     """The latent walk at the benchmark's Kanana-2 cell: 32 query heads on a
     row of 640 columns (512 latent + 64 rotated + padding) whose first 512
     are the values, over a 320-slot table; a chunk's 16,384 query rows in
-    tiles of 512 on the grid."""
+    tiles of 512 on the grid. And at the LongCat-Flash cell's: 64 heads on the
+    same row over a 144-slot table (9,216 tokens), a chunk's 32,768 rows."""
     from deepspeed_tpu.ops.pallas import paged_attention as pa
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     pool = sds((2049, 1, PAGE, 640), jnp.bfloat16)
-    q = sds((seqs, q_tokens, 32, 640), jnp.bfloat16)
+    q = sds((seqs, q_tokens, heads, 640), jnp.bfloat16)
     assert pa.mla_is_supported(q.shape, pool.shape, 512)
 
     def fn(q, bt, seen, q_len, pool):
         return pa.paged_mla(q, pool, bt, seen, q_len, value_dim=512,
                             softmax_scale=192 ** -0.5)
 
-    compiled = _compile(fn, (q, sds((seqs, 320), jnp.int32), sds((seqs,), jnp.int32),
+    compiled = _compile(fn, (q, sds((seqs, table), jnp.int32), sds((seqs,), jnp.int32),
                              sds((seqs,), jnp.int32), pool))
     assert "paged_mla" in compiled.as_text()
 
@@ -293,6 +296,8 @@ def _cell_program(name, rows, one_chip, monkeypatch):
     from deepspeed_tpu.inference.v2 import engine_v2
     from deepspeed_tpu.inference.v2.engine_factory import build_engine
     from deepspeed_tpu.models.kanana2 import Kanana2Config, Kanana2ForCausalLM
+    from deepspeed_tpu.models.longcat_flash import (LongcatFlashConfig,
+                                                    LongcatFlashForCausalLM)
     from deepspeed_tpu.models.mellum2 import Mellum2Config, Mellum2ForCausalLM
     from deepspeed_tpu.models.mistral import MistralForCausalLM, mistral_config
     from deepspeed_tpu.models.phi4flash import (Phi4FlashConfig,
@@ -308,6 +313,11 @@ def _cell_program(name, rows, one_chip, monkeypatch):
     elif cfg["driver"] == "serve_kanana2":
         share = cfg["experts_held"]
         model = Kanana2ForCausalLM(Kanana2Config.from_hf(
+            cfg, dtype=jnp.bfloat16, n_routed_experts=cfg["n_routed_experts_published"],
+            experts_held=(share["first"], share["count"])))
+    elif cfg["driver"] == "serve_longcat_flash":
+        share = cfg["experts_held"]
+        model = LongcatFlashForCausalLM(LongcatFlashConfig.from_hf(
             cfg, dtype=jnp.bfloat16, n_routed_experts=cfg["n_routed_experts_published"],
             experts_held=(share["first"], share["count"])))
     else:
@@ -340,9 +350,10 @@ def _cell_program(name, rows, one_chip, monkeypatch):
 @pytest.mark.parametrize("name,rows,bucket,kernels", [
     ("mistral-7b-l16", 64, 64, 1), ("mistral-7b-l16", 3, 4, 1),
     ("phi4-mini-flash", 64, 64, 5), ("phi4-mini-flash", 3, 4, 5),
-    ("mellum2-l12", 64, 64, 48), ("kanana2-l12-ep8", 64, 64, 45)],
+    ("mellum2-l12", 64, 64, 48), ("kanana2-l12-ep8", 64, 64, 45),
+    ("longcat-flash-l4-ep32", 64, 64, 20)],
     ids=["mistral64", "mistral4", "phi4flash64", "phi4flash4", "mellum2-64",
-         "kanana2-64"])
+         "kanana2-64", "longcat-flash-64"])
 def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
                                              name, rows, bucket, kernels):
     """The WHOLE ragged forward of a decode round, [64, 1] and [4, 1], as the
@@ -350,7 +361,9 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
     widths, the paged kernel (and phi4flash's scan; for mellum2 the paged
     kernel and the three grouped GEMMs in each of 12 layers, over 512 expert
     rows of which a padded row takes none; for kanana2 the latent walk in 12
-    layers and the grouped GEMMs over the 16 experts held in 11) at one token
+    layers and the grouped GEMMs over the 16 experts held in 11; for
+    longcat-flash the latent walk twice and the grouped GEMMs once in each of
+    4 double layers, the zero experts' rows past every group) at one token
     a row, read from the host's buffer or, by the row's source, from the ids
     the round before left on the device (the program's last array, one
     place a row of the engine's 64)."""
@@ -364,8 +377,11 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
 @pytest.mark.parametrize("tokens,k,experts,d,f,dtype,grad", [
     (512, 8, 64, 2304, 896, jnp.bfloat16, False),
     (64, 2, 8, 4096, 14336, jnp.bfloat16, False),
-    (512, 2, 8, 4096, 14336, jnp.float32, True)],
-    ids=["mellum2-chunk512", "mixtral-decode64", "mixtral-f32-train"])
+    (512, 2, 8, 4096, 14336, jnp.float32, True),
+    (512, 12, 16, 6144, 2048, jnp.bfloat16, False),
+    (64, 12, 16, 6144, 2048, jnp.bfloat16, False)],
+    ids=["mellum2-chunk512", "mixtral-decode64", "mixtral-f32-train",
+         "longcat-chunk512", "longcat-decode64"])
 def test_grouped_gemm_ffn_at_the_rules_tiles(for_tpu, one_chip, tokens, k,
                                              experts, d, f, dtype, grad):
     """The expert FFN alone at the tiles ``grouped_gemm.gmm_tiling`` picks for

@@ -377,6 +377,10 @@ def _served_family(name):
     elif name == "kanana2":
         from deepspeed_tpu.models.kanana2 import Kanana2Config, Kanana2ForCausalLM
         model = Kanana2ForCausalLM(Kanana2Config.tiny(experts_held=(4, 8)))
+    elif name == "longcat_flash":
+        from deepspeed_tpu.models.longcat_flash import (
+            LongcatFlashConfig, LongcatFlashForCausalLM)
+        model = LongcatFlashForCausalLM(LongcatFlashConfig.tiny(experts_held=(4, 8)))
     else:
         from deepspeed_tpu.models.keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
         model = KeyeVL2ForCausalLM(KeyeVL2Config.tiny(experts_held=(4, 2)))
@@ -399,7 +403,11 @@ _A_SHARE = {"experts_held", "experts_routed_over"}
      _EXPERTS | {"latent_pages"}),
     ("keye_vl2", _EXPERTS | _A_SHARE | {"index_pages", "index_row_bytes", "sparse_rows",
                                         "selected_tokens"},
-     _EXPERTS | {"index_pages", "sparse_rows", "selected_tokens"})])
+     _EXPERTS | {"index_pages", "sparse_rows", "selected_tokens"}),
+    # the counter group adds no name: what it counts is never on a span
+    ("longcat_flash", _EXPERTS | _A_SHARE | {"latent_pages", "latent_row_bytes", "zero_experts",
+                                             "kv_planes"},
+     _EXPERTS | {"latent_pages"})])
 def test_the_build_spans_attributes_are_the_ones_the_benchmark_reads(
         family, beyond, summed, build_spans):
     """The SET of attribute names on ``serving/build`` a served family, as
