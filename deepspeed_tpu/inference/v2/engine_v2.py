@@ -384,7 +384,8 @@ class InferenceEngineV2:
                     "live_pages": live_pages}
             rides = {"seq_bucket": seq_bucket, "chunk_bucket": chunk_bucket,
                      "context_tokens": context_tokens}
-            reports = [self._state.dispatch_report(seqs)]
+            reports = [self._state.dispatch_report(
+                seqs, arrays["seen"], arrays["q_len"], chunk_bucket)]
             if self._dispatch_report is not None:
                 reports.append(
                     self._dispatch_report(self._model_config, real_tokens))

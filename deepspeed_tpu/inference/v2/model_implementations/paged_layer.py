@@ -1,6 +1,7 @@
 """How a layer of a ragged forward touches its paged cache: the one place
 that knows how the stacked pools are laid out for the layer loop, how a
-round's K and V rows are written into them, how they are read and which
+round's K and V rows are written into them (page by page through the block
+table, or row by row: "The write" below), how they are read and which
 kernel reads them (the reference's ragged kernel set
 ``inference/v2/kernels/ragged_ops``: linear_blocked_kv_rotary -> scatter into
 the paged cache, blocked_flash -> paged attention, logits_gather ->
@@ -26,11 +27,26 @@ are ONE pool of ``L * (NB+1)`` pages (a free reshape, ``merge_layers``):
 layer ``i`` owns pages ``[i * (NB+1), (i+1) * (NB+1))``, reached by
 offsetting the block tables (``layer_rows``), its trash page among them
 (``layer_trash``). The merged pools ride the CARRY of the layer loop, the
-scatter updates them in place and the paged kernel reads pages through the
+write updates them in place and the paged kernel reads pages through the
 tables: no layer's pool is ever sliced out or written back. As scan inputs
 and outputs the pools were two buffers each (the whole KV pool again as
 scratch) and every round moved them through HBM ~13x. Pools of slots (a
 recurrent state a sequence) are merged and offset the same way.
+
+The write (``_write``, under the device scope ``paged_write``; ``_scatter_kv``,
+``_scatter_latent`` and ``_scatter_index`` are its entry points by leaf). A
+row's Q new tokens fill CONSECUTIVE slots of the pages its table names from
+``seen // bs`` on, so the write goes through the block table as the reader
+reads: PAGE-WISE (``_write_pages``), the at most ``pages_a_row(Q, bs)`` pages
+a row touches read, their new slots taken from the chunk laid out as pages,
+and written whole; a prompt chunk's 512 tokens are 9 page updates a pool and
+layer. Or ROW-WISE (``_write_rows``), one ``[W]`` row a (token, head), where
+that is fewer and cheaper updates: a decode row's one token. ``writes_pages``
+is the one rule between them, by static shapes, with the chip's timings that
+set it. Either way a real slot takes the same bytes, a slot that is not real
+keeps what it held, a page another sequence shares is never written (a row
+writes from ``seen`` on, in pages it alone holds) and what is padding lands
+in the layer's trash page, which may hold any value.
 """
 
 import jax
@@ -104,6 +120,43 @@ def _quantize_kv_rows(x):
     return q.reshape(x.shape), scale.reshape(x.shape[:-1])
 
 
+#: row updates that cost the chip what ONE whole-page update costs
+ROWS_A_PAGE = 16
+
+
+def pages_a_row(Q, block_size):
+    """The most pages ``Q`` consecutive token slots touch, wherever in a page
+    the first lies."""
+    return (Q + block_size - 2) // block_size + 1
+
+
+def writes_pages(Q, KV, block_size, quantized=False):
+    """THE rule of the write's form, from a dispatch's static shapes alone:
+    page-wise (``_write_pages``) where a row's ``Q x KV`` row updates are at
+    least ``ROWS_A_PAGE`` for each of the ``pages_a_row`` page updates that
+    replace them, row-wise (``_write_rows``) below that and for an ``(int8,
+    scale)`` pair, whose scale side pool ``[NB, KV, 1, bs]`` keeps the slot
+    in its LAST dimension (no cell serves int8 pages; the pair keeps the
+    scatter whole).
+
+    The chip's timings that set it (one TPU v5 lite, PR 53, the write alone
+    in a scan over the layers on the merged pool; PERF.md section 6 has the
+    table by pool and shape): a ROW update costs 67-79 ns at rows of 256-512 B and
+    ~120 ns at a latent row's 1,280 B, whatever the dispatch's shape: XLA
+    runs them one after the other. A PAGE update (the page read, chosen by
+    slot, written) costs 0.3-1.0 us at ``[1, C]`` and 0.8-1.5 us at ``[64,
+    Q]`` for pages of 64-256 KB (0.2-0.3 us at an index leaf's 16 KB). So a
+    page costs 4 to 21 rows, and at 16 rows a page no measured shape loses
+    more than the timing's floor: Mistral's ``[1, 512]`` (455 rows a page)
+    9.32 -> 0.56 ms both pools over 16 layers, ``[1, 16]`` (64) 0.48 -> 0.28,
+    ``[4, 8]`` (32) 0.75 -> 0.36, ``[64, 8]`` (32) 9.24 -> 3.59; a latent
+    ``[1, 512]`` (57) 0.89 -> 0.16 over 12 layers; while ``[64, 1]`` (8 rows
+    a page at Mistral's 8 heads, 1 at a latent row) stays row-wise at 1.45 ms
+    where pages take 2.70, as does a latent ``[1, 16]`` (8)."""
+    return not quantized and \
+        Q * KV >= ROWS_A_PAGE * pages_a_row(Q, block_size)
+
+
 def _write_slots(block_tables, seen, q_len, Q, block_size, trash):
     """(page, slot in the page), each [S*Q, 1], of a dispatch's ``[S, Q]``
     token slots; a padded slot goes to slot 0 of the ``trash`` page."""
@@ -120,44 +173,98 @@ def _write_slots(block_tables, seen, q_len, Q, block_size, trash):
     return bi, si
 
 
+def _write_rows(pools, rows, block_tables, seen, q_len, block_size, trash):
+    """The row-wise form: every pool [NB, KV, bs, W] of ``pools`` takes its
+    [S, Q, KV, W] of ``rows`` as S x Q x KV updates of one [W] row each."""
+    S, Q, KV = rows[0].shape[:3]
+    bi, si = _write_slots(block_tables, seen, q_len, Q, block_size, trash)
+    hi = jnp.arange(KV)[None, :]                              # [1, KV]
+    return tuple(
+        pool.at[bi, hi, si].set(x.reshape(S * Q, KV, -1).astype(pool.dtype))
+        for pool, x in zip(pools, rows))
+
+
+def _write_pages(pools, rows, block_tables, seen, q_len, block_size, trash):
+    """The page-wise form: a row's Q new tokens fill consecutive slots of at
+    most ``P = pages_a_row(Q, bs)`` pages its table names from ``seen // bs``
+    on, so every pool [NB, KV, bs, W] of ``pools`` takes its [S, Q, KV, W] of
+    ``rows`` as S x P updates of one whole page each: the new rows laid out
+    as pages at offset ``seen % bs`` (a slice of the padded chunk at a MAJOR
+    dimension, then one transpose of bs against KV), the slots outside
+    ``[seen, seen + q_len)`` keeping what the page held (the P pages read,
+    chosen by slot, written whole). A page none of whose slots is real is the
+    ``trash`` page, which may hold any value. Every real slot takes the bytes
+    the row-wise form gives it."""
+    S, Q, KV = rows[0].shape[:3]
+    bs, P = block_size, pages_a_row(Q, block_size)
+    first, shift = seen // bs, seen % bs                      # [S]
+    pos = first[:, None] * bs + jnp.arange(P * bs)[None, :]   # [S, P * bs]
+    real = (pos >= seen[:, None]) & (pos < (seen + q_len)[:, None])
+    real = real.reshape(S * P, bs)
+    entries = jnp.take_along_axis(
+        block_tables, first[:, None] + jnp.arange(P)[None, :], axis=1,
+        mode="clip").reshape(S * P)
+    pages = jnp.where(real.any(-1), entries, trash)           # [S * P]
+
+    def as_pages(x):
+        # slot j of the P pages holds the chunk's token j - shift
+        x = jnp.pad(x, ((0, 0), (bs, P * bs - Q), (0, 0), (0, 0)))
+        x = jax.vmap(lambda r, s: jax.lax.dynamic_slice_in_dim(
+            r, bs - s, P * bs, 0))(x, shift)                  # [S, P*bs, KV, W]
+        return x.reshape(S * P, bs, KV, -1).swapaxes(1, 2)    # [S*P, KV, bs, W]
+
+    return tuple(
+        pool.at[pages].set(jnp.where(real[:, None, :, None],
+                                     as_pages(x).astype(pool.dtype),
+                                     pool[pages]))
+        for pool, x in zip(pools, rows))
+
+
+def _write(pools, rows, block_tables, seen, q_len, block_size, trash):
+    """``rows`` [S, Q, KV, W] each into ``pools`` [NB, KV, bs, W] each, in the
+    form ``writes_pages`` picks for their shapes; the device scope
+    ``paged_write``."""
+    _, Q, KV, _ = rows[0].shape
+    form = _write_pages if writes_pages(Q, KV, block_size) else _write_rows
+    with jax.named_scope("paged_write"):
+        return form(pools, rows, block_tables, seen, q_len, block_size, trash)
+
+
 def _scatter_latent(pool, rows, block_tables, seen, q_len, block_size, trash):
     """Write [S, Q, W] new latent rows into the one-leaf pool [NB, 1, bs, W]
     via block tables: one whole row a token, padded slots to the ``trash``
-    page, indexed as ``_scatter_kv`` indexes (``_write_slots``)."""
-    S, Q, W = rows.shape
-    bi, si = _write_slots(block_tables, seen, q_len, Q, block_size, trash)
-    return pool.at[bi, jnp.zeros((1, 1), jnp.int32), si].set(
-        rows.reshape(S * Q, 1, W).astype(pool.dtype))
+    page (``_write``)."""
+    return _write((pool,), (rows[:, :, None],), block_tables, seen, q_len,
+                  block_size, trash)[0]
 
 
 def _scatter_kv(k_pool, v_pool, k, v, block_tables, seen, q_len, block_size,
                 trash):
     """Write [S, Q, KV, Dh] new KVs into the [NB, KV, bs, Dh] pool via block
-    tables.
+    tables (``_write``).
 
     Padded token slots are routed to the ``trash`` page.
     Analog of the reference's linear_blocked_kv_copy kernel. Quantized pools
     (``(int8, scale)`` pairs) quantize on-write: each token's row quantizes
     per (token, kv head) over Dh, and the fp32 scale scatters into the side
-    pool [NB, KV, 1, bs] under the same block/slot indices.
+    pool [NB, KV, 1, bs] under the same block/slot indices, row by row.
     """
     k_pool, k_scale = _pool_parts(k_pool)
     v_pool, v_scale = _pool_parts(v_pool)
+    if k_scale is None:
+        return _write((k_pool, v_pool), (k, v), block_tables, seen, q_len,
+                      block_size, trash)
     S, Q = k.shape[:2]
     bi, si = _write_slots(block_tables, seen, q_len, Q, block_size, trash)
     hi = jnp.arange(k.shape[2])[None, :]                      # [1, KV]
-    if k_scale is not None:
-        k, ks = _quantize_kv_rows(k)          # int8 [S,Q,KV,Dh], f32 [S,Q,KV]
-        v, vs = _quantize_kv_rows(v)
-        k_scale = k_scale.at[bi, hi, 0, si].set(ks.reshape(S * Q, -1))
-        v_scale = v_scale.at[bi, hi, 0, si].set(vs.reshape(S * Q, -1))
-    k_pool = k_pool.at[bi, hi, si].set(
-        k.reshape(S * Q, *k.shape[2:]).astype(k_pool.dtype))
-    v_pool = v_pool.at[bi, hi, si].set(
-        v.reshape(S * Q, *v.shape[2:]).astype(v_pool.dtype))
-    if k_scale is not None:
-        return (k_pool, k_scale), (v_pool, v_scale)
-    return k_pool, v_pool
+    k, ks = _quantize_kv_rows(k)              # int8 [S,Q,KV,Dh], f32 [S,Q,KV]
+    v, vs = _quantize_kv_rows(v)
+    k_scale = k_scale.at[bi, hi, 0, si].set(ks.reshape(S * Q, -1))
+    v_scale = v_scale.at[bi, hi, 0, si].set(vs.reshape(S * Q, -1))
+    with jax.named_scope("paged_write"):
+        k_pool, v_pool = _write_rows((k_pool, v_pool), (k, v), block_tables,
+                                     seen, q_len, block_size, trash)
+    return (k_pool, k_scale), (v_pool, v_scale)
 
 
 # -- the read -----------------------------------------------------------------

@@ -227,6 +227,9 @@ class DSStateManager:
         indexer's key, in one layer."""
         g, kvc = self.primary_group, self.kv_cache
         self._report_rides = {}
+        # what the write's rule takes beside a dispatch's token slots a row:
+        # the static shapes the forward sees (``paged_layer.writes_pages``)
+        self._write_shape = (*kvc.k_pool.shape[2:4], kvc.quantized)
         if g.leaves == 1:
             self._report_rides["latent_row_bytes"] = (
                 kvc.k_pool.shape[2] * kvc.k_pool.shape[4]
@@ -236,11 +239,20 @@ class DSStateManager:
                 kvc.i_pool.shape[4] * kvc.i_pool.dtype.itemsize
         self._window_freed_reported = 0
 
-    def dispatch_report(self, seqs):
+    def dispatch_report(self, seqs, seen, q_len, chunk):
         """What a dispatch of the sequences ``seqs`` (after their allocation)
         reports of the cache groups: ``(adds, rides)``, what it adds to the
         round's counts and what rides on its ``serving/build`` span alone.
-        The engine carries both and reads neither. The one K and V group
+        The engine carries both and reads neither. ``seen`` and ``q_len``:
+        the dispatch's rows as the program gets them (numpy, padded rows of
+        no tokens among them), ``chunk`` its token slots a row.
+
+        Every group: how the ``"kv"`` group's table is written, from the
+        lengths alone (``paged_layer.writes_pages`` on the shapes the forward
+        sees): ``write_pages``, the table entries a page-wise dispatch writes
+        whole (a row's ``ceil((seen + new) / bs) - seen // bs``), or
+        ``write_rows``, the token slots a row-wise dispatch writes one by
+        one; each in every layer and leaf. Beyond that the one K and V group
         reports nothing, and what reports nothing a row costs nothing a row.
 
         One leaf: ``latent_pages`` held now. An index leaf: ``index_pages``
@@ -253,7 +265,16 @@ class DSStateManager:
         group's ``<name>_live_pages`` of these rows ride."""
         g, kvc = self.primary_group, self.kv_cache
         held = kvc.num_blocks - kvc.free_blocks
-        adds, rides = {}, self._report_rides
+        # imported here: importing the forwards' package reaches this module
+        from deepspeed_tpu.inference.v2.model_implementations.paged_layer \
+            import writes_pages
+        bs = self.kv_block_size
+        if writes_pages(chunk, *self._write_shape):
+            written = np.where(q_len > 0, -(-(seen + q_len) // bs) - seen // bs, 0)
+            adds = {"write_pages": int(written.sum()), "write_rows": 0}
+        else:
+            adds = {"write_pages": 0, "write_rows": int(q_len.sum())}
+        rides = self._report_rides
         if g.leaves == 1:
             adds["latent_pages"] = held
         elif g.index_dim is not None:

@@ -282,9 +282,10 @@ class _Captured(Exception):
     pass
 
 
-def _cell_program(name, rows, one_chip, monkeypatch):
+def _cell_program(name, rows, one_chip, monkeypatch, tokens=1):
     """(layout, the lowered program) of the dispatch ``rows`` decoding
-    sequences make in the engine of the benchmark's configuration ``name``,
+    sequences (or one row of ``tokens`` prompt tokens) make in the engine of
+    the benchmark's configuration ``name``,
     as the engine enqueues it (``engine_v2.packed_forward``: the packed
     buffer sliced, then the family's forward), its arguments as shapes on
     ``one_chip``: the configuration's widths, layers and dtypes (weights as
@@ -340,7 +341,7 @@ def _cell_program(name, rows, one_chip, monkeypatch):
 
     monkeypatch.setattr(engine_v2, "packed_forward", spy)
     with pytest.raises(_Captured):
-        engine.put(list(range(rows)), [np.zeros(1, np.int32)] * rows)
+        engine.put(list(range(rows)), [np.zeros(tokens, np.int32)] * rows)
     forward, cfg, layout, *arrays, verify_k = got
     shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), tuple(arrays))
@@ -372,6 +373,35 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
     assert dict(layout)["src"] == (bucket,)
     assert lowered.in_avals[0][-1].shape == (64,)
     assert lowered.compile().as_text().count("tpu_custom_call") >= kernels
+
+
+@pytest.mark.parametrize("name,rows,tokens,shape", [
+    ("mistral-7b-l16", 1, 449, (1, 512)), ("mistral-7b-l16", 64, 1, (64, 1)),
+    ("kanana2-l12-ep8", 1, 449, (1, 512))],
+    ids=["mistral-chunk", "mistral-decode", "kanana2-chunk"])
+def test_a_cells_program_writes_its_pool_in_place(for_tpu, one_chip, monkeypatch,
+                                                  name, rows, tokens, shape):
+    """The cache write of a prompt chunk (page-wise: the pages a row fills
+    read, chosen by slot and written whole) and of a decode round (row-wise)
+    in the WHOLE program of a cell, compiled for the described chip: the
+    merged pool is updated where it lies. Every pool leaf comes back in the
+    buffer it came in (donated, aliased) and the program's scratch is
+    smaller than ONE leaf, so it holds no copy and no other layout of a
+    pool-shaped array."""
+    layout, lowered = _cell_program(name, rows, one_chip, monkeypatch, tokens)
+    assert dict(layout)["tokens"] == shape
+    pools = jax.tree.leaves(lowered.in_avals[0][1]["kv"])      # the cache argument
+    leaf = min(p.size * p.dtype.itemsize for p in pools)
+    assert leaf > 2 ** 27, "a pool too small to tell a copy from scratch"
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < leaf, (memory.temp_size_in_bytes, leaf)
+    assert memory.alias_size_in_bytes >= sum(p.size * p.dtype.itemsize for p in pools)
+    text = compiled.as_text()
+    for p in pools:
+        dims = ",".join(map(str, (p.shape[0] * p.shape[1],) + p.shape[2:]))
+        assert f"[{dims}]" in text, dims       # the merged pool, on the loop's carry
+        assert not re.search(rf"= \w+\[{dims}\]\S* (copy|transpose)\(", text)
 
 
 @pytest.mark.parametrize("tokens,k,experts,d,f,dtype,grad", [

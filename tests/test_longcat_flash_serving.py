@@ -566,10 +566,14 @@ def _moe_case(seed=0):
 
 #: taken at the parent commit (a7dc6ca, jax 0.9.0) by the same functions: a
 #: dispatch's whole program of the two families that share ``absorbed_mla`` and
-#: ``moe_ffn``, and ``moe_ffn`` alone with the new arguments absent
-PARENT = {"kanana2_chunk": ("a1565ca2c07c750a", 826),
-          "kanana2_held_decode": ("fa7b7d2973cc4098", 840),
-          "mellum2_chunk": ("50e9f48bf200d09d", 2394),
+#: ``moe_ffn``, and ``moe_ffn`` alone with the new arguments absent. The three
+#: whole programs were taken again at PR 53, which changed the one thing of
+#: them every family shares, the cache write (``paged_layer._write`` under the
+#: scope ``paged_write``: a1565ca2c07c750a / 826, fa7b7d2973cc4098 / 840 and
+#: 50e9f48bf200d09d / 2394 before it); the three of ``moe_ffn`` are a7dc6ca's
+PARENT = {"kanana2_chunk": ("4956e3d714e82ca1", 832),
+          "kanana2_held_decode": ("ffefcb20a8f34553", 849),
+          "mellum2_chunk": ("823e6bcb59b7c837", 2394),
           "moe_softmax": ("504b164bf310cd8e", 66),
           "moe_sigmoid_held_shared": ("ddc4a2af4b31a839", 98),
           "moe_gmm": ("ade9818f1c68fcc4", 1097)}

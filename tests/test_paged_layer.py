@@ -200,8 +200,8 @@ def test_forward_writes_the_pages_of_a_layer_by_layer_write(
     """One forward over a mixed batch leaves the stacked pools as writing
     each layer's K and V rows into THAT layer's pool, page by page through
     the sequence's own table, leaves them: every real token in its page and
-    slot, every padded position in its layer's trash page, every other page
-    as it was."""
+    slot, no padded position in a page of any sequence (the layer's trash
+    page may hold any value), every other page as it was."""
     impl, cfg, params = family
     tokens, q_len, seen, tables = _mixed_batch(cfg)
     wrote = []                       # a layer's (k, v) rows, in layer order
@@ -223,6 +223,7 @@ def test_forward_writes_the_pages_of_a_layer_by_layer_write(
     blank = float(SENTINEL * (SENTINEL if int8 else 1))   # int8 x its scale
     want = [np.full(shape, blank, np.float32) for _ in "kv"]
     touched = np.zeros(shape[:2], bool)
+    slots = np.zeros(shape[:2] + (BS,), bool)     # the real tokens' slots
     for layer, rows in enumerate(wrote):
         touched[layer, NB] = True                 # its trash page: any value
         for s in range(len(q_len)):
@@ -231,6 +232,7 @@ def test_forward_writes_the_pages_of_a_layer_by_layer_write(
                 for pool, row in zip(want, rows):
                     pool[layer, page, :, (seen[s] + t) % BS] = row[s, t]
                 touched[layer, page] = True
+                slots[layer, page, (seen[s] + t) % BS] = True
 
     for got, ref in zip(cache["kv"], want):
         data, scale = paged_layer._pool_parts(got)
@@ -245,9 +247,130 @@ def test_forward_writes_the_pages_of_a_layer_by_layer_write(
         real[:, NB] = False
         assert (np.abs(data - ref)[real] <= tol[real]).all()
         assert (data[~touched] == blank).all()
-        # padded positions went somewhere: to each layer's own trash page
-        assert (np.asarray(paged_layer._pool_parts(got)[0])[:, NB]
-                != SENTINEL).any(axis=(1, 2, 3)).all()
+        # no padded position landed in a page of any sequence: the slots
+        # written there are the real tokens' and no other (the layer's own
+        # trash page may hold any value)
+        raw = np.asarray(paged_layer._pool_parts(got)[0])
+        assert ((raw != SENTINEL).any(axis=(2, 4))[real] == slots[real]).all()
+
+
+# -- the write's forms: pages through the block table, or rows -----------------
+
+def _write_case(S, Q, KV, W, bs, seen, q_len, dtype=jnp.bfloat16, held=None,
+                width=None):
+    """A dispatch's ``[S, Q]`` slots over pools of random content: row ``s``
+    holds ``held[s]`` pages (what ``seen + q_len`` needs where not given),
+    the rest of its table names the trash page, the last of the pool."""
+    need = [-(-(a + b) // bs) for a, b in zip(seen, q_len)]
+    held = need if held is None else held
+    width = width or max(need) + 1
+    NB = sum(held) + 3
+    tables = np.full((S, width), NB, np.int32)
+    pages = iter(np.random.default_rng(1).permutation(NB))
+    for s in range(S):
+        tables[s, :held[s]] = [next(pages) for _ in range(held[s])]
+    return dict(S=S, Q=Q, KV=KV, W=W, bs=bs, dtype=dtype, NB=NB, tables=tables,
+                seen=np.asarray(seen, np.int32), q_len=np.asarray(q_len, np.int32))
+
+
+_WRITES = {
+    # [64, 1]: every offset of a page among the rows, first slots and last
+    "decode-64x1": lambda: _write_case(64, 1, 8, 128, 64, list(range(62, 126)), [1] * 64),
+    "decode-4x1-f32": lambda: _write_case(4, 1, 4, 128, 64, [0, 1, 63, 130], [1] * 4,
+                                          jnp.float32),
+    "chunk-1x16-crosses-a-page": lambda: _write_case(1, 16, 8, 128, 64, [63], [16]),
+    "chunk-1x16-inside-a-page": lambda: _write_case(1, 16, 1, 128, 64, [1], [9]),
+    # starts and ends inside pages, q_len < Q: a closed cell's usual chunk
+    "chunk-1x512-mid-pages": lambda: _write_case(1, 512, 8, 128, 64, [300], [449]),
+    "chunk-1x512-whole-pages": lambda: _write_case(1, 512, 4, 128, 64, [64], [512],
+                                                   jnp.float32),
+    # the row's last pages are past what it holds and past the table's width
+    "chunk-1x512-past-the-allocation": lambda: _write_case(1, 512, 8, 128, 64, [1], [400],
+                                                           width=8),
+    # a prompt's tail, a first chunk, a padded row (a table of trash pages)
+    # and a decode row in one verify-shaped dispatch
+    "mixed-4x8": lambda: _write_case(4, 8, 8, 128, 64, [63, 0, 0, 77], [8, 3, 0, 1]),
+    "mixed-4x8-kv4-bs8": lambda: _write_case(4, 8, 4, 64, 8, [7, 8, 0, 1], [8, 8, 0, 5],
+                                             jnp.float32),
+    "nothing-real-2x8": lambda: _write_case(2, 8, 4, 128, 64, [0, 70], [0, 0], held=[0, 2]),
+    # a page of one leaf, and the index leaf
+    "latent-1x512-w640": lambda: _write_case(1, 512, 1, 640, 64, [129], [500]),
+    "latent-64x1-w640": lambda: _write_case(64, 1, 1, 640, 64, list(range(0, 128, 2)),
+                                            [1] * 64),
+    "latent-4x8-w640-f32": lambda: _write_case(4, 8, 1, 640, 64, [63, 0, 0, 200], [8, 1, 0, 8],
+                                               jnp.float32),
+    "index-1x16-w128": lambda: _write_case(1, 16, 1, 128, 64, [63], [16]),
+    "index-4x8-w128": lambda: _write_case(4, 8, 1, 128, 64, [63, 0, 0, 77], [8, 3, 0, 1]),
+}
+
+
+def _written(pool, rows, c):
+    """``rows`` [S, Q, KV, W] at their pages and slots of ``pool``, in numpy."""
+    want = np.array(pool)
+    for s in range(c["S"]):
+        for t in range(c["q_len"][s]):
+            pos = c["seen"][s] + t
+            want[c["tables"][s, pos // c["bs"]], :, pos % c["bs"]] = rows[s, t]
+    return want
+
+
+@pytest.mark.parametrize("form", ["rows", "pages", "rule"])
+@pytest.mark.parametrize("case", sorted(_WRITES))
+def test_every_form_of_the_write_leaves_the_pages_the_row_wise_scatter_leaves(
+        case, form, monkeypatch):
+    """The row-wise scatter (the twin), the page-wise form and whichever the
+    rule picks for the shapes, through the entry point its leaf has: bit for
+    bit the same on every real slot, on every other slot of the pages a row
+    fills and on every page no real slot touches. Only the trash page may
+    differ."""
+    c = _WRITES[case]()
+    if form != "rule":
+        monkeypatch.setattr(paged_layer, "writes_pages", lambda *a: form == "pages")
+    rng = np.random.default_rng(2)
+    S, Q, KV, W, NB, dt = (c[k] for k in ("S", "Q", "KV", "W", "NB", "dtype"))
+    pool = lambda: jnp.asarray(rng.normal(size=(NB + 1, KV, c["bs"], W)), dt)
+    rows = lambda w=W: jnp.asarray(rng.normal(size=(S, Q, KV, w)), dt)
+    args = (jnp.asarray(c["tables"]), jnp.asarray(c["seen"]), jnp.asarray(c["q_len"]),
+            c["bs"], NB)
+    if case.startswith("latent"):
+        pools, new = (pool(),), (rows(),)
+        got = (paged_layer._scatter_latent(pools[0], new[0][:, :, 0], *args),)
+    elif case.startswith("index"):
+        # the key fills half of the row; zeros behind it
+        pools, keys = (pool(),), rows(W // 2)
+        new = (jnp.pad(keys, ((0, 0),) * 3 + ((0, W // 2),)),)
+        got = (paged_layer._scatter_index(pools[0], keys[:, :, 0], *args),)
+    else:
+        pools, new = (pool(), pool()), (rows(), rows())
+        got = paged_layer._scatter_kv(*pools, *new, *args)
+    for before, x, after in zip(pools, new, got):
+        after, want = np.asarray(after), _written(np.asarray(before), np.asarray(x), c)
+        assert after.dtype == want.dtype
+        np.testing.assert_array_equal(after[:NB], want[:NB])
+
+
+def test_int8_pages_take_the_row_wise_scatter_whatever_the_shapes(monkeypatch):
+    """An ``(int8, scale)`` pair keeps the row-wise form whole, whatever the
+    rule says of the shapes: the quantized rows and their scales where the
+    row-wise scatter puts them, bit for bit."""
+    c = _write_case(4, 8, 4, 128, 64, [63, 0, 0, 77], [8, 3, 0, 1])
+    monkeypatch.setattr(paged_layer, "writes_pages", lambda *a: True)
+    rng = np.random.default_rng(3)
+    NB, bs = c["NB"], c["bs"]
+    pool = lambda: (jnp.asarray(rng.integers(-127, 127, (NB + 1, 4, bs, 128)), jnp.int8),
+                    jnp.asarray(rng.normal(size=(NB + 1, 4, 1, bs)), jnp.float32))
+    pools = pool(), pool()
+    new = [jnp.asarray(rng.normal(size=(4, 8, 4, 128)), jnp.bfloat16) for _ in "kv"]
+    got = paged_layer._scatter_kv(*pools, *new, jnp.asarray(c["tables"]),
+                                  jnp.asarray(c["seen"]), jnp.asarray(c["q_len"]), bs, NB)
+    for (data, scale), x, (got_data, got_scale) in zip(pools, new, got):
+        q, s = paged_layer._quantize_kv_rows(x)
+        np.testing.assert_array_equal(
+            np.asarray(got_data)[:NB], _written(np.asarray(data), np.asarray(q), c)[:NB])
+        # a scale page is [KV, 1, bs]: the slot is its last dimension
+        want = _written(np.swapaxes(np.asarray(scale), -1, -2), np.asarray(s)[..., None], c)
+        np.testing.assert_array_equal(np.asarray(got_scale)[:NB],
+                                      np.swapaxes(want, -1, -2)[:NB])
 
 
 def _walk(jaxpr):

@@ -388,7 +388,7 @@ def _served_family(name):
 
 
 _EVERY_BUILD = {"round", "dispatch", "seqs", "seq_bucket", "chunk_bucket", "real_tokens",
-                "padded_slots", "context_tokens", "live_pages"}
+                "padded_slots", "context_tokens", "live_pages", "write_pages", "write_rows"}
 _FURTHER_GROUPS = {"window_pages_freed", "state_slots", "global_pages", "window_pages",
                    "window_live_pages"}
 _EXPERTS = {"expert_rows", "expert_rows_padded"}
@@ -424,9 +424,39 @@ def test_the_build_spans_attributes_are_the_ones_the_benchmark_reads(
     builds = build_spans()
     assert builds and all(set(a) == _EVERY_BUILD | beyond for a in builds)
     assert all(type(v) is int for a in builds for v in a.values())
-    summed = summed | {"real_tokens", "padded_slots", "live_pages"}
+    summed = summed | {"real_tokens", "padded_slots", "live_pages", "write_pages", "write_rows"}
     assert set(sched.counts) - {"ahead_rows_dropped"} == summed | {
         "rounds", "dispatches", "rounds_ahead", "ahead_rows", "dispatches_sorted"}
     for key in summed:
         assert getattr(sched, key) == sum(a[key] for a in builds), key
     assert sched.dispatches == len(builds) and sched.latent_pages == sched.counts["latent_pages"]
+
+
+def test_a_mixed_rounds_write_is_counted_from_the_rows_lengths(served, build_spans):
+    """A 449-token chunk behind 300 cached tokens beside 63 decode rows, at
+    the cells' limits (64 sequences, 512 tokens a round, pages of 64): the
+    chunk's dispatch writes page-wise the 8 table entries its tokens touch
+    (``ceil(749 / 64) - 300 // 64``) and no slot row by row, the ``[64, 1]``
+    dispatch its 63 token slots row by row and no page; the round's counts
+    are their sums, and each dispatch reports the form the forward's own
+    rule picks for its shapes."""
+    from deepspeed_tpu.inference.v2.model_implementations import paged_layer
+    cfg, model, params, _ = served
+    engine = InferenceEngineV2(model, params, config={
+        "state_manager": {"max_ragged_sequence_count": 64, "max_ragged_batch_size": 512,
+                          "max_context": 1024, "num_kv_blocks": 96},
+        "kv_cache": {"block_size": 64, "cache_dtype": "fp32"}})
+    rng = np.random.default_rng(53)
+    toks = lambda n: rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+    engine.put([0], [toks(300)])
+    engine.put(list(range(1, 64)), [toks(1) for _ in range(63)])
+    before = len(build_spans())
+    engine.put(list(range(64)), [toks(449)] + [toks(1) for _ in range(63)])
+    builds = {(a["seq_bucket"], a["chunk_bucket"]): a for a in build_spans()[before:]}
+    assert set(builds) == {(64, 1), (1, 512)}
+    assert (builds[1, 512]["write_pages"], builds[1, 512]["write_rows"]) == (8, 0)
+    assert (builds[64, 1]["write_pages"], builds[64, 1]["write_rows"]) == (0, 63)
+    assert (engine.last_counts["write_pages"], engine.last_counts["write_rows"]) == (8, 63)
+    heads, bs = engine._state.kv_cache.k_pool.shape[2:4]
+    for (_, Q), a in builds.items():
+        assert paged_layer.writes_pages(Q, heads, bs) == (a["write_pages"] > 0)
