@@ -26,7 +26,7 @@ layers add to on the device).
 A family is three things, resolved here: its ragged forward, its verify
 forward (or None) and its cache groups (``ragged/cache_groups.py``); two more
 where its module has them: ``prepare_params(cfg, params)``, the tree as its
-forward reads it, made once when the engine is built, and
+forward reads it, made once by ``InferenceEngineV2`` when it is built, and
 ``dispatch_report(cfg, real_tokens)``, what a dispatch reports of the family
 beside the engine's and the cache groups' own counts.
 """
@@ -133,6 +133,14 @@ def resolve_report_fn(model, family=None):
     return getattr(_implementation(model, family), "dispatch_report", None)
 
 
+def resolve_prepare_fn(model, family=None):
+    """The family's ``prepare_params(cfg, params)``: the tree as its forward
+    reads it, or ``None`` for a family that serves the tree as trained.
+    ``InferenceEngineV2`` applies it, once, however it was built; nothing
+    else does."""
+    return getattr(_implementation(model, family), "prepare_params", None)
+
+
 def resolve_cache_groups(model):
     """What the model keeps per sequence between dispatches: its own
     ``cache_groups(config)`` where the stack is not homogeneous, else the one
@@ -143,11 +151,10 @@ def resolve_cache_groups(model):
 
 
 def build_engine(model, params, engine_config=None, family=None):
-    """Build a ragged engine from an in-tree model + param tree."""
-    prepare = getattr(_implementation(model, family), "prepare_params", None)
-    if prepare is not None:
-        params = prepare(model.config, params)
+    """Build a ragged engine from an in-tree model + param tree (the tree
+    as the model trains it: the engine makes it ready for the family)."""
     return InferenceEngineV2(model, params, engine_config,
+                             prepare_fn=resolve_prepare_fn(model, family),
                              forward_fn=resolve_forward_fn(model, family),
                              verify_fn=resolve_verify_fn(model, family),
                              cache_groups=resolve_cache_groups(model),

@@ -76,20 +76,22 @@ class InferenceEngineV2:
     Args:
         model: the in-tree model — provides ``config`` (and, where the
             stack is not homogeneous, ``cache_groups``).
-        params: trained parameter pytree.
+        params: trained parameter pytree, as the model trains it: the
+            engine applies the family's ``prepare_params`` to it, here and
+            nowhere else (the caller's tree is left as it is).
         config: ``RaggedInferenceEngineConfig`` or dict.
-        forward_fn, verify_fn, cache_groups, report_fn: what
+        forward_fn, verify_fn, cache_groups, report_fn, prepare_fn: what
             ``engine_factory`` resolved for the family; resolved here when
             left out.
     """
 
     def __init__(self, model, params, config=None, forward_fn=None,
-                 verify_fn=None, cache_groups=None, report_fn=None):
+                 verify_fn=None, cache_groups=None, report_fn=None,
+                 prepare_fn=None):
         if not isinstance(config, RaggedInferenceEngineConfig):
             config = RaggedInferenceEngineConfig(config or {})
         self._config = config
         self._model_config = model.config
-        self._params = params
         cfg = self._model_config
         if forward_fn is None:
             # standalone construction: infer via the factory's policy map
@@ -104,6 +106,11 @@ class InferenceEngineV2:
         if report_fn is None:
             from deepspeed_tpu.inference.v2.engine_factory import resolve_report_fn
             report_fn = resolve_report_fn(model)
+        if prepare_fn is None:
+            from deepspeed_tpu.inference.v2.engine_factory import resolve_prepare_fn
+            prepare_fn = resolve_prepare_fn(model)
+        self._params = self._prepare(prepare_fn, params, family=getattr(
+            forward_fn, "__module__", "").rpartition(".")[2])
         self._ragged_forward = forward_fn
         self._verify_forward = verify_fn
         # what a dispatch reports beyond the engine's own counts is said by
@@ -160,6 +167,24 @@ class InferenceEngineV2:
         flightrec.register_collector("engine_v2/kv_stats", self.kv_stats)
         logger.info(f"InferenceEngineV2: S<={sm.max_ragged_sequence_count} "
                     f"tokens<={sm.max_ragged_batch_size} context<={sm.max_context}")
+
+    def _prepare(self, prepare_fn, params, family):
+        """The tree as the family's forward reads it
+        (``engine_factory.resolve_prepare_fn``), under the span
+        ``serving/prepare_params``: ``family`` is the forward's module,
+        ``leaves`` and ``bytes`` count the leaves of the prepared tree that
+        the caller's tree does not hold (0 for a family without the hook)."""
+        with telemetry.get_telemetry().span(
+                "serving/prepare_params", family=family) as span:
+            made = []
+            if prepare_fn is not None:
+                given = {id(leaf) for leaf in jax.tree.leaves(params)}
+                params = prepare_fn(self._model_config, params)
+                made = [leaf for leaf in jax.tree.leaves(params)
+                        if id(leaf) not in given]
+            span.set(leaves=len(made), bytes=sum(
+                leaf.size * leaf.dtype.itemsize for leaf in made))
+        return params
 
     # -- accounted host fetch (mirrors DeepSpeedEngine._host_fetch) --------
     @property

@@ -4,12 +4,21 @@
 paged cache, blocked_flash -> paged attention, logits_gather -> last-token
 logits).
 
-Operates directly on the training param pytree of
+Operates on the param pytree of
 ``deepspeed_tpu.models.llama.LlamaForCausalLM`` with ``scan_layers=True`` (the
-stacked-layer layout is exactly what ``lax.scan`` wants), so a trained
-checkpoint serves with zero conversion. All shapes are static: S sequence
-slots x Q new-token budget, MB-wide block tables, masked padding, and a trash
-block absorbing padded-slot KV writes.
+stacked-layer layout is exactly what ``lax.scan`` wants). The forward takes
+the training tree as it is, and the tree ``prepare_params`` makes of it once,
+when the engine is built: the same values with q, k and v's kernels stored
+``[L, heads, head_dim, hidden]``, as their product reads them. ``proj`` tells
+the two apart by the kernel's rank. Why a stored layout: from ``[L, hidden,
+heads * head_dim]`` the chip's compiler cuts each layer's kernel out of the
+stack into VMEM, transposes it there and only then multiplies, three
+operations where ``o_proj`` and the MLP are one fused product that reads its
+kernel from HBM once (``tests/test_chip_compile.py`` holds the compiled
+programs to that; ``rotary_embed`` below is written without a strided pair
+split for the same reason). All shapes are static: S sequence slots x Q
+new-token budget, MB-wide block tables, masked padding, and a trash block
+absorbing padded-slot KV writes.
 """
 
 import functools
@@ -17,7 +26,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.llama import rotary_embed
 from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
     _paged_attention, _pool_block_size, _scatter_kv, last_token, layer_rows,
     layer_trash, merge_layers, pool_pages_per_layer, split_layers, token_at)
@@ -27,6 +35,51 @@ def _rmsnorm(x, scale, eps):
     x32 = x.astype(jnp.float32)
     norm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
     return (norm * scale).astype(x.dtype)
+
+
+def rotary_embed(x, positions, theta):
+    """``models.llama.rotary_embed`` (adjacent column pairs) value for value,
+    without its strided pair split: ``x cos + partner sin``, a column's
+    partner its pair's other column, signed, by two lane rolls and a select.
+    x: [S, Q, heads, Dh]. Why a form of its own: ``x[..., ::2]`` makes the
+    chip's compiler lay a prompt chunk's q and k tokens-minor, and their
+    products then cut the kernel into VMEM first and read it through a
+    transposing copy (45 + 147 us a layer for q at ``[1, 512]`` where one
+    fused product does); from this form every dispatch program reads q, k
+    and v's kernels where they lie (``tests/test_chip_compile.py``)."""
+    dh = x.shape[-1]
+    freqs = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
+    angles = positions[..., None].astype(jnp.float32) * freqs      # [S, Q, dh/2]
+    cos = jnp.repeat(jnp.cos(angles), 2, axis=-1)[:, :, None, :]
+    sin = jnp.repeat(jnp.sin(angles), 2, axis=-1)[:, :, None, :]
+    partner = jnp.where(jnp.arange(dh) % 2 == 0,
+                        -jnp.roll(x, -1, axis=-1), jnp.roll(x, 1, axis=-1))
+    return (x * cos + partner * sin).astype(x.dtype)
+
+
+def prepare_params(cfg, params):
+    """The tree as the forward reads it: ``self_attn/{q,k,v}_proj/kernel``
+    re-laid from ``[L, hidden, heads * head_dim]`` to ``[L, heads, head_dim,
+    hidden]`` (module docstring); a permutation of the stored values, biases
+    and every other leaf as they are. The caller's tree is left whole. A
+    kernel that already has four axes stays, so a prepared tree comes back
+    as it is; a tree of shapes gives a tree of shapes."""
+    @jax.jit                      # k's and v's kernels share one program
+    def relay(kernel):
+        L, D, out = kernel.shape
+        return kernel.reshape(L, D, out // cfg.head_dim,
+                              cfg.head_dim).transpose(0, 2, 3, 1)
+
+    block = params["layers"]["block"]
+    attn = dict(block["self_attn"])
+    for name in ("q_proj", "k_proj", "v_proj"):
+        kernel = attn[name]["kernel"]
+        if len(kernel.shape) == 3:
+            shapes = isinstance(kernel, jax.ShapeDtypeStruct)
+            attn[name] = dict(attn[name], kernel=jax.eval_shape(relay, kernel)
+                              if shapes else relay(kernel))
+    return dict(params, layers=dict(params["layers"], block=dict(
+        block, self_attn=attn)))
 
 
 def _ragged_trunk(cfg, params, k_pool, v_pool, tokens, q_len, seen,
@@ -41,7 +94,7 @@ def _ragged_trunk(cfg, params, k_pool, v_pool, tokens, q_len, seen,
     trunk per caller. Returns (normed hidden [S, Q, D], k_pool, v_pool).
     """
     S, Q = tokens.shape
-    H, KV, Dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    H, Dh = cfg.num_attention_heads, cfg.head_dim
     bs = _pool_block_size(k_pool)  # [L, NB, KV, bs, Dh] (pair when int8)
     positions = seen[:, None] + jnp.arange(Q)[None, :]
 
@@ -60,15 +113,17 @@ def _ragged_trunk(cfg, params, k_pool, v_pool, tokens, q_len, seen,
         attn = lp["self_attn"]
         h = _rmsnorm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
 
-        def proj(p):
-            y = h @ p["kernel"].astype(cfg.dtype)
+        def proj(p):              # -> [S, Q, heads, Dh]
+            kernel = p["kernel"].astype(cfg.dtype)
+            if kernel.ndim == 3:  # prepare_params' [heads, head_dim, hidden]
+                y = jnp.einsum("sqd,hkd->sqhk", h, kernel)
+            else:                 # the training tree's [hidden, heads * head_dim]
+                y = (h @ kernel).reshape(S, Q, -1, Dh)
             if "bias" in p:  # qwen2-family qkv bias
-                y = y + p["bias"].astype(cfg.dtype)
+                y = y + p["bias"].astype(cfg.dtype).reshape(-1, Dh)
             return y
 
-        q = proj(attn["q_proj"]).reshape(S, Q, H, Dh)
-        k = proj(attn["k_proj"]).reshape(S, Q, KV, Dh)
-        v = proj(attn["v_proj"]).reshape(S, Q, KV, Dh)
+        q, k, v = (proj(attn[n]) for n in ("q_proj", "k_proj", "v_proj"))
         q = rotary_embed(q, positions, cfg.rope_theta)
         k = rotary_embed(k, positions, cfg.rope_theta)
         kp, vp = _scatter_kv(kp, vp, k, v, layer_tables, seen, q_len, bs,

@@ -404,6 +404,78 @@ def test_a_cells_program_writes_its_pool_in_place(for_tpu, one_chip, monkeypatch
         assert not re.search(rf"= \w+\[{dims}\]\S* (copy|transpose)\(", text)
 
 
+_MOVES = {"parameter", "constant", "dynamic-slice", "slice", "bitcast", "reshape",
+          "copy", "transpose"}
+
+
+def _moved_whole(text, sizes):
+    """The instructions of a compiled program, outside its fusions, that only
+    MOVE an array of one of ``sizes`` elements: a ``copy``, a ``transpose``,
+    or a fusion of nothing but slices, bitcasts, copies and transposes (a
+    kernel cut out of its stack into a buffer of its own, as
+    ``constant_dynamic-slice_fusion`` is). A slice, copy or transpose INSIDE
+    a fusion that multiplies is that product reading its operand in place,
+    and is not listed. ``[(what, shape and layout)]``, what = ``copy`` |
+    ``transpose`` | ``slice``."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?(\S+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None and not line.startswith("}"):
+            bodies[name].append(line)
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
+    instruction = re.compile(
+        r"\s*(?:ROOT )?%?\S+ = (\w+\[([\d,]*)\]\S*) ([\w\-]+)\(")
+    found = []
+    for name, body in bodies.items():
+        if name in fused:
+            continue
+        for line in body:
+            m = instruction.match(line)
+            if not m or int(np.prod([int(d) for d in m.group(2).split(",") if d])) not in sizes:
+                continue
+            what = m.group(3)
+            if what == "fusion":
+                called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+                inside = {i.group(3) for i in map(instruction.match, bodies[called]) if i}
+                what = "slice" if inside <= _MOVES else None
+            if what in ("copy", "transpose", "slice"):
+                found.append((what, m.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("rows,tokens,shape,moved", [
+    (64, 1, (64, 1), []), (3, 1, (4, 1), []),
+    (1, 449, (1, 512), []), (1, 200, (1, 256), []), (1, 100, (1, 128), [])],
+    ids=["decode64", "decode4", "chunk512", "chunk256", "chunk128"])
+def test_mistrals_programs_read_q_k_and_v_kernels_where_they_lie(
+        for_tpu, one_chip, monkeypatch, rows, tokens, shape, moved):
+    """The tree ``llama.prepare_params`` makes (q, k and v's kernels stored
+    ``[L, heads, head_dim, hidden]``; the engine applies it), in a Mistral
+    cell's WHOLE programs compiled for the described chip. No program holds
+    a copy, a transpose or a standalone slice of an array the size of one
+    layer's q, k or v kernel (``moved``, the count, so that a later change
+    sees it move): each is one fused product that reads its kernel out of
+    the stack in HBM once, as ``o_proj`` and the MLP are. From ``[L, hidden,
+    heads * head_dim]`` every program held six (each kernel cut into VMEM
+    and transposed there before its product); from the prepared tree under
+    ``models.llama.rotary_embed``'s strided pair split a chunk program of
+    128 tokens and more still held two (q's and k's kernel cut into VMEM,
+    their products' results laid tokens-minor for the split), which
+    ``llama.rotary_embed``'s form without the split removed."""
+    layout, lowered = _cell_program("mistral-7b-l16", rows, one_chip, monkeypatch, tokens)
+    assert dict(layout)["tokens"] == shape
+    attn = lowered.in_avals[0][0]["layers"]["block"]["self_attn"]
+    kernels = [attn[n]["kernel"] for n in ("q_proj", "k_proj", "v_proj")]
+    assert [k.shape for k in kernels] == [
+        (16, 32, 128, 4096), (16, 8, 128, 4096), (16, 8, 128, 4096)]
+    sizes = {int(np.prod(k.shape[1:])) for k in kernels}
+    found = _moved_whole(lowered.compile().as_text(), sizes)
+    assert sorted(what for what, _ in found) == moved, found
+
+
 @pytest.mark.parametrize("tokens,k,experts,d,f,dtype,grad", [
     (512, 8, 64, 2304, 896, jnp.bfloat16, False),
     (64, 2, 8, 4096, 14336, jnp.bfloat16, False),

@@ -116,6 +116,22 @@ def test_the_factory_resolves_the_family(served):
     assert full.softmax_scale == pytest.approx(192 ** -0.5)
 
 
+def test_an_engine_built_alone_prepares_the_tree_as_build_engine_does(served):
+    """``InferenceEngineV2(model, tree)`` cuts ``kv_b_proj`` itself (it served
+    the uncut tree to a forward that reads ``w_uk`` until the engine took the
+    family's ``prepare_params`` over from ``build_engine``): the same leaves,
+    the same logits, the caller's tree uncut."""
+    cfg, model, params, _, ids, _ = served
+    alone, built = InferenceEngineV2(model, params, ENGINE), _engine(served)
+    assert "kv_b_proj" in params["layers_0"]["self_attn"]
+    for engine in (alone, built):
+        attn = engine._params["layers_0"]["self_attn"]
+        assert "kv_b_proj" not in attn and attn["w_uk"].ndim == attn["w_uv"].ndim == 3
+    for a, b in zip(jax.tree.leaves(alone._params), jax.tree.leaves(built._params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(alone.put([0], [ids[0][:9]]), built.put([0], [ids[0][:9]]))
+
+
 def test_from_hf_reads_the_published_keys_and_refuses_what_is_not_served():
     import json
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

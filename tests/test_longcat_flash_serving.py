@@ -386,13 +386,14 @@ def test_a_round_fetches_no_counter_and_a_family_without_the_group_gets_no_leaf(
     assert engine.host_sync_count == 2 and counts["dispatches"] == 1   # on demand, counted
     assert counts["routed_rows"] == 6 * 4 * 2
     model = Kanana2ForCausalLM(Kanana2Config.tiny())
-    other = build_engine(model, model.init_params(jax.random.PRNGKey(1)), ENGINE)
+    tree = model.init_params(jax.random.PRNGKey(1))
+    other = build_engine(model, tree, ENGINE)
     syncs = other.host_sync_count
     assert other.device_counters() == {} and set(other._state.cache_view()) == {"kv"}
     assert other.host_sync_count == syncs            # nothing to fetch, nothing counted
     assert other._state.counter_group is None
     with pytest.raises(ValueError, match="one counter group"):
-        InferenceEngineV2(model, other._params, ENGINE, cache_groups=(
+        InferenceEngineV2(model, tree, ENGINE, cache_groups=(
             PagedGroup("kv", 3, 1, 256, leaves=1, value_dim=128),
             CounterGroup("a", ("x",)), CounterGroup("b", ("y",))))
     with pytest.raises(ValueError, match="names its fields"):

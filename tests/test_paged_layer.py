@@ -673,3 +673,169 @@ def test_the_index_write_is_one_padded_row_a_token_beside_its_k_and_v():
     np.testing.assert_array_equal(out[4, 0, 0, :Di], np.asarray(keys)[1, 0])
     assert not out[..., Di:].any() and not out[1].any() and not out[4, 0, 1:].any()
     assert out[6, 0, 0].any() and not out[6, 0, 1:].any()         # padding: the trash page
+
+
+# -- the llama family's tree, as trained and as prepared ---------------------------
+# ``llama.prepare_params`` stores q, k and v's kernels as their product reads
+# them; the forward takes either tree and tells them apart by the kernel's rank
+
+def _llama_mha():
+    from deepspeed_tpu.models.llama import LlamaConfig
+    return dataclasses.replace(LlamaConfig.tiny(),       # llama2's: KV heads = heads
+                               num_key_value_heads=4)
+
+
+def _mistral():
+    from deepspeed_tpu.models.mistral import tiny_mistral_config
+    return tiny_mistral_config(sliding_window=6)         # GQA under a window
+
+
+def _qwen2():
+    from deepspeed_tpu.models.qwen2 import tiny_qwen2_config
+    return tiny_qwen2_config()                           # q, k and v biases
+
+
+LLAMA_TREES = {"llama-mha": _llama_mha, "mistral-gqa-window": _mistral,
+               "qwen2-bias": _qwen2}
+
+
+@pytest.fixture(scope="module", params=sorted(LLAMA_TREES))
+def llama_tree(request):
+    """(model, the tree as trained): float32, seeded biases where it has any."""
+    from deepspeed_tpu.models.llama import LlamaForCausalLM
+    cfg = dataclasses.replace(LLAMA_TREES[request.param](), scan_layers=True,
+                              remat=False, dtype=jnp.float32)
+    model = LlamaForCausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        {"input_ids": np.zeros((1, 8), np.int32)})["params"]
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: 0.1 * jax.random.normal(next(keys), leaf.shape, leaf.dtype)
+        if path[-1].key == "bias" else leaf, params)
+    return model, params
+
+
+@pytest.mark.parametrize("verify", [False, True], ids=["plain", "verify"])
+def test_the_llama_forward_reads_the_tree_as_trained_and_as_prepared(llama_tree, verify):
+    """The same logits and the same pages from both trees, over a mixed
+    batch (a chunk across a page boundary, a decode row, a first chunk, a
+    padded row), through the plain forward and the verify forward (one
+    trunk); the caller's tree is left as it was, and a prepared tree
+    prepared again is itself."""
+    from deepspeed_tpu.inference.v2.model_implementations import llama
+    model, params = llama_tree
+    cfg = model.config
+    H, KV, Dh, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                    cfg.head_dim, cfg.hidden_size)
+    prepared = llama.prepare_params(cfg, params)
+    attn, was = prepared["layers"]["block"]["self_attn"], params["layers"]["block"]["self_attn"]
+    for name, heads in (("q_proj", H), ("k_proj", KV), ("v_proj", KV)):
+        assert was[name]["kernel"].shape == (cfg.num_hidden_layers, D, heads * Dh)
+        assert attn[name]["kernel"].shape == (cfg.num_hidden_layers, heads, Dh, D)
+        np.testing.assert_array_equal(                   # a permutation, value for value
+            np.asarray(attn[name]["kernel"]).transpose(0, 3, 1, 2).reshape(
+                was[name]["kernel"].shape), np.asarray(was[name]["kernel"]))
+        assert attn[name].get("bias") is was[name].get("bias")
+        assert ("bias" in attn[name]) == cfg.attention_bias
+    assert attn["o_proj"] is was["o_proj"] and prepared["norm"] is params["norm"]
+    again = llama.prepare_params(cfg, prepared)
+    assert all(a is b for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(prepared)))
+
+    tokens, q_len, seen, tables = _mixed_batch(cfg)
+    forward = (lambda *a: llama.ragged_forward_verify(*a, 3)) if verify \
+        else llama.ragged_forward
+    got = [forward(cfg, tree, {"kv": _pools(cfg, False)}, jnp.asarray(tokens),
+                   jnp.asarray(q_len), jnp.asarray(seen), {"kv": jnp.asarray(tables)})
+           for tree in (params, prepared)]
+    (logits, cache), (logits_p, cache_p) = got
+    assert logits.shape == ((4, 3) if verify else (4,)) + (cfg.vocab_size,)
+    np.testing.assert_allclose(np.asarray(logits_p), np.asarray(logits), rtol=1e-5, atol=1e-5)
+    for pool, pool_p in zip(cache["kv"], cache_p["kv"]):
+        real = np.ones(pool.shape[1], bool)
+        real[NB] = False                                 # the trash page: any value
+        assert (np.asarray(pool)[:, real] != SENTINEL).any()
+        np.testing.assert_allclose(np.asarray(pool_p)[:, real], np.asarray(pool)[:, real],
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "fp32"])
+def test_the_forwards_rotary_is_the_models_value_for_value(dtype):
+    """``llama.rotary_embed`` (no strided pair split: rolls and a select)
+    against ``models.llama.rotary_embed``: the same bits, jitted as the
+    forward runs them, at positions up to a Mistral context."""
+    from deepspeed_tpu.inference.v2.model_implementations import llama
+    from deepspeed_tpu.models.llama import rotary_embed
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 4, 128), jnp.float32).astype(dtype)
+    positions = jnp.asarray(np.random.default_rng(0).integers(0, 4096, (3, 5)))
+    for theta in (10000.0, 1000000.0):
+        got = jax.jit(llama.rotary_embed)(x, positions, theta)
+        want = jax.jit(rotary_embed)(x, positions, theta)
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+ENGINE_LIMITS = {"state_manager": {"max_ragged_sequence_count": 4, "max_ragged_batch_size": 32,
+                                   "max_context": 64, "num_kv_blocks": 16},
+                 "kv_cache": {"block_size": 8, "cache_dtype": "fp32"}}
+
+
+@pytest.fixture
+def prepare_spans():
+    """``spans()``: the attributes of this test's ``serving/prepare_params`` spans."""
+    telemetry.reset()
+    telemetry.configure(enabled=True)
+    yield lambda: [e["args"] for e in telemetry.get_telemetry().trace_events
+                   if e["name"] == "serving/prepare_params"]
+    telemetry.configure(enabled=False)
+    telemetry.reset()
+
+
+def test_an_engine_prepares_its_tree_once_however_it_is_built(llama_tree, prepare_spans):
+    """``InferenceEngineV2(...)`` alone and ``build_engine`` hold the same
+    prepared leaves, each says so in ONE ``serving/prepare_params`` span
+    (three leaves re-laid, their bytes), the caller's tree is the one it
+    handed over, and both serve the same logits."""
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.inference.v2.engine_factory import build_engine
+    model, params = llama_tree
+    cfg = model.config
+    leaves = jax.tree.leaves(params)
+    alone = InferenceEngineV2(model, params, ENGINE_LIMITS)
+    built = build_engine(model, params, ENGINE_LIMITS, family="mistral")
+    assert all(a is b for a, b in zip(jax.tree.leaves(params), leaves))
+    qkv = cfg.num_hidden_layers * cfg.hidden_size * cfg.head_dim * 4 * (
+        cfg.num_attention_heads + 2 * cfg.num_key_value_heads)
+    assert prepare_spans() == [{"family": "llama", "leaves": 3, "bytes": qkv}] * 2
+    for a, b in zip(jax.tree.leaves(alone._params), jax.tree.leaves(built._params)):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert alone._params["layers"]["block"]["self_attn"]["q_proj"]["kernel"].ndim == 4
+    prompt = np.arange(1, 12, dtype=np.int32)
+    np.testing.assert_array_equal(alone.put([0], [prompt]), built.put([0], [prompt]))
+
+
+def test_a_family_without_the_hook_says_it_prepared_nothing(prepare_spans):
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    _, model = FAMILIES["opt-scan"]()
+    params = model.init(jax.random.PRNGKey(0),
+                        {"input_ids": np.zeros((1, 8), np.int32)})["params"]
+    engine = InferenceEngineV2(model, params, ENGINE_LIMITS)
+    assert prepare_spans() == [{"family": "opt", "leaves": 0, "bytes": 0}]
+    assert all(a is b for a, b in zip(jax.tree.leaves(engine._params),
+                                      jax.tree.leaves(params)))
+
+
+def test_a_replica_over_tp_keeps_the_prepared_kernels_split_over_heads(llama_tree):
+    """``build_replica`` shards the tree as trained (q, k and v's kernels by
+    their columns: by heads) and the engine re-lays it: the prepared kernel
+    is still split over its heads, and over nothing else."""
+    from jax.sharding import PartitionSpec as P
+    from deepspeed_tpu.inference.v2.replica_group import build_replica
+    model, params = llama_tree
+    mesh, sched = build_replica(model, params, jax.devices()[:2], tp_size=2,
+                                engine_config=ENGINE_LIMITS)
+    attn = sched.engine._params["layers"]["block"]["self_attn"]
+    for name in ("q_proj", "k_proj", "v_proj"):
+        kernel = attn[name]["kernel"]
+        assert kernel.sharding.is_equivalent_to(
+            jax.sharding.NamedSharding(mesh, P(None, "tp", None, None)), 4), name
