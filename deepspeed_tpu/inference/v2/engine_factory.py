@@ -21,14 +21,17 @@ softmax-routed experts of which the tree may hold a share), and longcat_flash
 with a low-rank query and two dense FFNs each, beside one expert layer whose
 router's last columns are identity experts that compute nothing; one paged
 group of one leaf with two planes a layer, and a counter group the expert
-layers add to on the device).
+layers add to on the device), and kimi_linear (Kimi-Linear: three layers of
+gated delta-rule linear attention, a matrix state a head in a slot group, to
+one of latent attention without positions, whose pages are the one paged
+group's planes; Kanana-2's expert rule; a counter group).
 
 A family is three things, resolved here: its ragged forward, its verify
 forward (or None) and its cache groups (``ragged/cache_groups.py``); two more
 where its module has them: ``prepare_params(cfg, params)``, the tree as its
 forward reads it, made once by ``InferenceEngineV2`` when it is built, and
-``dispatch_report(cfg, real_tokens)``, what a dispatch reports of the family
-beside the engine's and the cache groups' own counts.
+``dispatch_report(cfg, real_tokens, chunk)``, what a dispatch reports of the
+family beside the engine's and the cache groups' own counts.
 """
 
 import importlib
@@ -47,7 +50,8 @@ _IMPLEMENTATION = {"llama": "llama", "mistral": "llama", "qwen2": "llama",
                    "phi": "parallel_block", "opt": "opt",
                    "phi4flash": "phi4flash", "mellum2": "mellum2",
                    "kanana2": "kanana2", "keye_vl2": "keye_vl2",
-                   "longcat_flash": "longcat_flash"}
+                   "longcat_flash": "longcat_flash",
+                   "kimi_linear": "kimi_linear"}
 
 #: families ``build_engine`` serves from an in-tree model and tree
 SERVED_FAMILIES = tuple(_IMPLEMENTATION)
@@ -55,7 +59,7 @@ SERVED_FAMILIES = tuple(_IMPLEMENTATION)
 SUPPORTED_FAMILIES = tuple(
     f for f in SERVED_FAMILIES
     if f not in ("phi4flash", "mellum2", "kanana2", "keye_vl2",
-                 "longcat_flash"))  # no HF converter
+                 "longcat_flash", "kimi_linear"))  # no HF converter
 
 #: the one place a config class names its family; any other is a llama tree
 _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
@@ -65,7 +69,8 @@ _FAMILY_OF_CONFIG = {"MixtralConfig": "mixtral",
                      "Mellum2Config": "mellum2",
                      "Kanana2Config": "kanana2",
                      "KeyeVL2Config": "keye_vl2",
-                     "LongcatFlashConfig": "longcat_flash"}
+                     "LongcatFlashConfig": "longcat_flash",
+                     "KimiLinearConfig": "kimi_linear"}
 
 
 def _implementation(model, family):
@@ -126,7 +131,8 @@ def resolve_verify_fn(model, family=None):
 
 
 def resolve_report_fn(model, family=None):
-    """The family's ``dispatch_report(cfg, real_tokens)``: what a dispatch
+    """The family's ``dispatch_report(cfg, real_tokens, chunk)`` (the
+    dispatch's real tokens, and the token slots a row of it): what a dispatch
     reports beyond what the engine and the cache groups say of it, as the two
     mappings ``moe_layer.dispatch_report`` describes; ``None`` for a family
     that exports none (no reporter, not a reporter of zeros)."""

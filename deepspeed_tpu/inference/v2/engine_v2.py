@@ -412,8 +412,8 @@ class InferenceEngineV2:
             reports = [self._state.dispatch_report(
                 seqs, arrays["seen"], arrays["q_len"], chunk_bucket)]
             if self._dispatch_report is not None:
-                reports.append(
-                    self._dispatch_report(self._model_config, real_tokens))
+                reports.append(self._dispatch_report(
+                    self._model_config, real_tokens, chunk_bucket))
             for more_adds, more_rides in reports:
                 adds.update(more_adds)
                 rides.update(more_rides)

@@ -40,24 +40,29 @@ from deepspeed_tpu.models.llama import (
     rope_frequencies, rotary_apply, rotary_tables)
 
 
-def prepare_params(cfg, params):
-    """The tree as the forward reads it: each layer's ``kv_b_proj`` [r, H *
-    (nope + v)] cut once into ``w_uk`` [r, H, nope] and ``w_uv`` [r, H, v],
-    whole buffers of their own, instead of two strided slices a dispatch. A
-    tree of shapes (a compile for a described chip) gives a tree of shapes."""
+def cut_kv_b(cfg, kv_b):
+    """``kv_b_proj``'s kernel [r, H * (nope + v)] as ``(w_uk [r, H, nope],
+    w_uv [r, H, v])``, whole buffers of their own; shapes give shapes."""
     H, dn = cfg.num_attention_heads, cfg.qk_nope_head_dim
 
     def cut(kv_b):
         kv_b = kv_b.reshape(kv_b.shape[0], H, -1)
         return kv_b[..., :dn] + 0, kv_b[..., dn:] + 0
 
+    return jax.eval_shape(cut, kv_b) if isinstance(kv_b, jax.ShapeDtypeStruct) \
+        else cut(kv_b)
+
+
+def prepare_params(cfg, params):
+    """The tree as the forward reads it: each layer's ``kv_b_proj`` [r, H *
+    (nope + v)] cut once into ``w_uk`` [r, H, nope] and ``w_uv`` [r, H, v],
+    whole buffers of their own, instead of two strided slices a dispatch. A
+    tree of shapes (a compile for a described chip) gives a tree of shapes."""
     out = dict(params)
     for l in range(cfg.num_hidden_layers):
         layer = dict(params[f"layers_{l}"])
         attn = dict(layer["self_attn"])
-        kv_b = attn.pop("kv_b_proj")["kernel"]
-        attn["w_uk"], attn["w_uv"] = jax.eval_shape(cut, kv_b) \
-            if isinstance(kv_b, jax.ShapeDtypeStruct) else cut(kv_b)
+        attn["w_uk"], attn["w_uv"] = cut_kv_b(cfg, attn.pop("kv_b_proj")["kernel"])
         layer["self_attn"] = attn
         out[f"layers_{l}"] = layer
     return out
@@ -70,7 +75,10 @@ def absorbed_mla(cfg, scope, attn, project_q, h, x, pool, tables, seen, q_len,
     family and LongCat-Flash (``longcat_flash.py``: a low-rank q, two of them
     a layer) share. ``attn``: ``kv_a_proj``, ``kv_a_layernorm``, ``w_uk``,
     ``w_uv``, ``o_proj``; ``project_q(h)`` -> [S, Q, H, nope + rope], traced
-    under ``mla_q``. The device scopes are ``<scope>/{mla_q,
+    under ``mla_q``. ``rope``: the rotary tables of the dispatch's positions,
+    or None for a model whose latent attention has no positions
+    (``kimi_linear.py``: the "rope" columns are then 64 more shared key
+    columns, as projected). The device scopes are ``<scope>/{mla_q,
     mla_latent_write, mla_read, mla_out}``."""
     S, Q, _ = x.shape
     H, r = cfg.num_attention_heads, cfg.kv_lora_rank
@@ -78,17 +86,18 @@ def absorbed_mla(cfg, scope, attn, project_q, h, x, pool, tables, seen, q_len,
     W, bs = pool.shape[-1], pool.shape[2]
     eps, dt = cfg.rms_norm_eps, cfg.dtype
     w_uk, w_uv = attn["w_uk"].astype(dt), attn["w_uv"].astype(dt)
+    rotate = (lambda t: t) if rope is None else (lambda t: rotary_apply(t, *rope))
     with jax.named_scope(scope):
         with jax.named_scope("mla_q"):
             q = project_q(h)
             q_lat = jnp.einsum("sqhd,chd->sqhc", q[..., :dn], w_uk)
             q_row = jnp.concatenate(
-                [q_lat, rotary_apply(q[..., dn:], *rope),
+                [q_lat, rotate(q[..., dn:]),
                  jnp.zeros((S, Q, H, W - r - dr), dt)], -1)
         with jax.named_scope("mla_latent_write"):
             ckv = h @ attn["kv_a_proj"]["kernel"].astype(dt)      # [S, Q, r + dr]
             c = _rmsnorm(ckv[..., :r], attn["kv_a_layernorm"]["scale"], eps)
-            k_pe = rotary_apply(ckv[..., None, r:], *rope)[..., 0, :]
+            k_pe = rotate(ckv[..., None, r:])[..., 0, :]
             row = jnp.concatenate(
                 [c, k_pe, jnp.zeros((S, Q, W - r - dr), dt)], -1)
             pool = _scatter_latent(pool, row, tables, seen, q_len, bs, trash)

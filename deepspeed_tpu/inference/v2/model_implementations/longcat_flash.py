@@ -50,13 +50,13 @@ from deepspeed_tpu.models.longcat_flash import COUNTER_FIELDS
 assert COUNTER_FIELDS == moe_layer.COUNTS + ("dispatches",)
 
 
-def dispatch_report(cfg, real_tokens):
+def dispatch_report(cfg, real_tokens, chunk=None):
     """``moe_layer.dispatch_report``'s two mappings (``expert_rows`` counts the
     rows ROUTED, zero experts' among them; which were which is the counter
     group's to say); on the span also the zero experts, the router's whole
     width and the planes a latent page index spans (``latent_pages`` and the
     engine's ``live_pages`` count page indices: x ``kv_planes`` in pages)."""
-    adds, rides = moe_layer.dispatch_report(cfg, real_tokens)
+    adds, rides = moe_layer.dispatch_report(cfg, real_tokens, chunk)
     return adds, dict(rides, zero_experts=cfg.zero_expert_num,
                       experts_routed_over=cfg.router_width,
                       kv_planes=2 * cfg.num_layers)

@@ -59,8 +59,9 @@ from deepspeed_tpu.ops.registry import pallas_interpret, takes_kernel
 COUNTS = ("routed_rows", "zero_rows", "held_rows", "experts_hit")
 
 
-def dispatch_report(cfg, real_tokens):
-    """What a dispatch of ``real_tokens`` real tokens reports of the expert
+def dispatch_report(cfg, real_tokens, chunk=None):
+    """What a dispatch of ``real_tokens`` real tokens (in rows of ``chunk``
+    token slots, which the expert layers do not ask) reports of the expert
     layers of a model of config ``cfg``: the family's module exports it
     (``engine_factory.resolve_report_fn``), and the engine carries the two
     mappings without reading them. Added to the round's counts:

@@ -297,6 +297,8 @@ def _cell_program(name, rows, one_chip, monkeypatch, tokens=1):
     from deepspeed_tpu.inference.v2 import engine_v2
     from deepspeed_tpu.inference.v2.engine_factory import build_engine
     from deepspeed_tpu.models.kanana2 import Kanana2Config, Kanana2ForCausalLM
+    from deepspeed_tpu.models.kimi_linear import (KimiLinearConfig,
+                                                  KimiLinearForCausalLM)
     from deepspeed_tpu.models.longcat_flash import (LongcatFlashConfig,
                                                     LongcatFlashForCausalLM)
     from deepspeed_tpu.models.mellum2 import Mellum2Config, Mellum2ForCausalLM
@@ -320,6 +322,11 @@ def _cell_program(name, rows, one_chip, monkeypatch, tokens=1):
         share = cfg["experts_held"]
         model = LongcatFlashForCausalLM(LongcatFlashConfig.from_hf(
             cfg, dtype=jnp.bfloat16, n_routed_experts=cfg["n_routed_experts_published"],
+            experts_held=(share["first"], share["count"])))
+    elif cfg["driver"] == "serve_kimi_linear":
+        share = cfg["experts_held"]
+        model = KimiLinearForCausalLM(KimiLinearConfig.from_hf(
+            cfg, dtype=jnp.bfloat16, num_experts=cfg["num_experts_published"],
             experts_held=(share["first"], share["count"])))
     else:
         model = MistralForCausalLM(mistral_config(dtype=jnp.bfloat16, **{
@@ -352,9 +359,9 @@ def _cell_program(name, rows, one_chip, monkeypatch, tokens=1):
     ("mistral-7b-l16", 64, 64, 1), ("mistral-7b-l16", 3, 4, 1),
     ("phi4-mini-flash", 64, 64, 5), ("phi4-mini-flash", 3, 4, 5),
     ("mellum2-l12", 64, 64, 48), ("kanana2-l12-ep8", 64, 64, 45),
-    ("longcat-flash-l4-ep32", 64, 64, 20)],
+    ("longcat-flash-l4-ep32", 64, 64, 20), ("kimi-linear-l16-ep16", 64, 64, 61)],
     ids=["mistral64", "mistral4", "phi4flash64", "phi4flash4", "mellum2-64",
-         "kanana2-64", "longcat-flash-64"])
+         "kanana2-64", "longcat-flash-64", "kimi-linear-64"])
 def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
                                              name, rows, bucket, kernels):
     """The WHOLE ragged forward of a decode round, [64, 1] and [4, 1], as the
@@ -364,7 +371,9 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
     rows of which a padded row takes none; for kanana2 the latent walk in 12
     layers and the grouped GEMMs over the 16 experts held in 11; for
     longcat-flash the latent walk twice and the grouped GEMMs once in each of
-    4 double layers, the zero experts' rows past every group) at one token
+    4 double layers, the zero experts' rows past every group; for kimi-linear
+    the one-step KDA kernel in 12 layers, the latent walk in 4 and the
+    grouped GEMMs in 15) at one token
     a row, read from the host's buffer or, by the row's source, from the ids
     the round before left on the device (the program's last array, one
     place a row of the engine's 64)."""
@@ -402,6 +411,37 @@ def test_a_cells_program_writes_its_pool_in_place(for_tpu, one_chip, monkeypatch
         dims = ",".join(map(str, (p.shape[0] * p.shape[1],) + p.shape[2:]))
         assert f"[{dims}]" in text, dims       # the merged pool, on the loop's carry
         assert not re.search(rf"= \w+\[{dims}\]\S* (copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("rows,tokens,shape,kernels", [
+    (64, 1, (64, 1), 61), (1, 449, (1, 512), 61)], ids=["decode64", "chunk512"])
+def test_kimi_linears_programs_update_slots_and_pages_where_they_lie(
+        for_tpu, one_chip, monkeypatch, rows, tokens, shape, kernels):
+    """Kimi-Linear's WHOLE programs at the published widths, compiled for the
+    described chip: 12 KDA kernels (``kda_step`` at one token a row,
+    ``kda_chunk`` for a chunk), 4 latent walks, 15 x 3 grouped GEMMs. The
+    1.6 GB of matrix states, the convolution tails and the latent pages come
+    back in the buffers they came in (donated, aliased through the kernels'
+    own ``input_output_aliases``), and the program's scratch is smaller than
+    a QUARTER of the state pool: it holds no copy of it, gathered or whole."""
+    layout, lowered = _cell_program("kimi-linear-l16-ep16", rows, one_chip, monkeypatch, tokens)
+    assert dict(layout)["tokens"] == shape and "state" in dict(layout)
+    cache = lowered.in_avals[0][1]
+    assert set(cache) == {"kv", "state", "counters"}
+    state = cache["state"]["kda"]
+    assert state.shape == (12, 65, 32, 128, 128) and state.dtype == jnp.float32
+    assert cache["state"]["conv"].shape == (12, 65, 3, 12288)
+    assert cache["kv"][0].shape[0] == 4 and cache["kv"][0].shape[-1] == 640
+    pools = jax.tree.leaves((cache["kv"], cache["state"]))
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(p.size * p.dtype.itemsize for p in pools)
+    assert memory.temp_size_in_bytes < state.size * 4 // 4, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= kernels
+    merged = ",".join(map(str, (12 * 65, 32, 128, 128)))
+    assert f"f32[{merged}]" in text
+    assert not re.search(rf"= f32\[{merged}\]\S* (copy|transpose)\(", text)
 
 
 _MOVES = {"parameter", "constant", "dynamic-slice", "slice", "bitcast", "reshape",

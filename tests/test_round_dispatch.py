@@ -334,8 +334,8 @@ def test_a_reporter_nobody_knows_reaches_the_spans_and_the_scheduler(served, bui
     cfg, model, params, _ = served
     asked = []
 
-    def report(config, real_tokens):
-        asked.append((config, real_tokens))
+    def report(config, real_tokens, chunk):
+        asked.append((config, real_tokens, chunk))
         return {"probe_pages": 3 + real_tokens}, {"probe_row_bytes": 96}
 
     engine = InferenceEngineV2(model, params, report_fn=report, config={
@@ -348,8 +348,9 @@ def test_a_reporter_nobody_knows_reaches_the_spans_and_the_scheduler(served, bui
     _serve(sched, cfg.vocab_size)
     builds = build_spans()
     assert builds and len(builds) == sched.dispatches == len(asked) > sched.rounds > 3
-    assert all(config is cfg for config, _ in asked)
-    assert [a["real_tokens"] for a in builds] == [n for _, n in asked]
+    assert all(config is cfg for config, _, _ in asked)
+    assert [(a["real_tokens"], a["chunk_bucket"]) for a in builds] \
+        == [(n, chunk) for _, n, chunk in asked]
     assert all(a["probe_pages"] == 3 + a["real_tokens"] and a["probe_row_bytes"] == 96
                for a in builds)
     assert sched.probe_pages == sum(a["probe_pages"] for a in builds) \
@@ -381,6 +382,9 @@ def _served_family(name):
         from deepspeed_tpu.models.longcat_flash import (
             LongcatFlashConfig, LongcatFlashForCausalLM)
         model = LongcatFlashForCausalLM(LongcatFlashConfig.tiny(experts_held=(4, 8)))
+    elif name == "kimi_linear":
+        from deepspeed_tpu.models.kimi_linear import KimiLinearConfig, KimiLinearForCausalLM
+        model = KimiLinearForCausalLM(KimiLinearConfig.tiny(experts_held=(4, 8)))
     else:
         from deepspeed_tpu.models.keye_vl2 import KeyeVL2Config, KeyeVL2ForCausalLM
         model = KeyeVL2ForCausalLM(KeyeVL2Config.tiny(experts_held=(4, 2)))
@@ -407,7 +411,15 @@ _A_SHARE = {"experts_held", "experts_routed_over"}
     # the counter group adds no name: what it counts is never on a span
     ("longcat_flash", _EXPERTS | _A_SHARE | {"latent_pages", "latent_row_bytes", "zero_experts",
                                              "kv_planes"},
-     _EXPERTS | {"latent_pages"})])
+     _EXPERTS | {"latent_pages"}),
+    # a slot group beside the one-leaf pages: the state manager's further-group
+    # names (no further PAGED group, so no ``<name>_live_pages``) and the KDA
+    # layers' own
+    ("kimi_linear", _EXPERTS | _A_SHARE | {
+        "latent_pages", "latent_row_bytes", "window_pages_freed", "state_slots",
+        "global_pages", "window_pages", "kda_step_rows", "kda_chunk_tokens", "kda_layers"},
+     _EXPERTS | {"latent_pages", "window_pages_freed", "state_slots", "kda_step_rows",
+                 "kda_chunk_tokens"})])
 def test_the_build_spans_attributes_are_the_ones_the_benchmark_reads(
         family, beyond, summed, build_spans):
     """The SET of attribute names on ``serving/build`` a served family, as
