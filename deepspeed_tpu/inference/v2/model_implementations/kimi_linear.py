@@ -10,13 +10,20 @@ Three kinds of state ride the ``cache`` pytree (``ragged/cache_groups.py``):
   ``rope=None``: nothing is rotated and no table is built). Plane ``p`` is the
   ``p``-th MLA layer's: a KDA layer has no page, so the stack's layer index
   and the pool's differ and ``layer_rows`` takes the plane's.
-* ``cache["state"]``: ``conv`` ``[KDA layers, slots+1, taps-1, 3 x H x dk]``
-  (the last inputs of the q, k and v convolutions side by side, the serving
-  dtype) and ``kda`` ``[KDA layers, slots+1, H, dk, dk]`` float32, row
-  ``tables["state"]`` of each a sequence's slot (the last absorbs padded
-  rows). A row whose ``seen`` is 0 starts from zero state and zero tails
-  whatever its slot held; positions ``>= q_len`` advance neither leaf
-  (``phi4flash._mamba``'s rule).
+* ``cache["state"]``: ``conv`` ``[KDA layers, slots+1, 4, 3 x H x dk]`` (the
+  last ``taps-1`` inputs of the q, k and v convolutions side by side in rows
+  ``0 .. taps-2``, the serving dtype; the rows up to a whole tile of four are
+  zeros that nothing reads) and ``kda`` ``[KDA layers, slots+1, H, dk, dk]``
+  float32, row ``tables["state"]`` of each a sequence's slot (the last
+  absorbs padded rows). A row whose ``seen`` is 0 starts from zero state and
+  zero tails whatever its slot held; positions ``>= q_len`` advance neither
+  leaf (``phi4flash._mamba``'s rule). Why a fourth row: the chip's tiling
+  pads three bfloat16 rows to four, and stored as three the pool was kept
+  compact between uses and re-laid WHOLE around every layer's gather and
+  scatter of a dispatch's rows (17 % of the serving cell's busy time). Do not
+  restore the three rows, and do not flatten a slot to one row either: that
+  program compiles and never ends its first dispatch on the chip (PERF.md,
+  PR 56).
 * ``cache["counters"]``: the expert layers' ``moe_layer.COUNTS``, summed over
   the layers of a dispatch and added on the device.
 
@@ -130,14 +137,15 @@ def _kda(cfg, attn, h, x, conv, state, slots, q_len, keep):
         with jax.named_scope("kda_conv"):
             w = attn["conv"].astype(f32)                       # [K, 3 W]
             K = w.shape[0]
-            tail = jnp.where(keep[:, None, None], conv[slots], 0).astype(dt)
+            tail = jnp.where(keep[:, None, None], conv[slots][:, :K - 1], 0).astype(dt)
             ext = jnp.concatenate([tail, qkv], axis=1)         # [S, K-1+Q, 3 W]
             c = jax.nn.silu(sum(ext[:, i:i + Q].astype(f32) * w[i] for i in range(K)))
             # the K-1 columns before position q_len: a row of no real tokens
             # keeps its columns, a padded position never shifts them
             idx = q_len[:, None] + jnp.arange(K - 1)[None, :]
-            conv = conv.at[slots].set(jnp.take_along_axis(
-                ext, idx[:, :, None], axis=1).astype(conv.dtype))
+            new = jnp.take_along_axis(ext, idx[:, :, None], axis=1).astype(conv.dtype)
+            conv = conv.at[slots].set(jnp.pad(
+                new, ((0, 0), (0, conv.shape[1] - (K - 1)), (0, 0))))
             heads = lambda a: a.reshape(S, Q, H, dk)
             unit = lambda a: a * jax.lax.rsqrt(
                 jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)

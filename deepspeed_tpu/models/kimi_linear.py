@@ -303,7 +303,13 @@ class KimiLinearForCausalLM:
         ``p``-th MLA layer's, three layers in four have no page), one slot
         group of two leaves over the KDA layers (the three convolutions'
         tails, and the matrix state a head in float32) and the expert layers'
-        device counts."""
+        device counts. A slot's tails are stored in whole tiles of FOUR rows
+        (``taps - 1`` = 3 rows of ``3 W`` values and one of zeros that nothing
+        reads): the chip's tiling pads three bfloat16 rows to four anyway, and
+        declared three rows a slot the compiler kept the pool compact between
+        uses and re-laid ALL of it around every KDA layer's gather and scatter
+        of a dispatch's rows (17 % of the serving cell's busy time; PERF.md,
+        PR 56, also for why the row is not flat)."""
         from deepspeed_tpu.inference.v2.model_implementations.moe_layer import COUNTS
         from deepspeed_tpu.inference.v2.ragged.cache_groups import (
             CounterGroup, PagedGroup, SlotGroup)
@@ -312,7 +318,7 @@ class KimiLinearForCausalLM:
         return (PagedGroup("kv", len(cfg.mla_layers), 1, cfg.latent_row_width,
                            leaves=1, value_dim=cfg.kv_lora_rank),
                 SlotGroup("state", (
-                    ("conv", (M, cfg.short_conv_kernel_size - 1, 3 * cfg.kda_width),
-                     jnp.dtype(cfg.dtype).name),
+                    ("conv", (M, -(-(cfg.short_conv_kernel_size - 1) // 4) * 4,
+                              3 * cfg.kda_width), jnp.dtype(cfg.dtype).name),
                     ("kda", (M, H, dk, dk), "float32"))),
                 CounterGroup("counters", COUNTS))

@@ -282,14 +282,17 @@ class _Captured(Exception):
     pass
 
 
-def _cell_program(name, rows, one_chip, monkeypatch, tokens=1):
+def _cell_program(name, rows, one_chip, monkeypatch, tokens=1, num_kv_blocks=256):
     """(layout, the lowered program) of the dispatch ``rows`` decoding
     sequences (or one row of ``tokens`` prompt tokens) make in the engine of
     the benchmark's configuration ``name``,
     as the engine enqueues it (``engine_v2.packed_forward``: the packed
     buffer sliced, then the family's forward), its arguments as shapes on
     ``one_chip``: the configuration's widths, layers and dtypes (weights as
-    shapes only), its engine limits, a small page pool."""
+    shapes only), its engine limits, a page pool of ``num_kv_blocks`` pages
+    (small unless a test asks for the cell's own: the engine's pools are
+    zeros on the HOST here, 7 GB at Kimi-Linear's 16,384 pages, freed with
+    the test)."""
     import json
     import os
     from benchmark import harness, weights
@@ -339,7 +342,7 @@ def _cell_program(name, rows, one_chip, monkeypatch, tokens=1):
     params = jax.eval_shape(lambda: weights.make_params(0, spec))
     engine = build_engine(model, params, dict(
         cfg["engine"], state_manager=dict(cfg["engine"]["state_manager"],
-                                          num_kv_blocks=256)))
+                                          num_kv_blocks=num_kv_blocks)))
     program, got = engine_v2.packed_forward, []
 
     def spy(*args):
@@ -430,7 +433,7 @@ def test_kimi_linears_programs_update_slots_and_pages_where_they_lie(
     assert set(cache) == {"kv", "state", "counters"}
     state = cache["state"]["kda"]
     assert state.shape == (12, 65, 32, 128, 128) and state.dtype == jnp.float32
-    assert cache["state"]["conv"].shape == (12, 65, 3, 12288)
+    assert cache["state"]["conv"].shape == (12, 65, 4, 12288)
     assert cache["kv"][0].shape[0] == 4 and cache["kv"][0].shape[-1] == 640
     pools = jax.tree.leaves((cache["kv"], cache["state"]))
     compiled = lowered.compile()
@@ -446,6 +449,14 @@ def test_kimi_linears_programs_update_slots_and_pages_where_they_lie(
 
 _MOVES = {"parameter", "constant", "dynamic-slice", "slice", "bitcast", "reshape",
           "copy", "transpose"}
+# one instruction of a compiled program's text: its name, its array (shape and
+# layout) and what it is
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%?(?P<name>\S+) = "
+                          r"(?P<array>\w+\[(?P<dims>[\d,]*)\]\S*) (?P<op>[\w\-]+)\(")
+
+
+def _elements(m):
+    return int(np.prod([int(d) for d in m["dims"].split(",") if d]))
 
 
 def _moved_whole(text, sizes):
@@ -466,24 +477,67 @@ def _moved_whole(text, sizes):
         elif name is not None and not line.startswith("}"):
             bodies[name].append(line)
     fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
-    instruction = re.compile(
-        r"\s*(?:ROOT )?%?\S+ = (\w+\[([\d,]*)\]\S*) ([\w\-]+)\(")
     found = []
     for name, body in bodies.items():
         if name in fused:
             continue
         for line in body:
-            m = instruction.match(line)
-            if not m or int(np.prod([int(d) for d in m.group(2).split(",") if d])) not in sizes:
+            m = _INSTRUCTION.match(line)
+            if not m or _elements(m) not in sizes:
                 continue
-            what = m.group(3)
+            what = m["op"]
             if what == "fusion":
                 called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
-                inside = {i.group(3) for i in map(instruction.match, bodies[called]) if i}
+                inside = {i["op"] for i in map(_INSTRUCTION.match, bodies[called]) if i}
                 what = "slice" if inside <= _MOVES else None
             if what in ("copy", "transpose", "slice"):
-                found.append((what, m.group(1)))
+                found.append((what, m["array"]))
     return found
+
+
+@pytest.mark.parametrize("rows,tokens,shape", [
+    (64, 1, (64, 1)), (3, 1, (4, 1)), (1, 449, (1, 512)), (1, 100, (1, 128))],
+    ids=["decode64", "decode4", "chunk512", "chunk128"])
+def test_kimi_linears_programs_gather_and_scatter_tails_without_moving_their_pool(
+        for_tpu, one_chip, monkeypatch, rows, tokens, shape):
+    """The convolution tails are stored in whole tiles of four rows a slot
+    (``KimiLinearForCausalLM.cache_groups``: three tails and a row of zeros),
+    and no program of the cell, compiled for the described chip at the cell's
+    OWN pool (16,384 pages: 13.5 GB of arguments, where the compiler starts
+    to trade time for memory), moves their pool: outside its multiplying
+    fusions it holds no ``copy``, ``transpose`` or fusion of slices and
+    bitcasts of an array of the pool's size (with four rows a slot or with
+    three), no ``remat_compressed`` / ``remat_uncompressed`` twin of it, and
+    the pool stays in HBM (no ``copy-done``, no ``S(1)``). Stored
+    ``[12, 65, 3, 12288]`` every program held two copies of the whole pool
+    (the argument, kept compact, re-laid to the padded tiles on the way in
+    and back on the way out) at any page pool, and at the cell's ten
+    compressed and ten uncompressed twins besides, around every KDA layer's
+    gather and scatter of 64 rows: 17 % of the cell's busy time on the chip.
+    Stored one FLAT row a slot, ``[12, 65, 36864]``, the programs hold none
+    of those either, but the compiler keeps that pool in VMEM (``S(1)``) and
+    expands each scatter into a loop of 64 ``dynamic-update-slice``s on it,
+    and on the chip the ``[64, 1]`` program never ended its first dispatch
+    (PERF.md, PR 56)."""
+    pages = 16384
+    layout, lowered = _cell_program("kimi-linear-l16-ep16", rows, one_chip, monkeypatch,
+                                    tokens, num_kv_blocks=pages)
+    assert dict(layout)["tokens"] == shape
+    cache = lowered.in_avals[0][1]
+    assert cache["kv"][0].shape[:2] == (4, pages + 1)
+    conv = cache["state"]["conv"]
+    assert conv.shape == (12, 65, 4, 12288) and conv.dtype == jnp.bfloat16
+    slots = conv.shape[0] * conv.shape[1]
+    sizes = {slots * 4 * 12288, slots * 3 * 12288}
+    text = lowered.compile().as_text()
+    assert _moved_whole(text, sizes) == []
+    pool = [m for m in map(_INSTRUCTION.match, text.splitlines())
+            if m and _elements(m) in sizes]
+    assert any(m["array"].startswith(f"bf16[{slots},4,12288]") for m in pool)   # the merged pool
+    twins = [m["name"] for m in pool if "remat_" in m["name"]]
+    assert not twins, twins
+    elsewhere = [m[0] for m in pool if "S(1)" in m["array"] or m["op"] == "copy-done"]
+    assert not elsewhere, elsewhere[:3]
 
 
 @pytest.mark.parametrize("rows,tokens,shape,moved", [

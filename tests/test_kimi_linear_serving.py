@@ -105,18 +105,19 @@ def test_the_factory_resolves_the_family(served):
     assert (pages.name, pages.layers, pages.kv_heads, pages.head_dim, pages.leaves,
             pages.value_dim, pages.window) == ("kv", 1, 1, 256, 1, 128, None)
     assert slots.name == "state" and slots.leaves == (
-        ("conv", (3, 3, 3 * 64), "float32"), ("kda", (3, 2, 32, 32), "float32"))
+        ("conv", (3, 4, 3 * 64), "float32"), ("kda", (3, 2, 32, 32), "float32"))
     assert counters.fields == moe_layer.COUNTS
     engine = _engine(served)
     assert isinstance(engine, InferenceEngineV2) and not engine.verify_supported
     groups = engine.kv_stats()["groups"]
     assert set(groups) == {"kv", "state"} and groups["state"]["total"] == 4
     # the published sizes are the defaults: 7 MLA planes of 640 columns beside
-    # 20 KDA layers' slots of three tails of 4,096 and 32 states of 128 x 128
+    # 20 KDA layers' slots of three tails of 4,096 (in a whole tile of FOUR rows
+    # a slot, the last of zeros) and 32 states of 128 x 128
     full = KimiLinearConfig()
     pages, slots, _ = KimiLinearForCausalLM.cache_groups(full)
     assert (pages.layers, pages.head_dim, pages.value_dim) == (7, 640, 512)
-    assert slots.leaves == (("conv", (20, 3, 12288), "bfloat16"),
+    assert slots.leaves == (("conv", (20, 4, 12288), "bfloat16"),
                             ("kda", (20, 32, 128, 128), "float32"))
     assert [full.layer_kind(l) for l in range(4)] == ["kda", "kda", "kda", "mla"]
     assert full.layer_kind(26) == "mla" and full.num_expert_layers == 26
@@ -510,6 +511,90 @@ def test_preempt_then_resume_reproduces_the_uninterrupted_logits(served):
         engine.flush(uid)
     assert all(g["free"] == g["total"] for g in engine.kv_stats()["groups"].values())
     assert engine.swap_stats == {"swap_outs": 1, "swap_ins": 1}
+
+
+def test_a_slot_taken_off_and_put_back_by_the_state_manager_keeps_its_four_rows(served):
+    """``swap_out_sequence`` / ``swap_in_sequence`` themselves, between two
+    decode rounds: they index ``pool[:, slot]`` and never look inside a slot,
+    so the tails' tile of four rows (three tails, one of zeros) lands on the
+    host and comes back, into ANOTHER slot, value for value, and the sequence
+    goes on as if it had stayed."""
+    ids, want = served[4], served[5]
+    engine = _engine(served)
+    state = engine._state
+    got = _feed(engine, 0, ids[0], (16, 6, 1, 1))
+    seq = state.get_sequence(0)
+    first = seq.slot
+    rows = lambda slot: {name: np.asarray(pool[:, slot])
+                         for name, pool in state.slot_pools.items()}
+    before = rows(first)
+    assert before["conv"].shape == (3, 4, 3 * 64)
+    assert np.abs(before["conv"][:, :3]).min() > 0 and not before["conv"][:, 3].any()
+    state.swap_out_sequence(0)
+    assert seq.is_swapped and seq.slot is None
+    assert [leaf.shape for leaf in seq.group_swap["state"]] == [(3, 4, 192), (3, 2, 32, 32)]
+    # another sequence takes the freed slot and writes its own tails there
+    _feed(engine, 1, ids[1], (9, 1))
+    assert state.get_sequence(1).slot == first
+    assert np.abs(rows(first)["conv"] - before["conv"]).max() > 0
+    state.swap_in_sequence(0)
+    assert seq.slot not in (None, first)
+    after = rows(seq.slot)
+    for name in before:
+        np.testing.assert_array_equal(before[name], after[name])
+    got.update(_feed(engine, 0, ids[0], (1, 1, 9, 1), start=24))
+    assert _worst(got, want[0]) < TOLERANCE
+
+
+def _conv_inputs(ref_cfg, params, ids):
+    """{KDA layer's index among them: [T, 3 W]}: what the reference's three
+    convolutions of that layer read (``h W_q``, ``h W_k``, ``h W_v`` side by
+    side), from the reference's own pieces layer by layer."""
+    c, out = reference._c(ref_cfg), []
+    with jax.default_matmul_precision("highest"):
+        x = params["embed_tokens"].astype(jnp.float32)[jnp.asarray(ids)]
+        for l in range(ref_cfg["num_hidden_layers"]):
+            p = reference._f32(params[f"layers_{l}"])
+            if reference.is_mla(ref_cfg, l):
+                x = reference._mla(c, "f32", (), p, x)
+            else:
+                h = reference._rms(x, p["input_layernorm"]["scale"], c["rms_norm_eps"])
+                out.append(np.concatenate([np.asarray(reference.matmul(
+                    h, p["self_attn"][n + "_proj"]["kernel"], "f32")) for n in "qkv"], 1))
+                x = reference._kda(c, "f32", (), p, x)
+            if l < c["first_k_dense_replace"]:
+                x = reference._dense(c, "f32", p, x)
+            else:
+                m = p["moe"]
+                x, _ = reference._moe(c, "f32", (), p, lambda j, m=m: (
+                    m["w1"][j], m["w3"][j], m["w2"][j]), x)
+    return dict(enumerate(out))
+
+
+@pytest.mark.parametrize("chunks", [(5,), (8, 5, 2), (2, 1, 6)],
+                         ids=["padded-chunk", "shorter-than-the-taps", "from-zero"])
+def test_a_slots_tails_are_the_last_three_inputs_of_q_k_and_v(served, chunks):
+    """After every dispatch (5 tokens in a chunk of 16, then 2: fewer than
+    the taps; 2 from a slot that starts at zero, then a decode row) a slot's
+    first ``taps - 1`` rows are the reference's inputs of the q, k and v
+    convolutions at the sequence's last three positions, zeros before its
+    start, q, k and v side by side: a padded position never shifts them. The
+    fourth row, which fills the tile, stays zero."""
+    cfg, _, params, ref_cfg, ids, _ = served
+    engine = _engine(served)
+    inputs = _conv_inputs(ref_cfg, params, ids[2][:sum(chunks)])
+    W, pos = cfg.kda_width, 0
+    for n in chunks:
+        engine.put([2], [ids[2][pos:pos + n]])
+        pos += n
+        if n > 1:
+            assert engine.last_batch_shapes[0][1] > n        # padded positions
+        slot = engine._state.get_sequence(2).slot
+        row = np.asarray(engine._state.slot_pools["conv"][:, slot])
+        assert row.shape == (len(inputs), 4, 3 * W) and not row[:, 3].any()
+        for m, x in inputs.items():
+            want = np.concatenate([np.zeros((3, 3 * W), np.float32), x[:pos]])[-3:]
+            assert np.max(np.abs(row[m, :3] - want)) < TOLERANCE, (m, pos)
 
 
 def test_admission_needs_a_slot(served):
