@@ -143,7 +143,8 @@ class InferenceEngineV2:
         self.dispatch = 0
         # the (sequence bucket, chunk bucket, verify_k) dispatched so far:
         # the dispatch that is first of its shape (``first_seen`` on its
-        # span) is the one that traced, compiled or loaded a program
+        # span) is this engine's guess at the one that built a program;
+        # ``built`` beside it is what jax did build (telemetry/buildlog.py)
         self._shapes_seen = set()
         # the ids the last round's samplers drew, left on the device for the
         # next round's forward (``packed_forward``'s ``kept``): one place a
@@ -422,6 +423,7 @@ class InferenceEngineV2:
             sp.end()
 
             sp = tm.span_begin("serving/dispatch", round=rnd, dispatch=n)
+            built = telemetry.build_count()
             part = tm.span_begin("serving/dispatch/h2d", round=rnd, dispatch=n)
             # ONE transfer a dispatch: a transfer costs the host the same
             # whatever it carries, so the arrays cross as one buffer that
@@ -439,8 +441,13 @@ class InferenceEngineV2:
             # flow through the jitted forwards as pytree leaves. The cache
             # (named groups of pools, donated) is threaded from one dispatch
             # of the round to the next
+            # the shape that names the program rides on the two spans a
+            # program is built under: the build ledger (telemetry/buildlog.py)
+            # copies the innermost open span's attributes into its record
+            buckets = {"seq_bucket": seq_bucket, "chunk_bucket": chunk_bucket,
+                       "verify_k": verify_k or 0}
             part = tm.span_begin("serving/dispatch/forward", round=rnd,
-                                 dispatch=n)
+                                 dispatch=n, **buckets)
             out, cache = packed_forward(
                 self._ragged_forward if verify_k is None
                 else self._verify_forward, self._model_config, layout,
@@ -451,7 +458,7 @@ class InferenceEngineV2:
             programs = 1
             if sample is not None:
                 part = tm.span_begin("serving/dispatch/sample", round=rnd,
-                                     dispatch=n)
+                                     dispatch=n, **buckets)
                 out, sampled_rows = sample(out, rows)
                 part.end()
                 programs = 2
@@ -461,7 +468,12 @@ class InferenceEngineV2:
             first_seen = int(shape not in self._shapes_seen)
             if first_seen:
                 self._shapes_seen.add(shape)
-            sp.set(programs=programs, first_seen=first_seen)
+            # ``built`` is the measurement (programs jax traced, lowered,
+            # compiled or loaded during this dispatch, whatever the cause),
+            # ``first_seen`` this engine's guess from its own shapes
+            built = telemetry.build_count() - built
+            sp.set(programs=programs, first_seen=first_seen, built=built,
+                   build_ms=telemetry.build_ms(built))
             sp.end()
             self.dispatch = n + 1
             parts.append((rows, out))

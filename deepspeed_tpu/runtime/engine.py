@@ -1650,6 +1650,7 @@ class DeepSpeedEngine:
         step = self.global_steps
         _span = telemetry.span_begin(FORWARD_GLOBAL_TIMER, step=step,
                                      fused=int(fused))
+        built = telemetry.build_count()
         with telemetry.span("fwd/shard_batch", step=step):
             batch = self._shard_batch(batch)
         if self._guards is not None and self._guards["checkify_on_overflow"]:
@@ -1678,7 +1679,7 @@ class DeepSpeedEngine:
             else:
                 self._loss_accum = self._loss_accum + loss
                 self._loss_accum_n += 1
-        _span.end()
+        self._end_built(_span, built)
         if self.wall_clock_breakdown:
             self.timers(FORWARD_GLOBAL_TIMER).stop(token=loss)
         return loss
@@ -1691,10 +1692,23 @@ class DeepSpeedEngine:
         therefore marks this bookkeeping on the host, not a grad pass."""
         assert self._staged_loss is not None, "backward() called before forward()"
         from deepspeed_tpu import telemetry
-        with telemetry.span(BACKWARD_GLOBAL_TIMER, step=self.global_steps):
-            staged_loss = self._staged_loss
-            self._staged_loss = None
+        _span = telemetry.span_begin(BACKWARD_GLOBAL_TIMER, step=self.global_steps)
+        built = telemetry.build_count()
+        staged_loss = self._staged_loss
+        self._staged_loss = None
+        self._end_built(_span, built)
         return staged_loss
+
+    @staticmethod
+    def _end_built(span, before):
+        """End ``fwd``, ``bwd`` or ``step`` with what jax built under it:
+        ``built`` programs (the build ledger's count since ``before``,
+        ``telemetry/buildlog.py``) and their ``build_ms``: 0 and 0.0 on
+        every step that found its executables."""
+        from deepspeed_tpu import telemetry
+        built = telemetry.build_count() - before
+        span.set(built=built, build_ms=telemetry.build_ms(built))
+        span.end()
 
     def is_gradient_accumulation_boundary(self):
         """reference engine.py:2153 semantics. ``_gas_offset`` rebases the
@@ -1776,6 +1790,7 @@ class DeepSpeedEngine:
             self._handle_slice_loss(e)
         from deepspeed_tpu import telemetry
         _span = telemetry.span_begin(STEP_GLOBAL_TIMER, step=self.global_steps)
+        built = telemetry.build_count()
         if self.wall_clock_breakdown:
             self.timers(STEP_GLOBAL_TIMER).start()
         if self.is_gradient_accumulation_boundary():
@@ -1829,7 +1844,7 @@ class DeepSpeedEngine:
         self.global_samples += self.micro_batch_size * self.topology.data_parallel_size
         if self.wall_clock_breakdown:
             self.timers(STEP_GLOBAL_TIMER).stop()
-        _span.end()
+        self._end_built(_span, built)
         if self._step_applied and telemetry.enabled():
             # goodput/MFU ledger mark + HBM sample, once per optimizer step
             telemetry.ledger_step(step=self.global_steps)

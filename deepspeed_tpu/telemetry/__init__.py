@@ -19,12 +19,25 @@ feeds the same sinks::
 Disabled (the default), every call here but ``span`` is a constant-time
 no-op — no jax sync, no file I/O — and a span is only its profiler annotation. See docs/OBSERVABILITY.md for config keys, the exporter
 matrix and the dispatch reason-code table.
+
+Two things record all the same, bounded and stdlib-cheap: the flight
+recorder (``flightrec``) and the **build ledger** (``buildlog``): one record
+a program jax traced, lowered, compiled or loaded, with the seconds of each
+part, the persistent cache's answer and the span it was built under, fed by
+``jax.monitoring``'s own compile events (``build_log()``, ``build_count()``,
+``build_ms(n)``). Steady state pays nothing for it: a call that finds its
+executable emits no event. Enabled, each record also goes through
+``record_compile``, so the compile stream and the goodput ledger's
+``compile`` category hold every jit build.
 """
 
-from deepspeed_tpu.telemetry import flightrec  # noqa: F401
+from deepspeed_tpu.telemetry import buildlog, flightrec  # noqa: F401
+from deepspeed_tpu.telemetry.buildlog import (  # noqa: F401
+    build_count, build_log, build_ms)
 from deepspeed_tpu.telemetry.core import Telemetry  # noqa: F401
 
 _GLOBAL = Telemetry()
+buildlog.install(sink=_GLOBAL)     # the build ledger: always on, enabled or not
 
 
 def get_telemetry():

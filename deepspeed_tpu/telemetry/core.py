@@ -16,7 +16,9 @@ One object owns every measurement stream the runtime produces:
   outcomes with reason codes from ``ops/registry.sharded_kernel_call``.
 - **compile** (``record_compile``): per-program compile seconds + persistent
   compilation-cache hit/miss (and AOT ``memory_analysis`` byte breakdown)
-  from the AOT path.
+  from the AOT path, and every jit build from the build ledger
+  (``telemetry/buildlog.py``: always on; it feeds this stream through
+  ``record_build`` when the pipeline is enabled).
 - **memory** (``record_memory`` / ``sample_memory``): HBM occupancy samples
   from ``accelerator.memory_stats()`` — per-point stream, process peak
   watermark, Chrome-trace counter track, and (on ``RESOURCE_EXHAUSTED``)
@@ -46,7 +48,8 @@ the pipeline is enabled or not.
 
 Disabled (the default) every entry point but ``span`` is a constant-time
 no-op, and a span is one short-lived annotation object: no lock, no file
-I/O, no state kept — see
+I/O, no state kept but the thread's innermost open span (for the build
+ledger: one store on enter, one on exit) — see
 ``tests/test_telemetry.py::test_disabled_noop_fast_path``.
 
 Beyond the standard library this module imports only ``jax.profiler`` (the
@@ -61,6 +64,7 @@ import os
 import socket
 import threading
 import time
+import weakref
 
 from jax.profiler import TraceAnnotation
 
@@ -69,6 +73,10 @@ from jax.profiler import TraceAnnotation
 # ring so an abnormal exit can flush them as a postmortem bundle — even
 # when this pipeline itself is disabled. Stdlib-only, so import-safe here.
 from deepspeed_tpu.telemetry import flightrec as _flightrec
+# the build ledger (telemetry/buildlog.py) names the span a program was built
+# under: every span keeps the thread's innermost open one there
+from deepspeed_tpu.telemetry import buildlog as _buildlog
+from deepspeed_tpu.telemetry.buildlog import _thread as _open
 
 #: event-name prefixes mirrored into the flight recorder ring. A module
 #: constant so the disabled-path check in record() allocates nothing.
@@ -213,9 +221,12 @@ class _Span(TraceAnnotation):
     device trace's clock; with no profiler session that is one small object
     and about a microsecond. ``_tm`` is the pipeline only when telemetry is
     enabled: then the span also feeds the span stats, the JSONL file and
-    the Chrome trace. A span never waits for the device."""
+    the Chrome trace. A span never waits for the device. ``_outer`` is a
+    weak reference to the span that was the thread's innermost when this one
+    opened: the build ledger names a program's cause by it, and a span that
+    an exception left unended stops counting as open once nothing holds it."""
 
-    __slots__ = ("_tm", "name", "tags", "_t0")
+    __slots__ = ("_tm", "name", "tags", "_t0", "_outer")
 
     def __init__(self, tm, name, tags):
         super().__init__("ds/" + name, **tags)
@@ -225,6 +236,7 @@ class _Span(TraceAnnotation):
         TraceAnnotation.__enter__(self)
         # None once ended; the clock is read only for the pipeline's sinks
         self._t0 = _now() if tm is not None else 0.0
+        self._outer, _open.span = _open.span, weakref.ref(self)
 
     def __enter__(self):
         return self
@@ -246,6 +258,7 @@ class _Span(TraceAnnotation):
         if t0 is None:
             return 0.0
         TraceAnnotation.__exit__(self, None, None, None)
+        _open.span = self._outer
         tm, self._tm = self._tm, None
         if tm is None:
             return 0.0
@@ -560,6 +573,31 @@ class Telemetry:
                 tags["memory"] = entry["memory"]
             self._emit_jsonl({"name": f"compile/{program}", "kind": "seconds",
                               "value": seconds, "tags": tags})
+
+    def record_build(self, rec, span_names):
+        """One record of the build ledger (``telemetry/buildlog.py``: a
+        program jax traced, lowered, compiled or loaded) into the compile
+        sinks, under its name and, for a serving dispatch, its buckets.
+        ``span_names``: the spans it was built under, innermost first; the
+        first with a ledger category books these seconds as its own when it
+        ends (a recompile inside ``fwd``), so they are taken from it here:
+        a second belongs to one bucket (the summary reads a bucket that is
+        in debt until its span ends as 0)."""
+        if not self.enabled:
+            return
+        tags = rec["tags"]
+        program = rec["program"]
+        if "seq_bucket" in tags:
+            program += f"[{tags['seq_bucket']}x{tags['chunk_bucket']}]"
+        seconds = _buildlog.seconds_of(rec)
+        self.record_compile(program, seconds,
+                            cache=rec["cache"] if rec["cache"] != "off" else None)
+        for name in span_names:
+            cat = _ledger_category(name)
+            if cat is not None:
+                with self._lock:
+                    self.ledger_secs[cat] -= seconds
+                break
 
     # ------------------------------------------------------------------
     # serving stream (docs/OBSERVABILITY.md "Serving")
@@ -1196,7 +1234,7 @@ class Telemetry:
     def _ledger_summary(self):
         # caller holds self._lock
         wall = max(_now() - self._ledger_epoch, 0.0)
-        secs = {k: round(v, 6) for k, v in self.ledger_secs.items()}
+        secs = {k: round(max(v, 0.0), 6) for k, v in self.ledger_secs.items()}
         accounted = sum(secs.values())
         secs["idle"] = round(max(wall - accounted, 0.0), 6)
         goodput = (self.ledger_secs["compute"] / wall) if wall > 0 else 0.0

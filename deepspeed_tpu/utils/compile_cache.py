@@ -30,9 +30,22 @@ def enable():
     return path
 
 
+def entries():
+    """(entries, bytes) of the cache directory now: the executables jax
+    keeps there (``*-cache``; their access-time files are not counted).
+    (0, 0) if the directory is absent."""
+    count = size = 0
+    try:
+        with os.scandir(cache_dir()) as it:
+            for entry in it:
+                if entry.name.endswith("-cache"):
+                    count += 1
+                    size += entry.stat().st_size
+    except FileNotFoundError:
+        pass
+    return count, size
+
+
 def entry_count():
     """Number of entries currently in the cache directory (0 if absent)."""
-    try:
-        return sum(1 for n in os.listdir(cache_dir()) if n.endswith("-cache"))
-    except FileNotFoundError:
-        return 0
+    return entries()[0]
