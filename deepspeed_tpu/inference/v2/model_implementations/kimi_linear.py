@@ -6,7 +6,7 @@ sparse-expert layers with a shared expert.
 Three kinds of state ride the ``cache`` pytree (``ragged/cache_groups.py``):
 
 * ``cache["kv"]`` is ``(pages,)``: ``[MLA layers, NB+1, 1, bs, W]``, Kanana-2's
-  latent row a token (``kanana2.absorbed_mla`` writes and reads it, told
+  latent row a token (``kanana2.latent_mla`` writes and reads it, told
   ``rope=None``: nothing is rotated and no table is built). Plane ``p`` is the
   ``p``-th MLA layer's: a KDA layer has no page, so the stack's layer index
   and the pool's differ and ``layer_rows`` takes the plane's.
@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.model_implementations import moe_layer
 from deepspeed_tpu.inference.v2.model_implementations.kanana2 import (
-    absorbed_mla, cut_kv_b)
+    cut_kv_b, latent_mla, latent_read_report)
 from deepspeed_tpu.inference.v2.model_implementations.llama import _rmsnorm
 from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
     _pool_block_size, last_token, layer_rows, layer_trash, merge_layers,
@@ -62,12 +62,14 @@ def dispatch_report(cfg, real_tokens, chunk):
     """``moe_layer.dispatch_report``'s two mappings and the KDA layers': added
     are ``kda_step_rows`` (rows that took the one-step update: a dispatch of
     one token slot a row) and ``kda_chunk_tokens`` (real tokens that took the
-    chunk form), each a layer; ``kda_layers`` rides. The slots held are the
-    state manager's ``state_slots``."""
+    chunk form), each a layer, and the MLA layers' read's form
+    (``kanana2.latent_read_report``); ``kda_layers`` rides. The slots held are
+    the state manager's ``state_slots``."""
     adds, rides = moe_layer.dispatch_report(cfg, real_tokens, chunk)
     step = chunk == 1
     adds = dict(adds, kda_step_rows=real_tokens if step else 0,
-                kda_chunk_tokens=0 if step else real_tokens)
+                kda_chunk_tokens=0 if step else real_tokens,
+                **latent_read_report(cfg, real_tokens, chunk))
     return adds, dict(rides, kda_layers=len(cfg.kda_layers))
 
 
@@ -211,7 +213,7 @@ def _mla_layer(cfg, dense, lp, x, pool, tables, seen, q_len, real, trash):
     H, dt = cfg.num_attention_heads, cfg.dtype
     attn = lp["self_attn"]
     h = _rmsnorm(x, lp["input_layernorm"]["scale"], cfg.rms_norm_eps)
-    x, pool = absorbed_mla(
+    x, pool = latent_mla(
         cfg, "mla_attn", attn,
         lambda h: (h @ attn["q_proj"]["kernel"].astype(dt)).reshape(
             S, Q, H, cfg.qk_head_dim),

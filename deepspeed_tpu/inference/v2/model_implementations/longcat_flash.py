@@ -6,8 +6,9 @@ leaf with TWO planes a layer, beside a counter group.
 
 ``cache["kv"]`` is ``(pages,)``: ``[2 * layers, NB+1, 1, bs, W]``; plane ``2 l
 + j`` keeps sub-block ``j`` of layer ``l``'s latent rows, written and read as
-Kanana-2's are (``kanana2.absorbed_mla``: the absorbed read through
-``paged_mla``, shared with that family, so that family's cell guards it).
+Kanana-2's are (``kanana2.latent_mla``: the read through ``paged_mla``,
+absorbed or up-projected in the walk by the chunk's length, shared with that
+family, so that family's cell guards it).
 ``cache["counters"]`` is the int32 accumulator of
 ``models.longcat_flash.COUNTER_FIELDS``: a dispatch adds its expert layers'
 ``moe_layer.COUNTS`` (what only the device knows: the rows that took a zero
@@ -39,7 +40,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.model_implementations import moe_layer
-from deepspeed_tpu.inference.v2.model_implementations.kanana2 import absorbed_mla
+from deepspeed_tpu.inference.v2.model_implementations.kanana2 import (
+    latent_mla, latent_read_report)
 from deepspeed_tpu.inference.v2.model_implementations.llama import _rmsnorm
 from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
     _pool_block_size, last_token, layer_rows, layer_trash, merge_layers,
@@ -50,13 +52,15 @@ from deepspeed_tpu.models.longcat_flash import COUNTER_FIELDS
 assert COUNTER_FIELDS == moe_layer.COUNTS + ("dispatches",)
 
 
-def dispatch_report(cfg, real_tokens, chunk=None):
+def dispatch_report(cfg, real_tokens, chunk):
     """``moe_layer.dispatch_report``'s two mappings (``expert_rows`` counts the
     rows ROUTED, zero experts' among them; which were which is the counter
     group's to say); on the span also the zero experts, the router's whole
     width and the planes a latent page index spans (``latent_pages`` and the
-    engine's ``live_pages`` count page indices: x ``kv_planes`` in pages)."""
+    engine's ``live_pages`` count page indices: x ``kv_planes`` in pages);
+    added besides, the latent read's form (``kanana2.latent_read_report``)."""
     adds, rides = moe_layer.dispatch_report(cfg, real_tokens, chunk)
+    adds = dict(adds, **latent_read_report(cfg, real_tokens, chunk))
     return adds, dict(rides, zero_experts=cfg.zero_expert_num,
                       experts_routed_over=cfg.router_width,
                       kv_planes=2 * cfg.num_layers)
@@ -113,8 +117,8 @@ def _layer(cfg, lp, x, pool, tables, seen, q_len, real, rope, trash):
                 S, Q, H, cfg.qk_head_dim)
 
         h = _rmsnorm(x, lp[f"input_layernorm_{j}"]["scale"], eps)
-        return absorbed_mla(cfg, f"mla_attn_{j}", attn, project_q, h, x, pool,
-                            tables[j], seen, q_len, rope, trash[j])
+        return latent_mla(cfg, f"mla_attn_{j}", attn, project_q, h, x, pool,
+                          tables[j], seen, q_len, rope, trash[j])
 
     def dense_ffn(j, h):
         with jax.named_scope(f"dense_ffn_{j}"):

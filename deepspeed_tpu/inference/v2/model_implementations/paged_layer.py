@@ -9,9 +9,12 @@ last-token logits). Every family's forward calls these and nothing else of
 the cache.
 
 Two kinds of page. A K and V pair (``_scatter_kv``, ``_paged_attention``),
-and a page of ONE leaf, a latent row a token (``_scatter_latent``,
-``latent_attention``: ``ragged/cache_groups.py`` ``leaves=1``), which is read
-once for the scores and the values.
+and a page of ONE leaf, a latent row a token (``_scatter_latent``;
+``ragged/cache_groups.py`` ``leaves=1``), which is read once for the scores
+and the values: absorbed (``_latent_attention``) or, for a prompt chunk, with
+the trip's keys and values up-projected for one head in VMEM
+(``_latent_attention_up``); ``up_projects_in_walk`` is the one rule between
+them, by static shapes.
 
 A K and V pair may keep an indexer's key beside it (``index_dim``): learned
 sparse attention (``dsa_attention``) writes it (``_scatter_index``), scores a
@@ -379,6 +382,92 @@ def _latent_attention_dense(q, pool, block_tables, seen, block_size,
         logits = jnp.where(key_pos <= qry_pos, logits, NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(q_s.dtype)
         return jnp.einsum("hqs,sv->qhv", probs, rows[:, :value_dim])
+
+    return jax.vmap(one_seq)(q, block_tables, seen)
+
+
+def up_projects_in_walk(Q, kv_lora_rank, qk_nope_head_dim, v_head_dim):
+    """THE rule of the latent read's form, from a dispatch's static shapes
+    alone (``Q`` token slots a row and the model's widths ``r``, ``dn``,
+    ``dv``), as ``writes_pages`` is the write's: True where the walk
+    up-projects a trip's keys and values for the tile's head in VMEM
+    (``_latent_attention_up``), False where it stays absorbed
+    (``_latent_attention``).
+
+    By count, a (query, key) pair of one head costs the absorbed walk ``2 r
+    + dr`` multiply-adds (scores over the row, values over the latent) and
+    the up-projecting walk ``dn + dr + dv``, plus the trip's up-projection
+    ``r (dn + dv)`` a key shared by the ``T`` queries of the head's tile
+    (``T = query_row_tile(Q)``: at most 512): the lesser is the second where
+    ``T (2 r - dn - dv) > r (dn + dv)``, from 171 queries at the three latent
+    families' widths (512, 128, 128). A decode row and a verify round's 8
+    stay absorbed by three orders and by one.
+
+    The chip's timings that set it (one TPU v5 lite, PR 58, the kernel alone,
+    ms at a context of 2 k / 8 k / 16 k, absorbed -> up-projecting with the
+    trips ``paged_attention._up_pages`` gives it; PERF.md section 6 has the
+    table): 32 heads ``[1, 512]`` 0.62 / 2.11 / 4.11 -> 0.41 / 1.34 / 2.58,
+    ``[1, 256]`` 0.32 / 1.07 / 2.07 -> 0.27 / 0.90 / 1.74, ``[1, 128]`` 0.21 /
+    0.55 / 1.05 -> 0.30 / 0.85 / 1.54; 64 heads twice each. The chip puts the
+    crossing where the count does, between 128 and 256 tokens, and ``[1,
+    128]`` loses by half (a tile of four heads' queries would up-project the
+    trip four times)."""
+    from deepspeed_tpu.ops.pallas.paged_attention import query_row_tile
+    r, up = kv_lora_rank, qk_nope_head_dim + v_head_dim
+    return query_row_tile(Q) * (2 * r - up) > r * up
+
+
+def _latent_attention_up(q, w_uk, w_uv, pool, block_tables, seen, block_size,
+                         q_len, softmax_scale):
+    """The read that up-projects in the walk: q [S, Q, H, dn + dr] as
+    projected (position part rotated) on the ONE latent row a token of
+    ``pool`` [NB, 1, bs, W] through ``w_uk`` [r, H, dn] and ``w_uv`` [r, H,
+    dv] -> [S, Q, H, dv], the heads' values. ``paged_mla`` with ``up`` (a
+    page crosses HBM once, nothing up-projected does) when Pallas is on and
+    the shapes tile, the dense gather twin elsewhere."""
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    r, _, dn = w_uk.shape
+    # the position part against the row's columns behind the latent, the
+    # padding's among them
+    q_row = jnp.pad(q, ((0, 0),) * 3 + ((0, dn + pool.shape[-1] - r - q.shape[-1]),))
+    if takes_kernel("paged_mla",
+                    pa.mla_is_supported(q_row.shape, pool.shape, r,
+                                        up_dims=(dn, w_uv.shape[-1])),
+                    f"q heads {tuple(q.shape[2:])} over latent pages "
+                    f"{tuple(pool.shape[1:])} violate the up-projecting "
+                    f"walk's tiling (need row width, latent, nope and value "
+                    f"widths%128==0, chunk and block_size%8==0), "
+                    f"O(max_context) reads"):
+        return pa.paged_mla(q_row, pool, block_tables, seen, q_len,
+                            value_dim=r, softmax_scale=softmax_scale,
+                            up=(w_uk, w_uv), interpret=pallas_interpret())
+    return _latent_attention_up_dense(q, w_uk, w_uv, pool, block_tables, seen,
+                                      block_size, softmax_scale)
+
+
+def _latent_attention_up_dense(q, w_uk, w_uv, pool, block_tables, seen,
+                               block_size, softmax_scale):
+    """Pure-XLA twin of ``paged_mla``'s ``up`` (gathers the full table, and
+    up-projects it for every head)."""
+    Q, MB = q.shape[1], block_tables.shape[1]
+    r, _, dn = w_uk.shape
+    dr = q.shape[-1] - dn
+
+    def one_seq(q_s, bt_s, seen_s):
+        rows = pool[bt_s][:, 0].reshape(MB * block_size, -1).astype(q_s.dtype)
+        c, k_pe = rows[:, :r], rows[:, r:r + dr]
+        k = jnp.einsum("sc,chd->shd", c, w_uk.astype(q_s.dtype))
+        v = jnp.einsum("sc,chd->shd", c, w_uv.astype(q_s.dtype))
+        logits = (jnp.einsum("qhd,shd->hqs", q_s[..., :dn], k,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("qhd,sd->hqs", q_s[..., dn:], k_pe,
+                               preferred_element_type=jnp.float32)) \
+            * softmax_scale
+        key_pos = jnp.arange(MB * block_size)[None, :]
+        qry_pos = (seen_s + jnp.arange(Q))[:, None]
+        logits = jnp.where(key_pos <= qry_pos, logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q_s.dtype)
+        return jnp.einsum("hqs,shv->qhv", probs, v)
 
     return jax.vmap(one_seq)(q, block_tables, seen)
 

@@ -123,7 +123,7 @@ class KimiLinearConfig:
         kw.update(over)
         return cls(**kw)
 
-    # what ``kanana2.absorbed_mla`` and ``moe_layer.dispatch_report`` read
+    # what ``kanana2.latent_mla`` and ``moe_layer.dispatch_report`` read
     @property
     def num_experts_per_tok(self):
         return self.num_experts_per_token

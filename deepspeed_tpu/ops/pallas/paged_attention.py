@@ -48,6 +48,26 @@ tiles of the ``H * Q`` query rows (a ``[1, 512]`` chunk has 16,384 of them,
 more than VMEM holds beside their accumulator), each tile walking the row's
 live pages anew. ``W`` is any multiple of 128.
 
+The latent walk that UP-PROJECTS (``paged_mla(up=(w_uk, w_uv))``, in a trace
+``paged_mla_up``; a prompt chunk's read where ``paged_layer.
+up_projects_in_walk`` says so): a further sub-mode of the latent mode, the
+same copies, buffers, ``live_pages`` and mask. A tile is ONE head's queries
+(all of them up to 512, tiles of them past that), q comes as projected
+(``dn`` columns, then the position part in the columns the row keeps behind
+its latent), and the step holds that head's ``W_UK_h`` and ``W_UV_h`` (blocks
+of ``dn`` and ``dv`` columns of ``w_uk`` / ``w_uv`` seen ``[r, H * d]``: the
+pipeline fetches a head's pair once, 256 KB). A trip's buffer ``c [keys, r]``
+becomes ``k_h = c W_UK_h`` and ``v_h = c W_UV_h`` in VMEM, rounded to the
+pool's dtype where the published forward rounds ``kv_b_proj``'s output, the
+scores are ``q . [k_h | row's columns behind the latent]`` and the values
+``p @ v_h`` into a ``[rows, dv]`` accumulator: a trip of 512 keys against 512
+queries is 335 MFLOP where the absorbed trip is 604, nothing up-projected
+touches HBM, and a page crosses HBM as before, once a head. Its trips are
+wider than the plan's (``_up_pages``: a score tile of 2 MB, 16 pages of 64 at
+512 rows; what a trip costs beside its keys is shared by more of them), and
+a row's LAST trip takes a half-size update where no page of its second half
+is live, so that at most half a trip is work on masked keys.
+
 A selection (``select=(scores, tau)``: learned sparse attention). A query
 reads only the keys whose index score is at or above its threshold
 (``scores`` [S, Q, MB * bs] float32, ``tau`` [S, Q]; one set a query token,
@@ -91,6 +111,18 @@ _STREAM_BYTES = 8 << 20
 _SCORE_BYTES = 1 << 20
 _VMEM_LIMIT_BYTES = 48 << 20
 _MAX_ROW_TILE = 512
+# the score tile of a trip of the walk that up-projects: see ``_up_pages``
+_UP_SCORE_BYTES = 2 << 20
+
+
+def query_row_tile(rows):
+    """The query rows a tile of the walk holds, of ``rows`` that belong
+    together: all of them up to ``_MAX_ROW_TILE``, else the largest tile of
+    whole sublane tiles that divides them."""
+    if rows <= _MAX_ROW_TILE:
+        return rows
+    return max((t for t in range(8, _MAX_ROW_TILE + 1, 8) if rows % t == 0),
+               default=rows)
 
 
 def _walk_plan(rows, kv, bs, dh, q_itemsize, pool_itemsize, table_width):
@@ -100,16 +132,31 @@ def _walk_plan(rows, kv, bs, dh, q_itemsize, pool_itemsize, table_width):
     resident = rows * (4 * dh * q_itemsize + 4 * dh + 2 * 4 * LANES)
     heads = max(g for g in range(1, kv + 1)
                 if kv % g == 0 and (g == 1 or g * resident <= _RESIDENT_BYTES))
-    row_tile = rows
-    if rows > _MAX_ROW_TILE:
-        row_tile = max((t for t in range(8, _MAX_ROW_TILE + 1, 8)
-                        if rows % t == 0), default=rows)
+    row_tile = query_row_tile(rows)
     pages = min(_STREAM_BYTES // (4 * heads * bs * dh * pool_itemsize),
                 _SCORE_BYTES // (4 * row_tile * bs), table_width)
     lane_pages = max(LANES // bs, 1)          # pages a full lane tile of keys
     if pages > lane_pages:
         pages -= pages % lane_pages
     return heads, row_tile, max(pages, 1)
+
+
+def _up_pages(row_tile, bs, row_bytes, table_width):
+    """Pages a trip of the walk that up-projects: an even count (a row's last
+    trip may take half), as many as a score tile of ``_UP_SCORE_BYTES`` and
+    both halves of the ONE pool's buffer in ``_STREAM_BYTES`` hold. Wider
+    than ``_walk_plan``'s, by what the chip showed of this form (one TPU v5
+    lite, PR 58, ``[1, 512]`` at 32 heads and 8 k): 4 / 8 / 16 / 32 pages a
+    trip 2.98 / 1.88 / 1.33 / 1.25 ms, a trip of P pages ~2.15 us + 0.19 us x
+    P, so that what a trip costs beside its keys is shared by more of them
+    (the mask's form and a mask left out moved nothing, the up-projection
+    left out 0.34 ms; the ABSORBED walk gains 4 % from 16 pages and is left
+    the plan's). With the half-size last trip 16 pages read 0.52 / 1.03 /
+    1.34 / 2.58 ms at 2.3 k / 6 k / 8 k / 16 k where the absorbed walk reads
+    0.74 / 1.61 / 2.12 / 4.10."""
+    pages = min(_UP_SCORE_BYTES // (4 * row_tile * bs),
+                _STREAM_BYTES // (2 * bs * row_bytes), table_width)
+    return max(pages - pages % 2, 2)
 
 
 def _flash_update(q, k, v, ks, vs, m_ref, l_ref, acc_ref, *, key0, row0,
@@ -167,6 +214,7 @@ def _finish(l_ref, acc_ref, dtype):
 def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
                  row_tile, latent=None, select=False, **mask):
     sel_hbm = sel_buf = None
+    up = ()
     if select:
         # ``sc_ref``: the scores, a row's whole [trips, keys] in VMEM for a
         # dispatch of one token a row, else in HBM with slabs copied by hand
@@ -183,8 +231,9 @@ def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
         sources = ((k_hbm, k_buf), (v_hbm, v_buf))
     else:
         # ``latent``: the row's value columns. One row a token in ONE pool;
-        # the second grid axis is a tile of the query rows
-        (q_ref, k_hbm, o_ref, k_buf, sems, slot_ref, m_scr, l_scr,
+        # the second grid axis is a tile of the query rows. ``up``: the
+        # tile's head's ``(W_UK, W_UV)`` where the walk up-projects
+        (q_ref, *up, k_hbm, o_ref, k_buf, sems, slot_ref, m_scr, l_scr,
          acc_scr) = refs
         sources = ((k_hbm, k_buf),)
     s, hg = pl.program_id(0), pl.program_id(1)
@@ -256,12 +305,37 @@ def _walk_kernel(bt_ref, seen_ref, qlen_ref, *refs, bs, pages, heads,
 
         each_copy(s, hg, trip, slot, wait)
 
-        if latent is not None:
+        if latent is not None and not up:
             k = k_buf[slot, :, 0].reshape(keys, k_buf.shape[-1])
             _flash_update(q_ref[0, 0], k, k[:, :latent], None, None,
                           m_scr.at[0], l_scr.at[0], acc_scr.at[0],
                           key0=trip * keys, row0=hg * row_tile,
                           seen_s=seen_ref[s], **mask)
+            return 0
+
+        if up:
+            def up_project(n):
+                # the update against the trip's first ``n`` pages: their keys
+                # and values of the tile's ONE head, rounded where
+                # ``kv_b_proj``'s output is; the row's other columns (the
+                # shared position part) stay the keys' last
+                k = k_buf[slot, :n, 0].reshape(n * bs, k_buf.shape[-1])
+                k_h, v = (jnp.dot(k[:, :latent], w[...],
+                                  preferred_element_type=jnp.float32)
+                          .astype(k.dtype) for w in up)
+                _flash_update(q_ref[0, 0],
+                              jnp.concatenate([k_h, k[:, latent:]], axis=1),
+                              v, None, None,
+                              m_scr.at[0], l_scr.at[0], acc_scr.at[0],
+                              key0=trip * keys, row0=hg * row_tile,
+                              seen_s=seen_ref[s], **mask)
+
+            # a row's last trip is on average half live: it takes the
+            # half-size update where no page of its second half is
+            left = live_pages(s) - trip * pages
+            pl.when(left > pages // 2)(functools.partial(up_project, pages))
+            pl.when(left <= pages // 2)(
+                functools.partial(up_project, pages // 2))
             return 0
 
         bias = None
@@ -534,14 +608,24 @@ def _grid_call(qt, k_pool, v_pool, block_tables, seen, q_len, k_scale,
 
 
 def paged_mla(q, pool, block_tables, seen, q_len, *, value_dim,
-              softmax_scale, interpret=False):
+              softmax_scale, up=None, interpret=False):
     """Attention of every query head over ONE latent row a token.
 
-    q [S, Q, H, W]: a head's query in the row's own columns (the latent part
-    absorbed through ``W_UK``, then the rotated position part, zeros in any
-    padding); ``pool`` [NB, 1, bs, W]: the one pool of a group of one leaf.
-    Returns [S, Q, H, value_dim]: ``softmax(q . row) @ row[:value_dim]``, for
-    the caller to take through ``W_UV``."""
+    ABSORBED (``up`` None). q [S, Q, H, W]: a head's query in the row's own
+    columns (the latent part absorbed through ``W_UK``, then the rotated
+    position part, zeros in any padding); ``pool`` [NB, 1, bs, W]: the one
+    pool of a group of one leaf. Returns [S, Q, H, value_dim]: ``softmax(q .
+    row) @ row[:value_dim]``, for the caller to take through ``W_UV``.
+
+    UP-PROJECTED IN THE WALK (``up=(w_uk [value_dim, H, dn], w_uv [value_dim,
+    H, dv])``). q [S, Q, H, dn + W - value_dim]: a head's query as projected
+    (its ``dn`` columns against ``k_h``, then the position part against the
+    row's columns behind the latent, zeros in any padding). A grid step holds
+    ONE head's queries (tiles of them past ``_MAX_ROW_TILE``) and that
+    head's ``W_UK_h`` and ``W_UV_h``; a trip's page buffer becomes ``k_h =
+    c W_UK_h`` and ``v_h = c W_UV_h`` in VMEM, rounded to the pool's dtype
+    where ``kv_b_proj``'s output is, and nothing up-projected touches HBM.
+    Returns [S, Q, H, dv]: the head's values."""
     from deepspeed_tpu.ops import registry
     from deepspeed_tpu.ops.registry import sharded_kernel_call
 
@@ -550,48 +634,63 @@ def paged_mla(q, pool, block_tables, seen, q_len, *, value_dim,
     block_config = registry.resolve_block_config(
         "paged_mha", {"bs": pool.shape[2], "dh": q.shape[-1]}, q.dtype)
 
-    def call(q_, bt_, sn_, ql_, pool_):
+    def call(q_, bt_, sn_, ql_, pool_, *up_):
         return _paged_mla_local(q_, pool_, bt_, sn_, ql_,
                                 value_dim=value_dim,
                                 softmax_scale=softmax_scale,
-                                interpret=interpret)
+                                up=up_ or None, interpret=interpret)
 
     # sequences shard over the data axes; the one latent row is every
-    # head's, so the pool stays whole on each shard
+    # head's, so the pool (and a head's up-projections) stays whole on each
+    # shard
+    up = tuple(up or ())
     return sharded_kernel_call(
-        call, [q, block_tables, seen, q_len, pool],
+        call, [q, block_tables, seen, q_len, pool, *up],
         [("data", None, None, None), ("data", None), ("data",), ("data",),
-         (None, None, None, None)],
+         (None, None, None, None)] + [(None, None, None)] * len(up),
         ("data", None, None, None), name="paged_mha",
         block_config=block_config)
 
 
 def _paged_mla_local(q, pool, block_tables, seen, q_len, *, value_dim,
-                     softmax_scale, interpret=False):
+                     softmax_scale, up=None, interpret=False):
     S, Q, H, W = q.shape
-    bs = pool.shape[2]
+    bs, width = pool.shape[2:]
     rows = H * Q
     # [S, Q, H, W] -> [S, 1, H*Q, W]: a head's queries together, so that a
     # row's chunk position is ``row % Q``
     qt = q.transpose(0, 2, 1, 3).reshape(S, 1, rows, W)
-    _, row_tile, pages = _walk_plan(
-        rows, 1, bs, W, qt.dtype.itemsize, pool.dtype.itemsize,
-        block_tables.shape[1])
+    if up:
+        # a tile lies inside ONE head's queries, and brings that head's
+        # columns of ``w [r, H, d]`` seen ``[r, H * d]``
+        row_tile, out_dim = query_row_tile(Q), up[1].shape[-1]
+        pages = _up_pages(row_tile, bs, width * pool.dtype.itemsize,
+                          block_tables.shape[1])
+        up = [w.reshape(w.shape[0], -1) for w in up]
+        up_specs = [pl.BlockSpec(
+            (w.shape[0], w.shape[1] // H),
+            lambda s, r, bt, sn, ql: (0, r // (Q // row_tile)),
+            memory_space=pltpu.VMEM) for w in up]
+    else:
+        _, row_tile, pages = _walk_plan(
+            rows, 1, bs, width, qt.dtype.itemsize, pool.dtype.itemsize,
+            block_tables.shape[1])
+        out_dim, up, up_specs = value_dim, [], []
     tile = lambda width: pl.BlockSpec(
         (1, 1, row_tile, width), lambda s, r, bt, sn, ql: (s, 0, r, 0),
         memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, rows // row_tile),
-        in_specs=[tile(W), pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=tile(value_dim),
+        in_specs=[tile(W), *up_specs, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile(out_dim),
         scratch_shapes=[
-            pltpu.VMEM((2, pages, 1, bs, W), pool.dtype),
+            pltpu.VMEM((2, pages, 1, bs, width), pool.dtype),
             pltpu.SemaphoreType.DMA((2, 1)),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((1, row_tile, LANES), jnp.float32),
             pltpu.VMEM((1, row_tile, LANES), jnp.float32),
-            pltpu.VMEM((1, row_tile, value_dim), jnp.float32),
+            pltpu.VMEM((1, row_tile, out_dim), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -602,23 +701,31 @@ def _paged_mla_local(q, pool, block_tables, seen, q_len, *, value_dim,
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((S, 1, rows, value_dim), qt.dtype),
+            out_shape=jax.ShapeDtypeStruct((S, 1, rows, out_dim), qt.dtype),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_VMEM_LIMIT_BYTES),
-            name="paged_mla",
+            name="paged_mla_up" if up else "paged_mla",
             interpret=interpret,
         )(block_tables.astype(jnp.int32), seen.astype(jnp.int32),
-          q_len.astype(jnp.int32), qt, pool)
-    return out.reshape(S, H, Q, value_dim).transpose(0, 2, 1, 3)
+          q_len.astype(jnp.int32), qt, *up, pool)
+    return out.reshape(S, H, Q, out_dim).transpose(0, 2, 1, 3)
 
 
-def mla_is_supported(q_shape, pool_shape, value_dim):
+def mla_is_supported(q_shape, pool_shape, value_dim, up_dims=None):
     """The latent walk copies pages by hand: the pool's rows fill lane
     tiles, the values are a lane-aligned run of their first columns, and
-    more than ``_MAX_ROW_TILE`` query rows divide into tiles of 8s."""
+    more than ``_MAX_ROW_TILE`` query rows divide into tiles of 8s.
+    ``up_dims``: ``(dn, dv)`` of the walk that up-projects, whose q is ``dn``
+    columns and the row's columns behind the latent: both whole lane tiles,
+    and a head's queries whole sublane tiles."""
     S, Q, H, W = q_shape
     rows = H * Q
     NB, heads, bs, width = pool_shape
+    if up_dims is not None:
+        dn, dv = up_dims
+        return (W == dn + width - value_dim and Q % 8 == 0
+                and dn % LANES == 0 and dv % LANES == 0
+                and mla_is_supported((S, Q, 1, width), pool_shape, value_dim))
     return (W == width and width % LANES == 0 and heads == 1
             and value_dim % LANES == 0 and value_dim <= width
             and bs % 8 == 0

@@ -156,6 +156,35 @@ def test_paged_mla_kanana2_geometry(for_tpu, one_chip, seqs, q_tokens, heads, ta
     assert "paged_mla" in compiled.as_text()
 
 
+@pytest.mark.parametrize("q_tokens,heads,table", [
+    (512, 32, 320), (256, 32, 320), (512, 64, 144), (256, 64, 144), (1024, 32, 320)],
+    ids=["chunk512", "chunk256", "longcat-chunk512", "longcat-chunk256", "chunk1024"])
+def test_paged_mla_up_projecting_walk_kanana2_geometry(for_tpu, one_chip, q_tokens, heads,
+                                                       table):
+    """The latent walk that up-projects a trip's keys and values for the
+    tile's head in VMEM, at the chunks the rule gives it: the Kanana-2 cell's
+    ``[1, 512]`` and ``[1, 256]`` (32 heads of 128 + 64 query columns against
+    the 640-column row, ``W_UK_h`` and ``W_UV_h`` [512, 128] a grid step, one
+    head's queries a step) and the LongCat-Flash cell's (64 heads); a chunk
+    of 1,024 in two tiles a head."""
+    from deepspeed_tpu.inference.v2.model_implementations import paged_layer
+    from deepspeed_tpu.ops.pallas import paged_attention as pa
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = sds((2049, 1, PAGE, 640), jnp.bfloat16)
+    q = sds((1, q_tokens, heads, 128 + 64), jnp.bfloat16)
+    w = sds((512, heads, 128), jnp.bfloat16)
+    assert paged_layer.up_projects_in_walk(q_tokens, 512, 128, 128)
+    assert pa.mla_is_supported((1, q_tokens, heads, 256), pool.shape, 512, up_dims=(128, 128))
+
+    def fn(q, w_uk, w_uv, bt, seen, q_len, pool):
+        return paged_layer._latent_attention_up(q, w_uk, w_uv, pool, bt, seen, PAGE, q_len,
+                                                192 ** -0.5)
+
+    compiled = _compile(fn, (q, w, w, sds((1, table), jnp.int32), sds((1,), jnp.int32),
+                             sds((1,), jnp.int32), pool))
+    assert _latent_walks(compiled.as_text()) == (0, 1)
+
+
 def test_paged_mla_refuses_a_row_that_does_not_fill_its_lane_tiles(for_tpu, one_chip):
     """A 576-wide row (the latent's 512 + 64 as they are) is not copied by
     hand: HBM's (8, 128) tiles hold it in 640 lanes and Mosaic refuses the
@@ -371,12 +400,13 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
     benchmark's serving cells dispatch it: every layer at the published
     widths, the paged kernel (and phi4flash's scan; for mellum2 the paged
     kernel and the three grouped GEMMs in each of 12 layers, over 512 expert
-    rows of which a padded row takes none; for kanana2 the latent walk in 12
-    layers and the grouped GEMMs over the 16 experts held in 11; for
-    longcat-flash the latent walk twice and the grouped GEMMs once in each of
-    4 double layers, the zero experts' rows past every group; for kimi-linear
-    the one-step KDA kernel in 12 layers, the latent walk in 4 and the
-    grouped GEMMs in 15) at one token
+    rows of which a padded row takes none; for kanana2 the ABSORBED latent
+    walk in 12 layers and the grouped GEMMs over the 16 experts held in 11;
+    for longcat-flash the absorbed latent walk twice and the grouped GEMMs
+    once in each of 4 double layers, the zero experts' rows past every group;
+    for kimi-linear the one-step KDA kernel in 12 layers, the absorbed latent
+    walk in 4 and the grouped GEMMs in 15; the walk that up-projects is a
+    prompt chunk's of 256 tokens or more, below) at one token
     a row, read from the host's buffer or, by the row's source, from the ids
     the round before left on the device (the program's last array, one
     place a row of the engine's 64)."""
@@ -384,7 +414,10 @@ def test_a_cells_decode_round_program_lowers(for_tpu, one_chip, monkeypatch,
     assert dict(layout)["tokens"] == (bucket, 1)
     assert dict(layout)["src"] == (bucket,)
     assert lowered.in_avals[0][-1].shape == (64,)
-    assert lowered.compile().as_text().count("tpu_custom_call") >= kernels
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= kernels
+    # a decode round's latent walks are the absorbed ones, every one
+    assert _latent_walks(text)[1] == 0
 
 
 @pytest.mark.parametrize("name,rows,tokens,shape", [
@@ -422,7 +455,8 @@ def test_kimi_linears_programs_update_slots_and_pages_where_they_lie(
         for_tpu, one_chip, monkeypatch, rows, tokens, shape, kernels):
     """Kimi-Linear's WHOLE programs at the published widths, compiled for the
     described chip: 12 KDA kernels (``kda_step`` at one token a row,
-    ``kda_chunk`` for a chunk), 4 latent walks, 15 x 3 grouped GEMMs. The
+    ``kda_chunk`` for a chunk), 4 latent walks (absorbed at one token a row,
+    up-projecting in the walk for the chunk of 512), 15 x 3 grouped GEMMs. The
     1.6 GB of matrix states, the convolution tails and the latent pages come
     back in the buffers they came in (donated, aliased through the kernels'
     own ``input_output_aliases``), and the program's scratch is smaller than
@@ -442,9 +476,128 @@ def test_kimi_linears_programs_update_slots_and_pages_where_they_lie(
     assert memory.temp_size_in_bytes < state.size * 4 // 4, memory.temp_size_in_bytes
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= kernels
+    assert _latent_walks(text) == ((4, 0) if tokens == 1 else (0, 4))
     merged = ",".join(map(str, (12 * 65, 32, 128, 128)))
     assert f"f32[{merged}]" in text
     assert not re.search(rf"= f32\[{merged}\]\S* (copy|transpose)\(", text)
+
+
+def _latent_walks(text):
+    """(absorbed, up-projecting) latent walks among a compiled program's
+    kernels, by the ``pallas_call``'s name."""
+    names = re.findall(r"%(paged_mla\w*?)(?:\.\d+)? = \S+ custom-call\(", text)
+    return names.count("paged_mla"), names.count("paged_mla_up")
+
+
+def _equations(closed):
+    """Every equation of a traced function, through every inner jaxpr (a
+    kernel's body among them): its primitive, its name stack (the device
+    scopes the metrics read) and its output types, in order. What a program's
+    text is made from, without the source locations the text carries."""
+    out = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            out.append((eqn.primitive.name, str(eqn.source_info.name_stack),
+                        tuple(str(v.aval) for v in eqn.outvars)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(closed.jaxpr)
+    return out
+
+
+@pytest.mark.parametrize("name,tokens,shape,walks", [
+    ("kanana2-l12-ep8", 449, (1, 512), (0, 12)), ("kanana2-l12-ep8", 200, (1, 256), (0, 12)),
+    ("kanana2-l12-ep8", 100, (1, 128), (12, 0)), ("longcat-flash-l4-ep32", 449, (1, 512), (0, 8))],
+    ids=["kanana2-512", "kanana2-256", "kanana2-128", "longcat-flash-512"])
+def test_a_latent_cells_chunk_program_takes_the_form_the_rule_picks(
+        for_tpu, one_chip, monkeypatch, name, tokens, shape, walks):
+    """The WHOLE program of a prompt chunk in the two latent cells, compiled
+    for the described chip: every latent walk of a ``[1, 512]`` and a ``[1,
+    256]`` dispatch up-projects in the walk (one a layer in Kanana-2's 12, two
+    a double layer in LongCat-Flash's 4) and none is absorbed; a ``[1, 128]``
+    dispatch's are all absorbed."""
+    layout, lowered = _cell_program(name, 1, one_chip, monkeypatch, tokens)
+    assert dict(layout)["tokens"] == shape
+    assert _latent_walks(lowered.compile().as_text()) == walks
+
+
+@pytest.mark.parametrize("seqs,q_tokens,rope", [(64, 1, True), (1, 128, True), (64, 1, False)],
+                         ids=["decode64", "chunk128", "decode64-nope"])
+def test_the_absorbed_read_is_the_parents_program(for_tpu, one_chip, seqs, q_tokens, rope):
+    """Where the rule keeps the absorbed walk, ``kanana2.latent_mla`` traces
+    equation for equation, the kernel's body included, what the function it
+    replaced traces (PR 57's ``absorbed_mla``, kept here word for word), so
+    that the program's text differs by source locations alone: nothing of a
+    decode round's or a short chunk's attention changed with the second form.
+    (Against the parent's own checkout the WHOLE ``[64, 1]`` programs of the
+    three latent cells, Kanana-2's ``[1, 128]`` and Mistral's and Mellum2's
+    ``[64, 1]`` and chunk programs were compared once, compiled for the
+    described chip, kernels' bodies decoded: equal. CHANGES.md, PR 58.)"""
+    from deepspeed_tpu.inference.v2.model_implementations import kanana2
+    from deepspeed_tpu.inference.v2.model_implementations.llama import _rmsnorm
+    from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (
+        _latent_attention, _scatter_latent)
+    from deepspeed_tpu.models.kanana2 import Kanana2Config
+    from deepspeed_tpu.models.llama import rotary_apply
+    cfg = Kanana2Config(dtype=jnp.bfloat16)
+    H, r, d = cfg.num_attention_heads, cfg.kv_lora_rank, cfg.hidden_size
+
+    def absorbed_mla(cfg, scope, attn, project_q, h, x, pool, tables, seen, q_len,
+                     rope, trash):
+        S, Q, _ = x.shape
+        H, r = cfg.num_attention_heads, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        W, bs = pool.shape[-1], pool.shape[2]
+        eps, dt = cfg.rms_norm_eps, cfg.dtype
+        w_uk, w_uv = attn["w_uk"].astype(dt), attn["w_uv"].astype(dt)
+        rotate = (lambda t: t) if rope is None else (lambda t: rotary_apply(t, *rope))
+        with jax.named_scope(scope):
+            with jax.named_scope("mla_q"):
+                q = project_q(h)
+                q_lat = jnp.einsum("sqhd,chd->sqhc", q[..., :dn], w_uk)
+                q_row = jnp.concatenate(
+                    [q_lat, rotate(q[..., dn:]),
+                     jnp.zeros((S, Q, H, W - r - dr), dt)], -1)
+            with jax.named_scope("mla_latent_write"):
+                ckv = h @ attn["kv_a_proj"]["kernel"].astype(dt)
+                c = _rmsnorm(ckv[..., :r], attn["kv_a_layernorm"]["scale"], eps)
+                k_pe = rotate(ckv[..., None, r:])[..., 0, :]
+                row = jnp.concatenate(
+                    [c, k_pe, jnp.zeros((S, Q, W - r - dr), dt)], -1)
+                pool = _scatter_latent(pool, row, tables, seen, q_len, bs, trash)
+            with jax.named_scope("mla_read"):
+                o_lat = _latent_attention(q_row, pool, tables, seen, bs, q_len,
+                                          r, cfg.softmax_scale)
+            with jax.named_scope("mla_out"):
+                o = jnp.einsum("sqhc,chd->sqhd", o_lat, w_uv)
+                x = x + o.reshape(S, Q, H * dv) @ attn["o_proj"]["kernel"].astype(dt)
+        return x, pool
+
+    def program(form):
+        def fn(attn, h, x, pool, tables, seen, q_len, cos, sin):
+            project_q = lambda h: (h @ attn["q_proj"]["kernel"]).reshape(
+                seqs, q_tokens, H, cfg.qk_head_dim)
+            return form(cfg, "mla_attn", attn, project_q, h, x, pool, tables, seen, q_len,
+                        (cos, sin) if rope else None, 2048)
+        return fn
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    attn = {"q_proj": {"kernel": sds((d, H * cfg.qk_head_dim))},
+            "kv_a_proj": {"kernel": sds((d, r + cfg.qk_rope_head_dim))},
+            "kv_a_layernorm": {"scale": sds((r,), jnp.float32)},
+            "w_uk": sds((r, H, cfg.qk_nope_head_dim)), "w_uv": sds((r, H, cfg.v_head_dim)),
+            "o_proj": {"kernel": sds((H * cfg.v_head_dim, d))}}
+    x = sds((seqs, q_tokens, d))
+    table = sds((seqs, q_tokens, 1, cfg.qk_rope_head_dim // 2), jnp.float32)
+    args = (attn, x, x, sds((2049, 1, PAGE, 640)), sds((seqs, 320), jnp.int32),
+            sds((seqs,), jnp.int32), sds((seqs,), jnp.int32), table, table)
+    assert not kanana2.up_projects(cfg, q_tokens)
+    now, then = (_equations(jax.make_jaxpr(program(form))(*args))
+                 for form in (kanana2.latent_mla, absorbed_mla))
+    assert sum(name == "pallas_call" for name, _, _ in now) == 1 and now == then
+    assert "paged_mla" in _compile(program(kanana2.latent_mla), args).as_text()
 
 
 _MOVES = {"parameter", "constant", "dynamic-slice", "slice", "bitcast", "reshape",

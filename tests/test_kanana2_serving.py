@@ -24,7 +24,7 @@ from deepspeed_tpu.inference.v2.engine_factory import (
     build_engine, resolve_cache_groups, resolve_forward_fn, resolve_verify_fn)
 from deepspeed_tpu.inference.v2 import engine_v2
 from deepspeed_tpu.inference.v2.engine_v2 import InferenceEngineV2
-from deepspeed_tpu.inference.v2.model_implementations import moe_layer
+from deepspeed_tpu.inference.v2.model_implementations import kanana2, moe_layer
 from deepspeed_tpu.inference.v2.scheduler import SplitFuseScheduler
 from deepspeed_tpu.models import kanana2 as model_file
 from deepspeed_tpu.models.kanana2 import Kanana2Config, Kanana2ForCausalLM
@@ -197,6 +197,71 @@ def test_chunks_and_decode_rows_through_the_pallas_walk_agree_too(served, monkey
         telemetry.configure(enabled=False)
         telemetry.reset()
     # the walk's own dispatch records it under the paged kernel's name
+    assert ("paged_mha", "tuning") in taken and ("paged_mla", "fallback") not in taken, taken
+
+
+def _long_prompt(served, n=160):
+    cfg, _, params, ref_cfg = served[:4]
+    ids = np.random.default_rng(7).integers(0, cfg.vocab_size, n).astype(np.int32)
+    return ids, np.asarray(reference.full_logits(ref_cfg, params, jnp.asarray(ids)))
+
+
+LONG = {**ENGINE, "state_manager": dict(ENGINE["state_manager"], max_ragged_batch_size=64,
+                                        max_context=256)}
+
+
+def test_a_chunk_past_the_rules_crossing_up_projects_in_the_walk_and_agrees_too(served, tmp_path):
+    """At the tiny widths (latent 128, heads of 32 + 32) the up-projecting
+    read is the lesser from 43 queries a head: a prompt fed in chunks of 64
+    takes it (``_latent_attention_up``'s dense twin here), the 22 tokens left
+    and the decode rows stay absorbed over the pages both forms wrote, and
+    the logits agree with the reference within the tolerance the absorbed
+    form alone is held to. Each ``serving/build`` span says which form its
+    dispatch's tokens took."""
+    cfg = served[0]
+    assert [kanana2.up_projects(cfg, Q) for Q in (1, 16, 32, 64, 128)] == \
+        [False, False, False, True, True]
+    ids, want = _long_prompt(served)
+    engine = build_engine(served[1], served[2], LONG)
+    got = {}
+    spans = _captured(tmp_path, lambda: got.update(
+        _feed(engine, 0, ids, (64, 64, 22) + (1,) * 3)))
+    assert _worst(got, want) < TOLERANCE
+    builds = [a for name, _, a in spans if name == "serving/build"]
+    assert [(int(a["chunk_bucket"]), int(a["latent_up_tokens"]),
+             int(a["latent_absorbed_tokens"])) for a in builds] == \
+        [(64, 64, 0), (64, 64, 0), (32, 0, 22), (1, 0, 1), (1, 0, 1), (1, 0, 1)]
+    assert all(int(a["latent_up_tokens"]) + int(a["latent_absorbed_tokens"])
+               == int(a["real_tokens"]) for a in builds)
+
+
+def test_the_up_projecting_walk_itself_serves_a_chunk_in_interpret_mode(monkeypatch):
+    """Heads of 128 + 128 columns on a latent of 512 (the published widths,
+    a narrow stream and one expert layer): a chunk of 256 goes through
+    ``paged_mla``'s ``up`` itself, interpreted, no dense twin is taken, and
+    the logits agree with the reference."""
+    from deepspeed_tpu import telemetry
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.delenv("DS_TPU_DISABLE_PALLAS", raising=False)
+    cfg = Kanana2Config.tiny(num_hidden_layers=2, num_attention_heads=2, kv_lora_rank=512,
+                             qk_nope_head_dim=128, v_head_dim=128, max_position_embeddings=514)
+    model = Kanana2ForCausalLM(cfg)
+    params = model.init_params(jax.random.PRNGKey(1))
+    ids = np.random.default_rng(11).integers(0, cfg.vocab_size, 300).astype(np.int32)
+    want = np.asarray(reference.full_logits(reference_config(cfg), params, jnp.asarray(ids)))
+    assert kanana2.up_projects(cfg, 256) and not kanana2.up_projects(cfg, 128)
+    telemetry.reset()
+    telemetry.configure(enabled=True)
+    try:
+        engine = build_engine(model, params, {
+            "state_manager": {"max_ragged_sequence_count": 4, "max_ragged_batch_size": 256,
+                              "max_context": 512, "num_kv_blocks": 64},
+            "kv_cache": {"block_size": 8, "cache_dtype": "fp32"}})
+        assert _worst(_feed(engine, 0, ids, (256, 40, 1, 1)), want) < TOLERANCE
+        taken = {k[:2] for k in telemetry.get_telemetry().dispatch_stats}
+    finally:
+        telemetry.configure(enabled=False)
+        telemetry.reset()
     assert ("paged_mha", "tuning") in taken and ("paged_mla", "fallback") not in taken, taken
 
 

@@ -218,6 +218,28 @@ def test_chunks_longer_than_the_chunk_forms_chunk_carry_the_state_inside(served)
     assert _worst(_feed(engine, 0, ids, (100, 5, 1, 1, 40, 1, 1)), want) < TOLERANCE
 
 
+def test_a_chunk_past_the_rules_crossing_up_projects_in_the_walk_and_agrees_too(served):
+    """The MLA layer's read without positions in its second form: at the tiny
+    widths the up-projecting read is the lesser from 43 queries a head
+    (``kanana2.up_projects``), so chunks of 64 take it (``rope=None``: the
+    position columns go in as projected), the tokens left and the decode rows
+    stay absorbed over the same plane, and the logits agree within the
+    tolerance; the dispatch's counts say which form it took."""
+    cfg, model, params, ref_cfg = served[:4]
+    ids = np.random.default_rng(5).integers(0, cfg.vocab_size, 160).astype(np.int32)
+    want = np.asarray(reference.full_logits(ref_cfg, params, jnp.asarray(ids)))
+    engine = build_engine(model, params, {**ENGINE, "state_manager": dict(
+        ENGINE["state_manager"], max_ragged_batch_size=64, max_context=256)})
+    pos, got, forms = 0, {}, []
+    for n in (64, 64, 22, 1, 1):
+        got[pos + n - 1] = engine.put([0], [ids[pos:pos + n]])[0]
+        forms.append((engine.last_counts["latent_up_tokens"],
+                      engine.last_counts["latent_absorbed_tokens"]))
+        pos += n
+    assert _worst(got, want) < TOLERANCE
+    assert forms == [(64, 0), (64, 0), (0, 22), (0, 1), (0, 1)]
+
+
 @pytest.mark.parametrize("rectangle", [False, True], ids=["by-class", "one-rectangle"])
 def test_rows_of_different_lengths_with_padding_advance_each_row_by_its_own(
         served, monkeypatch, rectangle):
@@ -646,8 +668,8 @@ def test_a_dropped_state_or_dropped_tails_fail_the_tolerance(served):
         assert _worst(got, want[0]) > 10 * TOLERANCE, leaf
 
 
-def test_absorbed_mla_without_positions_builds_no_table(served, monkeypatch):
-    """``rope=None``: neither the forward nor ``absorbed_mla`` touches the
+def test_latent_mla_without_positions_builds_no_table(served, monkeypatch):
+    """``rope=None``: neither the forward nor ``latent_mla`` touches the
     rotary tables or ``rotary_apply``."""
     from deepspeed_tpu.inference.v2.model_implementations import kanana2
     from deepspeed_tpu.models import llama
@@ -709,6 +731,8 @@ def test_spans_carry_the_kda_counts_and_the_device_counts_the_experts(served, tm
         assert a["kda_layers"] == 3 and 1 <= a["state_slots"] <= 2
         assert a["expert_rows"] == a["real_tokens"] * 3 * 3 and a["expert_rows_padded"] == 0
         assert a["latent_pages"] > 0 and a["latent_row_bytes"] == 256 * 4
+        # the MLA layers' read: every dispatch here is under the rule's crossing
+        assert (a["latent_up_tokens"], a["latent_absorbed_tokens"]) == (0, a["real_tokens"])
     assert any(a["kda_step_rows"] for a in builds) and any(a["kda_chunk_tokens"] for a in builds)
     moved = lambda key: getattr(sched, key) - before[key]
     assert sum(a["kda_step_rows"] for a in builds) == moved("kda_step_rows")

@@ -222,6 +222,27 @@ def test_chunked_prefill_then_decode_agrees_with_the_full_forward(served, chunks
     assert _worst(_feed(engine, 0, ids[0], chunks), want[0]) < TOLERANCE
 
 
+def test_a_chunk_past_the_rules_crossing_up_projects_in_the_walk_and_agrees_too(served):
+    """At the tiny widths the up-projecting read is the lesser from 43
+    queries a head (``kanana2.up_projects``): a prompt fed in chunks of 64
+    takes it in BOTH attentions of every double layer, the tokens left and
+    the decode rows stay absorbed over the same planes, and the logits agree
+    within the tolerance; the dispatch's counts say which form it took."""
+    cfg, model, params, ref_cfg = served[:4]
+    ids = np.random.default_rng(7).integers(0, cfg.vocab_size, 160).astype(np.int32)
+    want = np.asarray(reference.full_logits(ref_cfg, params, jnp.asarray(ids)))
+    engine = build_engine(model, params, {**ENGINE, "state_manager": dict(
+        ENGINE["state_manager"], max_ragged_batch_size=64, max_context=256)})
+    pos, got, forms = 0, {}, []
+    for n in (64, 64, 22, 1, 1):
+        got[pos + n - 1] = engine.put([0], [ids[pos:pos + n]])[0]
+        forms.append((engine.last_counts["latent_up_tokens"],
+                      engine.last_counts["latent_absorbed_tokens"]))
+        pos += n
+    assert _worst(got, want) < TOLERANCE
+    assert forms == [(64, 0), (64, 0), (0, 22), (0, 1), (0, 1)]
+
+
 def test_a_share_of_the_experts_through_the_engine_agrees_with_the_references_share(served):
     cfg, _, params, _, ids, _ = served
     held = dataclasses.replace(cfg, experts_held=(8, 4))
@@ -371,8 +392,9 @@ def test_the_device_counters_agree_with_a_numpy_count_of_the_same_routing(served
         pos += q
     assert got["experts_hit"] == hit
     # what the host's report says of the same dispatches: the rows routed, no more
-    adds, rides = resolve_report_fn(LongcatFlashForCausalLM(held))(held, n)
-    assert adds == {"expert_rows": got["routed_rows"], "expert_rows_padded": 0}
+    adds, rides = resolve_report_fn(LongcatFlashForCausalLM(held))(held, n, 16)
+    assert adds == {"expert_rows": got["routed_rows"], "expert_rows_padded": 0,
+                    "latent_up_tokens": 0, "latent_absorbed_tokens": n}
     assert rides == {"experts_held": 8, "experts_routed_over": 24, "zero_experts": 8,
                      "kv_planes": 4}
 
@@ -566,7 +588,7 @@ def _moe_case(seed=0):
 
 
 #: taken at the parent commit (a7dc6ca, jax 0.9.0) by the same functions: a
-#: dispatch's whole program of the two families that share ``absorbed_mla`` and
+#: dispatch's whole program of the two families that share ``latent_mla`` and
 #: ``moe_ffn``, and ``moe_ffn`` alone with the new arguments absent. The three
 #: whole programs were taken again at PR 53, which changed the one thing of
 #: them every family shares, the cache write (``paged_layer._write`` under the

@@ -96,8 +96,12 @@ def test_oom_postmortem_lists_top_live_buffers():
     """The RESOURCE_EXHAUSTED post-mortem names the buffers actually holding
     HBM — shape/dtype/nbytes/sharding, largest first — and lands on the
     Fault/* stream."""
+    import gc
+    # what an earlier test of this worker's left to the collector (an engine's
+    # tiny weights in a cycle) is not this test's: it ranks what is live now
+    gc.collect()
     telemetry.configure(enabled=True)
-    big = jnp.ones((512, 512), jnp.float32)   # 1MB — should rank first
+    big = jnp.ones((2048, 2048), jnp.float32)   # 16 MB: should rank first
     small = jnp.ones((8,), jnp.float32)
     jax.block_until_ready((big, small))
     report = telemetry.maybe_oom_postmortem(
@@ -105,7 +109,7 @@ def test_oom_postmortem_lists_top_live_buffers():
     assert report is not None
     top = report["top_buffers"]
     assert top and top[0]["nbytes"] >= big.nbytes
-    assert top[0]["shape"] == [512, 512] and "float32" in top[0]["dtype"]
+    assert top[0]["shape"] == [2048, 2048] and "float32" in top[0]["dtype"]
     assert "sharding" in top[0]
     assert report["live_bytes_total"] >= big.nbytes
     s = telemetry.summary()

@@ -347,6 +347,150 @@ def test_mla_is_supported():
 
 
 # ---------------------------------------------------------------------------
+# ``paged_mla`` with ``up``: the latent walk that up-projects a trip's keys
+# and values for the tile's ONE head in VMEM (a prompt chunk's read), against
+# its dense twin AND against the absorbed walk taken through ``W_UV``; and
+# the rule by static shapes that picks between the two forms.
+# ---------------------------------------------------------------------------
+
+from deepspeed_tpu.inference.v2.model_implementations.paged_layer import (  # noqa: E402
+    _latent_attention_up, _latent_attention_up_dense, up_projects_in_walk)
+from deepspeed_tpu.ops.pallas.paged_attention import query_row_tile  # noqa: E402
+
+#: a row of 128 latent columns + 16 position columns in 256; heads of 128 + 16
+UP = dict(r=128, dn=128, dr=16, dv=128, W=256, bs=16, scale=0.05)
+
+
+def make_up_case(Q, H, rotated, seed=0, trips=True):
+    """Four rows of one dispatch (``S > 1``) over a table wider than a trip
+    of ``P`` pages: one whose context ends inside a page in its second trip's
+    FIRST half (that trip takes the half-size update), one that starts its
+    sequence (``seen`` 0) with fewer tokens than the bucket, a padded row
+    (``q_len`` 0, the trash page) and one that ends in its second trip's
+    second half. Slots past a row's live pages point at a page of NaN.
+    ``trips`` False: short rows, one trip each."""
+    from deepspeed_tpu.models.llama import rope_frequencies, rotary_apply, rotary_tables
+    from deepspeed_tpu.ops.pallas.paged_attention import _up_pages
+    r, dn, dr, dv, W, bs = (UP[k] for k in ("r", "dn", "dr", "dv", "W", "bs"))
+    P = _up_pages(query_row_tile(Q), bs, 4 * W, 10 ** 6) if trips else 0
+    k = Q // (P * bs) + 1 if trips else 0           # whole trips under the chunk's end
+    ends = [(k * P + P // 2 - 1) * bs - 11, Q - 7, 0, (k * P + P // 2 + 2) * bs] if trips else \
+        [Q + 2 * bs + 5, Q - 7, 0, Q + 3 * bs]
+    q_len = np.asarray([Q, Q - 7, 0, Q], np.int32)
+    seen = np.asarray(ends, np.int32) - q_len
+    live = [-(-int(e) // bs) for e in ends]
+    S, MB, NB = len(live), max(live) + 3, sum(live) + 2
+    poison, trash = NB - 2, NB - 1
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    pool = jax.random.normal(ks[0], (NB, 1, bs, W), jnp.float32).at[..., r + dr:].set(0)
+    q = jax.random.normal(ks[1], (S, Q, H, dn + dr), jnp.float32)
+    w_uk = jax.random.normal(ks[2], (r, H, dn), jnp.float32) * r ** -0.5
+    w_uv = jax.random.normal(ks[3], (r, H, dv), jnp.float32) * r ** -0.5
+    pages = np.random.default_rng(seed).permutation(NB - 2)
+    bt = np.full((S, MB), poison, np.int32)
+    for i, n in enumerate(live):
+        bt[i, :max(n, 1)], pages = (pages[:n], pages[n:]) if n else (trash, pages)
+    if rotated:
+        # what the model does to the position part of q and of a written row;
+        # Kimi-Linear (``rope=None``) leaves both as projected
+        freqs = rope_frequencies(dr, 10000.0)
+        at = lambda pos: rotary_tables(jnp.asarray(pos), *freqs)
+        q = q.at[..., dn:].set(rotary_apply(
+            q[..., dn:], *at(seen[:, None] + np.arange(Q)[None, :])))
+        k_pe = pool[:, 0, :, None, r:r + dr]                       # [NB, bs, 1, dr]
+        pool = pool.at[:, 0, :, r:r + dr].set(rotary_apply(
+            k_pe, *at(np.arange(NB * bs).reshape(NB, bs) % 977))[:, :, 0])
+    return (q, w_uk, w_uv, pool, jnp.asarray(bt), jnp.asarray(seen), jnp.asarray(q_len)), poison
+
+
+@pytest.mark.parametrize("Q,H,rotated", [
+    (256, 32, True), (256, 64, False), (512, 32, False), (512, 64, True),
+    # past ``_MAX_ROW_TILE`` a head's queries are TILED inside the head (each
+    # tile up-projects the trip anew); the rule sees the tile
+    (1024, 4, True)])
+def test_mla_up_projecting_walk_agrees_with_its_twin_and_the_absorbed_walk(Q, H, rotated,
+                                                                           monkeypatch):
+    # a score tile an eighth of the chip's, so that contexts an eighth as long
+    # walk as many trips (and a half-size last one)
+    from deepspeed_tpu.ops.pallas import paged_attention
+    monkeypatch.setattr(paged_attention, "_UP_SCORE_BYTES", 1 << 18)
+    case, poison = make_up_case(Q, H, rotated, seed=Q + H)
+    assert case[4].shape[1] > 2 * paged_attention._up_pages(query_row_tile(Q), UP["bs"], 4 * UP["W"],
+                                                            10 ** 6)
+    q, w_uk, w_uv, pool, bt, seen, q_len = case
+    r, dn, bs, scale = UP["r"], UP["dn"], UP["bs"], UP["scale"]
+    assert query_row_tile(Q) == min(Q, 512) and up_projects_in_walk(Q, 512, 128, 128)
+    nan, zero = pool.at[poison].set(jnp.nan), pool.at[poison].set(0)
+    q_row = jnp.pad(q, ((0, 0),) * 3 + ((0, dn + UP["W"] - r - q.shape[-1]),))
+    assert mla_is_supported(q_row.shape, pool.shape, r, up_dims=(dn, UP["dv"]))
+    out_k = paged_mla(q_row, nan, bt, seen, q_len, value_dim=r, softmax_scale=scale,
+                      up=(w_uk, w_uv), interpret=True)
+    assert out_k.shape == (4, Q, H, UP["dv"])
+    assert np.isfinite(np.asarray(out_k)).all(), \
+        "the walk read past a row's live pages, or left a padded row undefined"
+    twin = _latent_attention_up_dense(q, w_uk, w_uv, zero, bt, seen, bs, scale)
+    # the absorbed walk on the same pages, taken through W_UV
+    q_abs = jnp.concatenate([jnp.einsum("sqhd,chd->sqhc", q[..., :dn], w_uk),
+                             q_row[..., dn:]], -1)
+    absorbed = jnp.einsum("sqhc,chd->sqhd", paged_mla(
+        q_abs, nan, bt, seen, q_len, value_dim=r, softmax_scale=scale, interpret=True), w_uv)
+    got = valid_rows(out_k, q_len)
+    assert got.size == (3 * Q - 7) * H * UP["dv"]
+    for want in (twin, absorbed):
+        np.testing.assert_allclose(got, valid_rows(want, q_len), atol=2e-4, rtol=1e-3)
+
+
+def test_mla_up_read_takes_the_twin_where_the_walk_does_not_tile(monkeypatch):
+    """``_latent_attention_up`` hands the kernel q padded to the row's
+    columns behind the latent; heads of 32 + 32 columns (a tiny model's) are
+    not lane tiles, and the read is the dense twin's."""
+    monkeypatch.setenv("DS_TPU_PALLAS_INTERPRET", "1")
+    monkeypatch.delenv("DS_TPU_DISABLE_PALLAS", raising=False)
+    case, poison = make_up_case(64, 4, True, seed=3, trips=False)
+    q, w_uk, w_uv, pool, bt, seen, q_len = case
+    pool = pool.at[poison].set(0)
+    got = _latent_attention_up(q, w_uk, w_uv, pool, bt, seen, UP["bs"], q_len, UP["scale"])
+    twin = _latent_attention_up_dense(q, w_uk, w_uv, pool, bt, seen, UP["bs"], UP["scale"])
+    np.testing.assert_allclose(valid_rows(got, q_len), valid_rows(twin, q_len),
+                               atol=2e-4, rtol=1e-3)
+    narrow = (w_uk[..., :32], w_uv[..., :32])
+    q32 = jnp.concatenate([q[..., :32], q[..., UP["dn"]:]], -1)
+    assert not mla_is_supported((4, 64, 4, 32 + 128), pool.shape, 128, up_dims=(32, 32))
+    got = _latent_attention_up(q32, *narrow, pool, bt, seen, UP["bs"], q_len, UP["scale"])
+    twin = _latent_attention_up_dense(q32, *narrow, pool, bt, seen, UP["bs"], UP["scale"])
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(twin))
+
+
+#: every chunk bucket the engines warm (``[1, 16]`` .. ``[1, 512]``), a decode
+#: dispatch's 1 and a verify round's 8
+BUCKETS = (1, 8, 16, 32, 64, 128, 256, 512)
+
+
+@pytest.mark.parametrize("family,widths", [
+    ("kanana2", (512, 128, 128)), ("longcat_flash", (512, 128, 128)),
+    ("kimi_linear", (512, 128, 128))])
+def test_the_rule_of_the_latent_reads_form(family, widths):
+    """By count the up-projecting walk is the lesser from 171 queries a head
+    at the three families' widths (``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``v_head_dim`` of their published configs): the 256 and 512 buckets take
+    it, every other dispatch stays absorbed; a chunk past the row tile is
+    judged by its tile."""
+    import importlib
+    cfg_cls = {"kanana2": "Kanana2Config", "longcat_flash": "LongcatFlashConfig",
+               "kimi_linear": "KimiLinearConfig"}[family]
+    fields = getattr(importlib.import_module(f"deepspeed_tpu.models.{family}"),
+                     cfg_cls).__dataclass_fields__
+    published = tuple(fields[k].default for k in
+                      ("kv_lora_rank", "qk_nope_head_dim", "v_head_dim"))
+    assert published == widths
+    assert [Q for Q in BUCKETS if up_projects_in_walk(Q, *widths)] == [256, 512]
+    assert up_projects_in_walk(1024, *widths) and up_projects_in_walk(176, *widths)
+    assert not up_projects_in_walk(168, *widths)
+    # a model whose heads are as wide as its latent gains nothing by it
+    assert not any(up_projects_in_walk(Q, 128, 128, 128) for Q in BUCKETS)
+
+
+# ---------------------------------------------------------------------------
 # Learned sparse attention: the walk under a selection, the index scores over
 # paged keys, and the threshold that stands for the selection
 # (``ops/pallas/sparse_index.py``). Slots past a row's live count point at a

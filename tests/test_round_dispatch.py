@@ -397,29 +397,31 @@ _FURTHER_GROUPS = {"window_pages_freed", "state_slots", "global_pages", "window_
                    "window_live_pages"}
 _EXPERTS = {"expert_rows", "expert_rows_padded"}
 _A_SHARE = {"experts_held", "experts_routed_over"}
+#: which form a dispatch's tokens took through the latent read (``kanana2.latent_read_report``)
+_LATENT_READ = {"latent_up_tokens", "latent_absorbed_tokens"}
 
 
 @pytest.mark.parametrize("family,beyond,summed", [
     ("llama", set(), set()),
     ("phi4flash", _FURTHER_GROUPS, {"window_pages_freed", "state_slots"}),
     ("mellum2", _FURTHER_GROUPS | _EXPERTS, {"window_pages_freed", "state_slots"} | _EXPERTS),
-    ("kanana2", _EXPERTS | _A_SHARE | {"latent_pages", "latent_row_bytes"},
-     _EXPERTS | {"latent_pages"}),
+    ("kanana2", _EXPERTS | _A_SHARE | _LATENT_READ | {"latent_pages", "latent_row_bytes"},
+     _EXPERTS | _LATENT_READ | {"latent_pages"}),
     ("keye_vl2", _EXPERTS | _A_SHARE | {"index_pages", "index_row_bytes", "sparse_rows",
                                         "selected_tokens"},
      _EXPERTS | {"index_pages", "sparse_rows", "selected_tokens"}),
     # the counter group adds no name: what it counts is never on a span
-    ("longcat_flash", _EXPERTS | _A_SHARE | {"latent_pages", "latent_row_bytes", "zero_experts",
-                                             "kv_planes"},
-     _EXPERTS | {"latent_pages"}),
+    ("longcat_flash", _EXPERTS | _A_SHARE | _LATENT_READ | {
+        "latent_pages", "latent_row_bytes", "zero_experts", "kv_planes"},
+     _EXPERTS | _LATENT_READ | {"latent_pages"}),
     # a slot group beside the one-leaf pages: the state manager's further-group
     # names (no further PAGED group, so no ``<name>_live_pages``) and the KDA
     # layers' own
-    ("kimi_linear", _EXPERTS | _A_SHARE | {
+    ("kimi_linear", _EXPERTS | _A_SHARE | _LATENT_READ | {
         "latent_pages", "latent_row_bytes", "window_pages_freed", "state_slots",
         "global_pages", "window_pages", "kda_step_rows", "kda_chunk_tokens", "kda_layers"},
-     _EXPERTS | {"latent_pages", "window_pages_freed", "state_slots", "kda_step_rows",
-                 "kda_chunk_tokens"})])
+     _EXPERTS | _LATENT_READ | {"latent_pages", "window_pages_freed", "state_slots",
+                                "kda_step_rows", "kda_chunk_tokens"})])
 def test_the_build_spans_attributes_are_the_ones_the_benchmark_reads(
         family, beyond, summed, build_spans):
     """The SET of attribute names on ``serving/build`` a served family, as
